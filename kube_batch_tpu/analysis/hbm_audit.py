@@ -220,22 +220,21 @@ def _mesh_extent(mesh, axes) -> int:
     return n
 
 
-def _shard_divisors(eqn, names_key: str, count: int) -> List[int]:
-    """Per-operand (or per-result) sharding divisor of a shard_map eqn:
-    the product of mesh-axis extents the in/out spec maps onto the value's
-    dims — global bytes ÷ divisor is what one device holds."""
-    mesh = eqn.params.get("mesh")
-    names = eqn.params.get(names_key)
-    if mesh is None or names is None:
-        return [1] * count
+def _shard_divisors(eqn, specs_key: str) -> List[int]:
+    """Per-operand (``in_specs``) or per-result (``out_specs``) sharding
+    divisor of a shard_map eqn: the product of mesh-axis extents the
+    PartitionSpec maps onto the value's dims — global bytes ÷ divisor is
+    what one device holds."""
+    mesh = eqn.params["mesh"]
     divs = []
-    for spec in names:
+    for spec in eqn.params[specs_key]:
         axes: List = []
-        for dim_axes in dict(spec).values():
-            axes.extend(dim_axes)
+        for dim_axes in spec:
+            if dim_axes is None:
+                continue
+            axes.extend(dim_axes if isinstance(dim_axes, tuple)
+                        else (dim_axes,))
         divs.append(_mesh_extent(mesh, axes))
-    if len(divs) < count:
-        divs += [1] * (count - len(divs))
     return divs
 
 
@@ -321,7 +320,7 @@ class _Liveness:
                 last[v] = n_eqns  # outputs survive the program
         owned: Dict = {}
         for i, eqn in enumerate(jaxpr.eqns):
-            out_divs = (_shard_divisors(eqn, "out_names", len(eqn.outvars))
+            out_divs = (_shard_divisors(eqn, "out_specs")
                         if str(eqn.primitive) == "shard_map"
                         else [1] * len(eqn.outvars))
             out_b = 0
@@ -368,7 +367,7 @@ class _Liveness:
         shard_div: Dict = {}
         for eqn in jaxpr.eqns:
             is_sm = str(eqn.primitive) == "shard_map"
-            divs = (_shard_divisors(eqn, "in_names", len(eqn.invars))
+            divs = (_shard_divisors(eqn, "in_specs")
                     if is_sm else [1] * len(eqn.invars))
             for v, d in zip(eqn.invars, divs):
                 if hasattr(v, "aval") and not _is_literal(v):
@@ -392,7 +391,7 @@ class _Liveness:
                     live -= in_bytes(v)  # donated and never read: free now
 
         for i, eqn in enumerate(jaxpr.eqns):
-            out_divs = (_shard_divisors(eqn, "out_names", len(eqn.outvars))
+            out_divs = (_shard_divisors(eqn, "out_specs")
                         if str(eqn.primitive) == "shard_map"
                         else [1] * len(eqn.outvars))
             out_b = 0

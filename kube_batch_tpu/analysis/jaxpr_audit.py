@@ -12,7 +12,7 @@ compiled, not what the source looks like.
 Mechanism: a REGISTRY of the package's jitted entry points (ops/ solves,
 the resident scatter, the Pallas round head).  Each entry is traced with
 ABSTRACT inputs (jax.ShapeDtypeStruct — no device work, no compile) under
-``jax.experimental.enable_x64`` so dtype promotion is visible instead of
+``jax.enable_x64`` so dtype promotion is visible instead of
 silently canonicalized away, then the closed jaxpr is walked recursively
 (while/cond/scan/pjit sub-jaxprs included) and linted:
 
@@ -827,7 +827,6 @@ def audit_entry(entry: EntryPoint) -> List[Finding]:
     (suppressed ones dropped; an allow with no reason is itself a KBT000,
     mirroring the static tier's contract)."""
     import jax
-    from jax.experimental import enable_x64
 
     path = f"<jaxpr:{entry.name}>"
     findings: List[Finding] = []
@@ -835,7 +834,7 @@ def audit_entry(entry: EntryPoint) -> List[Finding]:
 
     try:
         fn, args = entry.build()
-        with enable_x64():
+        with jax.enable_x64():
             traced = fn.trace(*args)
         closed = traced.jaxpr
     except Exception as e:  # noqa: BLE001 — a broken entry must not read as clean

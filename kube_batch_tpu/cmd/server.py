@@ -6,7 +6,10 @@ The reference serves Prometheus `/metrics` (+ pprof) on --listen-address
 
 - GET  /metrics                — Prometheus text exposition (same metric names)
 - GET  /healthz                — liveness
-- GET  /version
+- GET  /version                — JSON: version, jax version, and the device
+                                 this process runs on (platform, device_kind,
+                                 device_count), native-library state,
+                                 compile-cache directory
 - POST/DELETE /v1/pods         — informer-shaped ingest (JSON bodies per
 - POST/DELETE /v1/nodes          api/serialize.py); POST is add-or-update,
 - POST/DELETE /v1/podgroups      matching the informers' upsert handlers
@@ -21,7 +24,9 @@ The reference serves Prometheus `/metrics` (+ pprof) on --listen-address
 - GET  /v1/guard               — result-integrity guard plane state (per-
                                  fast-path breaker, trips, audits, bundles)
 - GET  /v1/trace               — cycle tracing plane: last cycle's span
-                                 tree + flight-recorder ring stats
+                                 tree, flight-recorder ring stats, and the
+                                 ring's solve dispatches tallied by
+                                 mode + engaged fast paths
 - GET  /v1/trace/dumps         — flight-recorder dump index; append
                                  /<name>/<trace.json|meta.json> to stream
                                  one dump's files (warm standbys and
@@ -65,6 +70,26 @@ from kube_batch_tpu.scheduler import Scheduler
 from kube_batch_tpu.version import version_string
 
 logger = logging.getLogger("kube_batch_tpu")
+
+
+def runtime_report() -> dict:
+    """What this process runs on, as JAX reports it — logged once at
+    start-up and served as ``/version``, so a client (``chip_smoke.py``)
+    learns the device from the serving process and not from a guess."""
+    import jax
+
+    from kube_batch_tpu.native import fast
+
+    devices = jax.devices()
+    return {
+        "version": version_string(),
+        "jax": jax.__version__,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "native": fast.resource_lib_state,
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+    }
 
 
 def _queue_status(cache: SchedulerCache) -> list:
@@ -165,7 +190,7 @@ def make_handler(cache: SchedulerCache, query_plane=None):
             elif self.path == "/healthz":
                 self._send(200, "ok", "text/plain")
             elif self.path == "/version":
-                self._send(200, version_string(), "text/plain")
+                self._send(200, json.dumps(runtime_report()))
             elif self.path == "/debug/stacks":
                 # pprof goroutine-dump analog (main.go:25 net/http/pprof)
                 import sys
@@ -591,10 +616,6 @@ def run_follower(opt: ServerOption) -> None:
     each follower owns its own devices and probe executables, so serving
     QPS adds up across follower processes while the leader pays one encode
     per cycle regardless of fan-out."""
-    from kube_batch_tpu.envutil import enable_persistent_compilation_cache
-
-    enable_persistent_compilation_cache()
-
     from kube_batch_tpu.replicate.follower import (
         FollowerCache,
         ReplicationFollower,
@@ -623,12 +644,12 @@ def run(opt: ServerOption) -> None:
     """app.Run (server.go:76-151): metrics/admin listener up front, then the
     scheduling loop — behind leader election when enabled. Option validation
     and --version live in cmd/main.py."""
-    if opt.follower:
-        return run_follower(opt)
     from kube_batch_tpu.envutil import enable_persistent_compilation_cache
 
     enable_persistent_compilation_cache()  # restart re-pays no solve compiles
-
+    logger.info("runtime: %s", json.dumps(runtime_report()))
+    if opt.follower:
+        return run_follower(opt)
     from kube_batch_tpu.cache.fake import FakeBinder, FakeEvictor
 
     from kube_batch_tpu.cache.volume import StandalonePVBinder

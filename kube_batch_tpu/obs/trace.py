@@ -409,8 +409,28 @@ class Tracer:
             }
         if self.recorder is not None:
             out["ring"] = self.recorder.stats()
+            out["solve_dispatches"] = self._solve_dispatches()
         out["last_cycle"] = self.last_cycle()
         return out
+
+    def _solve_dispatches(self) -> Dict[str, int]:
+        """Tally of the allocate solve dispatches still in the ring, keyed
+        ``mode[+engaged path...]`` ("single", "sharded+shard_map+topk+warm")
+        — which program has been running.  ``last_cycle`` alone loses that
+        to the next idle tick, a moment after the cycle that solved."""
+        tally: Dict[str, int] = {}
+
+        def walk(spans) -> None:
+            for sp in list(spans):
+                attrs = sp.attrs or {}
+                if sp.name == "solve_dispatch" and "mode" in attrs:
+                    key = "+".join([attrs["mode"], *attrs.get("engaged", ())])
+                    tally[key] = tally.get(key, 0) + 1
+                walk(sp.children)
+
+        for rec in self.recorder.records():
+            walk(rec.spans)
+        return tally
 
     def stage_attribution(self) -> Dict:
         """The seed-stable longitudinal summary for the sim report: span

@@ -59,8 +59,8 @@ def tie_break_hash_rows(ti: jnp.ndarray, ni: jnp.ndarray) -> jnp.ndarray:
     submission — sharing this one formula is what makes the probe's
     tie-breaks bit-identical to the committed solve's."""
     h = ti[:, None] * jnp.int32(_H1) + ni[None, :] * jnp.int32(_H2)
-    h = (h ^ jax.lax.shift_right_logical(h, 15)) * jnp.int32(_H3)
-    return jax.lax.shift_right_logical(h, 16)
+    h = (h ^ jax.lax.shift_right_logical(h, jnp.int32(15))) * jnp.int32(_H3)
+    return jax.lax.shift_right_logical(h, jnp.int32(16))
 
 
 def _tie_break_hash(T: int, N: int, t0=0, n0=0) -> jnp.ndarray:
@@ -251,12 +251,15 @@ def round_head_parts(snap: DeviceSnapshot, config: AllocateConfig,
 
     def head(idle, releasing, pending):
         if config.use_pallas:
-            from kube_batch_tpu.ops.pallas_kernels import masked_best_node
+            from kube_batch_tpu.ops.pallas_kernels import (
+                interpret_mode,
+                masked_best_node,
+            )
 
             return masked_best_node(
                 score, static_ok, snap.task_req, idle, releasing,
                 pending, snap.quanta,
-                interpret=jax.default_backend() != "tpu",
+                interpret=interpret_mode(),
             )
         fit_idle = fits(snap.task_req, idle, snap.quanta)
         # zero-releasing clusters (every allocate-only cycle) skip
@@ -836,12 +839,15 @@ def compact_candidates(view_p: DeviceSnapshot, pend_rows: jnp.ndarray,
     score = score_matrix(view_p, config.weights)
     score_static = jnp.where(static_ok, score, NEG)
     if config.use_pallas:
-        from kube_batch_tpu.ops.pallas_kernels import masked_topk_blocks
+        from kube_batch_tpu.ops.pallas_kernels import (
+            interpret_mode,
+            masked_topk_blocks,
+        )
 
         skey0, bval, bhash, bcol = masked_topk_blocks(
             score_static, view_p.task_req, idle0, releasing0,
             safe_rows, quanta, n0=n0,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_mode(),
         )
         triples = (bval, bhash, bcol)
         del triples  # block partials are a fusion detail; extraction below
@@ -1297,7 +1303,7 @@ def warm_refresh_table(t_idx, t_skey, t_hash, t_trunc, row_map, rows_m,
     vcnt2 = jnp.sum(ns > neg, axis=1, dtype=jnp.int32)
     spread = jnp.int32(max(W - k_min, 1))
     jitter = jax.lax.shift_right_logical(
-        jnp.maximum(rows_m, 0) * jnp.int32(_H1), 16
+        jnp.maximum(rows_m, 0) * jnp.int32(_H1), jnp.int32(16)
     ) % spread
     eroded = trunc & (vcnt2 < k_min + jitter)
     upd = jax.lax.dynamic_update_slice

@@ -19,8 +19,9 @@ the earlier tile — reproducing jnp.argmax's first-max-index semantics.
 The XLA path computes the same values with fused broadcasts; this kernel
 exists to cut the intermediate [T, N] bool traffic on real TPU. It is
 opt-in (AllocateConfig.use_pallas, wired to env KB_PALLAS=1 / the
-`allocate.pallas` conf argument by the allocate action) and falls back to
-interpret mode off-TPU so the parity tests run everywhere.
+`allocate.pallas` conf argument by the allocate action) and runs in
+interpret mode on the CPU backend only, so the parity tests run there
+(``interpret_mode``).
 
 TPU lowering constraints shape the kernel: everything is float32 or int32
 (no uint32, no bool refs — the Mosaic lowering in this jax version supports
@@ -93,18 +94,25 @@ def _kernel(score_ref, static_ref, req_ref, idle_ref, rel_ref, pending_ref,
         + j * TN + offs_ref[0, 1]
     )
     h = ti * jnp.int32(_H1) + ni * jnp.int32(_H2)
-    h = (h ^ jax.lax.shift_right_logical(h, 15)) * jnp.int32(_H3)
-    # Mosaic's argmax lowering is f32-only; the 16 hash bits are exactly
-    # representable in f32, so the cast preserves the ordering
-    tie_hash = jax.lax.shift_right_logical(h, 16).astype(jnp.float32)
+    h = (h ^ jax.lax.shift_right_logical(h, jnp.int32(15))) * jnp.int32(_H3)
+    # the hash rides f32 like the score key it is merged with; its 16 bits
+    # are exactly representable, so the cast preserves the ordering
+    tie_hash = jax.lax.shift_right_logical(h, jnp.int32(16)).astype(jnp.float32)
 
     lval = jnp.max(masked, axis=1)                            # [TM]
     tie = masked >= lval[:, None]
     hash_masked = jnp.where(tie, tie_hash, -1.0)
     lhash = jnp.max(hash_masked, axis=1)                      # [TM]
-    pick = jnp.argmax(hash_masked, axis=1).astype(jnp.int32)  # local col
-    lbest = pick + j * TN
+    # the first column among full (score, hash) ties, spelled as a min
+    # over the tied columns and NOT as jnp.argmax: compiled by Mosaic,
+    # argmax returns a later tied column (measured on a v5e, ~0.2% of rows
+    # at 50k×5k), and first-max-index is the order the XLA path and the
+    # cross-tile merge below both keep
     col = jax.lax.broadcasted_iota(jnp.int32, (TM, TN), 1)
+    pick = jnp.min(
+        jnp.where(hash_masked >= lhash[:, None], col, TN), axis=1
+    )                                                         # local col
+    lbest = pick + j * TN
     lchose = jnp.any(fit_idle & (col == pick[:, None]), axis=1)
     lval_c = lval[:, None]
     lhash_c = lhash[:, None]
@@ -255,15 +263,16 @@ def _topk_kernel(score_ref, req_ref, idle_ref, rel_ref, rows_ref,
         + j * TN + offs_ref[0, 0]
     )
     h = ti * jnp.int32(_H1) + ni * jnp.int32(_H2)
-    h = (h ^ jax.lax.shift_right_logical(h, 15)) * jnp.int32(_H3)
-    tie_hash = jax.lax.shift_right_logical(h, 16)
+    h = (h ^ jax.lax.shift_right_logical(h, jnp.int32(15))) * jnp.int32(_H3)
+    tie_hash = jax.lax.shift_right_logical(h, jnp.int32(16))
 
     # per-C-block two-key winner triples (the extraction's phase-1 input):
     # max key, max hash among key ties, first column among full ties
     # trace-time unroll over the static sub-block count (NODE_TILE /
     # TOPK_BLOCK = 8) inside the kernel body — no per-iteration dispatch;
-    # argmax rides f32 (Mosaic's argmax lowering is f32-only; hashes are
-    # 16-bit ints, so the cast is exact — same trick as the round head)
+    # the first column among full ties is a min over the tied columns, not
+    # jnp.argmax (see the round head: compiled argmax picks a later one)
+    cb = jax.lax.broadcasted_iota(jnp.int32, (TM, C), 1)
     for b in range(NB):
         sb = skey[:, b * C:(b + 1) * C]
         hb = tie_hash[:, b * C:(b + 1) * C]
@@ -273,9 +282,9 @@ def _topk_kernel(score_ref, req_ref, idle_ref, rel_ref, rows_ref,
         # kbt: allow[KBT005] static in-kernel unroll (see loop comment)
         hmask = jnp.where(tie, hb, -2)
         # kbt: allow[KBT005] static in-kernel unroll (see loop comment)
-        bcol = jnp.argmax(hmask.astype(jnp.float32), axis=1).astype(jnp.int32)
-        # kbt: allow[KBT005] static in-kernel unroll (see loop comment)
         bhash = jnp.max(hmask, axis=1)
+        # kbt: allow[KBT005] static in-kernel unroll (see loop comment)
+        bcol = jnp.min(jnp.where(hmask >= bhash[:, None], cb, C), axis=1)
         bval_ref[:, b:b + 1] = bval[:, None]
         bhash_ref[:, b:b + 1] = bhash[:, None]
         bcol_ref[:, b:b + 1] = bcol[:, None]
@@ -309,6 +318,12 @@ def masked_topk_blocks(
     q2 = quanta.reshape(1, R).astype(jnp.float32)
     offs = jnp.asarray([n0], jnp.int32).reshape(1, 1)
 
+    # the triples leave as [node tile, P, NB] and are laid out [P, N/C]
+    # below: an (tile_t, NB) block of a [P, N/C] array is one Mosaic
+    # refuses (NB = 8 lanes is neither 128-divisible nor the full width),
+    # while here NB is the array's whole last dimension
+    triple = pl.BlockSpec((None, tile_t, NB), lambda i, j: (j, i, 0))
+    triple_shape = jax.ShapeDtypeStruct((N // tile_n, P, NB), jnp.int32)
     skey, bval, bhash, bcol = pl.pallas_call(
         _topk_kernel,
         grid=grid,
@@ -323,15 +338,13 @@ def masked_topk_blocks(
         ],
         out_specs=[
             pl.BlockSpec((tile_t, tile_n), lambda i, j: (i, j)),  # skey
-            pl.BlockSpec((tile_t, NB), lambda i, j: (i, j)),      # bval
-            pl.BlockSpec((tile_t, NB), lambda i, j: (i, j)),      # bhash
-            pl.BlockSpec((tile_t, NB), lambda i, j: (i, j)),      # bcol
+            triple,                                               # bval
+            triple,                                               # bhash
+            triple,                                               # bcol
         ],
         out_shape=[
             jax.ShapeDtypeStruct((P, N), jnp.int32),
-            jax.ShapeDtypeStruct((P, N // TOPK_BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((P, N // TOPK_BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((P, N // TOPK_BLOCK), jnp.int32),
+            triple_shape, triple_shape, triple_shape,
         ],
         interpret=interpret,
     )(
@@ -343,7 +356,24 @@ def masked_topk_blocks(
         q2,
         offs,
     )
+    bval, bhash, bcol = (
+        x.transpose(1, 0, 2).reshape(P, N // TOPK_BLOCK)
+        for x in (bval, bhash, bcol)
+    )
     return skey, bval, bhash, bcol
+
+
+def interpret_mode() -> bool:
+    """The ``interpret`` argument every production call site passes: the
+    kernels compile on a TPU — or fail there with the compiler's message —
+    and are interpreted only on the CPU backend, where the parity tests
+    run.  Any other platform is refused rather than quietly interpreted."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels are written for TPU (and interpreted on "
+            f"cpu for tests); platform {backend!r} is not supported")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

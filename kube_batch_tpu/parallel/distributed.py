@@ -31,12 +31,6 @@ import jax
 # (ops.assignment's module-level jnp constants) initialises the XLA backend,
 # which must not happen before jax.distributed.initialize runs
 
-# fallback re-init guard for jax versions without
-# jax.distributed.is_initialized: without it a second initialize() call
-# skipped the guard entirely and raised from jax.distributed.initialize
-# (ADVICE.md #4). Set only on success, so a failed attempt stays retryable.
-_initialized = False
-
 
 def initialize(
     coordinator: Optional[str] = None,
@@ -54,46 +48,19 @@ def initialize(
     checks only the coordination-service client — backend-safe, and a
     failed earlier attempt (which leaves coordinator_address residue but no
     client) stays retryable."""
-    global _initialized
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        if is_init():
-            return  # already initialized
-    elif _initialized:
-        return  # module-level fallback guard (no is_initialized probe)
-    _enable_cpu_collectives()
+    if jax.distributed.is_initialized():
+        return
     if coordinator is None and num_processes is None:
         try:
             jax.distributed.initialize()
         except (RuntimeError, ValueError):
-            return  # single-process / no cluster env — stay local
-        _initialized = True
+            pass  # single-process / no cluster env — stay local
         return
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,
         process_id=process_id,
     )
-    _initialized = True
-
-
-def _enable_cpu_collectives() -> None:
-    """Select the gloo cross-process collectives implementation for
-    multi-process CPU backends.  The pjit path's GSPMD programs happened
-    to tolerate the default ("none") in the two-process smoke, but the
-    shard_map bodies' explicit psum/pmax/pmin/all_gather dispatch fails
-    there with "Multiprocess computations aren't implemented on the CPU
-    backend" unless a real collectives impl is registered.  Must run
-    BEFORE the CPU client is created; harmless on TPU/GPU backends (the
-    flag only affects make_cpu_client) and silently skipped on jaxlib
-    builds without gloo."""
-    try:
-        from jax._src.lib import xla_client
-
-        if hasattr(xla_client._xla, "make_gloo_tcp_collectives"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — best-effort, version-dependent
-        pass
 
 
 def global_mesh():

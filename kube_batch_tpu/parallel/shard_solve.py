@@ -225,12 +225,15 @@ def _allocate_body(snap, *, config, node_shards, task_shards):
             else jax.lax.dynamic_slice_in_dim(pending, t0, T_blk, axis=0)
         )
         if config.use_pallas:
-            from kube_batch_tpu.ops.pallas_kernels import masked_best_node_raw
+            from kube_batch_tpu.ops.pallas_kernels import (
+                interpret_mode,
+                masked_best_node_raw,
+            )
 
             pick, lval, lkey, lchose = masked_best_node_raw(
                 score, static_ok, req_blk, idle_b, rel_b, pending_b,
                 quanta, t0=t0, n0=n0,
-                interpret=jax.default_backend() != "tpu",
+                interpret=interpret_mode(),
             )
             lidx = pick + n0
         else:
@@ -678,15 +681,8 @@ def _histogram_bucket_body(snap, pend_rows, *, node_shards):
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-
-    try:
-        mapped = shard_map(body, mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax: check_rep renamed/removed
-        mapped = shard_map(body, mesh, in_specs=in_specs,
-                           out_specs=out_specs)
-    return jax.jit(mapped)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _snapshot_specs(mesh):

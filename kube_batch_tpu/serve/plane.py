@@ -350,10 +350,12 @@ class QueryPlane:
                 # the lease's own buffers are never read, so a concurrent
                 # swap can donate them mid-warm without consequence (shape
                 # and sharding are metadata — readable even off a donated
-                # array)
+                # array); a leaf still on the host has no sharding and
+                # takes the default placement the probe would give it
                 twin = jax.tree_util.tree_map(
                     lambda a: jax.device_put(
-                        jnp.zeros(a.shape, a.dtype), a.sharding),
+                        jnp.zeros(a.shape, a.dtype),
+                        getattr(a, "sharding", None)),
                     lease.snap,
                 )
                 self._probe(lease._replace(snap=twin), [req], record=False)

@@ -31,10 +31,7 @@ def register(name: str, fn) -> object:
 
 
 def _size(fn) -> int:
-    try:
-        return int(fn._cache_size())
-    except Exception:  # noqa: BLE001 — older jax without the probe
-        return 0
+    return int(fn._cache_size())
 
 
 def compile_counts() -> Dict[str, int]:
@@ -58,9 +55,6 @@ COLLECTIVE_PRIMS = (
     "psum", "pmax", "pmin", "all_gather", "all_to_all", "ppermute",
     "reduce_scatter", "psum_scatter",
 )
-#: jaxpr spellings that alias a canonical collective (jax renamed psum's
-#: primitive to ``psum2`` in 0.4.x; report it under the stable name)
-_PRIM_ALIASES = {"psum2": "psum"}
 _LOOP_PRIMS = ("while", "scan")
 
 
@@ -116,7 +110,7 @@ def collective_inventory(closed_jaxpr, *, detail: bool = False) -> Dict:
         # known scan lengths of the enclosing loops EXCLUDING the outermost
         # (per-round means "per iteration of the outermost loop").
         for eqn in jaxpr.eqns:
-            prim = _PRIM_ALIASES.get(str(eqn.primitive), str(eqn.primitive))
+            prim = str(eqn.primitive)
             if prim in COLLECTIVE_PRIMS:
                 in_loop = depth > 0
                 bucket = per["per_round" if in_loop else "per_solve"]

@@ -26,9 +26,9 @@ import sys
 # runnable as `python scripts/pipeline_smoke.py` from the repo root
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from kube_batch_tpu.envutil import apply_hardened_cpu_env  # noqa: E402
+from kube_batch_tpu.envutil import apply_cpu_env  # noqa: E402
 
-apply_hardened_cpu_env()
+apply_cpu_env()
 
 from kube_batch_tpu.sim.runner import run_preset  # noqa: E402
 

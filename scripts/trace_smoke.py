@@ -30,9 +30,9 @@ import time
 # runnable as `python scripts/trace_smoke.py` from the repo root
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from kube_batch_tpu.envutil import apply_hardened_cpu_env  # noqa: E402
+from kube_batch_tpu.envutil import apply_cpu_env  # noqa: E402
 
-apply_hardened_cpu_env()
+apply_cpu_env()
 
 _TMP = tempfile.mkdtemp(prefix="kb-trace-smoke-")
 os.environ["KB_TRACE_DIR"] = os.path.join(_TMP, "flight")

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import ShapeDtypeStruct as S
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -366,9 +366,9 @@ class TestNestedCollectiveInventory:
             c, _ = jax.lax.scan(round_step, x, None, length=2)
             return c
 
-        # check_rep=False: shard_map has no replication rule for `while`
+        # check_vma=False: as the production bodies are built (_shard_map)
         fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("nodes"),
-                               out_specs=P("nodes"), check_rep=False))
+                               out_specs=P("nodes"), check_vma=False))
         return fn.trace(S((512,), jnp.float32)).jaxpr
 
     def test_inner_scan_trip_count_amplifies_per_round_bytes(self):

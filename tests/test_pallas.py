@@ -142,3 +142,13 @@ def test_compiled_vs_interpret_agree_on_tpu():
     interp = masked_best_node(*args, interpret=True)
     for c, i in zip(compiled, interp):
         np.testing.assert_array_equal(np.asarray(c), np.asarray(i))
+
+
+def test_topk_build_kernel_matches_xla():
+    """The third kernel: masked sort-key plane + per-block winner triples of
+    the compacted solve's candidate build equal the same values from XLA
+    ops (the comparison the on-chip kernel check runs at 8,192×5,000)."""
+    from kube_batch_tpu.testing.pallas_bench import compare_topk_build
+
+    out = compare_topk_build(n_pend=512, n_nodes=600, reps=1)
+    assert out["pend_rows"] == 512 and out["outputs_match"], out

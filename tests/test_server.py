@@ -158,7 +158,18 @@ class TestAdminServer:
     def test_health_version_metrics(self, server):
         _, srv = server
         assert _get(srv.port, "/healthz") == "ok"
-        assert "kube-batch-tpu" in _get(srv.port, "/version")
+        # /version names the device the serving process runs on, as JAX
+        # reports it there (chip_smoke.py reads it instead of guessing)
+        import jax
+
+        ver = _get(srv.port, "/version")
+        assert "kube-batch-tpu" in ver["version"]
+        assert ver["jax"] == jax.__version__
+        assert (ver["platform"], ver["device_kind"], ver["device_count"]) == (
+            jax.devices()[0].platform, jax.devices()[0].device_kind,
+            len(jax.devices()))
+        assert ver["native"].startswith(("built", "loaded", "numpy"))
+        assert "compile_cache_dir" in ver
         assert "volcano_e2e_scheduling_latency_milliseconds" in _get(srv.port, "/metrics")
 
     def test_ingest_schedule_and_read_back(self, server):
