@@ -34,12 +34,14 @@ publisher), and talks to it through the HTTP API a client would use:
    child's log holds no failed cycle, no swallowed failure, no traceback;
    the child is alive at the end, and is then terminated and reaped.
 
-Exit 0 and, as the last line of stdout, one JSON object
-``{"ok": true, "device": {"platform", "kind", "count"}, "smoke": {...}}``
-only when every phase passed.  Any failure exits non-zero and prints no
-result (the reason goes to stderr and to ``<out>/failure.txt``).  The
-timings in ``smoke`` are smoke output — they include compilation and are
-not benchmark results.
+Exit 0 and two lines of stdout only when every phase passed: the run's
+summary ``{"smoke": {...}}``, then, as the last line, the verdict — one JSON
+object with exactly these keys, the device as the serving process reported
+it: ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Both are also left in ``<out>/result.json``.  Any failure exits non-zero and
+prints no result (the reason goes to stderr and to ``<out>/failure.txt``).
+The timings in ``smoke`` are smoke output — they include compilation and
+are not benchmark results.
 """
 
 from __future__ import annotations
@@ -627,17 +629,18 @@ def main(argv=None) -> int:
             pass
         print(msg, file=sys.stderr)
         return 1
-    result = {
+    # the last line is the verdict and holds nothing else: whoever runs the
+    # chip check reads exactly {"ok", "device": {"platform", "kind", "count"}}
+    verdict = {
         "ok": True,
-        "device": {"platform": smoke["platform"],
-                   "kind": smoke["device_kind"],
-                   "count": smoke["device_count"]},
-        "smoke": smoke,
+        "device": {"platform": str(smoke["platform"]),
+                   "kind": str(smoke["device_kind"]),
+                   "count": int(smoke["device_count"])},
     }
-    line = json.dumps(result)
     with open(os.path.join(out_dir, "result.json"), "w") as f:
-        f.write(line + "\n")
-    print(line)
+        f.write(json.dumps(verdict | {"smoke": smoke}) + "\n")
+    print(json.dumps({"smoke": smoke}))
+    print(json.dumps(verdict))
     return 0
 
 

@@ -34,10 +34,14 @@ def test_rehearsal_passes_on_cpu_and_parent_stays_off_jax(tmp_path):
     r = _smoke(tmp_path, "--platform", "cpu", *SIZE,
                "--rounds", "3", "--min-audits", "0")
     assert r.returncode == 0, r.stderr[-4000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["ok"] is True
-    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
-    smoke = out["smoke"]
+    summary, verdict = map(json.loads, r.stdout.strip().splitlines())
+    # the last line is the verdict with exactly these keys, nothing beside
+    assert verdict == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+    }
+    assert list(summary) == ["smoke"]
+    smoke = summary["smoke"]
     assert smoke["pods_bound"] == 1200 and smoke["rounds"] == 3
     assert smoke["solve_dispatches"]["single"] >= 1
     assert smoke["solve_dispatches"]["single+topk+warm"] >= 3
@@ -45,7 +49,7 @@ def test_rehearsal_passes_on_cpu_and_parent_stays_off_jax(tmp_path):
     assert smoke["whatif"]["sweep"]["max_fit"] == 64
     # the summary is also left beside the child's log
     with open(tmp_path / "out" / "result.json") as f:
-        assert json.load(f) == out
+        assert json.load(f) == verdict | summary
     # main() refuses to pass if this process ever imported jax; the module
     # itself must not pull it in either
     probe = subprocess.run(
