@@ -607,11 +607,6 @@ class AllocateAction(Action):
             )
         sp_solve.set(mode=self.last_solve_mode,
                      engaged=list(ginfo["engaged"]))
-        if self.last_solve_mode == "sharded":
-            tracer.annotate_collectives(
-                sp_solve, ginfo["config"], snap,
-                pend_rows=ginfo.get("pend_rows"),
-            )
         # shadow-oracle audit (guard tier 2): every KB_AUDIT_EVERY-th
         # dispatch re-runs the committed solve through its oracle path,
         # DISPATCHED here so the oracle re-solve overlaps the readback +

@@ -590,7 +590,8 @@ class SentinelConsumeRule(Rule):
 class SpanDisciplineRule(Rule):
     """Guard for the cycle tracing plane (kube_batch_tpu/obs): spans in the
     clock-seamed paths are created ONLY through the ``obs.trace`` context
-    managers (``tracer.span`` / ``device_span`` / ``cycle_span``), and a
+    managers (``tracer.span`` / ``device_span`` / ``cycle_span`` /
+    ``park_span`` / ``detached_span``), and a
     span body contains no clock reads of its own — the span IS the
     measurement.  Two bug classes this kills: (1) a hand-rolled Span (or a
     begin/end pair) that skips the context manager loses exception-safe
@@ -611,7 +612,8 @@ class SpanDisciplineRule(Rule):
     scope = ("scheduler.py", "actions/", "cache/", "sim/", "framework/",
              "serve/", "guard/", "plugins/")
 
-    SPAN_FACTORIES = {"span", "device_span", "cycle_span"}
+    SPAN_FACTORIES = {"span", "device_span", "cycle_span", "park_span",
+                      "detached_span"}
     TIME_ATTRS = WallClockRule.TIME_ATTRS
     DATETIME_ATTRS = WallClockRule.DATETIME_ATTRS
 

@@ -978,16 +978,7 @@ class SchedulerCache:
             t0 = (self._arrival_ts.pop(task.key(), None)
                   if pod is not None else None)
         if t0 is not None:
-            from kube_batch_tpu import metrics
-
-            lat_ms = [(telemetry.perf_counter() - t0) * 1e3]
-            metrics.observe_decision_latencies(lat_ms)
-            tr = getattr(self, "tracer", None)
-            if tr is not None:
-                # span-stamped twin of the histogram sample (obs/trace.py):
-                # the cycle's trace tree carries the same values, and an
-                # SLO breach arms a flight-recorder dump
-                tr.note_decision_latencies(lat_ms)
+            self._observe_decisions([t0], telemetry.perf_counter())
         try:
             if pod is not None:
                 self.binder.bind(pod, hostname)
@@ -1036,33 +1027,44 @@ class SchedulerCache:
                 staged = [(t, h, t.pod) for t, h in tasks_hosts]
             else:
                 staged = self._bulk_bind_locked(tasks_hosts, job_sums, node_sums)
-            lat_ms = self._note_bind_decisions_locked(staged)
-        if lat_ms:
-            from kube_batch_tpu import metrics
-
-            metrics.observe_decision_latencies(lat_ms)
-            tr = getattr(self, "tracer", None)
-            if tr is not None:
-                # the trace-tree twin of the histogram samples (obs/trace)
-                tr.note_decision_latencies(lat_ms)
+            arrivals, now = self._note_bind_decisions_locked(staged)
+        self._observe_decisions(arrivals, now)
         self._dispatch_async(staged)
 
-    def _note_bind_decisions_locked(self, staged) -> list:
+    def _note_bind_decisions_locked(self, staged) -> tuple:
         """Mark every staged dispatch in flight (update_pod's unacked-bind
         guard) and close the arrival→decision latency clocks; returns the
-        ms latencies for the histogram (observed outside the lock)."""
+        arrival stamps of the pods decided and the decision time (observed
+        outside the lock)."""
         now = telemetry.perf_counter()
         pop_ts = self._arrival_ts.pop
         inflight = self._inflight_bind_hosts
-        lat_ms = []
+        arrivals = []
         for task, hostname, pod in staged:
             if pod is None:
                 continue
             inflight[task._key] = hostname
             t0 = pop_ts(task._key, None)
             if t0 is not None:
-                lat_ms.append((now - t0) * 1e3)
-        return lat_ms
+                arrivals.append(t0)
+        return arrivals, now
+
+    def _observe_decisions(self, arrivals, now: float) -> None:
+        """The arrival→decision latency of the pods bound at ``now``, from
+        their arrival stamps: the histogram (and the bench's exact-sample
+        sink), its span-stamped twin on the cycle's trace record (an SLO
+        breach arms a flight-recorder dump), and the tracer's split of it
+        into the wait for the deciding cycle and the rest."""
+        if not arrivals:
+            return
+        from kube_batch_tpu import metrics
+
+        lat_ms = [(now - t0) * 1e3 for t0 in arrivals]
+        metrics.observe_decision_latencies(lat_ms)
+        tr = getattr(self, "tracer", None)
+        if tr is not None:
+            tr.note_decision_latencies(lat_ms)
+            tr.note_decision_parts(arrivals, now)
 
     def _settle_inflight(self, entries, bound: bool) -> None:
         """Clear in-flight bind markers once the dispatcher settled them.

@@ -64,6 +64,39 @@ class Histogram:
         return "\n".join(lines)
 
 
+class Summary:
+    """A sum and a count, no buckets: two lines a series, for quantities
+    read as a mean over a window (growth of the sum over growth of the
+    count) on a page that is rendered every few milliseconds.  The sum
+    prints ten significant digits: a mean of a few ms taken from the growth
+    of a sum that has reached 1e7 ms needs them."""
+
+    def __init__(self, name: str, help_text: str):
+        self.name = name
+        self.help = help_text
+        self._lock = threading.Lock()
+        self._sum = 0.0
+        self._count = 0
+
+    def observe_many(self, total: float, count: int) -> None:
+        """Record ``count`` samples that add up to ``total``."""
+        if count <= 0:
+            return
+        with self._lock:
+            self._sum += total
+            self._count += count
+
+    def render(self) -> str:
+        with self._lock:
+            total, count = self._sum, self._count
+        return "\n".join((
+            f"# HELP {self.name} {self.help}",
+            f"# TYPE {self.name} summary",
+            f"{self.name}_sum{{}} {total:.10g}",
+            f"{self.name}_count{{}} {count}",
+        ))
+
+
 class Counter:
     #: Prometheus exposition type — Gauge overrides (a counter that goes
     #: down reads as a reset to Prometheus clients)
@@ -342,6 +375,40 @@ WHATIF_SWEEPS = Counter(
     "Capacity sweeps (/v1/whatif/sweep) served",
 )
 
+# the span plane's counters (obs/trace.py): where a decision's latency went
+# (the wait for the deciding cycle to start; pods a cycle passed over), how
+# long a what-if sat in the batcher, and every compile JAX reports with its
+# seconds (jax.monitoring duration events — programs jitstats never tracked
+# and compiles outside a device span included)
+DECISION_QUEUE_WAIT = Summary(
+    f"{_SUBSYSTEM}_decision_queue_wait_milliseconds",
+    "Pod arrival to the start of the cycle that decided it (ms); the rest "
+    "of arrival_to_decision_latency was spent inside that cycle",
+)
+DECISIONS_LEFTOVER = Counter(
+    f"{_SUBSYSTEM}_decisions_leftover_total",
+    "Pods bound after two or more cycles had drained ingest since they "
+    "arrived (passed over by at least one cycle)",
+)
+WHATIF_QUEUE_WAIT = Summary(
+    f"{_SUBSYSTEM}_whatif_queue_wait_milliseconds",
+    "Whatif requests: enqueue to the start of the flush that took them (ms)",
+)
+JIT_COMPILE_SECONDS = Counter(
+    f"{_SUBSYSTEM}_jit_compile_seconds_total",
+    "Seconds JAX spent compiling, by phase (trace|lower|backend)",
+    ("phase",),
+)
+JIT_COMPILES = Counter(
+    f"{_SUBSYSTEM}_jit_compiles_total",
+    "Backend compiles JAX reported (persistent-cache hits included)",
+)
+# a sound window reads 0 from these, not "no such series"
+DECISIONS_LEFTOVER.add(0.0)
+JIT_COMPILES.add(0.0)
+for _phase in ("trace", "lower", "backend"):
+    JIT_COMPILE_SECONDS.add(0.0, _phase)
+
 METRICS = [
     E2E_LATENCY,
     PLUGIN_LATENCY,
@@ -388,6 +455,11 @@ METRICS = [
     REPLICATION_RESYNCS,
     REPLICATION_LAG,
     WHATIF_SWEEPS,
+    DECISION_QUEUE_WAIT,
+    DECISIONS_LEFTOVER,
+    WHATIF_QUEUE_WAIT,
+    JIT_COMPILE_SECONDS,
+    JIT_COMPILES,
 ]
 
 
@@ -577,6 +649,25 @@ def observe_decision_latencies(ms_values) -> None:
     sink = _decision_sink
     if sink is not None:
         sink.extend(ms_values)
+
+
+def observe_decision_queue_wait(total_ms: float, count: int) -> None:
+    DECISION_QUEUE_WAIT.observe_many(total_ms, count)
+
+
+def register_decisions_leftover(count: int) -> None:
+    if count:
+        DECISIONS_LEFTOVER.add(count)
+
+
+def observe_whatif_queue_wait(total_ms: float, count: int) -> None:
+    WHATIF_QUEUE_WAIT.observe_many(total_ms, count)
+
+
+def register_jit_compile(phase: str, seconds: float) -> None:
+    JIT_COMPILE_SECONDS.add(seconds, phase)
+    if phase == "backend":
+        JIT_COMPILES.inc()
 
 
 def register_trigger_wake(trigger: str) -> None:

@@ -12,11 +12,22 @@ same clock its report uses.  Device work is attributed via
 ``utils/jitstats``: a :meth:`Tracer.device_span` samples the jit
 compile-specialization count and the resident-scatter counters at entry
 and exit, so a retrace or an unexpected full re-upload is annotated onto
-the exact span that paid it (``compiles``/``retrace``/scatter deltas), and
-sharded dispatch spans can carry the traced collective-bytes inventory
-(``KB_TRACE_COLLECTIVES=1`` opt-in — the trace itself is a one-off
-program lowering, kept off the default path so the zero-retrace counters
-benches assert stay untouched).
+the exact span that paid it (``retrace``/scatter deltas).  Every compile
+JAX itself reports (``jax.monitoring`` duration events: trace, lower,
+backend) is counted process-wide with its seconds and stamped onto the
+innermost span open on the compiling thread (``compiles``/``compile_ms``)
+— programs nobody registered with jitstats included.
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` of its own name
+for its own interval, so under a profiler session the span tree sits on
+the host plane of the ``.xplane.pb`` beside the XLA events, on the
+profiler's clock (with no session running it costs a fraction of a
+microsecond).  The loop's parked time is two root spans
+(``park:floor``/``park:event``, :meth:`Tracer.park_span`) retained on the
+record of the cycle they precede; the read plane's flush tree
+(``whatif:*``, :meth:`Tracer.detached_span`) is timed, annotated and
+totalled but kept out of the cycle records.  ``span_counts``/``span_ms``
+total every span by name, children and roots alike.
 
 Complete per-cycle trace trees land in the flight recorder's ring
 (:mod:`kube_batch_tpu.obs.recorder`) and export as Chrome trace-event
@@ -40,10 +51,13 @@ pairs — the span IS the measurement; metrics feed from ``Span.dur_us``.
 
 from __future__ import annotations
 
-import itertools
+import bisect
 import os
 import threading
+from collections import deque
 from typing import Dict, List, Optional
+
+import numpy as np
 
 from kube_batch_tpu import metrics
 from kube_batch_tpu.envutil import env_flag
@@ -57,6 +71,78 @@ import time as _time  # identity sentinel only: `clock is _time` ⇒ no vt
 #: begin_cycle, and an unbounded current record would grow forever
 IMPLICIT_ROLL = 512
 
+#: where a finished ROOT span is retained (``Span._keep``): on the current
+#: cycle's record (the default), on the record of the cycle that FOLLOWS it
+#: (the loop's parked time), or nowhere (the read plane's flush tree)
+_KEEP_CYCLE, _KEEP_NEXT, _KEEP_NONE = 0, 1, 2
+
+#: cycle starts remembered for the leftover count — two are what the test
+#: "two or more cycles drained ingest since the pod arrived" needs
+_CYCLE_STARTS = 4
+
+# the open spans of each thread, innermost last.  ONE stack per thread for
+# every tracer of the process: a cache has one tracer and a thread works for
+# one cache at a time, and JAX's process-wide compile listener has to find
+# the span the compiling thread is inside without knowing whose it is.
+_OPEN = threading.local()
+
+
+def _open_spans() -> list:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation`` of ``name``."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    ann = _TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+#: jax.monitoring duration events of one compile -> the phase label
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_compile_event(event: str, duration: float, **_kw) -> None:
+    """JAX's duration listener (runs on the compiling thread): every trace,
+    lowering and backend compile of the process with its seconds — also of
+    programs ``utils/jitstats`` never heard of and of compiles outside any
+    device span — stamped onto the innermost span that thread has open."""
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    metrics.register_jit_compile(phase, duration)
+    stack = getattr(_OPEN, "stack", None)
+    if stack:
+        stack[-1]._note_compile(phase, duration)
+
+
+def _listen_for_compiles() -> None:
+    """Register the process-wide listener, once."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            from jax import monitoring
+
+            monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+            _listening = True
+
 
 class Span:
     """One traced region.  Created ONLY via the :class:`Tracer` context
@@ -64,11 +150,13 @@ class Span:
     supported — every ``span()`` call makes a fresh one."""
 
     __slots__ = ("name", "t0", "t1", "vt0", "vt1", "tid", "attrs",
-                 "children", "_tracer", "_record", "_cols", "_c0", "_sc0")
+                 "children", "_tracer", "_record", "_cols", "_c0", "_sc0",
+                 "_keep", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  record: Optional["CycleRecord"] = None,
-                 cols=None, attrs: Optional[Dict] = None):
+                 cols=None, attrs: Optional[Dict] = None,
+                 keep: int = _KEEP_CYCLE):
         self.name = name
         self.t0 = self.t1 = 0.0
         self.vt0 = self.vt1 = None
@@ -79,6 +167,8 @@ class Span:
         self._record = record  # explicit target (the writeback worker)
         self._cols = cols
         self._c0 = self._sc0 = None
+        self._keep = keep
+        self._annotation = None
 
     # -- timing -----------------------------------------------------------
     @property
@@ -98,11 +188,24 @@ class Span:
             self.attrs = {}
         self.attrs.update(attrs)
 
+    def _note_compile(self, phase: str, secs: float) -> None:
+        """One compile phase JAX reported while this span was the innermost
+        open one on its thread: which stage paid, and how much."""
+        if not self._tracer.enabled:
+            return
+        attrs = self.attrs
+        if attrs is None:
+            attrs = self.attrs = {}
+        if phase == "backend":
+            attrs["compiles"] = attrs.get("compiles", 0) + 1
+        attrs["compile_ms"] = round(
+            attrs.get("compile_ms", 0.0) + secs * 1e3, 3)
+
     # -- context manager --------------------------------------------------
     def __enter__(self) -> "Span":
         tracer = self._tracer
         self.tid = threading.get_ident()
-        stack = tracer._stack()
+        stack = _open_spans()
         stack.append(self)
         # device-attribution sampling happens OUTSIDE the stamped window so
         # the counter reads never inflate the span's own duration — and
@@ -120,6 +223,10 @@ class Span:
         clock = tracer.clock
         if clock is not None:
             self.vt0 = clock.monotonic()
+        # the same interval on the profiler's clock: under a profiler
+        # session the span shows on the host plane beside the XLA events
+        # (with none running this is a fraction of a microsecond)
+        self._annotation = _annotation(self.name)
         self.t0 = telemetry.perf_counter()
         return self
 
@@ -127,6 +234,7 @@ class Span:
         self.t1 = telemetry.perf_counter()
         tracer = self._tracer
         try:
+            self._annotation.__exit__(exc_type, exc, tb)
             clock = tracer.clock
             if clock is not None:
                 self.vt1 = clock.monotonic()
@@ -136,8 +244,10 @@ class Span:
                 compiles = jitstats.total_compiles() - self._c0
                 if compiles:
                     # a retrace annotated onto the OWNING span — the signal
-                    # the flat jit counters could never localize
-                    self.set(compiles=compiles, retrace=True)
+                    # the flat jit counters could never localize (a count of
+                    # new specializations of the functions jitstats tracks;
+                    # ``compiles`` is the compile listener's)
+                    self.set(retrace=compiles)
                 sc = _scatter_totals(self._cols)
                 delta = {k: sc[k] - self._sc0.get(k, 0)
                          for k in sc if sc[k] != self._sc0.get(k, 0)}
@@ -148,7 +258,7 @@ class Span:
         except Exception:  # noqa: BLE001 — attribution only; the stack
             pass           # unwind below must ALWAYS run
         finally:
-            stack = tracer._stack()
+            stack = _open_spans()
             stack.pop()
             if stack and self._record is None:
                 if tracer.enabled:
@@ -241,29 +351,32 @@ class Tracer:
             # settle (record_cycle is the settle path) would accumulate
             # forever on a long-running KB_TRACE=0 server
             recorder.enabled = self.enabled
-        self.collectives = env_flag("KB_TRACE_COLLECTIVES", False)
         # arrival→decision SLO (ms) that arms a flight dump; 0 = off
         try:
             self.slo_ms = float(os.environ.get("KB_TRACE_SLO_MS", "0") or 0)
         except ValueError:
             self.slo_ms = 0.0
         self._mu = threading.Lock()
-        self._tls = threading.local()
-        self._seq = itertools.count()
+        self._next_cycle = 0  # the number begin_cycle gives out next
         self.current: Optional[CycleRecord] = None
+        # parked-time spans waiting for the record of the cycle they precede
+        self._preceding: List[Span] = []
+        # the newest root of each detached tree (the read plane's last
+        # flush), for /v1/trace: what a dump of the cycles cannot show
+        self._last_detached: Dict[str, Span] = {}
+        # when the last few cycles started: a cycle drains ingest first, so
+        # a pod that arrived before two of these was passed over by a cycle
+        self._cycle_starts: deque = deque(maxlen=_CYCLE_STARTS)
         # seed-stable longitudinal stats (the sim report's section)
         self.cycles_total = 0
         self.spans_total = 0
         self.span_counts: Dict[str, int] = {}
+        # wall milliseconds by span name, children and roots alike (NOT
+        # seed-stable, so not in stage_attribution; on /v1/trace only:
+        # ~30 names would be ~60 more lines on every /metrics scrape)
+        self.span_ms: Dict[str, float] = {}
         self.retraces_attributed = 0
-        self._collective_cache: Dict = {}
-
-    # -- thread-local span stack -----------------------------------------
-    def _stack(self) -> list:
-        stack = getattr(self._tls, "stack", None)
-        if stack is None:
-            stack = self._tls.stack = []
-        return stack
+        _listen_for_compiles()
 
     # -- cycle bracket ----------------------------------------------------
     def begin_cycle(self, reason: str = "tick") -> CycleRecord:
@@ -271,13 +384,28 @@ class Tracer:
         returns the record so the pipelined caller can hand it to the
         writeback worker."""
         vt0 = self.clock.monotonic() if self.clock is not None else None
-        rec = CycleRecord(next(self._seq), reason,
-                          telemetry.perf_counter(), vt0)
+        t0 = telemetry.perf_counter()
         with self._mu:
+            rec = CycleRecord(self._take_cycle_number(), reason, t0, vt0)
+            # the parked time before this cycle leads its record
+            rec.spans, self._preceding = self._preceding, []
+            self._cycle_starts.append(t0)
             prev, self.current = self.current, rec
         if prev is not None:
             self._finalize(prev)
         return rec
+
+    def _take_cycle_number(self) -> int:
+        """The next record's number (caller holds ``_mu``)."""
+        n = self._next_cycle
+        self._next_cycle = n + 1
+        return n
+
+    def next_cycle_number(self) -> int:
+        """The number the next ``begin_cycle`` will give its record — what
+        a parked-time span says it precedes."""
+        with self._mu:
+            return self._next_cycle
 
     def end_cycle(self) -> None:
         with self._mu:
@@ -302,8 +430,11 @@ class Tracer:
             self.span_counts[span.name] = (
                 self.span_counts.get(span.name, 0) + 1
             )
+            self.span_ms[span.name] = (
+                self.span_ms.get(span.name, 0.0) + span.dur_ms
+            )
             if span.attrs and span.attrs.get("retrace"):
-                self.retraces_attributed += span.attrs.get("compiles", 1)
+                self.retraces_attributed += int(span.attrs["retrace"])
 
     def _close_root(self, span: Span) -> None:
         """A span finished with no parent on its thread: attach it to its
@@ -311,10 +442,25 @@ class Tracer:
         feed the per-stage latency surface.  The histogram observes even
         with KB_TRACE=0 — the knob disables RETENTION (ring, dumps, device
         attribution), never the latency metrics spans feed (the same
-        contract as the action/plugin histograms reading sp.dur_us)."""
-        metrics.observe_stage_latency(span.name, span.dur_ms)
+        contract as the action/plugin histograms reading sp.dur_us).
+
+        The read plane's detached trees stay off the histogram too: their
+        totals are on /v1/trace, and a stage label is 13 more lines on a
+        page the benchmark's decision channel renders every few ms."""
+        keep = span._keep
+        if keep != _KEEP_NONE:
+            metrics.observe_stage_latency(span.name, span.dur_ms)
         if self.enabled:
             self._count_span(span)
+            if keep != _KEEP_CYCLE:
+                with self._mu:
+                    if keep == _KEEP_NONE:
+                        self._last_detached[span.name] = span
+                    else:
+                        # bounded: a loop that parks and never cycles again
+                        # (shutdown) must not grow the list
+                        self._preceding = self._preceding[-3:] + [span]
+                return
             with self._mu:
                 rec = span._record
                 if rec is None:
@@ -323,7 +469,8 @@ class Tracer:
                         # direct-driven flows (bench one_cycle, tests) never
                         # bracket cycles — collect under an implicit record
                         rec = self.current = CycleRecord(
-                            next(self._seq), "implicit", span.t0, span.vt0
+                            self._take_cycle_number(), "implicit", span.t0,
+                            span.vt0
                         )
                 rec.spans.append(span)
                 roll = (rec is self.current
@@ -340,9 +487,7 @@ class Tracer:
 
     def device_span(self, name: str, cols=None, **attrs) -> Span:
         """A span that attributes device work: jit compile delta (retraces
-        land on the owning span) and resident scatter/upload deltas; the
-        dispatching action additionally calls :meth:`annotate_collectives`
-        on sharded dispatches."""
+        land on the owning span) and resident scatter/upload deltas."""
         return Span(self, name, cols=cols if self.enabled else None,
                     attrs=attrs or None)
 
@@ -352,6 +497,21 @@ class Tracer:
         writeback stage runs on its own worker thread after its cycle's
         record was already finalized into the ring."""
         return Span(self, name, record=record, attrs=attrs or None)
+
+    def park_span(self, name: str, **attrs) -> Span:
+        """A root span of the loop's parked time between two cycles: it
+        feeds the stage histogram like any root, and is retained on the
+        record of the cycle it PRECEDES (no cycle is open while the loop
+        is parked, and an implicit record each would flood the ring)."""
+        return Span(self, name, attrs=attrs or None, keep=_KEEP_NEXT)
+
+    def detached_span(self, name: str, **attrs) -> Span:
+        """A root span of a plane that is no part of the cycle (the read
+        plane's flush, ~28 a second): it and its children are timed,
+        annotated for the profiler and totalled by name, and kept out of
+        the cycle records and the ring, which the solving cycles would
+        otherwise leave within seconds."""
+        return Span(self, name, attrs=attrs or None, keep=_KEEP_NONE)
 
     # -- cycle annotations -------------------------------------------------
     def note_cycle_attr(self, key: str, value) -> None:
@@ -383,6 +543,33 @@ class Tracer:
                            f"KB_TRACE_SLO_MS={self.slo_ms:g}",
                 )
 
+    def note_decision_parts(self, arrivals, now: float) -> None:
+        """Split the arrival→decision latency of the pods bound at ``now``
+        (``arrivals``: their arrival stamps on the telemetry clock): the
+        wait until the deciding cycle started, and how many of them were
+        passed over — bound after two or more cycles had started (and so
+        drained ingest) since they arrived.  Observed with KB_TRACE=0 too:
+        these are latency metrics, not retention."""
+        if not arrivals:
+            return
+        with self._mu:
+            rec = self.current
+            starts = tuple(self._cycle_starts)
+        # a bind outside any cycle (direct drives) waited all its latency
+        t_cycle = rec.t0 if rec is not None else now
+        if len(arrivals) == 1:
+            # bind(): one pod a call, a hundred calls a backfill cycle
+            wait_ms = max(t_cycle - arrivals[0], 0.0) * 1e3
+            left = len(starts) - bisect.bisect_right(starts, arrivals[0]) >= 2
+        else:
+            # bulk_bind(): the 50,000-pod cold drain pays milliseconds
+            arr = np.asarray(arrivals, dtype=np.float64)
+            wait_ms = float(np.clip(t_cycle - arr, 0.0, None).sum()) * 1e3
+            left = int((len(starts) - np.searchsorted(
+                starts, arr, side="right") >= 2).sum())
+        metrics.observe_decision_queue_wait(wait_ms, len(arrivals))
+        metrics.register_decisions_leftover(int(left))
+
     def anomaly(self, reason: str, detail: str = "") -> None:
         """Route a non-guard anomaly (budget shed, duplicate bind) to the
         flight recorder."""
@@ -405,7 +592,10 @@ class Tracer:
                 "cycles_traced": self.cycles_total,
                 "spans_total": self.spans_total,
                 "span_counts": dict(self.span_counts),
+                "span_ms": {k: round(v, 3) for k, v in self.span_ms.items()},
                 "retraces_attributed": self.retraces_attributed,
+                "last_detached": {k: sp.to_dict() for k, sp
+                                  in self._last_detached.items()},
             }
         if self.recorder is not None:
             out["ring"] = self.recorder.stats()
@@ -444,53 +634,6 @@ class Tracer:
                 "stages": dict(sorted(self.span_counts.items())),
                 "retraces_attributed": self.retraces_attributed,
             }
-
-    # -- sharded collective attribution (opt-in, memoized) ----------------
-    def annotate_collectives(self, span: Span, config, snap,
-                             pend_rows=None) -> None:
-        """Attach the traced per-round/per-solve collective result bytes
-        (``utils/jitstats.collective_inventory``) to a sharded dispatch
-        span.  Opt-in (``KB_TRACE_COLLECTIVES=1``) and memoized per (mesh,
-        config, shapes): the one-off program trace this needs must not run
-        on the default path, where the benches' zero-retrace counters are
-        part of the acceptance evidence."""
-        if not (self.enabled and self.collectives):
-            return
-        try:
-            from kube_batch_tpu.parallel.mesh import (
-                default_mesh,
-                shard_map_enabled,
-            )
-
-            if not shard_map_enabled():
-                return
-            mesh = default_mesh()
-            if mesh is None:
-                return
-            T = int(snap.task_req.shape[0])
-            N = int(snap.node_idle.shape[0])
-            pend = int(pend_rows.shape[0]) if pend_rows is not None else None
-            key = (id(mesh), config, T, N, pend)
-            hash(key)
-            if key not in self._collective_cache:
-                from kube_batch_tpu.analysis.jaxpr_audit import (
-                    abstract_snapshot,
-                )
-                from kube_batch_tpu.parallel.mesh import collective_stats
-
-                stats = collective_stats(
-                    mesh, config=config, snap=abstract_snapshot(T=T, N=N),
-                    pend_bucket=pend,
-                )
-                self._collective_cache[key] = {
-                    "per_round_bytes": stats["per_round_bytes"],
-                    "per_solve_bytes": stats["per_solve_bytes"],
-                }
-            out = self._collective_cache[key]
-        except Exception:  # noqa: BLE001 — attribution only
-            return
-        if out:
-            span.set(collective_bytes=out)
 
 
 # --------------------------------------------------------------------------

@@ -26,6 +26,7 @@ period (default: the schedule period)."""
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -59,22 +60,32 @@ class CycleTrigger:
     Deadline arithmetic reads the INJECTED clock (the Scheduler's clock
     seam) so tests can pace it; the blocking itself is the condition
     variable's (real-time) wait, re-armed against the injected deadline
-    each lap."""
+    each lap.
 
-    def __init__(self, clock=None):
+    The parked time is the loop's own: with a ``tracer`` the two phases of
+    :meth:`wait_for_work` are root spans on the loop thread, ``park:floor``
+    (the rate floor) and ``park:event`` (nothing pending, until a signal or
+    the idle tick), kept on the record of the cycle they precede."""
+
+    def __init__(self, clock=None, tracer=None):
         self.clock = clock if clock is not None else time
+        self.tracer = tracer
         # the guard lock is created HERE (not Condition's default, which
         # would be born inside the threading module) so the runtime lockdep
         # checker tracks it: notify() under the cache's big lock records the
         # big→trigger edge, and any reverse nesting would report
         self._cond = threading.Condition(lock=threading.Lock())
         self._pending = False
+        # when the first notify() not yet consumed came (injected clock)
+        self._signalled_at = 0.0
 
     def notify(self) -> None:
         """Wake the loop (never blocks; safe from any thread, including
         under the cache's locks — the condition guard is a leaf)."""
         with self._cond:
-            self._pending = True
+            if not self._pending:
+                self._pending = True
+                self._signalled_at = self.clock.monotonic()
             self._cond.notify_all()
 
     def poll(self) -> bool:
@@ -92,18 +103,42 @@ class CycleTrigger:
         into one cycle per ``min_period``, so a hot ingest stream cannot
         busy-spin the solve."""
         clock = self.clock
+        floor_sp = None
         floor_rem = min_period - (clock.monotonic() - cycle_start)
         if floor_rem > 0:
-            clock.sleep(floor_rem)
-        deadline = cycle_start + max_period
+            with self._parked("park:floor") as floor_sp:
+                clock.sleep(floor_rem)
+        with self._parked("park:event") as event_sp:
+            reason, signalled_ms = self._await_signal(
+                cycle_start + max_period)
+        if floor_sp is not None:
+            floor_sp.set(woke_by=reason)
+        if event_sp is not None:
+            # signalled_ms: how long the wake had been asked for when the
+            # cycle started — the rest of the last cycle and the floor, for
+            # a signal that came mid-cycle
+            event_sp.set(woke_by=reason, signalled_ms=round(signalled_ms, 3))
+        return reason
+
+    def _parked(self, name: str):
+        """The span of one parked phase (nothing, without a tracer)."""
+        tracer = self.tracer
+        if tracer is None:
+            return contextlib.nullcontext()
+        return tracer.park_span(
+            name, before_cycle=tracer.next_cycle_number())
+
+    def _await_signal(self, deadline: float):
+        """(wake reason, ms the consumed signal had been pending)."""
+        clock = self.clock
         with self._cond:
             while not self._pending:
                 rem = deadline - clock.monotonic()
                 if rem <= 0:
-                    return "floor"
+                    return "floor", 0.0
                 self._cond.wait(rem)
             self._pending = False
-            return "ingest"
+            return "ingest", (clock.monotonic() - self._signalled_at) * 1e3
 
 
 class Scheduler:
@@ -165,11 +200,11 @@ class Scheduler:
         # EWMA of measured cycle cost (seconds) — the adaptive floor's p50
         # estimator; None until the first pipelined cycle completes
         self.cycle_cost_ewma: Optional[float] = None
-        self.trigger = CycleTrigger(clock=self.clock)
         # the cycle tracing plane (kube_batch_tpu/obs): per-cache span
         # recorder + flight-recorder ring; virtual-time stamping follows
         # the injected clock so sim traces attribute on the report's clock
         self.tracer = tracer_of(cache, clock=self.clock)
+        self.trigger = CycleTrigger(clock=self.clock, tracer=self.tracer)
         # the writeback stage: one worker, double-buffered — at most one
         # cycle's (status flush + binder drain) in flight while the next
         # cycle computes; _await_writeback is the stage barrier

@@ -27,6 +27,7 @@ from collections import deque
 from concurrent.futures import Future
 from typing import Callable, List, Optional, Tuple
 
+from kube_batch_tpu import metrics
 from kube_batch_tpu.envutil import env_int
 
 
@@ -102,12 +103,17 @@ class MicroBatcher:
             return True
         return now - self._pending[0][2] >= self.window_s
 
-    def _take(self) -> List[Tuple[object, Future]]:
+    def _take(self, now: float) -> List[Tuple[object, Future]]:
+        """The next batch off the queue (caller holds the condition).  How
+        long each request sat here is observed at ``now``, the start of its
+        flush: the wait crosses threads, so no span can hold it."""
         n = min(self.max_batch, len(self._pending))
-        out = []
+        out, waited = [], 0.0
         for _ in range(n):
-            req, fut, _t = self._pending.popleft()
+            req, fut, enqueued = self._pending.popleft()
             out.append((req, fut))
+            waited += now - enqueued
+        metrics.observe_whatif_queue_wait(waited * 1e3, n)
         return out
 
     def tick(self, now: Optional[float] = None) -> int:
@@ -118,7 +124,7 @@ class MicroBatcher:
         with self._cond:
             if not self._due(now):
                 return 0
-            batch = self._take()
+            batch = self._take(now)
         self._run_flush(batch)
         return len(batch)
 
@@ -152,7 +158,7 @@ class MicroBatcher:
                     self._cond.wait(max(remaining, 0.0))
                 if self._stopped:
                     break
-                batch = self._take()
+                batch = self._take(self.clock.monotonic())
             self._run_flush(batch)
         # drain on stop: fail whatever is still queued
         with self._cond:

@@ -20,6 +20,7 @@ broker and points :attr:`QueryPlane.head_fn` at the stream head.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 from concurrent.futures import Future
@@ -28,6 +29,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from kube_batch_tpu import metrics
+from kube_batch_tpu.obs.trace import tracer_of
 from kube_batch_tpu.serve.batcher import MicroBatcher, _env_float
 from kube_batch_tpu.serve.lease import LeaseBroker, SnapshotLease
 from kube_batch_tpu.utils import telemetry
@@ -194,6 +196,10 @@ class QueryPlane:
         )
         self.dispatches = 0
         self.requests_served = 0
+        self.flushes = 0  # the sequence number a flush's span carries
+        # the cache's one span plane: a flush is a tree of its own there
+        # (whatif:*), never part of a cycle's record
+        self.tracer = tracer_of(cache)
 
     def close(self) -> None:
         self.batcher.stop()
@@ -358,7 +364,11 @@ class QueryPlane:
                         getattr(a, "sharding", None)),
                     lease.snap,
                 )
-                self._probe(lease._replace(snap=twin), [req], record=False)
+                # a tree of its own: the compile lands on its whatif:probe
+                # child (/v1/trace last_detached), outside every cycle record
+                with self.tracer.detached_span("whatif:prewarm"):
+                    self._probe(lease._replace(snap=twin), [req],
+                                record=False)
             except Exception:  # noqa: BLE001 — warm-up only; serving still works cold
                 logger.exception("whatif probe pre-warm failed")
 
@@ -415,6 +425,17 @@ class QueryPlane:
         if not batch:
             return
         metrics.observe_whatif_batch(len(batch), self.batcher.depth())
+        self.flushes += 1
+        # the flush's own span tree (this is the batcher's thread): timed,
+        # on the profiler's clock and totalled by name, never on a cycle's
+        # record — ~28 flushes a second would push the solving cycles out
+        # of the 256-cycle ring within seconds
+        with self.tracer.detached_span(
+                "whatif:flush", seq=self.flushes, batch=len(batch)) as sp:
+            self._flush_traced(batch, sp)
+
+    def _flush_traced(self, batch, sp_flush) -> None:
+        tracer = self.tracer
         # a mixed window splits by the evictions flag: with_evictions is a
         # static jit arg selecting a superset program, so one --evictions
         # request must not make every co-batched plain probe pay the
@@ -431,7 +452,12 @@ class QueryPlane:
         ]
         done = []
         done_sweeps = []
-        with self.broker.dispatch(timeout=self.dispatch_timeout) as lease:
+        with contextlib.ExitStack() as held:
+            # from entering the broker until the lease is held or refused:
+            # a resident swap in flight, or no lease since the last one
+            with tracer.span("whatif:lease"):
+                lease = held.enter_context(
+                    self.broker.dispatch(timeout=self.dispatch_timeout))
             if lease is None:
                 err = WhatifError(
                     503, "no snapshot lease published yet (scheduler warming)"
@@ -440,6 +466,7 @@ class QueryPlane:
                     if self._deliver(fut, error=err):
                         metrics.register_whatif_request("error")
                 return
+            sp_flush.set(lease_version=lease.version)
             for sub in subs:
                 if not sub:
                     continue
@@ -463,6 +490,11 @@ class QueryPlane:
                         fut, error=WhatifError(500, f"sweep failed: {e}")
                     ):
                         metrics.register_whatif_request("error")
+        # delivered once the lease is released, so a waiting swap goes first
+        with tracer.span("whatif:deliver"):
+            self._deliver_all(done, done_sweeps)
+
+    def _deliver_all(self, done, done_sweeps) -> None:
         for req, fut, resp in done_sweeps:
             if not self._deliver(fut, result=resp):
                 continue
@@ -618,40 +650,48 @@ class QueryPlane:
 
         from kube_batch_tpu.ops.probe import probe_solve
 
-        pbatch, rows = self._encode(lease, reqs)
+        # children of the caller's root (a flush, a pre-warm): one of each
+        # per sub-batch and per sweep dispatch
+        tracer = self.tracer
+        with tracer.span("whatif:encode"):
+            pbatch, rows = self._encode(lease, reqs)
         with_evictions = any(r["evictions"] for r in reqs)
-        if lease.mesh is not None:
-            from kube_batch_tpu.parallel.mesh import sharded_probe_solve
+        # program dispatch + device_get: the device's share of a flush
+        with tracer.span("whatif:probe", batch=len(reqs)):
+            if lease.mesh is not None:
+                from kube_batch_tpu.parallel.mesh import sharded_probe_solve
 
-            res = sharded_probe_solve(
-                lease.snap, pbatch, rows, lease.mesh, lease.config,
-                lease.evict_config, with_evictions,
-            )
-        else:
-            res = probe_solve(
-                lease.snap, pbatch, rows, lease.config,
-                lease.evict_config, with_evictions,
-            )
-        if record:  # pre-warm dispatches stay out of the serving counters
-            self.dispatches += 1
-            metrics.register_whatif_dispatch()
-        if not with_evictions:
-            # the eviction fields are all-zeros placeholders on this
-            # program, and victims is [B, T]-sized — at big snapshots that
-            # dead transfer would rival the batch window itself.  None is
-            # an empty pytree: device_get skips it, and _decode only reads
-            # these fields for evictions requests (the flush partitions
-            # windows by that flag, so the sub-batch is uniform)
-            res = res._replace(
-                claim_node=None, victims=None, evict_covered=None
-            )
-        # kbt: allow[KBT010] THE sanctioned serving choke point: one
-        # blocking transfer per batch window — the whole point of the
-        # micro-batcher is that every queued request shares it
-        host = jax.device_get(res)
-        return [
-            self._decode(lease, r, host, b) for b, r in enumerate(reqs)
-        ]
+                res = sharded_probe_solve(
+                    lease.snap, pbatch, rows, lease.mesh, lease.config,
+                    lease.evict_config, with_evictions,
+                )
+            else:
+                res = probe_solve(
+                    lease.snap, pbatch, rows, lease.config,
+                    lease.evict_config, with_evictions,
+                )
+            if record:  # pre-warm dispatches stay out of the serving counters
+                self.dispatches += 1
+                metrics.register_whatif_dispatch()
+            if not with_evictions:
+                # the eviction fields are all-zeros placeholders on this
+                # program, and victims is [B, T]-sized — at big snapshots
+                # that dead transfer would rival the batch window itself.
+                # None is an empty pytree: device_get skips it, and _decode
+                # only reads these fields for evictions requests (the flush
+                # partitions windows by that flag, so the sub-batch is
+                # uniform)
+                res = res._replace(
+                    claim_node=None, victims=None, evict_covered=None
+                )
+            # kbt: allow[KBT010] THE sanctioned serving choke point: one
+            # blocking transfer per batch window — the whole point of the
+            # micro-batcher is that every queued request shares it
+            host = jax.device_get(res)
+        with tracer.span("whatif:decode"):
+            return [
+                self._decode(lease, r, host, b) for b, r in enumerate(reqs)
+            ]
 
     def _staleness(self, lease: SnapshotLease) -> dict:
         """The version-token-bounded staleness block every verdict

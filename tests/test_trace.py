@@ -2,11 +2,15 @@
 export validity, the flight recorder's anomaly windows, trace-on vs
 trace-off decision bit-exactness over randomized churn, the pipelined
 writeback overlap rendered as overlapping spans, the span-stamped
-arrival→decision latencies, and the guard trip-rate alert evaluator."""
+arrival→decision latencies and their two parts, the guard trip-rate alert
+evaluator, and the span plane on the profiler's clock: parked-time spans,
+per-name totals, the compile listener, the read plane's detached flush
+tree, and the span names found on a jax.profiler trace's host plane."""
 
 from __future__ import annotations
 
 import json
+import threading
 import time
 import urllib.request
 
@@ -35,7 +39,9 @@ from kube_batch_tpu.obs.trace import (
     tracer_of,
     validate_chrome_trace,
 )
-from kube_batch_tpu.scheduler import Scheduler
+from kube_batch_tpu.metrics import metrics as prom
+from kube_batch_tpu.scheduler import CycleTrigger, Scheduler
+from kube_batch_tpu.utils import telemetry
 from kube_batch_tpu.sim import kubelet as kl
 from kube_batch_tpu.testing.synthetic import GiB
 
@@ -161,9 +167,7 @@ def _observable_state(cache) -> dict:
 
 class TestSpans:
     def _tracer(self, tmp_path, **kw):
-        rec = FlightRecorder(ring=16, directory=str(tmp_path),
-                             post_cycles=0)
-        return Tracer(recorder=rec, enabled=True, **kw), rec
+        return _tracer(tmp_path, **kw)
 
     def test_nesting_builds_a_tree(self, tmp_path):
         tr, rec = self._tracer(tmp_path)
@@ -349,6 +353,8 @@ class TestTraceInert:
         """Tracing must be provably inert: the same churn stream under
         KB_TRACE=1 and KB_TRACE=0 produces identical binds, statuses,
         conditions, and queue writebacks (serial and pipelined bodies)."""
+        from kube_batch_tpu.serve.plane import QueryPlane
+
         monkeypatch.setenv("KB_TRACE", "0")
         c_off = _mk_cache()
         s_off = _mk_scheduler(c_off)
@@ -357,6 +363,11 @@ class TestTraceInert:
         c_on = _mk_cache()
         s_on = _mk_scheduler(c_on)
         assert c_on.tracer.enabled
+        # a read plane on both: cycle 4 carries a what-if flush, whose span
+        # tree (traced side only) must change no answer and no decision
+        planes = [QueryPlane(c, start_thread=False) for c in (c_off, c_on)]
+        whatif = {"queue": "q0", "count": 2,
+                  "requests": {"cpu": 500, "memory": GiB}}
         ch_off, ch_on = _Churner(c_off, seed), _Churner(c_on, seed)
         for _ in range(3):
             ch_off.add_gang()
@@ -364,6 +375,13 @@ class TestTraceInert:
         for cycle in range(8):
             ch_off.step()
             ch_on.step()
+            if cycle == 4:
+                answers = []
+                for qp in planes:
+                    fut = qp.submit(dict(whatif))
+                    qp.batcher.tick(now=qp.batcher.clock.monotonic() + 1e6)
+                    answers.append(fut.result(timeout=120))
+                assert answers[0] == answers[1]
             if cycle % 2:
                 s_off.run_once()
                 s_on.run_once()
@@ -376,6 +394,10 @@ class TestTraceInert:
         # and the traced side actually traced
         assert c_on.tracer.cycles_total >= 8
         assert c_on.tracer.spans_total > 0
+        assert c_on.tracer.span_counts["whatif:flush"] == 1
+        assert "whatif:flush" not in c_off.tracer.span_counts
+        for qp in planes:
+            qp.close()
         c_off.stop()
         c_on.stop()
 
@@ -386,23 +408,41 @@ class TestTraceInert:
 
 
 class TestPipelinedOverlap:
-    def test_writeback_span_overlaps_next_cycle_compute(self):
+    def test_writeback_span_overlaps_next_cycle_compute(self, monkeypatch):
         """Cycle N's writeback span (its own worker-thread track) must
         overlap cycle N+1's session_open span in wall time — the exported
         trace renders the pipeline's overlap structure directly."""
+        import kube_batch_tpu.scheduler as scheduler_mod
+
         cache = _mk_cache()
         sched = _mk_scheduler(cache)
         _add_gang(cache, 1)
         sched.run_once_pipelined()  # warm compile out of the way
         orig_flush = cache.flush_binds
+        # a handshake inside the two spans: cycle N's flush (inside its
+        # writeback span) is held until cycle N+1 is inside session_open,
+        # and that open does not return before the flush has started — the
+        # overlap is made, not hoped for from a sleep that a loaded host
+        # outlasts (or that ends before the worker thread is scheduled)
+        flushing, opened = threading.Event(), threading.Event()
+        real_open = scheduler_mod.open_session
 
-        def slow_flush():
-            time.sleep(0.08)
+        def held_flush():
+            flushing.set()
+            assert opened.wait(timeout=60), "the next cycle never opened"
             return orig_flush()
 
-        cache.flush_binds = slow_flush
+        def open_and_release(*args, **kw):
+            ssn = real_open(*args, **kw)
+            assert flushing.wait(timeout=60), "the writeback never started"
+            opened.set()
+            return ssn
+
+        cache.flush_binds = held_flush
         _add_gang(cache, 2)
         sched.run_once_pipelined()   # cycle N: hands writeback to worker
+        # hooked only now: cycle N's own open must not release its flush
+        monkeypatch.setattr(scheduler_mod, "open_session", open_and_release)
         _add_gang(cache, 3)
         sched.run_once_pipelined()   # cycle N+1 computes under N's egress
         sched.drain_pipeline()
@@ -562,6 +602,352 @@ class TestAlerts:
         sched.run_once()
         st = cache.alert_evaluator.state()
         assert st["alerts"]["guard_trips"]["firing"] is True
+        cache.stop()
+
+
+# ---------------------------------------------------------------------------
+# the span plane's own counters: parked time, per-name totals, compiles,
+# the read plane's detached tree, a decision's two parts
+# ---------------------------------------------------------------------------
+
+
+class _TickClock:
+    """``telemetry.perf_counter`` stand-in: every read is one second after
+    the last, so a span's duration is the number of reads inside it."""
+
+    def __init__(self, start=0.0, step=1.0):
+        self.t, self.step = start, step
+
+    def __call__(self) -> float:
+        self.t += self.step
+        return self.t
+
+
+def _counter(metric, *labels) -> float:
+    return metric._values.get(labels, 0.0)
+
+
+def _tracer(tmp_path, **kw):
+    rec = FlightRecorder(ring=16, directory=str(tmp_path), post_cycles=0)
+    return Tracer(recorder=rec, enabled=True, **kw), rec
+
+
+class TestParkSpans:
+    def test_park_spans_carry_woke_by_and_start_no_record(self, tmp_path):
+        from kube_batch_tpu.sim.clock import VirtualClock
+
+        clock = VirtualClock(start=100.0)
+        tr, rec = _tracer(tmp_path)
+        trig = CycleTrigger(clock=clock, tracer=tr)
+        floors0 = prom.STAGE_LATENCY._count[("park:floor",)]
+        events0 = prom.STAGE_LATENCY._count[("park:event",)]
+        trig.notify()  # pending from t=100, consumed after the 50 ms floor
+        assert trig.wait_for_work(100.0, 0.05, 1.0) == "ingest"
+        # the idle tick: nothing pending and the period over -> "floor"
+        assert trig.wait_for_work(100.0, 0.0, 0.05) == "floor"
+        # root spans of the loop thread: on the stage histogram, counted ...
+        assert prom.STAGE_LATENCY._count[("park:floor",)] == floors0 + 1
+        assert prom.STAGE_LATENCY._count[("park:event",)] == events0 + 2
+        assert tr.span_counts == {"park:floor": 1, "park:event": 2}
+        # ... and no record of their own: no implicit one, nothing ringed
+        assert tr.current is None and rec.records() == []
+        assert tr.cycles_total == 0
+        record = tr.begin_cycle("pipelined")
+        with tr.span("session_open"):
+            pass
+        tr.end_cycle()
+        # they lead the record of the cycle they precede
+        assert [s.name for s in record.spans] == [
+            "park:floor", "park:event", "park:event", "session_open"]
+        floor, woken, idle = record.spans[:3]
+        assert floor.attrs == {"before_cycle": record.cycle,
+                               "woke_by": "ingest"}
+        assert woken.attrs == {"before_cycle": record.cycle,
+                               "woke_by": "ingest", "signalled_ms": 50.0}
+        assert idle.attrs["woke_by"] == "floor"
+        assert idle.attrs["signalled_ms"] == 0.0
+        assert len(rec.records()) == 1
+
+    def test_parked_loop_that_never_cycles_again_keeps_few_spans(
+            self, tmp_path):
+        tr, _ = _tracer(tmp_path)
+        for _ in range(50):
+            with tr.park_span("park:event"):
+                pass
+        assert len(tr._preceding) <= 4
+        assert tr.span_counts["park:event"] == 50
+
+
+class TestSpanTotals:
+    def test_span_ms_grows_by_a_childs_and_a_roots_duration(
+            self, tmp_path, monkeypatch):
+        monkeypatch.setattr(telemetry, "perf_counter", _TickClock())
+        tr, _ = _tracer(tmp_path)
+        tr.begin_cycle("test")          # read 1
+        with tr.span("root"):           # 2 .. 5
+            with tr.span("child"):      # 3 .. 4
+                pass
+        with tr.span("root"):           # 6 .. 7
+            pass
+        tr.end_cycle()
+        state = tr.state()
+        assert state["span_counts"] == {"child": 1, "root": 2}
+        assert state["span_ms"] == {"child": 1000.0, "root": 4000.0}
+
+    def test_disabled_tracer_totals_nothing(self, tmp_path):
+        tr = Tracer(enabled=False)
+        with tr.span("root"):
+            with tr.span("child"):
+                pass
+        assert tr.span_ms == {} and tr.span_counts == {}
+
+
+class TestCompileListener:
+    def test_untracked_jit_compile_is_counted_and_stamped(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+
+        tr, _ = _tracer(tmp_path)
+        x = jnp.arange(7.0)
+        x.block_until_ready()
+        # a program utils/jitstats has never heard of
+        fn = jax.jit(lambda v: v * 3.0 + 1.0)
+        compiles0 = _counter(prom.JIT_COMPILES)
+        backend0 = _counter(prom.JIT_COMPILE_SECONDS, "backend")
+        traced0 = tr.retraces_attributed
+        tr.begin_cycle("test")
+        with tr.span("stage:first") as first:
+            fn(x).block_until_ready()
+        compiles1 = _counter(prom.JIT_COMPILES)
+        assert compiles1 >= compiles0 + 1
+        assert _counter(prom.JIT_COMPILE_SECONDS, "backend") > backend0
+        assert first.attrs["compiles"] == compiles1 - compiles0
+        assert first.attrs["compile_ms"] > 0
+        with tr.span("stage:second") as second:
+            fn(x).block_until_ready()
+        tr.end_cycle()
+        assert _counter(prom.JIT_COMPILES) == compiles1
+        assert not second.attrs
+        # jitstats never saw it: the old counter keeps its meaning
+        assert tr.retraces_attributed == traced0
+
+    def test_compile_outside_any_span_is_still_counted(self):
+        import jax
+        import jax.numpy as jnp
+
+        Tracer(enabled=True)  # the listener is process-wide, registered once
+        Tracer(enabled=True)
+        x = jnp.arange(5.0)
+        x.block_until_ready()
+        compiles0 = _counter(prom.JIT_COMPILES)
+        jax.jit(lambda v: v - 2.0)(x).block_until_ready()
+        assert _counter(prom.JIT_COMPILES) == compiles0 + 1
+
+
+class TestReadPlaneSpans:
+    def test_flush_is_totalled_and_kept_out_of_the_records(self):
+        from kube_batch_tpu.serve.plane import QueryPlane
+
+        cache = _mk_cache()
+        sched = _mk_scheduler(cache)
+        qp = QueryPlane(cache, start_thread=False)
+        _add_gang(cache, 1)
+        sched.run_once()  # publishes the lease
+        tr, rec = cache.tracer, cache.flight_recorder
+        ringed = [(r.cycle, len(r.spans)) for r in rec.records()]
+        cycles0, current0 = tr.cycles_total, tr.current
+        stages0 = set(prom.STAGE_LATENCY._count)
+        waits0 = (prom.WHATIF_QUEUE_WAIT._sum, prom.WHATIF_QUEUE_WAIT._count)
+        # the batcher's injected clock: the request sits 250 ms in the queue
+        t_enqueue = qp.batcher.clock.monotonic()
+        futs = [qp.submit({"queue": "q0", "count": 2,
+                           "requests": {"cpu": 500, "memory": GiB}}),
+                qp.submit_sweep({"queue": "q1", "max_count": 4,
+                                 "requests": {"cpu": 500, "memory": GiB}})]
+        t_flush = qp.batcher.clock.monotonic() + 0.25
+        assert qp.batcher.tick(now=t_flush) == 2
+        assert futs[0].result(timeout=120)["feasible"]
+        assert futs[1].result(timeout=120)["max_fit"] == 4
+        state = tr.state()
+        for name in ("whatif:flush", "whatif:lease", "whatif:encode",
+                     "whatif:probe", "whatif:decode", "whatif:deliver"):
+            assert state["span_counts"][name] >= 1, name
+            assert state["span_ms"][name] >= 0.0
+        assert state["span_counts"]["whatif:flush"] == 1
+        # one probe for the plain sub-batch, the rest the sweep's dispatches
+        assert state["span_counts"]["whatif:probe"] == qp.dispatches >= 2
+        assert (state["span_ms"]["whatif:flush"]
+                >= state["span_ms"]["whatif:probe"])
+        flush = state["last_detached"]["whatif:flush"]
+        assert flush["attrs"]["seq"] == 1 and flush["attrs"]["batch"] == 2
+        assert flush["attrs"]["lease_version"] == qp.broker.current().version
+        assert [c["name"] for c in flush["children"]][0] == "whatif:lease"
+        # no record added to the ring, no span to a cycle's record, no
+        # stage label on /metrics
+        assert [(r.cycle, len(r.spans)) for r in rec.records()] == ringed
+        assert tr.cycles_total == cycles0 and tr.current is current0
+        assert set(prom.STAGE_LATENCY._count) == stages0
+        assert not any("whatif:" in key for key in state["solve_dispatches"])
+        # the wait in the batcher crosses threads: a counter, not a span
+        waited = prom.WHATIF_QUEUE_WAIT._sum - waits0[0]
+        assert prom.WHATIF_QUEUE_WAIT._count - waits0[1] == 2
+        assert waited == pytest.approx(
+            2 * (t_flush - t_enqueue) * 1e3, abs=2 * 50.0)
+        assert waited >= 2 * 250.0
+        qp.close()
+        cache.stop()
+
+
+class TestDecisionParts:
+    def _late_gang(self, cache):
+        cache.add_pod_group(PodGroup(
+            name="late", namespace="tr", uid="pg-late", min_member=2,
+            queue="q0", creation_index=50,
+        ))
+
+        def member(k):
+            return Pod(
+                name=f"late-{k}", namespace="tr", uid=f"pod-late-{k}",
+                requests={"cpu": 500.0, "memory": 1 * GiB},
+                annotations={GROUP_NAME_ANNOTATION: "late"},
+                phase=PodPhase.PENDING, creation_index=5000 + k,
+            )
+
+        return member
+
+    def test_queue_wait_plus_in_cycle_part_is_the_kept_latency(
+            self, monkeypatch):
+        """The kept arrival→decision latency, unchanged, splits at the
+        start of the deciding cycle; a pod that a cycle passed over counts
+        once as a leftover, a pod bound by its first cycle never."""
+        cache = _mk_cache()
+        sched = _mk_scheduler(cache)
+        _add_gang(cache, 1)
+        sched.run_once_pipelined()  # the solve compiles on the real clock
+        sched.drain_pipeline()
+        clock = _TickClock(start=1000.0, step=0.001)
+        monkeypatch.setattr(telemetry, "perf_counter", clock)
+        sink = []
+        prom_metrics.set_decision_latency_sink(sink)
+        wait0 = (prom.DECISION_QUEUE_WAIT._sum, prom.DECISION_QUEUE_WAIT._count)
+        left0 = _counter(prom.DECISIONS_LEFTOVER)
+        decided0 = prom.DECISION_LATENCY._count[()]
+        try:
+            member = self._late_gang(cache)
+            cache.add_pod(member(0))       # half a gang: cycle A passes it over
+            arrival_0 = clock.t
+            clock.t += 2.0
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+            assert sink == [] and _counter(prom.DECISIONS_LEFTOVER) == left0
+            cache.add_pod(member(1))       # the gang is whole: cycle B binds
+            arrival_1 = clock.t
+            clock.t += 3.0
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+        finally:
+            prom_metrics.set_decision_latency_sink(None)
+        assert len(sink) == 2
+        assert prom.DECISION_LATENCY._count[()] == decided0 + 2
+        deciding = cache.flight_recorder.records()[-1]
+        assert sorted(deciding.attrs["decision_lat_ms"]) == sorted(
+            round(v, 3) for v in sink)
+        # wait = arrival -> the deciding cycle's start, for each pod
+        waited = prom.DECISION_QUEUE_WAIT._sum - wait0[0]
+        assert prom.DECISION_QUEUE_WAIT._count - wait0[1] == 2
+        want = ((deciding.t0 - arrival_0) + (deciding.t0 - arrival_1)) * 1e3
+        assert waited == pytest.approx(want, abs=1e-6)
+        # the rest of the kept latency was spent inside that cycle: both
+        # pods were bound at one instant of it
+        in_cycle = (sum(sink) - waited) / 2
+        bound_at = arrival_0 + max(sink) / 1e3
+        assert in_cycle == pytest.approx((bound_at - deciding.t0) * 1e3,
+                                         abs=1e-6)
+        assert 0 < in_cycle < 1000.0
+        # member 0 saw two cycles start since it arrived, member 1 one
+        assert _counter(prom.DECISIONS_LEFTOVER) == left0 + 1
+        sched.close()
+        cache.stop()
+
+    def test_bind_outside_any_cycle_waited_all_its_latency(self, tmp_path):
+        tr, _ = _tracer(tmp_path)
+        wait0 = (prom.DECISION_QUEUE_WAIT._sum, prom.DECISION_QUEUE_WAIT._count)
+        left0 = _counter(prom.DECISIONS_LEFTOVER)
+        tr.note_decision_parts([10.0, 11.0], now=12.0)
+        assert prom.DECISION_QUEUE_WAIT._sum - wait0[0] == pytest.approx(3000.0)
+        assert prom.DECISION_QUEUE_WAIT._count - wait0[1] == 2
+        assert _counter(prom.DECISIONS_LEFTOVER) == left0
+
+    @pytest.mark.parametrize("arrivals,waited_ms,leftover", [
+        ([0.5], 3500.0, 1),             # bind(): one pod, the scalar form
+        ([2.5], 1500.0, 0),
+        ([0.5, 1.5, 2.5, 4.5], 7500.0, 2),   # bulk_bind(): searchsorted
+    ])
+    def test_one_pod_and_a_batch_split_alike(
+            self, tmp_path, monkeypatch, arrivals, waited_ms, leftover):
+        monkeypatch.setattr(telemetry, "perf_counter", _TickClock())
+        tr, _ = _tracer(tmp_path)
+        tr.begin_cycle("a")     # starts at 1.0
+        tr.begin_cycle("b")     # 2.0 (finalizing "a" reads the clock: 3.0)
+        tr.begin_cycle("c")     # 4.0, the deciding cycle
+        assert list(tr._cycle_starts) == [1.0, 2.0, 4.0]
+        wait0 = (prom.DECISION_QUEUE_WAIT._sum, prom.DECISION_QUEUE_WAIT._count)
+        left0 = _counter(prom.DECISIONS_LEFTOVER)
+        tr.note_decision_parts(arrivals, now=4.75)
+        assert (prom.DECISION_QUEUE_WAIT._sum - wait0[0]
+                == pytest.approx(waited_ms))
+        assert prom.DECISION_QUEUE_WAIT._count - wait0[1] == len(arrivals)
+        assert _counter(prom.DECISIONS_LEFTOVER) == left0 + leftover
+
+
+class TestProfilerClock:
+    def test_span_names_are_on_the_profilers_host_plane(self, tmp_path):
+        """Every span is also a ``TraceAnnotation``: a short jax.profiler
+        trace (CPU) around two pipelined cycles holds ``session_open`` and
+        ``park:event`` on the host plane, read with ``ProfileData`` the way
+        benchmark/tests/test_trace_reduce.py reads a chip trace."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        cache = _mk_cache()
+        sched = _mk_scheduler(cache)
+        _add_gang(cache, 1)
+        sched.run_once_pipelined()  # compile before the trace opens
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # as benchmark/serve.py traces
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            _add_gang(cache, 2)
+            sched.run_once_pipelined()
+            sched.trigger.notify()
+            assert sched.trigger.wait_for_work(
+                sched.clock.monotonic(), 0.0, 1.0) == "ingest"
+            _add_gang(cache, 3)
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+        finally:
+            jax.profiler.stop_trace()
+        found = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+        assert len(found) == 1
+        on_host = {}
+        for plane in ProfileData.from_file(found[0]).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        on_host.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.duration_ns))
+        for name in ("session_open", "park:event", "action:allocate",
+                     "solve_dispatch", "writeback"):
+            assert name in on_host, name
+        assert len(on_host["session_open"]) == 2
+        # on one clock: the parked span lies between the two cycles' opens
+        (open_a, _), (open_b, _) = sorted(on_host["session_open"])
+        (parked, _), = on_host["park:event"]
+        assert open_a < parked < open_b
+        sched.close()
         cache.stop()
 
 
