@@ -298,6 +298,9 @@ class SchedulerCache:
         # bind-decision latency histogram; stamped at ingest for pending
         # unbound owned pods, popped at the bind decision or pod deletion
         self._arrival_ts: Dict[str, float] = {}
+        # bind decisions made so far (bind / bulk_bind, under the big lock);
+        # the event-driven loop reads its growth over a cycle as progress
+        self.binds_total = 0
 
     # ------------------------------------------------------------------
     # exclusive-session gate (no-clone session mode)
@@ -975,8 +978,10 @@ class SchedulerCache:
             # the right state; the caller (Statement/dispatch) finishes the
             # BINDING transition itself
             pod = self.pods.get(task.key())
-            t0 = (self._arrival_ts.pop(task.key(), None)
-                  if pod is not None else None)
+            t0 = None
+            if pod is not None:
+                self.binds_total += 1
+                t0 = self._arrival_ts.pop(task.key(), None)
         if t0 is not None:
             self._observe_decisions([t0], telemetry.perf_counter())
         try:
@@ -1040,14 +1045,27 @@ class SchedulerCache:
         pop_ts = self._arrival_ts.pop
         inflight = self._inflight_bind_hosts
         arrivals = []
+        binds = 0
         for task, hostname, pod in staged:
             if pod is None:
                 continue
+            binds += 1
             inflight[task._key] = hostname
             t0 = pop_ts(task._key, None)
             if t0 is not None:
                 arrivals.append(t0)
+        self.binds_total += binds
         return arrivals, now
+
+    def left_schedulable_pending(self, binds_before: int) -> bool:
+        """Whether bind decisions were made since ``binds_total`` read
+        ``binds_before`` AND schedulable pods are pending still (the mask
+        of the allocate action's idle-cycle skip) — what the event-driven
+        loop wakes itself for after a cycle.  Takes the big lock: an
+        out-of-session mutation may be re-growing the columns."""
+        with self._lock:
+            return (self.binds_total > binds_before
+                    and self.columns.has_schedulable_pending())
 
     def _observe_decisions(self, arrivals, now: float) -> None:
         """The arrival→decision latency of the pods bound at ``now``, from

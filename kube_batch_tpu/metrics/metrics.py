@@ -285,8 +285,16 @@ DECISION_LATENCY = Histogram(
 )
 TRIGGER_WAKES = Counter(
     f"{_SUBSYSTEM}_cycle_trigger_wakes_total",
-    "Scheduling-cycle wakeups, by trigger (ingest|floor)",
+    "Scheduling-cycle wakeups, by trigger (ingest: a raised signal woke "
+    "the loop, arrival churn or the loop's own for pods its last cycle "
+    "left pending | floor: the idle tick); the two sum to every wake",
     ("trigger",),
+)
+SELF_WAKES = Counter(
+    f"{_SUBSYSTEM}_cycle_self_wakes_total",
+    "Cycles woken by the loop's own signal alone: its last cycle bound "
+    "pods and left schedulable ones pending (each also counted on "
+    "cycle_trigger_wakes_total under trigger=ingest)",
 )
 PIPELINE_OVERLAP = Histogram(
     f"{_SUBSYSTEM}_pipeline_writeback_overlap_milliseconds",
@@ -404,6 +412,7 @@ JIT_COMPILES = Counter(
     "Backend compiles JAX reported (persistent-cache hits included)",
 )
 # a sound window reads 0 from these, not "no such series"
+SELF_WAKES.add(0.0)
 DECISIONS_LEFTOVER.add(0.0)
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
@@ -439,6 +448,7 @@ METRICS = [
     WHATIF_SNAPSHOT_VERSION,
     DECISION_LATENCY,
     TRIGGER_WAKES,
+    SELF_WAKES,
     PIPELINE_OVERLAP,
     STAGED_INGEST,
     QUEUE_SHARE,
@@ -671,6 +681,13 @@ def register_jit_compile(phase: str, seconds: float) -> None:
 
 
 def register_trigger_wake(trigger: str) -> None:
+    """One loop wake, by :meth:`CycleTrigger.wait_for_work`'s reason.  The
+    loop's own wake (``"leftover"``) has a counter of its own and is a
+    raised signal like any other on the labelled series, so that series'
+    two labels keep summing to the cycles run."""
+    if trigger == "leftover":
+        SELF_WAKES.inc()
+        trigger = "ingest"
     TRIGGER_WAKES.inc(trigger)
 
 

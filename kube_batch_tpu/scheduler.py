@@ -17,7 +17,9 @@ allocate is the in-cycle instance of the same mechanism).  Cycle
 triggering is event-driven: the cache's dirty-version advance wakes a
 condition variable, so an arrival burst schedules immediately instead of
 waiting out the reference's fixed 1 s tick, while an idle cluster ticks at
-the slow floor.  Knobs: ``KB_PIPELINE=0`` restores the serial
+the slow floor; a cycle that bound pods and left schedulable ones pending
+raises the trigger itself, so the next cycle starts as soon as the rate
+floor allows.  Knobs: ``KB_PIPELINE=0`` restores the serial
 wait.Until loop (the bit-exactness oracle), ``KB_PERIOD_MIN`` pins the
 minimum spacing between cycle starts (rate floor for bursts; unset, the
 floor ADAPTS to an EWMA of the cycle's own measured cost — see
@@ -56,6 +58,12 @@ class CycleTrigger:
     MID-cycle — wakes the next cycle as soon as the ``min_period`` rate
     floor allows; with no signal the loop idles until ``max_period`` since
     the last cycle start (the reference's 1 s tick becomes the slow floor).
+    The loop raises the trigger itself, ``notify(leftover=True)``, after a
+    cycle that bound pods and left schedulable ones pending
+    (:meth:`Scheduler._run_forever_pipelined`): the cache keeps its own
+    in-session dirty advances from the trigger, so nothing else would wake
+    the loop for them before the idle tick.  Such a wake reports
+    ``"leftover"`` unless an ingest signal came beside it, in either order.
 
     Deadline arithmetic reads the INJECTED clock (the Scheduler's clock
     seam) so tests can pace it; the blocking itself is the condition
@@ -75,33 +83,41 @@ class CycleTrigger:
         # checker tracks it: notify() under the cache's big lock records the
         # big→trigger edge, and any reverse nesting would report
         self._cond = threading.Condition(lock=threading.Lock())
-        self._pending = False
+        # the wake reason of the signal not yet consumed (None: no signal):
+        # "ingest", or "leftover" while only the loop itself has asked
+        self._pending: Optional[str] = None
         # when the first notify() not yet consumed came (injected clock)
         self._signalled_at = 0.0
 
-    def notify(self) -> None:
+    def notify(self, leftover: bool = False) -> None:
         """Wake the loop (never blocks; safe from any thread, including
-        under the cache's locks — the condition guard is a leaf)."""
+        under the cache's locks — the condition guard is a leaf).
+        ``leftover`` marks the loop's own wake for what its last cycle left
+        pending; an ingest signal beside it wins the wake reason."""
         with self._cond:
-            if not self._pending:
-                self._pending = True
+            if self._pending is None:
                 self._signalled_at = self.clock.monotonic()
+                self._pending = "leftover" if leftover else "ingest"
+            elif not leftover:
+                self._pending = "ingest"
             self._cond.notify_all()
 
     def poll(self) -> bool:
         """Consume a pending signal without waiting (the sim's virtual-time
         pacing asks 'would the trigger fire now?' instead of blocking)."""
         with self._cond:
-            pending, self._pending = self._pending, False
-            return pending
+            pending, self._pending = self._pending, None
+            return pending is not None
 
     def wait_for_work(self, cycle_start: float, min_period: float,
                       max_period: float) -> str:
         """Block until the next cycle should start; returns the wake reason
-        (``"ingest"`` — signalled arrival churn; ``"floor"`` — the idle
-        period elapsed).  The rate floor is enforced first: bursts coalesce
-        into one cycle per ``min_period``, so a hot ingest stream cannot
-        busy-spin the solve."""
+        (``"ingest"`` — signalled arrival churn; ``"leftover"`` — only the
+        loop's own signal, for what its last cycle left pending;
+        ``"floor"`` — the idle period elapsed).  The rate floor is enforced
+        first: bursts coalesce into one cycle per ``min_period``, so neither
+        a hot ingest stream nor a chain of self-wakes can busy-spin the
+        solve."""
         clock = self.clock
         floor_sp = None
         floor_rem = min_period - (clock.monotonic() - cycle_start)
@@ -132,13 +148,13 @@ class CycleTrigger:
         """(wake reason, ms the consumed signal had been pending)."""
         clock = self.clock
         with self._cond:
-            while not self._pending:
+            while self._pending is None:
                 rem = deadline - clock.monotonic()
                 if rem <= 0:
                     return "floor", 0.0
                 self._cond.wait(rem)
-            self._pending = False
-            return "ingest", (clock.monotonic() - self._signalled_at) * 1e3
+            reason, self._pending = self._pending, None
+            return reason, (clock.monotonic() - self._signalled_at) * 1e3
 
 
 class Scheduler:
@@ -494,8 +510,21 @@ class Scheduler:
         cache-stop bracket).  Ingest staging routes watch churn through the
         leaf staging buffer, the dirty tracker's version advance wakes the
         trigger, and shutdown drains every in-flight stage before the cache
-        stops."""
+        stops.
+
+        The loop wakes ITSELF for what a cycle left behind: a cycle that
+        returned without raising, bound at least one pod and left
+        schedulable pods pending (the solve's round cap passes gangs over
+        that the next cycle places) raises the trigger, because nothing
+        else would before the idle tick — the cache suppresses its own
+        in-session dirty advances.  A self-woken cycle that binds nothing
+        ends the chain, so pods that fit nowhere cost one extra cycle after
+        each cycle that made progress and never a spin; every link binds at
+        least one pod, so a chain is no longer than the backlog.  Evictions
+        are no progress here: a victim's termination arrives as an ingest
+        event."""
         cache = self.cache
+        left_behind = getattr(cache, "left_schedulable_pending", None)
         enable = getattr(cache, "enable_ingest_staging", None)
         signal = getattr(cache, "set_ingest_signal", None)
         if signal is not None:
@@ -510,12 +539,15 @@ class Scheduler:
         try:
             while not self._stop:
                 tick = self.clock.monotonic()
+                binds = getattr(cache, "binds_total", 0)
                 try:
                     self.run_once_pipelined()
                     # successful cycles only: a fast-CRASHING cycle must
                     # not drag the adaptive floor down and turn the loop
                     # into a high-frequency crash retry
                     self._note_cycle_cost(self.clock.monotonic() - tick)
+                    if left_behind is not None and left_behind(binds):
+                        self.trigger.notify(leftover=True)
                 except Exception:  # noqa: BLE001 — next cycle self-corrects
                     logger.exception("scheduling cycle failed")
                     self._recover_failed_cycle()

@@ -348,6 +348,63 @@ class TestCycleTrigger:
         assert reason == "ingest"
 
 
+class TestLeftoverTrigger:
+    """The loop's own wake (``notify(leftover=True)``) on the sim's virtual
+    clock: it has its own reason on the park spans, the rate floor holds
+    for it, and an ingest signal beside it wins the reason."""
+
+    def _trigger(self, **kw):
+        from kube_batch_tpu.sim.clock import VirtualClock
+
+        clock = VirtualClock(start=100.0)
+        return CycleTrigger(clock=clock, **kw), clock
+
+    def test_self_wake_reports_leftover(self):
+        trig, clock = self._trigger()
+        trig.notify(leftover=True)
+        assert trig.wait_for_work(100.0, 0.0, 5.0) == "leftover"
+        assert clock.monotonic() == 100.0  # neither floor nor idle tick
+        # consumed: the next wait runs to the idle tick
+        assert trig.wait_for_work(100.0, 0.0, 0.0) == "floor"
+
+    def test_floor_holds_for_a_leftover_wake(self):
+        """test_min_period_coalesces_bursts's form: a self-wake raised at
+        the cycle's end still waits out the rate floor, and both park
+        spans of the cycle it precedes say who woke it."""
+        from kube_batch_tpu.obs.trace import Tracer
+
+        tr = Tracer(enabled=True)
+        trig, clock = self._trigger(tracer=tr)
+        trig.notify(leftover=True)
+        assert trig.wait_for_work(100.0, 0.08, 5.0) == "leftover"
+        assert clock.monotonic() == pytest.approx(100.08)
+        record = tr.begin_cycle("pipelined")
+        tr.end_cycle()
+        floor, event = record.spans
+        assert (floor.name, event.name) == ("park:floor", "park:event")
+        assert floor.attrs["woke_by"] == event.attrs["woke_by"] == "leftover"
+        assert event.attrs["signalled_ms"] == pytest.approx(80.0)
+
+    @pytest.mark.parametrize("ingest_first", [True, False])
+    def test_ingest_beside_a_self_wake_reports_ingest(self, ingest_first):
+        trig, clock = self._trigger()
+        if ingest_first:
+            trig.notify()
+        trig.notify(leftover=True)
+        if not ingest_first:
+            clock.sleep(0.01)
+            trig.notify()
+        assert trig.wait_for_work(100.0, 0.0, 5.0) == "ingest"
+        # one signal, one wake: nothing of the self-wake is left over
+        assert trig.poll() is False
+
+    def test_poll_consumes_a_self_wake_as_a_bool(self):
+        trig, _ = self._trigger()
+        trig.notify(leftover=True)
+        assert trig.poll() is True
+        assert trig.poll() is False
+
+
 class TestAdaptiveMinPeriod:
     """KB_PERIOD_MIN unset → the trigger's coalescing floor tracks an EWMA
     of the cycle's own measured cost (a 200 ms solve shouldn't re-trigger
@@ -471,6 +528,174 @@ class TestRunForeverPipelined:
         sched = Scheduler(_mk_cache(n_nodes=1),
                           conf=load_scheduler_conf(None))
         assert sched.pipelined is False
+
+
+def _wakes(*triggers) -> float:
+    values = prom_metrics.metrics.TRIGGER_WAKES._values
+    return sum(values.get((t,), 0.0) for t in triggers)
+
+
+def _self_wakes() -> float:
+    return prom_metrics.metrics.SELF_WAKES._values.get((), 0.0)
+
+
+class TestLeftoverWake:
+    """The event-driven loop wakes itself for what a cycle left behind: a
+    cycle that bound pods and left schedulable ones pending is followed by
+    the next as soon as the floor allows, not at the next event or the idle
+    tick — and a cycle that binds nothing, or raises, never is.  The loop
+    runs on a real thread; the test follows it by handshake (every entry
+    into the trigger's wait is announced), never by sleeping."""
+
+    UNFIT_CPU = 10_000_000.0  # no node of _mk_cache has a tenth of it
+
+    def _pod(self, cache, name, cpu):
+        cache.add_pod_group(PodGroup(
+            name=name, namespace="ns", uid=f"pg-{name}", min_member=1,
+            queue="q0", creation_index=1,
+        ))
+        cache.add_pod(Pod(
+            name=f"{name}-0", namespace="ns", uid=f"u-{name}",
+            requests={"cpu": cpu}, phase=PodPhase.PENDING,
+            annotations={GROUP_NAME_ANNOTATION: name}, creation_index=2,
+        ))
+
+    def _loop(self, cache, max_period):
+        """(scheduler, its log, the park handshake).  The log holds, in the
+        loop's order: ("cycle", end time) after each cycle, ("park", the
+        signal pending as the loop enters its wait) and (wake reason, wake
+        time) when the wait returns; every "park" releases the semaphore
+        once."""
+        sched = Scheduler(cache, conf=load_scheduler_conf(None),
+                          schedule_period=max_period)
+        sched.pipelined = True
+        sched.min_period_pinned, sched.min_period = True, 0.0
+        log, parked = [], threading.Semaphore(0)
+        cycle, wait = sched.run_once_pipelined, sched.trigger.wait_for_work
+
+        def run_once_pipelined():
+            try:
+                cycle()
+            finally:
+                log.append(("cycle", time.monotonic()))
+
+        def wait_for_work(*args):
+            log.append(("park", sched.trigger._pending))
+            parked.release()
+            reason = wait(*args)
+            log.append((reason, time.monotonic()))
+            return reason
+
+        sched.run_once_pipelined = run_once_pipelined
+        sched.trigger.wait_for_work = wait_for_work
+        return sched, log, parked
+
+    @staticmethod
+    def _run(sched, log, parked, parks, timeout=120.0):
+        """Run the loop until it has entered its wait ``parks`` times, then
+        stop it; returns the log and when stop() was called."""
+        t = threading.Thread(target=sched.run_forever, daemon=True)
+        t.start()
+        try:
+            for _ in range(parks):
+                assert parked.acquire(timeout=timeout), log
+        finally:
+            stopped_at = time.monotonic()
+            sched.stop()
+            t.join(timeout=30.0)
+        assert not t.is_alive()
+        return log, stopped_at
+
+    def test_progress_beside_pending_pods_wakes_one_more_cycle(self):
+        cache = _mk_cache()
+        self._pod(cache, "fits", 500.0)
+        self._pod(cache, "unfit", self.UNFIT_CPU)
+        sched, log, parked = self._loop(cache, max_period=5.0)
+        self_wakes0, wakes0 = _self_wakes(), _wakes("ingest", "floor")
+        log, stopped_at = self._run(sched, log, parked, parks=2)
+        # cycle 1 binds `fits` and leaves `unfit` pending -> the loop wakes
+        # itself; cycle 2 binds nothing -> it parks with nothing pending,
+        # and only stop()'s own signal ends that wait
+        assert [e[0] for e in log] == [
+            "cycle", "park", "leftover", "cycle", "park", "ingest"]
+        assert [e[1] for e in log if e[0] == "park"] == ["leftover", None]
+        assert log[2][1] - log[0][1] < 1.0, "woken at the 5 s idle tick"
+        assert log[5][1] >= stopped_at, "a third cycle before stop()"
+        assert cache.binder.binds.get("ns/fits-0") is not None
+        assert "ns/unfit-0" not in cache.binder.binds
+        assert _self_wakes() == self_wakes0 + 1
+        # nothing is left out of the series cycles are counted from: two
+        # cycles ran, and ingest + floor grew by two (the self-wake under
+        # "ingest"; stop()'s wake stands in for the start-up cycle's none)
+        assert _wakes("ingest", "floor") == wakes0 + 2
+        assert _wakes("leftover") == 0.0, "a third label"
+        # the ring's self-woken cycle says so on its park span
+        park = sched.tracer.recorder.last_record().spans[0]
+        assert (park.name, park.attrs["woke_by"]) == ("park:event", "leftover")
+
+    def test_no_progress_beside_pending_pods_wakes_nothing(self):
+        cache = _mk_cache()
+        self._pod(cache, "unfit", self.UNFIT_CPU)
+        sched, log, parked = self._loop(cache, max_period=0.05)
+        self_wakes0 = _self_wakes()
+        log, _ = self._run(sched, log, parked, parks=4)
+        # the first cycle plus one per idle tick, none self-woken
+        assert [e[1] for e in log if e[0] == "park"][:4] == [None] * 4
+        reasons = [e[0] for e in log if e[0] not in ("cycle", "park")]
+        assert reasons[:3] == ["floor"] * 3
+        assert "leftover" not in reasons
+        assert cache.binder.binds == {}
+        assert _self_wakes() == self_wakes0
+
+    def test_a_cycle_that_raises_does_not_self_wake(self):
+        cache = _mk_cache()
+        self._pod(cache, "fits", 500.0)
+        self._pod(cache, "unfit", self.UNFIT_CPU)
+        sched, log, parked = self._loop(cache, max_period=0.05)
+        cycle, state = sched.run_once_pipelined, {"n": 0}
+
+        def failing_after_its_binds():
+            cycle()
+            state["n"] += 1
+            if state["n"] == 1:
+                # the bind is acknowledged before the recovery's re-list,
+                # so no later cycle binds `fits` a second time
+                sched.drain_pipeline()
+                raise RuntimeError("planted: the cycle dies after binding")
+
+        sched.run_once_pipelined = failing_after_its_binds
+        self_wakes0 = _self_wakes()
+        log, _ = self._run(sched, log, parked, parks=3)
+        assert cache.binder.binds.get("ns/fits-0") is not None
+        # progress and pending pods, but the cycle failed: the recovery's
+        # re-list or the idle tick wakes the loop, never the loop itself;
+        # the cycles after it bind nothing
+        assert "leftover" not in [e[0] for e in log]
+        assert "leftover" not in [e[1] for e in log if e[0] == "park"]
+        assert _self_wakes() == self_wakes0
+
+    @pytest.mark.parametrize("site", ["bind", "bulk_bind"])
+    def test_both_bind_sites_count_as_progress(self, site):
+        """The tally is the cache's own, kept where the bind is made: a pod
+        whose arrival->decision clock is gone (popped by an earlier, failed
+        bind) still counts; pending pods without a bind are no reason to
+        wake, and neither is a bind with nothing pending."""
+        cache = _mk_cache()
+        self._pod(cache, "fits", 500.0)
+        self._pod(cache, "unfit", self.UNFIT_CPU)
+        cache._arrival_ts.clear()
+        before = cache.binds_total
+        assert not cache.left_schedulable_pending(before)
+        task = cache.jobs["ns/fits"].tasks["ns/fits-0"]
+        if site == "bind":
+            cache.bind(task, "n0")
+        else:
+            cache.bulk_bind([(task, "n0")])
+        cache.flush_binds()
+        assert cache.binds_total == before + 1
+        assert cache.left_schedulable_pending(before)
+        cache.delete_pod(cache.pods["ns/unfit-0"])
+        assert not cache.left_schedulable_pending(before)
 
 
 class TestBudgetShedOverlappedClose:
