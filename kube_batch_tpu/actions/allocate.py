@@ -304,13 +304,16 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
 
     sentinel_on = guard is not None and guard.enabled
     impl = None
+    demoted = False  # did a demotion pick this dispatch's program?
     if guard is not None and not guard.allow("shard_map"):
         impl = "pjit"  # shard_map demoted → the pjit oracle
+        demoted = True
     if guard is not None and not guard.allow("pallas") and config.use_pallas:
         config = config._replace(use_pallas=False)
     k = resolve_topk()
     if guard is not None and not guard.allow("topk"):
         k = 0  # compaction demoted → the full-matrix oracle
+        demoted = True
     pend_rows, k = plan_topk_bucket(snap, cols, k)
 
     def ginfo(engaged, sentinel, dev, cfg):
@@ -388,6 +391,13 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
                 "sharded", info, ginfo(engaged + ["topk"], None, dev, cfg),
             )
         dev = resident_snap(cols, snap, mesh)
+        if demoted:
+            # the full [T, N] matrix as a demotion's target: only where it
+            # holds the cluster (a cold start runs it undemoted, and the
+            # deployment is sized for that)
+            _require_full_matrix_fit(
+                "the demotion's target, the sharded full-matrix solve,",
+                dev, config, mesh, impl)
         if sentinel_on:
             res, v, h, e = sentinel_sharded_allocate_solve(
                 dev, config, mesh, impl=impl
@@ -450,6 +460,9 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
             "single", info, ginfo(engaged + ["topk"], None, dev, cfg),
         )
     dev = resident_snap(cols, snap)
+    if demoted:
+        _require_full_matrix_fit(
+            "the demotion's target, the full-matrix solve,", dev, config)
     if sentinel_on:
         from kube_batch_tpu.ops.invariants import allocate_sentinel_solve
 
@@ -457,6 +470,28 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
         return res, "single", None, ginfo(engaged, (v, h, e), dev, config)
     return (allocate_solve(dev, config), "single", None,
             ginfo(engaged, None, dev, config))
+
+
+def _require_full_matrix_fit(what, dev, config, mesh=None, impl=None):
+    """Raise :class:`guard.OracleUnfit` unless the full [T, N] allocate
+    program holds ``dev`` on one device (guard/fit.py): across ``mesh``
+    with ``impl``, or on a single device."""
+    from kube_batch_tpu.guard.fit import require_fit
+
+    if mesh is None:
+        require_fit(what, allocate_solve, dev, config)
+        return
+    from kube_batch_tpu.parallel.mesh import (
+        NODE_AXIS,
+        _impl as resolve_impl,
+        allocate_solve_fn,
+    )
+
+    require_fit(
+        what, allocate_solve_fn(mesh, config, impl=impl), dev, mesh=mesh,
+        spmd_shards=(dict(mesh.shape)[NODE_AXIS]
+                     if resolve_impl(impl) == "pjit" else 1),
+    )
 
 
 def dispatch_allocate_oracle(snap, config, cols, mode):
@@ -541,6 +576,9 @@ class AllocateAction(Action):
         self._host_place_count = 0
         self._n_applied = 0
         self._ports_by_node: Optional[Dict[int, set]] = None
+        # (mode, bucket, T, N) of the bucketed fit-error histograms this
+        # action has dispatched: each is compiled with its first solve
+        self._fit_histograms_seen: set = set()
 
     def execute(self, ssn) -> None:
         self.last_phase_ms = {}
@@ -567,7 +605,7 @@ class AllocateAction(Action):
             )
             return
 
-        from kube_batch_tpu.obs.trace import tracer_of
+        from kube_batch_tpu.obs.trace import solve_program, tracer_of
 
         tracer = tracer_of(ssn.cache)
         t0 = telemetry.perf_counter()
@@ -594,19 +632,31 @@ class AllocateAction(Action):
         # multi-chip parts shard the node axis over the ICI mesh — the
         # production analog of the reference's always-on 16-worker fan-out
         # (scheduler_helper.go:34-64); single-chip or small-N stays local
-        from kube_batch_tpu.guard import guard_of
+        from kube_batch_tpu.guard import OracleUnfit, guard_of
 
         gp = guard_of(ssn.cache)
         config = session_allocate_config(ssn)
         # device-attributed span: a retrace or an unexpected full resident
         # upload is annotated onto THIS dispatch, not smeared into a p50
-        with tracer.device_span("solve_dispatch", cols=cols) as sp_solve:
-            result, self.last_solve_mode, topk_info, ginfo = (
-                dispatch_allocate_solve(snap, config, cols=cols, guard=gp,
-                                        warm=True, tracer=tracer)
-            )
-        sp_solve.set(mode=self.last_solve_mode,
-                     engaged=list(ginfo["engaged"]))
+        try:
+            with tracer.device_span("solve_dispatch", cols=cols) as sp_solve:
+                result, self.last_solve_mode, topk_info, ginfo = (
+                    dispatch_allocate_solve(snap, config, cols=cols,
+                                            guard=gp, warm=True,
+                                            tracer=tracer)
+                )
+        except OracleUnfit as e:
+            # a demotion whose target cannot hold the cluster: no program
+            # ran, nothing below runs (no replay, no binds, no fit errors)
+            gp.fail_closed("allocate", str(e))
+            return
+        tracer.note_solve_dispatch(
+            sp_solve, "allocate", self.last_solve_mode, ginfo["engaged"],
+            program=solve_program(
+                ginfo["engaged"],
+                rebuilt=bool(((topk_info or {}).get("warm") or {}).get("cold")),
+            ),
+        )
         # shadow-oracle audit (guard tier 2): every KB_AUDIT_EVERY-th
         # dispatch re-runs the committed solve through its oracle path,
         # DISPATCHED here so the oracle re-solve overlaps the readback +
@@ -614,7 +664,8 @@ class AllocateAction(Action):
         # replay — audit cycles pay device time, never critical-path time
         audit_dev = None
         if ginfo["engaged"] and gp.audit_due("allocate"):
-            with tracer.device_span("audit_dispatch"):
+            with tracer.device_span("audit_dispatch",
+                                    mode=self.last_solve_mode):
                 audit_dev = dispatch_allocate_oracle(
                     snap, config, cols, self.last_solve_mode
                 )
@@ -691,52 +742,20 @@ class AllocateAction(Action):
         # failure cycles don't read as a replay-phase regression.
         t_fit0 = telemetry.perf_counter()
         fail_hist_dev = None
-        if bool(np.any(pending & (assigned < 0))):
-            # the compacted dispatch's [P] pending bucket covers every
-            # schedulable-pending row, and the histogram is only ever read
-            # at unplaced pending rows — so failure cycles walk [P, N]
-            # instead of [T, N] whenever a bucket exists (ROADMAP standing
-            # item: the PR 10 bucket applies to the histogram verbatim)
-            p_rows = ginfo.get("pend_rows")
-            with tracer.device_span("fit_histogram_dispatch"):
-                if self.last_solve_mode == "sharded":
-                    from kube_batch_tpu.parallel.mesh import (
-                        TASK_AXIS as _TA,
-                        default_mesh as _dm,
-                        sharded_failure_histogram,
-                        sharded_failure_histogram_bucket,
-                    )
-
-                    mesh = _dm()
-                    # the bucketed body requires a 1-D node mesh, exactly
-                    # like the compacted solve (which also declined on a
-                    # 2-D grid even though the bucket was planned)
-                    if dict(mesh.shape).get(_TA, 1) != 1:
-                        p_rows = None
-                    if p_rows is not None:
-                        fail_hist_dev = sharded_failure_histogram_bucket(
-                            resident_snap(cols, snap, mesh), p_rows, mesh
-                        )
-                    else:
-                        fail_hist_dev = sharded_failure_histogram(
-                            resident_snap(cols, snap, mesh), mesh
-                        )
-                elif p_rows is not None:
-                    from kube_batch_tpu.ops.assignment import (
-                        failure_histogram_bucket_solve,
-                    )
-
-                    fail_hist_dev = failure_histogram_bucket_solve(
-                        resident_snap(cols, snap), p_rows
-                    )
-                else:
-                    from kube_batch_tpu.ops.assignment import (
-                        failure_histogram_solve,
-                    )
-
-                    fail_hist_dev = failure_histogram_solve(
-                        resident_snap(cols, snap)
-                    )
+        p_rows = ginfo.get("pend_rows")
+        unplaced = bool(np.any(pending & (assigned < 0)))
+        # the steady path's histogram program is compiled with the first
+        # compacted solve of its shapes, not by the first cycle that leaves
+        # a pod unplaced: that cycle may come minutes into serving, and the
+        # compile (8.5 s at 150k x 5k on four chips) would stop decisions
+        # there.  Such a prewarm is dispatched and never read.
+        first = p_rows is not None and self._first_fit_histogram(snap, p_rows)
+        if unplaced or first:
+            with tracer.device_span("fit_histogram_dispatch",
+                                    prewarm=not unplaced):
+                hist_dev = self._dispatch_fit_histogram(cols, snap, p_rows)
+            if unplaced:
+                fail_hist_dev = hist_dev
         t_fit1 = telemetry.perf_counter()
         with tracer.span("host_replay"):
             self._replay(ssn, snap, meta, assigned, pipelined, task_job)
@@ -775,6 +794,62 @@ class AllocateAction(Action):
             )
 
     # ------------------------------------------------------------------
+    # fit-error histogram (the lazy [P, N] / [T, N] predicate re-walk)
+    # ------------------------------------------------------------------
+    def _first_fit_histogram(self, snap, p_rows) -> bool:
+        """True once per (mode, bucket, task and node capacity), and marks
+        it: this action has not dispatched the bucketed histogram program
+        of these shapes before."""
+        key = (self.last_solve_mode, int(p_rows.shape[0]),
+               int(snap.task_req.shape[0]), int(snap.node_alloc.shape[0]))
+        if key in self._fit_histograms_seen:
+            return False
+        self._fit_histograms_seen.add(key)
+        return True
+
+    def _dispatch_fit_histogram(self, cols, snap, p_rows):
+        """Dispatch the failure histogram of this cycle's solve; returns
+        the device array ([P, N_REASONS] over the bucket, else [T, ...]).
+
+        The compacted dispatch's [P] pending bucket covers every
+        schedulable-pending row, and the histogram is only ever read at
+        unplaced pending rows — so failure cycles walk [P, N] instead of
+        [T, N] whenever a bucket exists (ROADMAP standing item: the PR 10
+        bucket applies to the histogram verbatim)."""
+        if self.last_solve_mode == "sharded":
+            from kube_batch_tpu.parallel.mesh import (
+                TASK_AXIS as _TA,
+                default_mesh as _dm,
+                sharded_failure_histogram,
+                sharded_failure_histogram_bucket,
+            )
+
+            mesh = _dm()
+            # the bucketed body requires a 1-D node mesh, exactly like the
+            # compacted solve (which also declined on a 2-D grid even
+            # though the bucket was planned)
+            if dict(mesh.shape).get(_TA, 1) != 1:
+                p_rows = None
+            if p_rows is not None:
+                return sharded_failure_histogram_bucket(
+                    resident_snap(cols, snap, mesh), p_rows, mesh
+                )
+            return sharded_failure_histogram(
+                resident_snap(cols, snap, mesh), mesh
+            )
+        if p_rows is not None:
+            from kube_batch_tpu.ops.assignment import (
+                failure_histogram_bucket_solve,
+            )
+
+            return failure_histogram_bucket_solve(
+                resident_snap(cols, snap), p_rows
+            )
+        from kube_batch_tpu.ops.assignment import failure_histogram_solve
+
+        return failure_histogram_solve(resident_snap(cols, snap))
+
+    # ------------------------------------------------------------------
     # guard plane wiring (tiers 1 + 2)
     # ------------------------------------------------------------------
     def _consume_sentinel(self, ssn, gp, snap, config, ginfo, verdict, vhist,
@@ -796,13 +871,17 @@ class AllocateAction(Action):
         oracle (read back AFTER the host replay — the oracle re-solve ran
         overlapped with it)."""
         from kube_batch_tpu.guard import make_heal, sentinel_bundle_thunk
+        from kube_batch_tpu.obs.trace import tracer_of
 
-        # kbt: allow[KBT010] sanctioned post-replay audit readback: the
-        # oracle was dispatched before the replay precisely so this read
-        # overlaps host work instead of stalling the cycle
-        a_assigned, a_pipelined = jax.device_get(
-            (audit_dev.assigned, audit_dev.pipelined)
-        )
+        # the oracle was dispatched before the replay precisely so that
+        # this read overlaps host work instead of stalling the cycle; what
+        # of the oracle's device time the replay did not cover is this span
+        with tracer_of(ssn.cache).device_span(
+                "audit_wait", mode=self.last_solve_mode):
+            # kbt: allow[KBT010] sanctioned post-replay audit readback
+            a_assigned, a_pipelined = jax.device_get(
+                (audit_dev.assigned, audit_dev.pipelined)
+            )
         n = meta.n_tasks
         mism = int(
             np.sum(a_assigned[:n] != assigned)

@@ -126,17 +126,23 @@ class BackfillAction(Action):
         snap = snap._replace(
             job_schedulable=snap.job_schedulable & jnp.asarray(safe_np)
         )
-        from kube_batch_tpu.guard import guard_of
+        from kube_batch_tpu.guard import OracleUnfit, guard_of
         from kube_batch_tpu.obs.trace import tracer_of
 
         gp = guard_of(ssn.cache)
         tracer = tracer_of(ssn.cache)
         config = session_allocate_config(ssn)
-        with tracer.device_span("solve_dispatch", cols=cols,
-                                action="backfill"):
-            result, _mode, _topk, ginfo = dispatch_allocate_solve(
-                snap, config, cols=cols, guard=gp
-            )
+        try:
+            with tracer.device_span("solve_dispatch", cols=cols,
+                                    action="backfill") as sp_solve:
+                result, mode, _topk, ginfo = dispatch_allocate_solve(
+                    snap, config, cols=cols, guard=gp
+                )
+        except OracleUnfit as e:
+            gp.fail_closed("backfill", str(e))
+            return
+        tracer.note_solve_dispatch(sp_solve, "backfill", mode,
+                                   ginfo["engaged"])
         # this swap retired the what-if lease on donating backends — re-arm
         # it off the same (memoized) resident snapshot.  The gang-safe
         # job_schedulable mask above is probe-invisible: a probe's task

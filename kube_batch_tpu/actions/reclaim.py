@@ -164,7 +164,10 @@ def solve_claims(ssn, mode: str):
                 sentinel = (v_dev, h_dev, e_dev)
             else:
                 result = evict_solve(dev, config)
-    sp.set(engaged=list(engaged))
+    tracer.note_solve_dispatch(
+        sp, mode, "sharded" if mesh is not None else "single", engaged,
+        program="evict",
+    )
     # this swap retired the what-if lease on donating backends — re-arm it
     # off the same (memoized) resident snapshot so serving doesn't stay
     # dark until the next cycle's allocate

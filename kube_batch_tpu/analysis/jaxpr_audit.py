@@ -78,6 +78,10 @@ class EntryPoint:
         default_factory=lambda: {"*": ()})
     allow: Dict[str, str] = dataclasses.field(default_factory=dict)
     steady: bool = False
+    #: node shards of a program jitted with node-axis in/out shardings and
+    #: no shard_map (the pjit oracles): tier C charges a value that carries
+    #: the global node axis at bytes ÷ this (hbm_audit._Liveness)
+    spmd_shards: int = 1
 
 
 # --------------------------------------------------------------------------
@@ -700,9 +704,12 @@ def _build_repl_scatter(mesh, sp: Optional[ShapePoint] = None):
     )
 
 
-def sharded_registry() -> Tuple[EntryPoint, ...]:
+def sharded_registry(n_devices: Optional[int] = None
+                     ) -> Tuple[EntryPoint, ...]:
     """Entry points for the mesh-sharded solve path — empty on single-device
-    backends (no mesh to shard over).  BOTH implementations are traced:
+    backends (no mesh to shard over); over every device by default, over
+    the first ``n_devices`` where a caller asks what ONE host's chips hold
+    (the v5e-4 envelope tests).  BOTH implementations are traced:
     the shard_map bodies (the production path — KBT101-104 must cover the
     authored-collective programs) and the pjit oracle (KB_SHARD_MAP=0), so
     neither can silently regress.  On ≥4-device backends a 2-D
@@ -718,7 +725,7 @@ def sharded_registry() -> Tuple[EntryPoint, ...]:
     from kube_batch_tpu.parallel.mesh import make_mesh
 
     # _N (8) must divide the mesh for the per-shard scatter's local indexing
-    n_dev = len(jax.devices())
+    n_dev = min(n_devices or len(jax.devices()), len(jax.devices()))
     while n_dev > 1 and _N % n_dev:
         n_dev -= 1
     mesh = make_mesh(n_dev)
@@ -726,7 +733,7 @@ def sharded_registry() -> Tuple[EntryPoint, ...]:
     entries = []
     for impl in ("shard_map", "pjit"):
         tag = f"[{impl}]"
-        entries += [
+        made = [
             EntryPoint(f"parallel.mesh.sharded_allocate_solve{tag}",
                        p(_build_sharded_allocate, mesh, impl)),
             EntryPoint(f"parallel.mesh.sharded_allocate_topk_solve{tag}",
@@ -763,6 +770,10 @@ def sharded_registry() -> Tuple[EntryPoint, ...]:
                 p(_build_sharded_sentinel_evict, mesh, "preempt", impl),
                 steady=True),
         ]
+        if impl == "pjit":
+            # a pjit's intermediates carry no specs: tier C models them
+            made = [dataclasses.replace(e, spmd_shards=n_dev) for e in made]
+        entries += made
     entries += [
         EntryPoint("parallel.mesh.sharded_enqueue_gate",
                    p(_build_sharded_gate, mesh), steady=True),

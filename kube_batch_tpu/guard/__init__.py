@@ -15,7 +15,9 @@ Three tiers, wired through every dispatching action:
    full upload), and dumps a self-contained diagnostics bundle
    (guard/bundle.py) that ``python -m kube_batch_tpu.sim --replay-bundle``
    reloads for deterministic offline triage; half-open probes re-promote
-   after KB_GUARD_COOLDOWN clean cycles.
+   after KB_GUARD_COOLDOWN clean cycles.  A demotion whose target does not
+   fit the device (guard/fit.py: the full [T, N] matrix of a cluster sized
+   for the compacted path) fails closed instead of landing on it.
 
 Knobs: ``KB_GUARD=0`` (escape hatch — no sentinel, no audits, no
 demotion), ``KB_AUDIT_EVERY`` (default 64; 0 = audits off),
@@ -23,6 +25,7 @@ demotion), ``KB_AUDIT_EVERY`` (default 64; 0 = audits off),
 ``KB_GUARD_DIR`` (diagnostics bundle directory).
 """
 
+from kube_batch_tpu.guard.fit import OracleUnfit
 from kube_batch_tpu.guard.plane import (
     FAST_PATHS,
     GuardPlane,
@@ -34,6 +37,6 @@ from kube_batch_tpu.guard.plane import (
 )
 
 __all__ = [
-    "FAST_PATHS", "GuardPlane", "consume_assignment_sentinel",
+    "FAST_PATHS", "GuardPlane", "OracleUnfit", "consume_assignment_sentinel",
     "consume_sentinel", "guard_of", "make_heal", "sentinel_bundle_thunk",
 ]

@@ -189,6 +189,20 @@ class GuardPlane:
         self.trip(action, engaged, reason="audit", detail=detail,
                   dump=dump, heal=heal)
 
+    def fail_closed(self, action: str, detail: str) -> None:
+        """A demotion whose target does not fit the device (guard/fit.py):
+        the action discards the cycle's solve — no program runs, nothing
+        binds — and says so.  No path changes state: the breaker's clock
+        runs on, and its half-open probe lets the fast path try again."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self.failed_closed += 1
+        metrics.register_guard_trip(action, "unfit")
+        logger.error(
+            "guard plane (%s): %s — failing closed: no solve, no binds "
+            "this cycle", action, detail)
+
     def trip(self, action: str, engaged: Sequence[str], reason: str,
              detail: str = "", hist=None,
              dump: Optional[Callable[[], str]] = None,

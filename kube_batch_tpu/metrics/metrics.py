@@ -322,7 +322,7 @@ QUEUE_ENTITLEMENT = Gauge(
 GUARD_TRIPS = Counter(
     f"{_SUBSYSTEM}_guard_trips_total",
     "Result-integrity trips (condemned solves), by action and reason "
-    "(invariant|audit)",
+    "(invariant|audit|unfit: a demotion's target does not fit the device)",
     ("action", "reason"),
 )
 GUARD_AUDITS = Counter(
@@ -411,6 +411,20 @@ JIT_COMPILES = Counter(
     f"{_SUBSYSTEM}_jit_compiles_total",
     "Backend compiles JAX reported (persistent-cache hits included)",
 )
+# which solve program a dispatch ran and where: the counter the benchmark
+# reads where GET /v1/trace only tallies the flight recorder's ring
+SOLVE_DISPATCHES = Counter(
+    f"{_SUBSYSTEM}_solve_dispatches_total",
+    "Device solve dispatches, by action, mode (single|sharded) and program "
+    "(cold: full matrix | topk: compacted, table built this solve | "
+    "warm: compacted, table carried | evict)",
+    ("action", "mode", "program"),
+)
+DEVICE_PEAK_BYTES = Gauge(
+    f"{_SUBSYSTEM}_device_peak_bytes",
+    "peak_bytes_in_use of each local device, refreshed at most once a cycle",
+    ("device",),
+)
 # a sound window reads 0 from these, not "no such series"
 SELF_WAKES.add(0.0)
 DECISIONS_LEFTOVER.add(0.0)
@@ -470,6 +484,8 @@ METRICS = [
     WHATIF_QUEUE_WAIT,
     JIT_COMPILE_SECONDS,
     JIT_COMPILES,
+    SOLVE_DISPATCHES,
+    DEVICE_PEAK_BYTES,
 ]
 
 
@@ -678,6 +694,25 @@ def register_jit_compile(phase: str, seconds: float) -> None:
     JIT_COMPILE_SECONDS.add(seconds, phase)
     if phase == "backend":
         JIT_COMPILES.inc()
+
+
+def register_solve_dispatch(action: str, mode: str, program: str) -> None:
+    SOLVE_DISPATCHES.inc(action, mode, program)
+
+
+def refresh_device_peak_bytes() -> None:
+    """Read every local device's ``peak_bytes_in_use`` into the gauge.  The
+    scheduling loop calls this once a cycle; nothing does per scrape (the
+    benchmark's decision channel reads /metrics every few milliseconds).
+    A backend that reports no memory statistics (the CPU's) leaves no
+    series."""
+    import jax
+
+    for device in jax.local_devices():
+        stats = device.memory_stats()
+        if stats and "peak_bytes_in_use" in stats:
+            DEVICE_PEAK_BYTES.set(
+                float(stats["peak_bytes_in_use"]), str(device.id))
 
 
 def register_trigger_wake(trigger: str) -> None:

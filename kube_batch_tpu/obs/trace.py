@@ -514,6 +514,19 @@ class Tracer:
         return Span(self, name, attrs=attrs or None, keep=_KEEP_NONE)
 
     # -- cycle annotations -------------------------------------------------
+    def note_solve_dispatch(self, span: Span, action: str, mode: str,
+                            engaged, program: Optional[str] = None) -> None:
+        """Say which program a ``solve_dispatch`` span ran (``mode``,
+        ``engaged``, ``program``) and count it on ``/metrics``
+        (``volcano_solve_dispatches_total``), from the same values: the
+        span's attributes and the counter cannot drift apart.  ``program``
+        defaults to :func:`solve_program` of ``engaged``.  The counter
+        moves with ``KB_TRACE=0`` too, like every metric a span feeds."""
+        if program is None:
+            program = solve_program(engaged)
+        span.set(mode=mode, engaged=list(engaged), program=program)
+        metrics.register_solve_dispatch(action, mode, program)
+
     def note_cycle_attr(self, key: str, value) -> None:
         if not self.enabled:
             return
@@ -607,13 +620,18 @@ class Tracer:
         """Tally of the allocate solve dispatches still in the ring, keyed
         ``mode[+engaged path...]`` ("single", "sharded+shard_map+topk+warm")
         — which program has been running.  ``last_cycle`` alone loses that
-        to the next idle tick, a moment after the cycle that solved."""
+        to the next idle tick, a moment after the cycle that solved.
+        Shadowed by ``volcano_solve_dispatches_total`` on ``/metrics``
+        (:meth:`note_solve_dispatch`), which counts every dispatch since
+        the start and not only those the ring still holds; kept for the
+        readers that ask which program is running NOW."""
         tally: Dict[str, int] = {}
 
         def walk(spans) -> None:
             for sp in list(spans):
                 attrs = sp.attrs or {}
-                if sp.name == "solve_dispatch" and "mode" in attrs:
+                if (sp.name == "solve_dispatch" and "mode" in attrs
+                        and attrs.get("action", "allocate") == "allocate"):
                     key = "+".join([attrs["mode"], *attrs.get("engaged", ())])
                     tally[key] = tally.get(key, 0) + 1
                 walk(sp.children)
@@ -634,6 +652,17 @@ class Tracer:
                 "stages": dict(sorted(self.span_counts.items())),
                 "retraces_attributed": self.retraces_attributed,
             }
+
+
+def solve_program(engaged, rebuilt: bool = False) -> str:
+    """The program label of an allocate-shaped dispatch, from the fast
+    paths it engaged: ``warm`` (the compacted solve over the candidate
+    table carried across cycles), ``topk`` (the compacted solve that built
+    its table this solve: the per-solve build, or a warm plan that
+    ``rebuilt`` every row), ``cold`` (the full [T, N] matrix)."""
+    if "warm" in engaged and not rebuilt:
+        return "warm"
+    return "topk" if "topk" in engaged else "cold"
 
 
 # --------------------------------------------------------------------------

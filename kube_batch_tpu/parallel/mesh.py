@@ -250,7 +250,10 @@ def sharded_allocate_solve(
 
 
 def _solve(snap: DeviceSnapshot, config: AllocateConfig) -> AllocateResult:
-    return allocate_solve(snap, config)
+    # the pjit body: the guard's shadow oracle and shard_map's demotion
+    # target; named so that a device trace tells its ops from the fast path's
+    with jax.named_scope("pjit_oracle"):
+        return allocate_solve(snap, config)
 
 
 def allocate_topk_solve_fn(mesh: Mesh, config: AllocateConfig,

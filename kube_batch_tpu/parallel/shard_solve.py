@@ -88,7 +88,8 @@ def _gather_tasks(x, task_shards):
     axis is unsharded)."""
     if task_shards == 1:
         return x
-    return jax.lax.all_gather(x, TASK_AXIS, axis=0, tiled=True)
+    with jax.named_scope("xchip_gather_tasks"):
+        return jax.lax.all_gather(x, TASK_AXIS, axis=0, tiled=True)
 
 
 def _gather_nodes(x, node_shards):
@@ -96,7 +97,8 @@ def _gather_nodes(x, node_shards):
     the replicated global [N, ...] array the solve tail consumes."""
     if node_shards == 1:
         return x
-    return jax.lax.all_gather(x, NODE_AXIS, axis=0, tiled=True)
+    with jax.named_scope("xchip_gather_nodes"):
+        return jax.lax.all_gather(x, NODE_AXIS, axis=0, tiled=True)
 
 
 def _block_view(snap, t0, T_blk, task_shards):
@@ -169,9 +171,10 @@ def _combine_best(lval, lkey, lidx, lextra=None):
     parts = [vkey, lkey, lidx]
     if lextra is not None:
         parts.append(lextra)
-    g = jax.lax.all_gather(
-        jnp.stack(parts, axis=0), NODE_AXIS, axis=0, tiled=False
-    )                                                  # [S, 3|4, T]
+    with jax.named_scope("xchip_argmax"):
+        g = jax.lax.all_gather(
+            jnp.stack(parts, axis=0), NODE_AXIS, axis=0, tiled=False
+        )                                              # [S, 3|4, T]
     gv, gk, gi = g[:, 0], g[:, 1], g[:, 2]
     vmax_k = jnp.max(gv, axis=0)
     # the key map is a bijection, so the max key's preimage IS the max value
@@ -311,7 +314,8 @@ def _allocate_topk_body(snap, pend_rows, *, config, node_shards):
     payload = jnp.concatenate(
         [ks, kh, ki, n_feas_l[:, None]], axis=1
     )                                                  # [P, 3K+1] i32
-    g = jax.lax.all_gather(payload, NODE_AXIS, axis=0, tiled=False)
+    with jax.named_scope("xchip_topk_merge"):
+        g = jax.lax.all_gather(payload, NODE_AXIS, axis=0, tiled=False)
     # shard-major concat: positions ascend with the global node index, so
     # the merge's first-position tie rule keeps jnp.argmax semantics
     skeys = jnp.transpose(g[:, :, 0:K], (1, 0, 2)).reshape(P_rows, -1)
@@ -332,7 +336,8 @@ def _allocate_topk_body(snap, pend_rows, *, config, node_shards):
     def _gn1(x):  # [K?, N_loc] sharded along axis 1
         if node_shards == 1:
             return x
-        return jax.lax.all_gather(x, NODE_AXIS, axis=1, tiled=True)
+        with jax.named_scope("xchip_gather_nodes"):
+            return jax.lax.all_gather(x, NODE_AXIS, axis=1, tiled=True)
 
     snap_repl = snap._replace(
         node_idle=idle0, node_releasing=rel0, node_used=used0,
@@ -442,9 +447,10 @@ def _warm_allocate_body(snap, pend_rows, t_idx, t_skey, t_hash, t_trunc,
     own = (changed_nodes >= 0) & (loc >= 0) & (loc < N_loc)
     view_lc = _asg.node_view(view_lm, jnp.where(own, loc, -1))
     skey_part = _asg.fresh_block_skey(view_lc, quanta, config)
-    skey_c = jax.lax.psum(
-        jnp.where(own[None, :], skey_part, 0), NODE_AXIS
-    )
+    with jax.named_scope("xchip_changed_keys"):
+        skey_c = jax.lax.psum(
+            jnp.where(own[None, :], skey_part, 0), NODE_AXIS
+        )
     skey_c = jnp.where(
         (changed_nodes >= 0)[None, :], skey_c, _asg._I32_MIN
     )
@@ -460,7 +466,8 @@ def _warm_allocate_body(snap, pend_rows, t_idx, t_skey, t_hash, t_trunc,
     )
     Pi = rerank_rows.shape[0]
     payload = jnp.concatenate([ks, kh, ki, nf_l[:, None]], axis=1)
-    g = jax.lax.all_gather(payload, NODE_AXIS, axis=0, tiled=False)
+    with jax.named_scope("xchip_topk_merge"):
+        g = jax.lax.all_gather(payload, NODE_AXIS, axis=0, tiled=False)
     skeys = jnp.transpose(g[:, :, 0:W], (1, 0, 2)).reshape(Pi, -1)
     hashes = jnp.transpose(g[:, :, W:2 * W], (1, 0, 2)).reshape(Pi, -1)
     idxs = jnp.transpose(g[:, :, 2 * W:3 * W], (1, 0, 2)).reshape(Pi, -1)
@@ -482,7 +489,8 @@ def _warm_allocate_body(snap, pend_rows, t_idx, t_skey, t_hash, t_trunc,
     def _gn1(x):
         if node_shards == 1:
             return x
-        return jax.lax.all_gather(x, NODE_AXIS, axis=1, tiled=True)
+        with jax.named_scope("xchip_gather_nodes"):
+            return jax.lax.all_gather(x, NODE_AXIS, axis=1, tiled=True)
 
     snap_repl = snap._replace(
         node_idle=idle0, node_releasing=rel0, node_used=used0,
@@ -619,7 +627,8 @@ def _evict_body(snap, *, config, node_shards, task_shards):
             fits(view.task_req, snap.node_idle, snap.quanta) & static_ok,
             axis=1,
         )
-        any_g = jax.lax.psum(any_l.astype(jnp.int32), NODE_AXIS) > 0
+        with jax.named_scope("xchip_any_bid"):
+            any_g = jax.lax.psum(any_l.astype(jnp.int32), NODE_AXIS) > 0
         fia = _gather_tasks(any_g, task_shards)
     return evi.evict_rounds(snap, config, bids, fia, n_nodes=N)
 
@@ -647,7 +656,8 @@ def _histogram_body(snap, *, node_shards, task_shards):
     )
     # every histogram column is an integer count over nodes — one exact
     # O(T × N_REASONS) psum reduces the per-shard partial counts
-    h = jax.lax.psum(h, NODE_AXIS)
+    with jax.named_scope("xchip_histogram"):
+        h = jax.lax.psum(h, NODE_AXIS)
     return _gather_tasks(h, task_shards)
 
 
@@ -670,7 +680,8 @@ def _histogram_bucket_body(snap, pend_rows, *, node_shards):
         FeasibilityMasks(static_ok, fit_i, fit_r,
                          static_ok & (fit_i | fit_r)),
     )
-    h = jax.lax.psum(h, NODE_AXIS)
+    with jax.named_scope("xchip_histogram"):
+        h = jax.lax.psum(h, NODE_AXIS)
     scat = jnp.where(pend_rows >= 0, pend_rows, T)
     return jnp.zeros((T + 1, N_REASONS), jnp.int32).at[scat].set(h)[:T]
 
@@ -773,7 +784,8 @@ def _probe_body(snap, batch, probe_rows, *, config, evict_config,
     used_l = jnp.sum(
         jnp.where(snap.node_valid[:, None], snap.node_used, 0.0), axis=0
     )
-    used = jax.lax.psum(used_l, NODE_AXIS)
+    with jax.named_scope("xchip_used_sum"):
+        used = jax.lax.psum(used_l, NODE_AXIS)
     oc_idle = jnp.maximum(snap.total * prb.OVERCOMMIT_FACTOR - used, 0.0)
 
     def one(g):
@@ -834,7 +846,8 @@ def _probe_body(snap, batch, probe_rows, *, config, evict_config,
             # every histogram column is an integer count over nodes — one
             # exact psum reduces the per-shard partials (same argument as
             # the sharded failure-histogram solve)
-            return jax.lax.psum(h, NODE_AXIS)
+            with jax.named_scope("xchip_histogram"):
+                return jax.lax.psum(h, NODE_AXIS)
 
         return prb.probe_gang_core(
             snap, view, g, config, evict_config, with_evictions,

@@ -389,6 +389,8 @@ class Scheduler:
             guard.end_cycle()
             # trip-rate SLO alerting rides the same deterministic clock
             alerts_of(self.cache).evaluate(guard)
+        # how full each device has been: once a cycle, never per scrape
+        metrics.refresh_device_peak_bytes()
         if self.on_cycle_end is not None:
             self.on_cycle_end()
 

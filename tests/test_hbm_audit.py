@@ -105,6 +105,84 @@ class TestSelfEnforcement:
                 f"cross-reference: {key}"
 
 
+class TestEnvelope:
+    """Kubernetes' published envelope (150,000 pods on 5,000 nodes:
+    ``benchmark/configs/k8s-envelope-150k-5k.json``) on the four chips of
+    one v5e-4 host: what one device holds of each program there."""
+
+    FOUR_CHIPS = [
+        "parallel.mesh.sharded_allocate_solve[shard_map]",
+        "parallel.mesh.sentinel_sharded_allocate_solve[shard_map]",
+        "parallel.mesh.sharded_allocate_topk_solve[shard_map]",
+        "parallel.mesh.sentinel_sharded_allocate_topk_solve[shard_map]",
+        "parallel.mesh.sharded_warm_allocate_solve[shard_map]",
+        "parallel.mesh.sentinel_sharded_warm_allocate_solve[shard_map]",
+        "parallel.mesh.sharded_evict_solve[reclaim][shard_map]",
+        "parallel.mesh.sharded_evict_solve[preempt][shard_map]",
+        "parallel.mesh.sharded_failure_histogram[shard_map]",
+        # the guard's shadow oracle and shard_map's demotion target
+        "parallel.mesh.sharded_allocate_solve[pjit]",
+        "parallel.mesh.sharded_evict_solve[reclaim][pjit]",
+    ]
+    ONE_CHIP_OVER = [
+        "ops.assignment.allocate_solve",
+        "ops.invariants.allocate_sentinel_solve",
+        "ops.assignment.failure_histogram_solve",
+        "ops.eviction.evict_solve[reclaim]",
+        "ops.eviction.evict_solve[preempt]",
+    ]
+
+    @pytest.fixture(scope="class")
+    def point(self):
+        return next(sp for sp in shape_points() if sp.name == "envelope-150k")
+
+    @pytest.fixture(scope="class")
+    def reports(self, point):
+        assert len(jax.devices()) >= 4, "conftest's forced mesh missing"
+        return {e.name: audit_entry_at(e, point)
+                for e in tuple(REGISTRY) + sharded_registry(4)}
+
+    def test_the_point_is_the_documented_limit(self, point):
+        assert (point.tasks, point.nodes) == (150_000, 5_000)
+        assert (point.T, point.N) == (150_528, 5_120)
+
+    @pytest.mark.parametrize("name", FOUR_CHIPS)
+    def test_fits_one_of_four_devices(self, reports, name):
+        rep = reports[name]
+        assert rep.traced and "KBT201" not in _rules(rep), rep.findings
+        assert 0 < rep.peak_bytes <= 8 * GIB   # half a v5e, by this count
+
+    @pytest.mark.parametrize("name", ONE_CHIP_OVER)
+    def test_does_not_fit_one_device_and_says_why(self, reports, name):
+        rep = reports[name]
+        assert "KBT201" in _rules(rep) and rep.peak_bytes > 16 * GIB
+        key = next(k for k in HBM_ALLOWLIST
+                   if k[1:] == ("KBT201", "envelope-150k")
+                   and _glob_match(name, k[0]))
+        assert "this deployment lives on 4 chips" in HBM_ALLOWLIST[key]
+
+    def test_the_steady_path_fits_either_way(self, reports):
+        for name in ("ops.assignment.allocate_topk_solve",
+                     "ops.assignment.warm_allocate_solve",
+                     "ops.invariants.warm_allocate_sentinel_solve"):
+            assert "KBT201" not in _rules(reports[name]), name
+
+    def test_the_oracle_is_charged_by_its_node_shards(self, point):
+        """A pjit's intermediates carry no specs: a value with the global
+        node axis is charged at bytes / node shards, and left alone where
+        the entry declares none."""
+        oracle = next(e for e in sharded_registry(4)
+                      if e.name == "parallel.mesh.sharded_allocate_solve[pjit]")
+        assert oracle.spmd_shards == 4
+        import dataclasses
+
+        whole = audit_entry_at(dataclasses.replace(oracle, spmd_shards=1),
+                               point)
+        sharded = audit_entry_at(oracle, point)
+        assert whole.peak_bytes > 16 * GIB and "KBT201" in _rules(whole)
+        assert sharded.peak_bytes < whole.peak_bytes / 3.5
+
+
 class TestPlantedBugs:
     def test_planted_over_budget_program_is_detected(self):
         rep = audit_entry_at(
