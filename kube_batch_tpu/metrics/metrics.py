@@ -296,6 +296,26 @@ SELF_WAKES = Counter(
     "pods and left schedulable ones pending (each also counted on "
     "cycle_trigger_wakes_total under trigger=ingest)",
 )
+# the trigger's settle hold (CycleTrigger._settle): how often an ingest
+# wake waited for the rest of its burst, what ended the wait, how many
+# signals one cycle then took (signals / holds = the coalescing factor),
+# and what the waiting cost
+SETTLE_HOLDS = Counter(
+    f"{_SUBSYSTEM}_cycle_settle_holds_total",
+    "Ingest wakes held for the rest of their burst before the cycle "
+    "started, by what ended the hold (quiet: no further signal for the "
+    "quiet gap | cap: the bound from the first signal | stop: shutdown)",
+    ("ended_by",),
+)
+SETTLE_SIGNALS = Counter(
+    f"{_SUBSYSTEM}_cycle_settle_signals_total",
+    "Ingest signals folded into held wakes (over settle_holds_total: "
+    "signals a deciding cycle took at once)",
+)
+SETTLE_HELD = Summary(
+    f"{_SUBSYSTEM}_cycle_settle_milliseconds",
+    "Time ingest wakes were held for the rest of their burst (ms)",
+)
 PIPELINE_OVERLAP = Histogram(
     f"{_SUBSYSTEM}_pipeline_writeback_overlap_milliseconds",
     "Writeback-stage time overlapped behind the next cycle (ms)",
@@ -427,6 +447,9 @@ DEVICE_PEAK_BYTES = Gauge(
 )
 # a sound window reads 0 from these, not "no such series"
 SELF_WAKES.add(0.0)
+SETTLE_SIGNALS.add(0.0)
+for _ended_by in ("quiet", "cap"):
+    SETTLE_HOLDS.add(0.0, _ended_by)
 DECISIONS_LEFTOVER.add(0.0)
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
@@ -463,6 +486,9 @@ METRICS = [
     DECISION_LATENCY,
     TRIGGER_WAKES,
     SELF_WAKES,
+    SETTLE_HOLDS,
+    SETTLE_SIGNALS,
+    SETTLE_HELD,
     PIPELINE_OVERLAP,
     STAGED_INGEST,
     QUEUE_SHARE,
@@ -724,6 +750,14 @@ def register_trigger_wake(trigger: str) -> None:
         SELF_WAKES.inc()
         trigger = "ingest"
     TRIGGER_WAKES.inc(trigger)
+
+
+def register_settle_hold(ended_by: str, signals: int, held_ms: float) -> None:
+    """One settle hold of the trigger: what ended it, the ingest signals
+    its wake had gathered by then, and how long the loop was held."""
+    SETTLE_HOLDS.inc(ended_by)
+    SETTLE_SIGNALS.add(signals)
+    SETTLE_HELD.observe_many(held_ms, 1)
 
 
 def observe_pipeline_overlap(ms: float) -> None:

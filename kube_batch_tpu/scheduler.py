@@ -19,12 +19,17 @@ condition variable, so an arrival burst schedules immediately instead of
 waiting out the reference's fixed 1 s tick, while an idle cluster ticks at
 the slow floor; a cycle that bound pods and left schedulable ones pending
 raises the trigger itself, so the next cycle starts as soon as the rate
-floor allows.  Knobs: ``KB_PIPELINE=0`` restores the serial
-wait.Until loop (the bit-exactness oracle), ``KB_PERIOD_MIN`` pins the
-minimum spacing between cycle starts (rate floor for bursts; unset, the
-floor ADAPTS to an EWMA of the cycle's own measured cost — see
-:meth:`Scheduler._note_cycle_cost`), ``KB_PERIOD_MAX`` the idle tick
-period (default: the schedule period)."""
+floor allows.  A burst is several requests a few milliseconds apart, and
+the first wakes the parked loop: the trigger therefore lets the burst
+finish arriving (a settle hold of at most an eighth of a cycle's cost
+without a further signal, half a cycle's in all) before it starts the ONE
+cycle that decides it, where the first request's cycle used to bind
+nothing and make the rest wait for a second.  Knobs: ``KB_PIPELINE=0``
+restores the serial wait.Until loop (the bit-exactness oracle),
+``KB_PERIOD_MIN`` pins the minimum spacing between cycle starts (rate
+floor for bursts; unset, the floor ADAPTS to an EWMA of the cycle's own
+measured cost — see :meth:`Scheduler._note_cycle_cost`), ``KB_PERIOD_MAX``
+the idle tick period (default: the schedule period)."""
 
 from __future__ import annotations
 
@@ -65,6 +70,19 @@ class CycleTrigger:
     the loop for them before the idle tick.  Such a wake reports
     ``"leftover"`` unless an ingest signal came beside it, in either order.
 
+    An ingest wake SETTLES before its cycle starts (:meth:`_settle`): a
+    burst is a handful of requests milliseconds apart, each of which
+    signals, and a cycle started on the first drains that one alone, binds
+    nothing, and makes the others wait for it, the floor and a second
+    cycle.  So the loop starts the cycle once no further ingest signal has
+    come for a quiet gap, or once a cap has passed since the first
+    unconsumed signal, whichever is first; every signal that lands
+    meanwhile is folded into the same wake.  Both bounds are the caller's
+    (:meth:`Scheduler.settle_window`: shares of the measured cycle cost);
+    a signal already older than either when the floor ends holds nothing,
+    and ``"leftover"`` and ``"floor"`` wakes and :meth:`Scheduler.stop`
+    never hold.  ``poll()`` (the sim) knows no hold.
+
     Deadline arithmetic reads the INJECTED clock (the Scheduler's clock
     seam) so tests can pace it; the blocking itself is the condition
     variable's (real-time) wait, re-armed against the injected deadline
@@ -73,7 +91,8 @@ class CycleTrigger:
     The parked time is the loop's own: with a ``tracer`` the two phases of
     :meth:`wait_for_work` are root spans on the loop thread, ``park:floor``
     (the rate floor) and ``park:event`` (nothing pending, until a signal or
-    the idle tick), kept on the record of the cycle they precede."""
+    the idle tick, then the settle hold as its child span ``settle``),
+    kept on the record of the cycle they precede."""
 
     def __init__(self, clock=None, tracer=None):
         self.clock = clock if clock is not None else time
@@ -88,36 +107,64 @@ class CycleTrigger:
         self._pending: Optional[str] = None
         # when the first notify() not yet consumed came (injected clock)
         self._signalled_at = 0.0
+        # the ingest signals not yet consumed: how many, when the last one
+        # came, and the widest gap between two of them (what the settle
+        # hold's quiet gap has to outlast to keep a burst in one cycle)
+        self._signals = 0
+        self._last_signal_at = 0.0
+        self._widest_gap = 0.0
+        # stop() asked for this wake: it is never held
+        self._stopping = False
 
-    def notify(self, leftover: bool = False) -> None:
+    def notify(self, leftover: bool = False, stop: bool = False) -> None:
         """Wake the loop (never blocks; safe from any thread, including
         under the cache's locks — the condition guard is a leaf).
         ``leftover`` marks the loop's own wake for what its last cycle left
-        pending; an ingest signal beside it wins the wake reason."""
+        pending; an ingest signal beside it wins the wake reason.  ``stop``
+        marks the wake of a loop that is shutting down, which the settle
+        hold lets through at once."""
         with self._cond:
+            now = self.clock.monotonic()
             if self._pending is None:
-                self._signalled_at = self.clock.monotonic()
+                self._signalled_at = now
                 self._pending = "leftover" if leftover else "ingest"
             elif not leftover:
                 self._pending = "ingest"
+            if not leftover:
+                if self._signals:
+                    self._widest_gap = max(self._widest_gap,
+                                           now - self._last_signal_at)
+                self._signals += 1
+                self._last_signal_at = now
+            if stop:
+                self._stopping = True
             self._cond.notify_all()
 
     def poll(self) -> bool:
         """Consume a pending signal without waiting (the sim's virtual-time
         pacing asks 'would the trigger fire now?' instead of blocking)."""
         with self._cond:
-            pending, self._pending = self._pending, None
-            return pending is not None
+            return self._take() is not None
+
+    def _take(self) -> Optional[str]:
+        """Consume the pending signal (the caller holds the guard)."""
+        reason, self._pending = self._pending, None
+        self._signals = 0
+        self._widest_gap = 0.0
+        self._stopping = False
+        return reason
 
     def wait_for_work(self, cycle_start: float, min_period: float,
-                      max_period: float) -> str:
+                      max_period: float, settle=None) -> str:
         """Block until the next cycle should start; returns the wake reason
         (``"ingest"`` — signalled arrival churn; ``"leftover"`` — only the
         loop's own signal, for what its last cycle left pending;
         ``"floor"`` — the idle period elapsed).  The rate floor is enforced
         first: bursts coalesce into one cycle per ``min_period``, so neither
         a hot ingest stream nor a chain of self-wakes can busy-spin the
-        solve."""
+        solve.  ``settle`` is the ``(quiet gap, cap)`` in seconds of the
+        hold an ingest wake pays for the rest of its burst (``None``: no
+        hold)."""
         clock = self.clock
         floor_sp = None
         floor_rem = min_period - (clock.monotonic() - cycle_start)
@@ -125,14 +172,22 @@ class CycleTrigger:
             with self._parked("park:floor") as floor_sp:
                 clock.sleep(floor_rem)
         with self._parked("park:event") as event_sp:
-            reason, signalled_ms = self._await_signal(
-                cycle_start + max_period)
+            if not self._await_signal(cycle_start + max_period):
+                reason, signalled_ms = "floor", 0.0
+            else:
+                if settle is not None:
+                    self._settle(*settle)
+                with self._cond:
+                    signalled_ms = (
+                        clock.monotonic() - self._signalled_at) * 1e3
+                    reason = self._take()
         if floor_sp is not None:
             floor_sp.set(woke_by=reason)
         if event_sp is not None:
             # signalled_ms: how long the wake had been asked for when the
             # cycle started — the rest of the last cycle and the floor, for
-            # a signal that came mid-cycle
+            # a signal that came mid-cycle; the settle hold, for a burst's
+            # first
             event_sp.set(woke_by=reason, signalled_ms=round(signalled_ms, 3))
         return reason
 
@@ -144,17 +199,64 @@ class CycleTrigger:
         return tracer.park_span(
             name, before_cycle=tracer.next_cycle_number())
 
-    def _await_signal(self, deadline: float):
-        """(wake reason, ms the consumed signal had been pending)."""
+    def _await_signal(self, deadline: float) -> bool:
+        """Park until a signal is pending (True; not consumed: only this
+        thread consumes) or ``deadline`` has passed with none (False)."""
         clock = self.clock
         with self._cond:
             while self._pending is None:
                 rem = deadline - clock.monotonic()
                 if rem <= 0:
-                    return "floor", 0.0
+                    return False
                 self._cond.wait(rem)
-            reason, self._pending = self._pending, None
-            return reason, (clock.monotonic() - self._signalled_at) * 1e3
+            return True
+
+    def _settle_due(self, quiet: float, cap: float):
+        """(when the pending wake's hold ends, what ends it), or None for a
+        wake that is never held (the caller holds the guard)."""
+        if self._pending != "ingest" or self._stopping:
+            return None
+        quiet_at = self._last_signal_at + quiet
+        cap_at = self._signalled_at + cap
+        return (quiet_at, "quiet") if quiet_at <= cap_at else (cap_at, "cap")
+
+    def _settle(self, quiet: float, cap: float) -> None:
+        """The settle hold of a pending ingest wake: wait, on the same
+        condition variable and against the injected clock like
+        :meth:`_await_signal`, until ``quiet`` seconds have passed since
+        the last ingest signal or ``cap`` since the first unconsumed one.
+        Each further signal restarts the quiet gap and is folded into this
+        wake; the cap bounds the hold under a continuous stream.  A wake
+        whose hold is already over when it is looked at (a signal that came
+        mid-cycle and waited out the floor) pays nothing: no span, no
+        count."""
+        clock = self.clock
+        with self._cond:
+            due = self._settle_due(quiet, cap)
+            if due is None or due[0] <= clock.monotonic():
+                return
+        tracer = self.tracer
+        span = (tracer.span("settle", q_ms=round(quiet * 1e3, 3))
+                if tracer is not None else contextlib.nullcontext())
+        held_from = clock.monotonic()
+        with span as sp:
+            with self._cond:
+                while True:
+                    due = self._settle_due(quiet, cap)
+                    if due is None:
+                        ended_by = "stop"
+                        break
+                    end, ended_by = due
+                    rem = end - clock.monotonic()
+                    if rem <= 0:
+                        break
+                    self._cond.wait(rem)
+                signals, widest = self._signals, self._widest_gap
+            if sp is not None:
+                sp.set(signals=signals, ended_by=ended_by,
+                       widest_gap_ms=round(widest * 1e3, 3))
+        metrics.register_settle_hold(
+            ended_by, signals, (clock.monotonic() - held_from) * 1e3)
 
 
 class Scheduler:
@@ -450,6 +552,30 @@ class Scheduler:
                 self.max_period,
             )
 
+    # the settle hold's two bounds (CycleTrigger._settle), as shares of the
+    # same EWMA: the hold risks the quiet gap to save a whole cycle, so it
+    # asks for an eighth of one, within clamps that keep a burst's requests
+    # (a few ms apart on the wire) inside it and a lone event's price small;
+    # the cap, from the burst's first signal, is half a cycle and at most
+    # 100 ms, so a continuous stream still gets a cycle that often
+    SETTLE_QUIET_SHARE = 1 / 8
+    SETTLE_QUIET_MIN = 0.005
+    SETTLE_QUIET_MAX = 0.025
+    SETTLE_CAP_SHARE = 1 / 2
+    SETTLE_CAP_MAX = 0.1
+
+    def settle_window(self):
+        """The ``(quiet gap, cap)`` in seconds an ingest wake may be held
+        for the rest of its burst, from the measured cycle cost (the EWMA
+        is fed whether or not KB_PERIOD_MIN pins the floor); None, no
+        hold, until a cycle has been measured."""
+        ewma = self.cycle_cost_ewma
+        if ewma is None:
+            return None
+        quiet = min(max(ewma * self.SETTLE_QUIET_SHARE,
+                        self.SETTLE_QUIET_MIN), self.SETTLE_QUIET_MAX)
+        return quiet, min(ewma * self.SETTLE_CAP_SHARE, self.SETTLE_CAP_MAX)
+
     def drain_pipeline(self) -> None:
         """Join the in-flight writeback stage and apply any still-staged
         ingest — the deterministic post-cycle state the serial run_once
@@ -554,7 +680,8 @@ class Scheduler:
                     logger.exception("scheduling cycle failed")
                     self._recover_failed_cycle()
                 reason = self.trigger.wait_for_work(
-                    tick, self.min_period, self.max_period
+                    tick, self.min_period, self.max_period,
+                    self.settle_window(),
                 )
                 metrics.register_trigger_wake(reason)
         finally:
@@ -576,7 +703,7 @@ class Scheduler:
     def stop(self) -> None:
         self._stop = True
         # a stopping pipelined loop may be idling at the slow floor — wake it
-        self.trigger.notify()
+        self.trigger.notify(stop=True)
 
     def close(self) -> None:
         """Retire the pipelined writeback pool with a bounded drain.

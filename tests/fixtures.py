@@ -4,6 +4,7 @@ fake-backend assembly."""
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, Optional
 
 from kube_batch_tpu.api.pod import GROUP_NAME_ANNOTATION, Node, Pod, PodGroup, Queue
@@ -80,3 +81,42 @@ def build_cache(
     for p in pods:
         cache.add_pod(p)
     return cache
+
+
+class PacedCondition:
+    """A :class:`CycleTrigger`'s condition variable on the trigger's own
+    injected (virtual) clock, for one thread: a wait never blocks, it moves
+    the clock on — to the wait's end, or only as far as the next scripted
+    signal that falls inside it, which it then raises.  ``install`` puts it
+    in the trigger's place; ``script`` is ``[(virtual time, notify()'s
+    keywords)]``."""
+
+    def __init__(self, trigger, script=()):
+        self.trigger, self.clock = trigger, trigger.clock
+        self.script = sorted(script, key=lambda s: s[0])
+        # re-entrant: a scripted notify() runs inside the caller's guard
+        self._guard = threading.RLock()
+
+    @classmethod
+    def install(cls, trigger, script=()) -> "PacedCondition":
+        trigger._cond = cond = cls(trigger, script)
+        return cond
+
+    def __enter__(self):
+        self._guard.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self._guard.release()
+
+    def notify_all(self) -> None:
+        pass
+
+    def wait(self, timeout: float) -> None:
+        end = self.clock.monotonic() + timeout
+        if self.script and self.script[0][0] <= end:
+            at, kwargs = self.script.pop(0)
+            self.clock.advance_to(at)
+            self.trigger.notify(**kwargs)
+        else:
+            self.clock.advance_to(end)

@@ -405,6 +405,131 @@ class TestLeftoverTrigger:
         assert trig.poll() is False
 
 
+def _settle_counts() -> dict:
+    m = prom_metrics.metrics
+    counts = {by: m.SETTLE_HOLDS._values.get((by,), 0.0)
+              for by in ("quiet", "cap", "stop")}
+    counts["signals"] = m.SETTLE_SIGNALS._values.get((), 0.0)
+    counts["held_ms"], counts["held"] = m.SETTLE_HELD._sum, m.SETTLE_HELD._count
+    return counts
+
+
+def _grown(before: dict) -> dict:
+    """What the settle counters grew by, zeros left out."""
+    after = _settle_counts()
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+class TestSettleHold:
+    """The trigger's settle hold on the virtual clock (``PacedCondition``:
+    every wait moves the clock on instead of blocking, and raises the
+    signals scripted to fall inside it): an ingest wake starts its cycle
+    once no further signal has come for the quiet gap, or at the cap from
+    the first unconsumed signal; signals are ms after t = 100 s, and the
+    loop gets to look at ``look`` ms (the rest of a cycle and its floor)."""
+
+    T0 = 100.0
+    QUIET, CAP = 0.010, 0.050
+
+    def _trigger(self, signals, look=0, tracer=None):
+        from kube_batch_tpu.sim.clock import VirtualClock
+        from tests.fixtures import PacedCondition
+
+        clock = VirtualClock(start=self.T0)
+        trig = CycleTrigger(clock=clock, tracer=tracer)
+        at = [(self.T0 + ms / 1e3, kw) for ms, kw in signals]
+        for t, kw in at:
+            if t <= self.T0 + look / 1e3:  # raised before the loop looks
+                clock.advance_to(t)
+                trig.notify(**kw)
+        clock.advance_to(self.T0 + look / 1e3)
+        PacedCondition.install(
+            trig, [s for s in at if s[0] > self.T0 + look / 1e3])
+        return trig, clock
+
+    @pytest.mark.parametrize(
+        "signals, look, quiet, cap, starts, ended_by, folded", [
+            # a second signal inside the quiet gap joins the same wake and
+            # restarts the gap; a third, later than first + quiet, too
+            pytest.param((0, 3), 0, QUIET, CAP, 13, "quiet", 2, id="folded"),
+            pytest.param((0, 8, 16), 0, QUIET, CAP, 26, "quiet", 3,
+                         id="gap-restarts"),
+            pytest.param((0,), 0, QUIET, CAP, 10, "quiet", 1, id="lone"),
+            # a continuous stream: the cap, from the FIRST signal
+            pytest.param(tuple(range(0, 100, 4)), 0, QUIET, CAP, 50, "cap",
+                         13, id="stream"),
+            # a cap below the quiet gap (a cycle cheaper than 10 ms) wins
+            pytest.param((0,), 0, QUIET, 0.002, 2, "cap", 1, id="cap<quiet"),
+            # a signal that came mid-cycle pays what is left of its gap ...
+            pytest.param((0,), 4, QUIET, CAP, 10, "quiet", 1, id="part-left"),
+            # ... and nothing once it is older than the gap, or the cap
+            pytest.param((0,), 30, QUIET, CAP, 30, None, 0, id="older-quiet"),
+            pytest.param((0, 45), 55, 0.020, CAP, 55, None, 0,
+                         id="older-cap"),
+        ])
+    def test_the_hold_ends_at_the_quiet_gap_or_the_cap(
+            self, signals, look, quiet, cap, starts, ended_by, folded):
+        from kube_batch_tpu.obs.trace import Tracer
+
+        tr = Tracer(enabled=True)
+        trig, clock = self._trigger([(ms, {}) for ms in signals], look,
+                                    tracer=tr)
+        before = _settle_counts()
+        reason = trig.wait_for_work(self.T0 - 1.0, 0.0, 5.0, (quiet, cap))
+        assert reason == "ingest"
+        assert clock.monotonic() == pytest.approx(self.T0 + starts / 1e3)
+        assert trig.poll() is False, "one wake, every signal consumed"
+        record = tr.begin_cycle("pipelined")
+        tr.end_cycle()
+        event, = record.spans
+        assert event.attrs["signalled_ms"] == pytest.approx(starts, abs=1e-3)
+        if ended_by is None:
+            assert _grown(before) == {} and event.children == []
+            assert "settle" not in tr.span_counts
+            return
+        grown = _grown(before)
+        assert grown.pop("held_ms") == pytest.approx(starts - look)
+        assert grown == {ended_by: 1.0, "signals": float(folded), "held": 1}
+        settle, = event.children
+        gaps = [b - a for a, b in zip(signals, signals[1:])][:folded - 1]
+        assert (settle.name, settle.attrs) == ("settle", {
+            "q_ms": quiet * 1e3, "signals": folded, "ended_by": ended_by,
+            "widest_gap_ms": pytest.approx(max(gaps, default=0.0)),
+        })
+
+    @pytest.mark.parametrize("signals, settle, reason, starts, grew", [
+        pytest.param([(0, {"leftover": True})], (QUIET, CAP), "leftover", 0,
+                     {}, id="leftover"),
+        pytest.param([], (QUIET, CAP), "floor", 0, {}, id="floor"),
+        pytest.param([(0, {"stop": True})], (QUIET, CAP), "ingest", 0, {},
+                     id="stop"),
+        # stop() lets a wake that is being held go at once
+        pytest.param([(0, {}), (4, {"stop": True})], (QUIET, CAP), "ingest",
+                     4, {"stop": 1.0, "signals": 2.0, "held": 1,
+                         "held_ms": 4.0}, id="stop-mid-hold"),
+        # no cycle measured yet (the EWMA is None): no window, no hold
+        pytest.param([(0, {})], None, "ingest", 0, {}, id="no-ewma"),
+    ])
+    def test_what_never_holds(self, signals, settle, reason, starts, grew):
+        trig, clock = self._trigger(signals)
+        before = _settle_counts()
+        assert trig.wait_for_work(self.T0, 0.0, 0.0, settle) == reason
+        assert clock.monotonic() == pytest.approx(self.T0 + starts / 1e3)
+        assert _grown(before) == pytest.approx(grew)
+        # a stop() is consumed with its wake: the next ingest wake holds
+        trig.notify()
+        trig.wait_for_work(self.T0, 0.0, 0.0, (self.QUIET, self.CAP))
+        assert clock.monotonic() == pytest.approx(
+            self.T0 + starts / 1e3 + self.QUIET)
+
+    def test_poll_knows_no_hold(self):
+        """The sim's pacing consumes a signal at once, and with it the
+        count the next wake's hold would report."""
+        trig, clock = self._trigger([(0, {}), (0, {})])
+        assert trig.poll() is True
+        assert (trig._signals, clock.monotonic()) == (0, self.T0)
+
+
 class TestAdaptiveMinPeriod:
     """KB_PERIOD_MIN unset → the trigger's coalescing floor tracks an EWMA
     of the cycle's own measured cost (a 200 ms solve shouldn't re-trigger
@@ -464,6 +589,24 @@ class TestAdaptiveMinPeriod:
         # the EWMA still tracks (observability), the floor does not move
         assert sched.cycle_cost_ewma == pytest.approx(0.5)
         assert sched.min_period == pytest.approx(0.123)
+
+    @pytest.mark.parametrize("pinned", [False, True])
+    @pytest.mark.parametrize("cost, window", [
+        (None, None),              # nothing measured: no hold
+        (0.001, (0.005, 0.0005)),  # the quiet gap's lower clamp
+        (0.100, (0.0125, 0.050)),  # an eighth and a half of a cycle
+        (0.160, (0.020, 0.080)),
+        (0.300, (0.025, 0.100)),   # both upper clamps
+        (5.000, (0.025, 0.100)),   # the cold drain's cost
+    ])
+    def test_settle_window_follows_the_cycle_cost(self, cost, window, pinned):
+        """The hold's two bounds are shares of the same EWMA, clamped; a
+        pinned KB_PERIOD_MIN pins the floor, not the hold."""
+        sched = self._sched(**({"KB_PERIOD_MIN": "0.123"} if pinned else {}))
+        assert sched.settle_window() is None
+        if cost is not None:
+            sched._note_cycle_cost(cost)
+        assert sched.settle_window() == pytest.approx(window)
 
     def test_pipelined_loop_feeds_the_ewma(self):
         """The real loop wires measured cycle costs into the floor: after a
@@ -696,6 +839,101 @@ class TestLeftoverWake:
         assert cache.left_schedulable_pending(before)
         cache.delete_pod(cache.pods["ns/unfit-0"])
         assert not cache.left_schedulable_pending(before)
+
+
+class TestOneCycleABurst:
+    """Through the real loop and cache: a burst whose requests reach the
+    staging buffer milliseconds apart is decided by ONE cycle.  The loop
+    runs on a real thread against a clock only the test moves, so the hold
+    ends when the test says, never by the machine's speed; the test follows
+    the loop by handshake (its entry into the wait, and into the hold)."""
+
+    CPU = 12000.0  # a node of _mk_cache holds one such pod, not two
+
+    def _gang(self, name, index):
+        pg = PodGroup(name=name, namespace="ns", uid=f"pg-{name}",
+                      min_member=1, queue="q0", creation_index=index)
+        pod = Pod(name=f"{name}-0", namespace="ns", uid=f"u-{name}",
+                  requests={"cpu": self.CPU}, phase=PodPhase.PENDING,
+                  annotations={GROUP_NAME_ANNOTATION: name},
+                  creation_index=index)
+        return pg, pod
+
+    def test_delete_then_post_are_decided_by_one_cycle_in_arrival_order(self):
+        from kube_batch_tpu.sim.clock import VirtualClock
+
+        cache = _mk_cache(n_nodes=1)
+        old_pg, old_pod = self._gang("old", 1)
+        cache.add_pod_group(old_pg)
+        cache.add_pod(old_pod)
+        clock = VirtualClock(start=100.0)
+        sched = Scheduler(cache, conf=load_scheduler_conf(None),
+                          schedule_period=1e6, clock=clock)
+        sched.pipelined = True
+        sched.min_period_pinned, sched.min_period = True, 0.0
+        # a cycle costs nothing on a clock that stands still: give the
+        # hold the window a 200 ms cycle would
+        sched.settle_window = lambda: (0.025, 0.1)
+        parked, settling = threading.Semaphore(0), threading.Semaphore(0)
+        wait, settle = sched.trigger.wait_for_work, sched.trigger._settle
+
+        def wait_for_work(*args):
+            parked.release()
+            return wait(*args)
+
+        def _settle(*args):
+            settling.release()
+            return settle(*args)
+
+        sched.trigger.wait_for_work = wait_for_work
+        sched.trigger._settle = _settle
+        t = threading.Thread(target=sched.run_forever, daemon=True)
+        t.start()
+        try:
+            # the start-up cycle binds `old` on the only node
+            assert parked.acquire(timeout=120.0)
+            assert cache.binder.event.wait(30.0)  # the writeback's drain
+            assert cache.binder.binds == {"ns/old-0": "n0"}
+            opens0 = sched.tracer.span_counts["session_open"]
+            before = _settle_counts()
+            # the burst: four requests, 1 ms apart, DELETEs first
+            new_pg, new_pod = self._gang("new", 2)
+            cache.delete_pod(cache.pods["ns/old-0"])
+            assert settling.acquire(timeout=30.0), "the first signal wakes"
+            for request in (lambda: cache.delete_pod_group(old_pg),
+                            lambda: cache.add_pod_group(new_pg),
+                            lambda: cache.add_pod(new_pod)):
+                clock.sleep(0.001)
+                request()
+            # held: nothing is drained while the burst is still arriving
+            with cache._ingest_lock:
+                assert len(cache._ingest_staged) == 4
+            clock.sleep(0.025)  # the quiet gap after the last request
+            assert parked.acquire(timeout=120.0)
+        finally:
+            sched.stop()
+            t.join(timeout=30.0)
+        assert not t.is_alive()
+        # ONE cycle drained all four, the DELETE before the POST: `new`
+        # sits where `old` sat, which a drain in any other order, or a
+        # cycle for the DELETEs alone, could not have decided at once
+        assert sched.tracer.span_counts["session_open"] == opens0 + 1
+        assert cache.binder.binds.get("ns/new-0") == "n0"
+        assert "ns/old-0" not in cache.pods
+        grown = _grown(before)
+        assert grown.pop("held_ms") == pytest.approx(28.0)
+        assert grown == {"quiet": 1.0, "signals": 4.0, "held": 1}
+        record = next(r for r in reversed(sched.tracer.recorder.records())
+                      if any(s.name == "ingest_drain"
+                             and s.attrs.get("events") == 4
+                             for s in r.spans))
+        event = next(s for s in record.spans if s.name == "park:event")
+        assert event.attrs["woke_by"] == "ingest"
+        assert event.attrs["signalled_ms"] == pytest.approx(28.0)
+        hold, = event.children
+        assert (hold.name, hold.attrs) == ("settle", {
+            "q_ms": 25.0, "signals": 4, "ended_by": "quiet",
+            "widest_gap_ms": pytest.approx(1.0)})
 
 
 class TestBudgetShedOverlappedClose:

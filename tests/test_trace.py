@@ -668,6 +668,43 @@ class TestParkSpans:
         assert idle.attrs["signalled_ms"] == 0.0
         assert len(rec.records()) == 1
 
+    def test_settle_is_a_child_of_park_event_and_inside_its_stage_sum(
+            self, tmp_path, monkeypatch):
+        """The trigger's settle hold is time the loop stays parked: its
+        span sits INSIDE ``park:event``, so the stage sum of that root
+        (what ``loop_accounted_ms_per_s`` adds up, root by root) contains
+        the hold, no new root appears beside it, and ``/v1/trace`` totals
+        the hold under its own name like any child."""
+        from kube_batch_tpu.sim.clock import VirtualClock
+        from tests.fixtures import PacedCondition
+
+        # every stamp one second after the last: park:event is entered
+        # (1), settle entered (2) and left (3), park:event left (4)
+        monkeypatch.setattr(telemetry, "perf_counter", _TickClock())
+        tr, _ = _tracer(tmp_path)
+        trig = CycleTrigger(clock=VirtualClock(start=100.0), tracer=tr)
+        PacedCondition.install(trig, [(100.003, {})])
+        stages0 = dict(prom.STAGE_LATENCY._count)
+        parked0 = prom.STAGE_LATENCY._sum[("park:event",)]
+        trig.notify()
+        assert trig.wait_for_work(100.0, 0.0, 5.0, (0.010, 0.050)) == "ingest"
+        state = tr.state()
+        assert state["span_counts"] == {"settle": 1, "park:event": 1}
+        assert state["span_ms"] == {"settle": 1000.0, "park:event": 3000.0}
+        grown = {k: n - stages0.get(k, 0)
+                 for k, n in prom.STAGE_LATENCY._count.items()
+                 if n != stages0.get(k, 0)}
+        assert grown == {("park:event",): 1}, "a root beside park:event"
+        assert prom.STAGE_LATENCY._sum[("park:event",)] - parked0 == 3000.0
+        record = tr.begin_cycle("pipelined")
+        tr.end_cycle()
+        event, = record.spans
+        assert [c.name for c in event.children] == ["settle"]
+        assert event.children[0].attrs == {
+            "q_ms": 10.0, "signals": 2, "ended_by": "quiet",
+            "widest_gap_ms": pytest.approx(3.0)}
+        assert record.to_dict()["spans"][0]["children"][0]["name"] == "settle"
+
     def test_parked_loop_that_never_cycles_again_keeps_few_spans(
             self, tmp_path):
         tr, _ = _tracer(tmp_path)
