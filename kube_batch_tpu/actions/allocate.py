@@ -21,8 +21,9 @@ from __future__ import annotations
 
 import logging
 import os
+from functools import partial
 from kube_batch_tpu.utils import telemetry
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -34,11 +35,7 @@ from kube_batch_tpu.api.types import TaskStatus
 from kube_batch_tpu.framework.interface import Action
 from kube_batch_tpu.framework.session import FitFailure, JOB_READY
 from kube_batch_tpu import metrics
-from kube_batch_tpu.ops.assignment import (
-    AllocateConfig,
-    allocate_solve,
-    allocate_topk_solve,
-)
+from kube_batch_tpu.ops.assignment import AllocateConfig, allocate_solve
 
 logger = logging.getLogger("kube_batch_tpu")
 
@@ -96,17 +93,16 @@ def resolve_warm() -> bool:
 
 def _warm_state(cols, mesh, impl, config, guard, warm: bool, k: int):
     """The carried-table state for this dispatch slot, or None when the
-    warm path must not run: opt-out (KB_WARM=0), guard demotion, the
-    Pallas head (its fused build is a cold-build kernel), no ColumnStore,
-    or an explicitly cold caller (the backfill real-request pass solves a
-    mid-cycle snapshot and must not consume the allocate carry's deltas).
+    warm path must not run: opt-out (KB_WARM=0), guard demotion, no
+    ColumnStore, or an explicitly cold caller (the backfill real-request
+    pass solves a mid-cycle snapshot and must not consume the allocate
+    carry's deltas).
 
     Called BEFORE the resident swap so a fresh state still absorbs this
     cycle's delta record and cold-builds the same dispatch."""
     if (
         not warm or cols is None or k <= 0
         or not resolve_warm()
-        or config.use_pallas
         # a custom score row may read ANY snapshot field (the seam's
         # contract) — including per-cycle state the carry's invalidation
         # sources don't track (queue_alloc, job rows, statuses), which
@@ -210,28 +206,6 @@ def _run_bounds(sorted_arr) -> list:
     ).tolist()
 
 
-class _PhaseMarks:
-    """Accumulating wall-clock sub-phase marks: each mark() charges the
-    elapsed time since the previous one to `sink[key]` (in ms)."""
-
-    def __init__(self, sink: Dict[str, float]):
-        self.sink = sink
-        self.t = telemetry.perf_counter()
-
-    def mark(self, key: str) -> None:
-        now = telemetry.perf_counter()
-        self.sink[key] = self.sink.get(key, 0.0) + (now - self.t) * 1e3
-        self.t = now
-
-
-def _pallas_enabled(ssn) -> bool:
-    """Opt into the fused Pallas round-head kernel via an `allocate.pallas`
-    argument on any conf tier plugin (Arguments are free-form string maps,
-    arguments.go:26-66) or env KB_PALLAS=1 (pallas_kernels.py)."""
-    env = os.environ.get("KB_PALLAS", "").lower() in ("1", "true", "yes")
-    return ssn.conf_flag("allocate.pallas", default=env)
-
-
 def build_session_snapshot(ssn):
     """(DeviceSnapshot, meta) for the session — columnar row space when the
     session is exclusive, object rebuild for isolated sessions.  Shared by
@@ -254,15 +228,79 @@ def session_allocate_config(ssn) -> AllocateConfig:
         gang=ssn.plugin_enabled("gang"),
         drf=ssn.plugin_enabled("drf"),
         proportion=ssn.plugin_enabled("proportion"),
-        use_pallas=_pallas_enabled(ssn),
         weights=ssn.score_weights,
+    )
+
+
+class AllocateDispatchPlan(NamedTuple):
+    """What one allocate-shaped dispatch will run, decided on the host
+    before anything touches the device (:func:`plan_allocate_dispatch`)."""
+
+    mesh: Optional[object]   # the mesh the solve shards over; None = one device
+    impl: Optional[str]      # "pjit" where shard_map is demoted, else None
+    #                          (KB_SHARD_MAP selects, parallel.mesh._impl)
+    kind: str                # "full" | "topk"; the dispatch turns "topk"
+    #                          into "warm" once a carried-table plan exists
+    k: int                   # candidate-list width K (0 in the full program)
+    pend_rows: Optional[np.ndarray]  # the [P] pending bucket as planned
+    demoted: bool            # a guard demotion picked this program
+    sentinel: bool           # the invariant tail is fused behind the solve
+    engaged: Tuple[str, ...]  # the guard fast paths the program engages
+    wstate: Optional[object]  # carried-table state, None = cold build
+
+
+def plan_allocate_dispatch(snap, config, cols, guard, warm
+                           ) -> AllocateDispatchPlan:
+    """Choose the program of one allocate-shaped dispatch from what the
+    host can observe: the guard's demotions first (shard_map to its pjit
+    oracle, compaction to the full matrix), then the compaction plan, then
+    whether a mesh exists and the cluster is wide enough to shard, then
+    the carried-table state.  Touches no device, and runs BEFORE the
+    resident swap (:func:`_warm_state` says why)."""
+    from kube_batch_tpu.parallel.mesh import (
+        TASK_AXIS,
+        _impl as resolve_impl,
+        default_mesh,
+        should_shard,
+    )
+
+    impl = None
+    demoted = False
+    if guard is not None and not guard.allow("shard_map"):
+        impl = "pjit"  # shard_map demoted → the pjit oracle
+        demoted = True
+    k = resolve_topk()
+    if guard is not None and not guard.allow("topk"):
+        k = 0  # compaction demoted → the full-matrix oracle
+        demoted = True
+    pend_rows, k = plan_topk_bucket(snap, cols, k)
+    mesh = default_mesh() if should_shard(snap.node_alloc.shape[0]) else None
+    engaged: Tuple[str, ...] = ()
+    if mesh is not None and resolve_impl(impl) == "shard_map":
+        engaged = ("shard_map",)
+    kind, wstate = "full", None
+    # the compacted body requires a 1-D node mesh — the 2-D task-axis
+    # grid is the cold-start HBM escape, where compaction can't apply
+    if pend_rows is not None and (
+        mesh is None or dict(mesh.shape).get(TASK_AXIS, 1) == 1
+    ):
+        kind = "topk"
+        engaged += ("topk",)
+        wstate = _warm_state(
+            cols, mesh, None if mesh is None else resolve_impl(impl),
+            config, guard, warm, k)
+    return AllocateDispatchPlan(
+        mesh=mesh, impl=impl, kind=kind, k=k, pend_rows=pend_rows,
+        demoted=demoted, sentinel=guard is not None and guard.enabled,
+        engaged=engaged, wstate=wstate,
     )
 
 
 def dispatch_allocate_solve(snap, config, cols=None, guard=None,
                             warm=False, tracer=None):
     """Shard-or-local solve dispatch; returns (result, mode, topk_info,
-    ginfo).
+    ginfo): plan (:func:`plan_allocate_dispatch`), resident swap, program
+    lookup (parallel.mesh.allocate_program), one call.
 
     ``warm=True`` (the allocate action's steady path) lets the compacted
     program run WARM-STARTED: the [P, K] candidate table carries across
@@ -279,216 +317,99 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
 
     ``topk_info`` records the compaction decision ({"k", "bucket"} when
     the KB_TOPK compacted program ran, None otherwise) — the action folds
-    the solve's exhaustion counters into it for the bench/sim.
+    the solve's exhaustion counters into it for the sim.
 
     ``guard`` (a :class:`kube_batch_tpu.guard.GuardPlane`) makes the
     dispatch GUARDED: demoted fast paths fall back to their oracles
-    (KB_TOPK=0 / pjit / use_pallas off) and the sentinel-fused program
-    variants run, returning the invariant verdict + histogram in ``ginfo``
-    ("sentinel") alongside the engaged fast-path names ("engaged") and the
-    compaction plan ("pend_rows", for the diagnostics bundle).  The caller
-    MUST feed the verdict through ``guard.consume_verdict`` before acting
-    on the result (rule KBT013 enforces this at every dispatch site)."""
+    (KB_TOPK=0 / pjit / the cold table build) and the sentinel-fused
+    program variants run, returning the invariant verdict + histogram in
+    ``ginfo`` ("sentinel") alongside the engaged fast-path names
+    ("engaged") and the compaction plan ("pend_rows", for the diagnostics
+    bundle).  The caller MUST feed the verdict through
+    ``guard.consume_verdict`` before acting on the result (rule KBT013
+    enforces this at every dispatch site)."""
     # kbt: allow[KBT013] the dispatch RETURNS the sentinel verdict to its
     # caller — consume_verdict happens at the action's readback, the one
     # place the verdict exists on host
-    from kube_batch_tpu.parallel.mesh import (
-        TASK_AXIS,
-        default_mesh,
-        sentinel_sharded_allocate_solve,
-        sentinel_sharded_allocate_topk_solve,
-        sharded_allocate_solve,
-        sharded_allocate_topk_solve,
-        should_shard,
-    )
+    from kube_batch_tpu.parallel.mesh import allocate_program
 
-    sentinel_on = guard is not None and guard.enabled
-    impl = None
-    demoted = False  # did a demotion pick this dispatch's program?
-    if guard is not None and not guard.allow("shard_map"):
-        impl = "pjit"  # shard_map demoted → the pjit oracle
-        demoted = True
-    if guard is not None and not guard.allow("pallas") and config.use_pallas:
-        config = config._replace(use_pallas=False)
-    k = resolve_topk()
-    if guard is not None and not guard.allow("topk"):
-        k = 0  # compaction demoted → the full-matrix oracle
-        demoted = True
-    pend_rows, k = plan_topk_bucket(snap, cols, k)
-
-    def ginfo(engaged, sentinel, dev, cfg):
-        return {
-            "engaged": engaged, "sentinel": sentinel,
-            "pend_rows": pend_rows, "impl": impl,
-            # the exact (post-resident-swap) snapshot the solve consumed —
-            # what a trip's diagnostics bundle must capture
-            "dev": dev,
-            # the EFFECTIVE config the program ran with (demotions applied:
-            # use_pallas off, topk as dispatched) — a bundle must replay
-            # the condemned program, not the session's nominal one
-            "config": cfg,
-        }
-
-    if should_shard(snap.node_alloc.shape[0]):
-        mesh = default_mesh()
-        from kube_batch_tpu.parallel.mesh import _impl as resolve_impl
-
-        engaged = ["shard_map"] if resolve_impl(impl) == "shard_map" else []
-        if config.use_pallas:
-            engaged.append("pallas")
-        # the compacted body requires a 1-D node mesh — the 2-D task-axis
-        # grid is the cold-start HBM escape, where compaction can't apply
-        if pend_rows is not None and dict(mesh.shape).get(TASK_AXIS, 1) == 1:
-            info = {"k": k, "bucket": int(pend_rows.shape[0])}
-            cfg = config._replace(topk=k)
-            wstate = _warm_state(cols, mesh, resolve_impl(impl), config,
-                                 guard, warm, k)
-            dev = resident_snap(cols, snap, mesh)
-            wplan = _warm_plan(wstate, cols, pend_rows, k, config, tracer)
-            if wplan is not None:
-                from kube_batch_tpu.parallel.mesh import (
-                    sentinel_sharded_warm_allocate_solve,
-                    sharded_warm_allocate_solve,
-                )
-
-                info["warm"] = dict(wstate.last)
-                cfg_w = config._replace(topk=wplan["w"])
-                ptuple = (wplan["row_map"], wplan["changed"],
-                          wplan["rerank_rows"], wplan["rerank_slots"])
-                if sentinel_on:
-                    res, v, h, e, _t, _er = _warm_commit(
-                        wstate,
-                        lambda: sentinel_sharded_warm_allocate_solve(
-                            dev, pend_rows, wplan["table"], ptuple, cfg_w,
-                            warm_k_min(k), mesh, impl=impl,
-                        ),
-                    )
-                    # ginfo carries the EFFECTIVE config (topk=W): a trip
-                    # bundle replays the cold compacted program at the
-                    # condemned program's own width (the carry itself is
-                    # not replayable — the table is cross-cycle state)
-                    return (res, "sharded", info,
-                            ginfo(engaged + ["topk", "warm"], (v, h, e),
-                                  dev, cfg_w))
-                res, _t, _er = _warm_commit(
-                    wstate,
-                    lambda: sharded_warm_allocate_solve(
-                        dev, pend_rows, wplan["table"], ptuple, cfg_w,
-                        warm_k_min(k), mesh, impl=impl,
-                    ),
-                )
-                return (res, "sharded", info,
-                        ginfo(engaged + ["topk", "warm"], None, dev, cfg_w))
-            if sentinel_on:
-                res, v, h, e = sentinel_sharded_allocate_topk_solve(
-                    dev, pend_rows, cfg, mesh, impl=impl
-                )
-                return (res, "sharded", info,
-                        ginfo(engaged + ["topk"], (v, h, e), dev, cfg))
-            return (
-                sharded_allocate_topk_solve(dev, pend_rows, cfg, mesh,
-                                            impl=impl),
-                "sharded", info, ginfo(engaged + ["topk"], None, dev, cfg),
-            )
-        dev = resident_snap(cols, snap, mesh)
-        if demoted:
-            # the full [T, N] matrix as a demotion's target: only where it
-            # holds the cluster (a cold start runs it undemoted, and the
-            # deployment is sized for that)
-            _require_full_matrix_fit(
-                "the demotion's target, the sharded full-matrix solve,",
-                dev, config, mesh, impl)
-        if sentinel_on:
-            res, v, h, e = sentinel_sharded_allocate_solve(
-                dev, config, mesh, impl=impl
-            )
-            return (res, "sharded", None,
-                    ginfo(engaged, (v, h, e), dev, config))
-        return (
-            sharded_allocate_solve(dev, config, mesh, impl=impl),
-            "sharded", None, ginfo(engaged, None, dev, config),
-        )
-    engaged = ["pallas"] if config.use_pallas else []
-    if pend_rows is not None:
-        info = {"k": k, "bucket": int(pend_rows.shape[0])}
-        cfg = config._replace(topk=k)
-        wstate = _warm_state(cols, None, None, config, guard, warm, k)
-        dev = resident_snap(cols, snap)
-        wplan = _warm_plan(wstate, cols, pend_rows, k, config, tracer)
+    plan = plan_allocate_dispatch(snap, config, cols, guard, warm)
+    mesh, kind, pend_rows = plan.mesh, plan.kind, plan.pend_rows
+    dev = resident_snap(cols, snap, mesh)
+    # cfg is the EFFECTIVE config the program runs with (demotions applied,
+    # topk as dispatched: K, or the carried table's width W) — a trip
+    # bundle must replay the condemned program, not the session's nominal
+    # one (the carry itself is not replayable: the table is cross-cycle
+    # state, so a warm trip replays the cold compacted program at W)
+    cfg, args, k_min, info = config, (dev,), 0, None
+    if kind == "topk":
+        info = {"k": plan.k, "bucket": int(pend_rows.shape[0])}
+        cfg = config._replace(topk=plan.k)
+        args = (dev, pend_rows)
+        wplan = _warm_plan(plan.wstate, cols, pend_rows, plan.k, config,
+                           tracer)
         if wplan is not None:
-            from kube_batch_tpu.ops.assignment import warm_allocate_solve
-
-            info["warm"] = dict(wstate.last)
-            cfg_w = config._replace(topk=wplan["w"])
-            ptuple = (wplan["row_map"], wplan["changed"],
-                      wplan["rerank_rows"], wplan["rerank_slots"])
-            if sentinel_on:
-                from kube_batch_tpu.ops.invariants import (
-                    warm_allocate_sentinel_solve,
-                )
-
-                res, v, h, e, _t, _er = _warm_commit(
-                    wstate,
-                    lambda: warm_allocate_sentinel_solve(
-                        dev, pend_rows, wplan["table"], ptuple, cfg_w,
-                        warm_k_min(k),
-                    ),
-                )
-                # effective config (topk=W) — see the sharded site
-                return (res, "single", info,
-                        ginfo(engaged + ["topk", "warm"], (v, h, e), dev,
-                              cfg_w))
-            res, _t, _er = _warm_commit(
-                wstate,
-                lambda: warm_allocate_solve(
-                    dev, pend_rows, wplan["table"], ptuple, cfg_w,
-                    warm_k_min(k),
-                ),
-            )
-            return (res, "single", info,
-                    ginfo(engaged + ["topk", "warm"], None, dev, cfg_w))
-        if sentinel_on:
-            from kube_batch_tpu.ops.invariants import (
-                allocate_topk_sentinel_solve,
-            )
-
-            res, v, h, e = allocate_topk_sentinel_solve(dev, pend_rows, cfg)
-            return (res, "single", info,
-                    ginfo(engaged + ["topk"], (v, h, e), dev, cfg))
-        return (
-            allocate_topk_solve(dev, pend_rows, cfg),
-            "single", info, ginfo(engaged + ["topk"], None, dev, cfg),
-        )
-    dev = resident_snap(cols, snap)
-    if demoted:
+            kind = "warm"
+            info["warm"] = dict(plan.wstate.last)
+            cfg = config._replace(topk=wplan["w"])
+            k_min = warm_k_min(plan.k)
+            args += (*wplan["table"], wplan["row_map"], wplan["changed"],
+                     wplan["rerank_rows"], wplan["rerank_slots"])
+    elif plan.demoted:
+        # the full [T, N] matrix as a demotion's target: only where it
+        # holds the cluster (a cold start runs it undemoted, and the
+        # deployment is sized for that)
         _require_full_matrix_fit(
-            "the demotion's target, the full-matrix solve,", dev, config)
-    if sentinel_on:
-        from kube_batch_tpu.ops.invariants import allocate_sentinel_solve
+            allocate_program("full", mesh, plan.impl, config, False),
+            dev, config, mesh, plan.impl)
+    call = partial(
+        _call_program,
+        allocate_program(kind, mesh, plan.impl, cfg, plan.sentinel, k_min),
+        args, mesh, kind, cfg, k_min)
+    if kind == "warm":
+        # the last two outputs are the refreshed table the commit adopts
+        out = _warm_commit(plan.wstate, call)[:-2]
+    else:
+        out = call() if plan.sentinel else (call(),)
+    ginfo = {
+        "engaged": list(plan.engaged) + (["warm"] if kind == "warm" else []),
+        "sentinel": tuple(out[1:]) or None,  # (verdict, hist, checksum)
+        "pend_rows": pend_rows, "impl": plan.impl,
+        # the exact (post-resident-swap) snapshot the solve consumed —
+        # what a trip's diagnostics bundle must capture
+        "dev": dev,
+        "config": cfg,
+    }
+    return out[0], "single" if mesh is None else "sharded", info, ginfo
 
-        res, v, h, e = allocate_sentinel_solve(dev, config)
-        return res, "single", None, ginfo(engaged, (v, h, e), dev, config)
-    return (allocate_solve(dev, config), "single", None,
-            ginfo(engaged, None, dev, config))
+
+def _call_program(fn, args, mesh, kind, cfg, k_min):
+    """The one call of an allocate program (parallel.mesh.allocate_program):
+    under its mesh, where the statics are baked in; on one device the
+    programs take them at the call, as their other callers (the oracle,
+    bundle replay) pass them."""
+    if mesh is not None:
+        with mesh:
+            return fn(*args)
+    if kind == "warm":
+        return fn(*args, config=cfg, k_min=k_min)
+    return fn(*args, cfg)
 
 
-def _require_full_matrix_fit(what, dev, config, mesh=None, impl=None):
-    """Raise :class:`guard.OracleUnfit` unless the full [T, N] allocate
-    program holds ``dev`` on one device (guard/fit.py): across ``mesh``
-    with ``impl``, or on a single device."""
+def _require_full_matrix_fit(fn, dev, config, mesh, impl):
+    """Raise :class:`guard.OracleUnfit` unless ``fn``, the bare full [T, N]
+    allocate program, holds ``dev`` on one device (guard/fit.py): across
+    ``mesh`` with ``impl``, or on a single device."""
     from kube_batch_tpu.guard.fit import require_fit
+    from kube_batch_tpu.parallel.mesh import NODE_AXIS, _impl as resolve_impl
 
     if mesh is None:
-        require_fit(what, allocate_solve, dev, config)
+        require_fit("the demotion's target, the full-matrix solve,",
+                    fn, dev, config)
         return
-    from kube_batch_tpu.parallel.mesh import (
-        NODE_AXIS,
-        _impl as resolve_impl,
-        allocate_solve_fn,
-    )
-
     require_fit(
-        what, allocate_solve_fn(mesh, config, impl=impl), dev, mesh=mesh,
+        "the demotion's target, the sharded full-matrix solve,", fn, dev,
+        mesh=mesh,
         spmd_shards=(dict(mesh.shape)[NODE_AXIS]
                      if resolve_impl(impl) == "pjit" else 1),
     )
@@ -496,11 +417,10 @@ def _require_full_matrix_fit(what, dev, config, mesh=None, impl=None):
 
 def dispatch_allocate_oracle(snap, config, cols, mode):
     """The shadow-oracle dispatch for an allocate-shaped audit: the same
-    snapshot through the all-oracle program (KB_TOPK=0, use_pallas off;
-    pjit impl when the committed solve ran sharded).  ``resident_snap`` is
-    memoized on the snap object, so this re-dispatch is device work only —
-    no re-upload."""
-    oracle_cfg = config._replace(topk=0, use_pallas=False)
+    snapshot through the all-oracle program (KB_TOPK=0; pjit impl when the
+    committed solve ran sharded).  ``resident_snap`` is memoized on the
+    snap object, so this re-dispatch is device work only — no re-upload."""
+    oracle_cfg = config._replace(topk=0)
     if mode == "sharded":
         from kube_batch_tpu.parallel.mesh import (
             default_mesh,
@@ -551,9 +471,6 @@ class AllocateAction(Action):
     name = "allocate"
 
     def __init__(self):
-        # per-phase ms of the most recent execute() — read by bench.py via
-        # get_action("allocate").last_phase_ms
-        self.last_phase_ms: Dict[str, float] = {}
         # "single" | "sharded" — which solve the last execute() dispatched
         self.last_solve_mode = "single"
         # bidding rounds the last solve executed (early exits make this
@@ -561,7 +478,7 @@ class AllocateAction(Action):
         self.last_solve_rounds = 0
         # candidate-compaction record of the most recent execute():
         # {"k", "bucket", "exhausted", "reentries"} when the KB_TOPK
-        # compacted program ran, None otherwise (bench/sim evidence)
+        # compacted program ran, None otherwise (sim evidence)
         self.last_topk = None
         # warm-carry record ({"cold", "reranked", "changed", ...}) when
         # the KB_WARM carried-table program ran, None otherwise
@@ -581,7 +498,6 @@ class AllocateAction(Action):
         self._fit_histograms_seen: set = set()
 
     def execute(self, ssn) -> None:
-        self.last_phase_ms = {}
         self.last_fallback = {}
         self.last_host_discards = 0
         self.last_solve_rounds = 0
@@ -614,8 +530,6 @@ class AllocateAction(Action):
             # the snapshot/solve/replay entirely (the reference's loop with
             # an empty pending set is ~free; ours must be too at a 1 s
             # schedule period)
-            self.last_phase_ms = {"snapshot_build": 0.0, "solve": 0.0,
-                                  "fit_errors": 0.0, "replay": 0.0}
             # serving deployments still need a lease for this state: an
             # idle cluster is exactly when capacity-planning what-ifs
             # arrive.  The snapshot build + resident swap run only when a
@@ -628,7 +542,6 @@ class AllocateAction(Action):
             return
         with tracer.span("snapshot_build"):
             snap, meta = build_session_snapshot(ssn)
-        t1 = telemetry.perf_counter()
         # multi-chip parts shard the node axis over the ICI mesh — the
         # production analog of the reference's always-on 16-worker fan-out
         # (scheduler_helper.go:34-64); single-chip or small-N stays local
@@ -687,8 +600,7 @@ class AllocateAction(Action):
                  sentinel[2] if sentinel is not None else np.int32(0))
             )
         sp_wait.set(rounds=int(rounds_run))
-        # convergence diagnostic (round-cap tuning); NOT in last_phase_ms —
-        # that dict is ms-typed for the bench phases map
+        # convergence diagnostic (round-cap tuning)
         self.last_solve_rounds = int(rounds_run)
         if topk_info is not None:
             topk_info = dict(
@@ -697,7 +609,7 @@ class AllocateAction(Action):
         self.last_topk = topk_info
         # warm-carry record of this execute ({"cold", "reranked",
         # "changed", "bucket_live", "w"} when the carried-table program
-        # ran, None otherwise) — bench incremental_solve / sim evidence
+        # ran, None otherwise) — sim evidence
         self.last_warm = (topk_info or {}).get("warm")
         assigned = assigned[: meta.n_tasks]
         pipelined = pipelined[: meta.n_tasks]
@@ -709,13 +621,7 @@ class AllocateAction(Action):
             # below this line runs: no replay, no binds, no fit errors.
             # The guard has already demoted the engaged fast paths, healed
             # the resident cache, and dumped the diagnostics bundle.
-            self.last_phase_ms.update(
-                snapshot_build=(t1 - t0) * 1e3,
-                solve=(telemetry.perf_counter() - t1) * 1e3,
-                fit_errors=0.0, replay=0.0,
-            )
             return
-        t2 = telemetry.perf_counter()
         task_job = np.asarray(snap.task_job)[: meta.n_tasks]
         # fit errors only for tasks of jobs that are IN this session (the
         # columnar row space also carries rows of jobs the session dropped —
@@ -738,9 +644,6 @@ class AllocateAction(Action):
         # scheduler module's staged loop overlaps the close-time status
         # flush and the binder drain with the NEXT cycle the same way) —
         # the async-binder seam extended one stage earlier into the cycle.
-        # Timed under its own key (dispatch + post-replay readback) so
-        # failure cycles don't read as a replay-phase regression.
-        t_fit0 = telemetry.perf_counter()
         fail_hist_dev = None
         p_rows = ginfo.get("pend_rows")
         unplaced = bool(np.any(pending & (assigned < 0)))
@@ -756,10 +659,8 @@ class AllocateAction(Action):
                 hist_dev = self._dispatch_fit_histogram(cols, snap, p_rows)
             if unplaced:
                 fail_hist_dev = hist_dev
-        t_fit1 = telemetry.perf_counter()
         with tracer.span("host_replay"):
             self._replay(ssn, snap, meta, assigned, pipelined, task_job)
-        t3 = telemetry.perf_counter()
         if fail_hist_dev is not None:
             # blocks only on whatever the device hasn't finished during the
             # replay; fit-error recording touches job diagnostic dicts the
@@ -772,20 +673,13 @@ class AllocateAction(Action):
                     ssn, meta, np.asarray(fail_hist_dev), assigned, task_job,
                     pending,
                 )
-        t4 = telemetry.perf_counter()
-        # update, not replace: _replay already folded its replay_* sub-phases in
-        self.last_phase_ms.update(
-            snapshot_build=(t1 - t0) * 1e3,
-            solve=(t2 - t1) * 1e3,
-            fit_errors=((t_fit1 - t_fit0) + (t4 - t3)) * 1e3,
-            replay=(t3 - t_fit1) * 1e3,
-        )
         if self._n_applied:
             # amortized per-task latency over placements actually APPLIED
             # (bulk-committed + statement-committed), so the histogram count
             # matches real placements (metrics.go:66-72 analog)
             metrics.observe_task_latencies(
-                (t4 - t0) * 1e6 / self._n_applied, self._n_applied
+                (telemetry.perf_counter() - t0) * 1e6 / self._n_applied,
+                self._n_applied,
             )
         if audit_dev is not None:
             self._compare_audit(
@@ -906,10 +800,6 @@ class AllocateAction(Action):
         placed = np.flatnonzero(assigned >= 0)
         if placed.size == 0:
             return
-        # sub-phase wall clock (folded into last_phase_ms as replay_*) — the
-        # host replay is the cycle's second-biggest phase and its internals
-        # must stay visible in the bench artifact
-        _mark = _PhaseMarks(self.last_phase_ms).mark
         # group placements by job, preserving device task order within a job;
         # groups are (job_idx, lo, hi) ranges over the sorted flat arrays
         order = np.argsort(task_job[placed], kind="stable")
@@ -961,7 +851,6 @@ class AllocateAction(Action):
         task_objs = meta.task_objs
         node_names = meta.node_names
         n_groups = len(bounds) - 1
-        _mark("replay_prep")
 
         # ---- promote host-ports-only jobs back to the bulk path --------
         # A job is "slow" when any task carries host-only constraints, but
@@ -1084,7 +973,6 @@ class AllocateAction(Action):
         by_node: Dict[int, Tuple[list, list]] = {}
         # shared by the columnar count update and the bulk_bind job sums
         n_alloc_applied = np.bincount(pjobs[alloc_sel], minlength=nJ)
-        _mark("replay_sums")
 
         cols = ssn.columns
         columnar = (
@@ -1137,7 +1025,6 @@ class AllocateAction(Action):
                 | np.any(node_pipe_sum != 0.0, axis=1)
             )
             ssn.fire_columnar_allocations(cols, job_total_sum)
-            _mark("replay_columns")
 
         if fast_residue:
             # ---- flat residue: binds / bucket moves / node registration
@@ -1267,7 +1154,6 @@ class AllocateAction(Action):
                     allocs, pipes,
                     spec.wrap_vec(node_alloc_sum[ni]), spec.wrap_vec(node_pipe_sum[ni]),
                 )
-        _mark("replay_residue")
 
         if binds:
             # BindVolumes precedes every dispatch (statement.go:253-277)
@@ -1288,7 +1174,6 @@ class AllocateAction(Action):
                 for ni in np.flatnonzero(node_alloc_cnt).tolist()
             }
             ssn.cache.bulk_bind(binds, job_sums=job_sums, node_sums=node_sums)
-        _mark("replay_bind")
 
         # slow path after every bulk placement has landed: host predicates
         # observe them; jobs the bulk path demoted replay sequentially too
@@ -1359,9 +1244,9 @@ class AllocateAction(Action):
                 job.uid, int(idxs.size),
             )
             # the session carries the control signal (backfill's real-request
-            # gate reads ssn.host_discards — ADVICE.md #5: the registry
+            # gate reads ssn.host_discards — round-5 ADVICE #5: the registry
             # singleton's counter crossed wires between scheduler instances);
-            # the instance counter stays as a bench/diagnostics record
+            # the instance counter stays as a diagnostics record
             self.last_host_discards += 1
             ssn.host_discards += 1
             stmt.discard()

@@ -71,7 +71,7 @@ class BackfillAction(Action):
         # rides the SESSION (set by allocate's discard path): the action
         # registry is a process-global singleton, and reading its counter
         # here crossed wires between scheduler instances sharing a process
-        # (tests, the simulator's many schedulers) — ADVICE.md #5
+        # (tests, the simulator's many schedulers) — round-5 ADVICE #5
         if not getattr(ssn, "host_discards", 0):
             return
         import jax

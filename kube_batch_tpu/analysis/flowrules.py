@@ -608,8 +608,6 @@ _DEVICE_SOURCES = {
     "kube_batch_tpu.ops.eviction.evict_solve",
     "kube_batch_tpu.ops.probe.probe_solve",
     "kube_batch_tpu.parallel.mesh.sharded_allocate_solve",
-    "kube_batch_tpu.parallel.mesh.sharded_allocate_topk_solve",
-    "kube_batch_tpu.parallel.mesh.sharded_warm_allocate_solve",
     "kube_batch_tpu.parallel.mesh.sharded_failure_histogram",
     "kube_batch_tpu.parallel.mesh.sharded_failure_histogram_bucket",
     "kube_batch_tpu.parallel.mesh.sharded_evict_solve",

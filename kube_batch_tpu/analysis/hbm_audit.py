@@ -73,9 +73,7 @@ Known slack vs XLA's real allocator (documented, deliberate):
 All slack overestimates: a clean tier-C verdict is conservative-safe.
 
 Run via ``python -m kube_batch_tpu.analysis --hbm`` (``--hbm-only`` for
-just this tier), the check.sh gate, the tier-1 self-enforcement test, or
-``bench.py``'s hbm_headroom section (bytes-vs-budget per entry per point,
-tracked across PRs like any perf number).
+just this tier), the check.sh gate, or the tier-1 self-enforcement test.
 """
 
 from __future__ import annotations
@@ -327,7 +325,7 @@ class _Liveness:
             body = eqn.params.get("jaxpr")
             return (self._scan_program(getattr(body, "jaxpr", body))
                     if body is not None else 0)
-        # pjit / closed_call / custom_* / remat / shard_map / pallas_call:
+        # pjit / closed_call / custom_* / remat / shard_map:
         # walk every reachable sub-jaxpr; shard_map bodies carry per-shard
         # LOCAL avals, so their internal peak is already per-device
         return sum(self._scan_program(s) for s in _sub_jaxprs(eqn))
@@ -686,7 +684,7 @@ HBM_ALLOWLIST: Dict[Tuple[str, str, str], str] = {
     #    exhaustion fallback keep [P, N] score/hash planes ----------------
     ("ops.assignment.allocate_topk_solve", "KBT202", "*"):
         "ROADMAP 1.(2): the candidate-table build scores [P, N] planes "
-        "(and the exhaustion fallback re-enters them); blocked/pallas "
+        "(and the exhaustion fallback re-enters them); a blocked "
         "table rebuild is the planned fix",
     ("ops.assignment.allocate_topk_solve", "KBT201", "northstar-1m"):
         "ROADMAP 1.(2): the [P, N] build planes are ~26 GiB each at "
@@ -881,9 +879,7 @@ def headroom_report(
     registry: Optional[Sequence[EntryPoint]] = None,
     points: Optional[Sequence[ShapePoint]] = None,
 ) -> Dict:
-    """bytes-vs-budget per entry per shape point — the bench's
-    hbm_headroom section records this so the headroom trajectory is
-    tracked across PRs like any other perf number."""
+    """bytes-vs-budget per entry per shape point."""
     if registry is None:
         registry = tuple(REGISTRY) + sharded_registry()
     if points is None:

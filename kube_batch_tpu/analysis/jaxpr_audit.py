@@ -10,7 +10,7 @@ module is the JaxPruner-style answer (PAPERS.md): audit what actually gets
 compiled, not what the source looks like.
 
 Mechanism: a REGISTRY of the package's jitted entry points (ops/ solves,
-the resident scatter, the Pallas round head).  Each entry is traced with
+the resident scatter).  Each entry is traced with
 ABSTRACT inputs (jax.ShapeDtypeStruct — no device work, no compile) under
 ``jax.enable_x64`` so dtype promotion is visible instead of
 silently canonicalized away, then the closed jaxpr is walked recursively
@@ -68,9 +68,8 @@ class EntryPoint:
     ``allow`` suppresses one audit rule for this entry, reason mandatory.
     ``steady`` declares the program steady-path/sparse: dispatched every
     cycle at scale, so tier C's KBT202 asserts it materializes no
-    task-axis × node-axis plane (the full-matrix oracle and the pallas tile
-    kernels are NOT steady — the first is the cold reference, the second
-    are fixed-tile building blocks)."""
+    task-axis × node-axis plane (the full-matrix oracle is NOT steady: it is
+    the cold reference)."""
 
     name: str
     build: Callable[..., Tuple[Callable, Tuple]]
@@ -367,41 +366,6 @@ def _build_enqueue_gate(sp: Optional[ShapePoint] = None):
     )
 
 
-def _build_pallas_round_head(sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.ops.pallas_kernels import NODE_TILE, TASK_TILE, masked_best_node
-
-    ax = sp or _AUDIT_POINT
-    T, N = TASK_TILE, NODE_TILE  # one tile — grid multiples are guaranteed
-    return masked_best_node, (
-        S((T, N), jnp.float32), S((T, N), jnp.bool_), S((T, ax.R), jnp.float32),
-        S((N, ax.R), jnp.float32), S((N, ax.R), jnp.float32), S((T,), jnp.bool_),
-        S((ax.R,), jnp.float32), True,  # interpret=True: auditable off-TPU
-    )
-
-
-def _build_pallas_topk_blocks(sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.ops.pallas_kernels import (
-        NODE_TILE,
-        TASK_TILE,
-        masked_topk_blocks,
-    )
-
-    ax = sp or _AUDIT_POINT
-    P, N = TASK_TILE, NODE_TILE
-    return masked_topk_blocks, (
-        S((P, N), jnp.float32), S((P, ax.R), jnp.float32),
-        S((N, ax.R), jnp.float32), S((N, ax.R), jnp.float32),
-        S((P,), jnp.int32), S((ax.R,), jnp.float32),
-        0, True,  # n0=0, interpret=True: auditable off-TPU
-    )
-
-
 def _abstract_probe_batch(B=2, G=4, R=_R, W=_W):
     """A ProbeBatch of ShapeDtypeStructs + the [G] row oracle — the query
     plane's serving shapes at audit scale."""
@@ -513,10 +477,6 @@ REGISTRY: Tuple[EntryPoint, ...] = (
                donate=_scatter_donation(), steady=True),
     EntryPoint("ops.admission.enqueue_gate", _build_enqueue_gate,
                steady=True),
-    EntryPoint("ops.pallas_kernels.masked_best_node",
-               _build_pallas_round_head),
-    EntryPoint("ops.pallas_kernels.masked_topk_blocks",
-               _build_pallas_topk_blocks),
     EntryPoint("ops.probe.probe_solve", _build_probe, steady=True),
     EntryPoint("ops.probe.probe_solve[topk-inert]", _build_topk_probe,
                steady=True),
@@ -800,7 +760,7 @@ def sharded_registry(n_devices: Optional[int] = None
 
 def _iter_jaxprs(jaxpr) -> Iterable:
     """The jaxpr and every sub-jaxpr reachable through eqn params
-    (pjit/while/cond/scan/pallas bodies)."""
+    (pjit/while/cond/scan bodies)."""
     yield jaxpr
     for eqn in jaxpr.eqns:
         for param in eqn.params.values():

@@ -528,18 +528,14 @@ class SentinelConsumeRule(Rule):
     scope = ("actions/",)
 
     #: callables whose results become binds/evictions — the committed
-    #: solve dispatch surface (single-device, sharded, and the actions'
-    #: own dispatch helpers)
+    #: solve dispatch surface (single-device, sharded, the allocate
+    #: dispatch's program lookup, and the actions' own dispatch helpers)
     DISPATCH_FNS = {
-        "dispatch_allocate_solve", "allocate_solve", "allocate_topk_solve",
-        "warm_allocate_solve", "warm_allocate_sentinel_solve",
+        "dispatch_allocate_solve", "allocate_program",
+        "allocate_solve", "allocate_topk_solve", "warm_allocate_solve",
         "allocate_sentinel_solve", "allocate_topk_sentinel_solve",
         "evict_solve", "evict_sentinel_solve",
-        "sharded_allocate_solve", "sharded_allocate_topk_solve",
-        "sharded_warm_allocate_solve",
-        "sharded_evict_solve", "sentinel_sharded_allocate_solve",
-        "sentinel_sharded_allocate_topk_solve",
-        "sentinel_sharded_warm_allocate_solve",
+        "sharded_allocate_solve", "sharded_evict_solve",
         "sentinel_sharded_evict_solve",
         "dispatch_enqueue_gate",
     }

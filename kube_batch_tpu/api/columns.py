@@ -1077,14 +1077,6 @@ class ColumnStore:
         resident drops, guard heals): the next warm dispatch cold-builds."""
         self._warm_tables.clear()
 
-    def warm_counters(self) -> Dict[str, Dict]:
-        """Per-slot warm-table counters for the bench / sim evidence."""
-        return {
-            f"{'single' if mesh is None else 'sharded'}"
-            f"{'' if impl is None else ':' + impl}": st.counters()
-            for (mesh, impl), st in self._warm_tables.items()
-        }
-
     def revalidate_resident(self, cache) -> Dict:
         """Warm-standby revalidation (leader failover): decide whether the
         surviving per-cycle device caches may keep serving after the host

@@ -84,7 +84,7 @@ class StandalonePVBinder:
         answers without labels; a topology-restricted PV evaluates its full
         required nodeSelectorTerms against the candidate's labels. Unknown
         labels fail closed — the PV_NODE_RESTRICTED_UNKNOWN floor of
-        ADVICE.md #1 — so an unlabeled/unseen node never fails open."""
+        round-5 ADVICE #1 — so an unlabeled/unseen node never fails open."""
         if pv.node is None or pv.node == hostname:
             return True
         terms = getattr(pv, "node_terms", ())
@@ -237,7 +237,7 @@ class K8sPVLedger(StandalonePVBinder):
     MAX_PENDING_WRITES = 256
     # seconds between timer-driven retry flushes while writes are queued —
     # an IDLE scheduler (no further binds) must still drain the queue
-    # (ADVICE.md #2: retries used to wait for the next bind_volumes call)
+    # (round-5 ADVICE #2: retries used to wait for the next bind_volumes call)
     RETRY_FLUSH_INTERVAL = 5.0
 
     def __init__(self, transport=None, bucket=None):
@@ -481,7 +481,7 @@ class K8sPVLedger(StandalonePVBinder):
         """A dropped claimRef PATCH must also drop its `bound` entry, or the
         cluster-side bind is lost for good: the unbound-PVC watch event
         deliberately doesn't clear `bound` (the in-flight-PATCH race above),
-        so nothing else would ever re-derive the write (ADVICE.md #2).
+        so nothing else would ever re-derive the write (round-5 ADVICE #2).
         Selected-node annotation drops need no ledger undo — the claim re-
         annotates on the task's next allocate/bind. Caller holds the lock."""
         for path, body in dropped:

@@ -507,7 +507,7 @@ class TokenBucket:
         wait is the time until its own token mints — so concurrent waiters
         (the 16-worker status pool, the binder, the pv-writes thread) sleep
         in parallel instead of serializing behind whoever holds the lock
-        (ADVICE.md #3). Aggregate rate is unchanged: tokens still mint at
+        (round-5 ADVICE #3). Aggregate rate is unchanged: tokens still mint at
         qps with a burst cap, and reservations are FIFO by lock order."""
         with self._lock:
             now = self._time.monotonic()

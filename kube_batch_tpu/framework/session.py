@@ -197,7 +197,7 @@ class Session:
         # demotion dead-ends) — the backfill action's real-request pass keys
         # off this. Carried on the session, NOT the process-global action
         # registry singleton: multiple Scheduler/cache instances in one
-        # process (tests, the simulator) must not cross wires (ADVICE.md #5)
+        # process (tests, the simulator) must not cross wires (round-5 ADVICE #5)
         self.host_discards = 0
         # the staged StatusFlush, stashed here by close_session as soon as
         # staging succeeds: if the close's own finally raises afterwards,
@@ -295,7 +295,7 @@ class Session:
     def conf_flag(self, key: str, default: bool = False) -> bool:
         """A free-form boolean argument searched across every tier's plugin
         Arguments (arguments.go:26-66) — the conf surface for action-level
-        toggles: `allocate.pallas`, and the sanctioned-divergence escape
+        toggles: the sanctioned-divergence escape
         hatches `preempt.referenceExact` / `reclaim.referenceExact`
         (PARITY.md "known divergences")."""
         for tier in self.tiers:

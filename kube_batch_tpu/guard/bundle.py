@@ -10,8 +10,8 @@ the violation report.  The write uses cache/persistence.py's atomic idiom
 never leaves a half bundle that replays differently.
 
 ``python -m kube_batch_tpu.sim --replay-bundle <dir>`` reloads a bundle
-and re-runs the condemned program AND its oracle (KB_TOPK=0 / full-matrix
-/ use_pallas off) on the captured snapshot, sentinel-fused both ways —
+and re-runs the condemned program AND its oracle (KB_TOPK=0 /
+full-matrix) on the captured snapshot, sentinel-fused both ways —
 deterministic reproduction of the trip without the cluster, the workload,
 or the timing that produced it.
 
@@ -35,7 +35,7 @@ import numpy as np
 logger = logging.getLogger("kube_batch_tpu")
 
 _KNOBS = (
-    "KB_TOPK", "KB_SHARD_MAP", "KB_SHARD", "KB_TASK_SHARDS", "KB_PALLAS",
+    "KB_TOPK", "KB_SHARD_MAP", "KB_SHARD", "KB_TASK_SHARDS",
     "KB_GUARD", "KB_AUDIT_EVERY", "KB_GUARD_COOLDOWN", "KB_DEVICE_CACHE",
     "KB_SNAPSHOT_DELTA", "KB_PIPELINE", "JAX_PLATFORMS",
 )
@@ -130,11 +130,14 @@ def load_bundle(path: str):
 
 
 def _rebuild_config(meta: Dict):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
+    from kube_batch_tpu.ops.assignment import (
+        AllocateConfig,
+        without_removed_fields,
+    )
     from kube_batch_tpu.ops.eviction import EvictConfig
     from kube_batch_tpu.ops.scoring import ScoreWeights
 
-    d = dict(meta["config"])
+    d = without_removed_fields(meta["config"])  # a bundle from before PR 29
     w = d.pop("weights", None)
     dropped = []
     if w is not None:
@@ -208,7 +211,7 @@ def replay_bundle(path: str) -> Dict:
     else:
         fast_res, fv, fh, _e = allocate_sentinel_solve(snap, config)
         fast_name = "full"
-    oracle_cfg = config._replace(topk=0, use_pallas=False)
+    oracle_cfg = config._replace(topk=0)
     orc_res, ov, oh, _oe = allocate_sentinel_solve(snap, oracle_cfg)
     (f_assigned, f_pipe, fv, fh, o_assigned, o_pipe, ov, oh) = jax.device_get(
         (fast_res.assigned, fast_res.pipelined, fv, fh,

@@ -3,15 +3,15 @@
 Generalizes the transport layer's :class:`k8s.transport.CircuitBreaker`
 discipline from "is this apiserver reachable" to "is this solve fast path
 producing lawful results": each demotable fast path (KB_TOPK compaction,
-the shard_map collective bodies, the Pallas round head) carries a health
-state —
+the shard_map collective bodies, the KB_WARM carried table) carries a
+health state —
 
     healthy ──trip──▶ demoted ──KB_GUARD_COOLDOWN clean cycles──▶ probing
        ▲                 ▲                                           │
        └── clean probe ──┘◀──────────── trip during probe ───────────┘
 
 A demoted path's dispatches run the ORACLE program (KB_TOPK=0 / pjit /
-use_pallas=False — the same knobs the tests pin bit-exactness against);
+KB_WARM=0 — the same knobs the tests pin bit-exactness against);
 ``probing`` is the half-open state: the next dispatch runs the fast path
 again under the sentinel, and one clean engaged cycle re-promotes.  Time
 is counted in SCHEDULING CYCLES (the Scheduler's loop calls
@@ -48,11 +48,11 @@ logger = logging.getLogger("kube_batch_tpu")
 
 #: the demotable fast paths — each has a per-dispatch oracle knob the
 #: demotion flips (actions/allocate.py dispatch + parallel/mesh.py impl
-#: selection + the session's use_pallas flag).  "warm" is the carried
+#: selection).  "warm" is the carried
 #: candidate-table path (KB_WARM): demotion pins the compacted solve to
 #: its cold per-solve build, and the trip heal drops the carried table
 #: with the resident caches (ColumnStore.drop_resident)
-FAST_PATHS = ("topk", "shard_map", "pallas", "warm")
+FAST_PATHS = ("topk", "shard_map", "warm")
 
 HEALTHY, DEMOTED, PROBING = "healthy", "demoted", "probing"
 

@@ -377,7 +377,7 @@ def priority_class_from_k8s(obj: dict) -> PriorityClass:
 # PersistentVolume.node_terms, and the ledger evaluates them against
 # candidate node labels (the reference volumebinder's behavior) — the
 # sentinel only bites when labels for the candidate are unknown, keeping
-# ADVICE.md #1's fail-closed floor without its zonal over-restriction.
+# round-5 ADVICE #1's fail-closed floor without its zonal over-restriction.
 PV_NODE_RESTRICTED_UNKNOWN = "__pv-node-affinity-unrecognized__"
 
 
@@ -407,7 +407,7 @@ def _pv_node_affinity(spec: dict) -> Tuple[Optional[str], tuple]:
         # term are AND'd, so a term pairing a hostname pin with e.g. a zone
         # requirement pins conditionally and must evaluate in full — taking
         # the hostname alone would fail open on a node whose other labels
-        # don't match (the ADVICE.md #1 bug class again)
+        # don't match (the round-5 ADVICE #1 bug class again)
         if len(term) != 1:
             continue
         key, op, values = term[0]

@@ -352,7 +352,7 @@ GUARD_AUDITS = Counter(
 )
 GUARD_PATH_DEMOTED = Gauge(
     f"{_SUBSYSTEM}_guard_path_demoted",
-    "1 while a fast path is demoted to its oracle (topk|shard_map|pallas)",
+    "1 while a fast path is demoted to its oracle (topk|shard_map|warm)",
     ("path",),
 )
 # cycle tracing plane (kube_batch_tpu/obs): per-stage latency straight off

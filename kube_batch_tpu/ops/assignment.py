@@ -46,7 +46,7 @@ NEG = jnp.float32(-3.0e38)
 
 # the multiplicative hash constants as wrapped int32 (two's complement):
 # int32 wrapping arithmetic is bit-identical to uint32 mod-2^32, and staying
-# in int32 avoids uint32<->float casts TPU Pallas doesn't support
+# in int32 avoids uint32<->float casts on the device
 _H1 = 0x9E3779B1 - (1 << 32)
 _H2 = 0x85EBCA77 - (1 << 32)
 _H3 = 0xCA87C3EB - (1 << 32)
@@ -100,12 +100,25 @@ class AllocateConfig(NamedTuple):
     gang: bool = True        # gang plugin (JobReady commit gate)
     drf: bool = True         # drf job ordering
     proportion: bool = True  # queue overused gating + queue order
-    use_pallas: bool = False  # fused round-head kernel (ops/pallas_kernels)
     topk: int = 0            # top-K candidate compaction width (the
     #                          allocate_topk_solve path only; 0 in every
     #                          full-matrix program — see KB_TOPK in
     #                          actions/allocate.py's dispatch)
     weights: ScoreWeights = ScoreWeights()
+
+
+def without_removed_fields(fields: dict) -> dict:
+    """The fields of an AllocateConfig serialized by an older version (a
+    guard bundle's meta.json, a replication frame from a leader not yet
+    upgraded) without what this version no longer has: ``use_pallas``,
+    whose kernel PR 29 removed.  Off, it is dropped; on, the program it
+    names cannot be run here, and that is an error."""
+    fields = dict(fields)
+    if fields.pop("use_pallas", False):
+        raise ValueError(
+            "config has use_pallas=true: the Pallas round-head kernel was "
+            "removed in PR 29, so its program cannot be run")
+    return fields
 
 
 class AllocateResult(NamedTuple):
@@ -237,10 +250,6 @@ def round_head_parts(snap: DeviceSnapshot, config: AllocateConfig,
     gang would occupy — and reuses static_ok/score for its eviction bids
     and fit-error histogram; sharing ONE head body is what keeps probe
     answers structurally bit-identical to the committed solve."""
-    if tie_hash is not None and config.use_pallas:
-        # the Pallas kernel computes its own (offset-parameterized) hash
-        # from arange rows — an explicit row override cannot route there
-        raise ValueError("tie_hash override requires use_pallas=False")
     static_ok = static_predicates(snap)           # [T, N]
     score = score_matrix(snap, config.weights)
     # static predicates folded into the score once — every round reuses it
@@ -250,17 +259,6 @@ def round_head_parts(snap: DeviceSnapshot, config: AllocateConfig,
         tie_hash = _tie_break_hash(T, N)
 
     def head(idle, releasing, pending):
-        if config.use_pallas:
-            from kube_batch_tpu.ops.pallas_kernels import (
-                interpret_mode,
-                masked_best_node,
-            )
-
-            return masked_best_node(
-                score, static_ok, snap.task_req, idle, releasing,
-                pending, snap.quanta,
-                interpret=interpret_mode(),
-            )
         fit_idle = fits(snap.task_req, idle, snap.quanta)
         # zero-releasing clusters (every allocate-only cycle) skip
         # the second [T, N] fit entirely: with an all-zero budget the
@@ -838,31 +836,15 @@ def compact_candidates(view_p: DeviceSnapshot, pend_rows: jnp.ndarray,
     static_ok = static_predicates(view_p)
     score = score_matrix(view_p, config.weights)
     score_static = jnp.where(static_ok, score, NEG)
-    if config.use_pallas:
-        from kube_batch_tpu.ops.pallas_kernels import (
-            interpret_mode,
-            masked_topk_blocks,
-        )
-
-        skey0, bval, bhash, bcol = masked_topk_blocks(
-            score_static, view_p.task_req, idle0, releasing0,
-            safe_rows, quanta, n0=n0,
-            interpret=interpret_mode(),
-        )
-        triples = (bval, bhash, bcol)
-        del triples  # block partials are a fusion detail; extraction below
-        # recomputes them from skey0 (the kernel's win is the fused
-        # fit+mask+sort-key emit, not the cheap [P, B] triples)
-    else:
-        fit0 = fits(view_p.task_req, idle0, quanta)
-        fit0_rel = jax.lax.cond(
-            jnp.any(releasing0 > 0.0),
-            lambda rel: fits(view_p.task_req, rel, quanta),
-            lambda rel: jnp.zeros_like(fit0),
-            releasing0,
-        )
-        masked0 = jnp.where(fit0 | fit0_rel, score_static, NEG)
-        skey0 = f32_sort_key(masked0)
+    fit0 = fits(view_p.task_req, idle0, quanta)
+    fit0_rel = jax.lax.cond(
+        jnp.any(releasing0 > 0.0),
+        lambda rel: fits(view_p.task_req, rel, quanta),
+        lambda rel: jnp.zeros_like(fit0),
+        releasing0,
+    )
+    masked0 = jnp.where(fit0 | fit0_rel, score_static, NEG)
+    skey0 = f32_sort_key(masked0)
     neg_key = _neg_key()
     # dtype pinned: the count rides the shard merge's i32 payload and must
     # stay i32 under the jaxpr audit's x64 probe

@@ -433,19 +433,6 @@ def warm_sentinel_solve_fn():
     return _WARM_SENTINEL
 
 
-def warm_allocate_sentinel_solve(snap, pend_rows, table, plan,
-                                 config: AllocateConfig, k_min: int):
-    """Dispatch-facing sentinel-fused warm solve: same calling shape as
-    ops.assignment.warm_allocate_solve, returning ``(result, verdict,
-    hist, checksum, table', eroded)``."""
-    t_idx, t_skey, t_hash, t_trunc = table
-    row_map, changed, rr, rslots = plan
-    return warm_sentinel_solve_fn()(
-        snap, pend_rows, t_idx, t_skey, t_hash, t_trunc,
-        row_map, changed, rr, rslots, config=config, k_min=k_min,
-    )
-
-
 @partial(jax.jit, static_argnames=("config",))
 def evict_sentinel_solve(snap: DeviceSnapshot, config: EvictConfig):
     """evict_solve (reclaim/preempt) with the fused invariant tail."""

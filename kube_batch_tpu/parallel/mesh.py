@@ -296,17 +296,6 @@ def allocate_topk_solve_fn(mesh: Mesh, config: AllocateConfig,
     return fn
 
 
-def sharded_allocate_topk_solve(
-    snap: DeviceSnapshot, pend_rows, config: AllocateConfig, mesh: Mesh,
-    impl: Optional[str] = None,
-) -> AllocateResult:
-    """The compacted allocate solve jitted over the mesh (pending-row
-    bucket replicated, node columns sharded, ledgers back node-sharded)."""
-    fn = allocate_topk_solve_fn(mesh, config, impl=impl)
-    with mesh:
-        return fn(snap, pend_rows)
-
-
 def warm_allocate_solve_fn(mesh: Mesh, config: AllocateConfig, k_min: int,
                            impl: Optional[str] = None):
     """The memoized jitted WARM-STARTED compacted solve for (mesh, config,
@@ -345,58 +334,6 @@ def warm_allocate_solve_fn(mesh: Mesh, config: AllocateConfig, k_min: int,
         jitstats.register(f"sharded_warm_allocate_solve[{impl}]", fn)
         _jit_cache[key] = fn
     return fn
-
-
-def sharded_warm_allocate_solve(snap, pend_rows, table, plan,
-                                config: AllocateConfig, k_min: int,
-                                mesh: Mesh, impl: Optional[str] = None):
-    """The warm-started compacted solve over the mesh — same calling
-    shape as ops.assignment.warm_allocate_solve, returning
-    ``(AllocateResult, table', eroded)``; the refreshed table comes back
-    replicated and carries to the next cycle as-is."""
-    fn = warm_allocate_solve_fn(mesh, config, k_min, impl=impl)
-    t_idx, t_skey, t_hash, t_trunc = table
-    row_map, changed, rr, rslots = plan
-    with mesh:
-        return fn(snap, pend_rows, t_idx, t_skey, t_hash, t_trunc,
-                  row_map, changed, rr, rslots)
-
-
-def sentinel_warm_allocate_solve_fn(mesh: Mesh, config: AllocateConfig,
-                                    k_min: int,
-                                    impl: Optional[str] = None):
-    from kube_batch_tpu.ops.invariants import (
-        allocate_invariants,
-        eligibility_checksum,
-    )
-
-    impl = _impl(impl)
-    key = (mesh, config, "sentinel_warm", k_min, impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        inner = warm_allocate_solve_fn(mesh, config, k_min, impl=impl)
-
-        def fused(snap, pend_rows, *rest):
-            res, table, eroded = inner(snap, pend_rows, *rest)
-            verdict, hist = allocate_invariants(snap, res, config)
-            return (res, verdict, hist, eligibility_checksum(snap),
-                    table, eroded)
-
-        fn = jax.jit(fused)
-        jitstats.register(f"sentinel_sharded_warm_allocate_solve[{impl}]",
-                          fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sentinel_sharded_warm_allocate_solve(snap, pend_rows, table, plan,
-                                         config, k_min, mesh, impl=None):
-    fn = sentinel_warm_allocate_solve_fn(mesh, config, k_min, impl=impl)
-    t_idx, t_skey, t_hash, t_trunc = table
-    row_map, changed, rr, rslots = plan
-    with mesh:
-        return fn(snap, pend_rows, t_idx, t_skey, t_hash, t_trunc,
-                  row_map, changed, rr, rslots)
 
 
 def failure_histogram_bucket_fn(mesh: Mesh, impl: Optional[str] = None):
@@ -524,33 +461,41 @@ def _evict(snap: DeviceSnapshot, config: EvictConfig) -> EvictResult:
 # --------------------------------------------------------------------------
 
 
+def _sentinel_fn(key, name: str, inner_fn, invariants, config,
+                 carries_table: bool = False):
+    """The memoized jitted ``inner`` program with ``invariants`` and the
+    eligibility checksum fused behind it: ``(result, verdict, hist,
+    checksum)``, and behind those the refreshed table and erosion flag of
+    a warm program (``carries_table``), which returns ``(result, table',
+    eroded)``.  ``inner_fn`` builds the inner program on first use."""
+    fn = _jit_cache.get(key)
+    if fn is None:
+        from kube_batch_tpu.ops.invariants import eligibility_checksum
+
+        inner = inner_fn()
+
+        def fused(snap, *rest):
+            out = inner(snap, *rest)
+            res, *carry = out if carries_table else (out,)
+            verdict, hist = invariants(snap, res, config)
+            return (res, verdict, hist, eligibility_checksum(snap), *carry)
+
+        fn = jax.jit(fused)
+        jitstats.register(name, fn)
+        _jit_cache[key] = fn
+    return fn
+
+
 def sentinel_allocate_solve_fn(mesh: Mesh, config: AllocateConfig,
                                impl: Optional[str] = None):
     from kube_batch_tpu.ops.invariants import allocate_invariants
 
     impl = _impl(impl)
-    key = (mesh, config, "sentinel_alloc", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        inner = allocate_solve_fn(mesh, config, impl=impl)
-
-        from kube_batch_tpu.ops.invariants import eligibility_checksum
-
-        def fused(snap):
-            res = inner(snap)
-            verdict, hist = allocate_invariants(snap, res, config)
-            return res, verdict, hist, eligibility_checksum(snap)
-
-        fn = jax.jit(fused)
-        jitstats.register(f"sentinel_sharded_allocate_solve[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sentinel_sharded_allocate_solve(snap, config, mesh, impl=None):
-    fn = sentinel_allocate_solve_fn(mesh, config, impl=impl)
-    with mesh:
-        return fn(snap)
+    return _sentinel_fn(
+        (mesh, config, "sentinel_alloc", impl),
+        f"sentinel_sharded_allocate_solve[{impl}]",
+        lambda: allocate_solve_fn(mesh, config, impl=impl),
+        allocate_invariants, config)
 
 
 def sentinel_allocate_topk_solve_fn(mesh: Mesh, config: AllocateConfig,
@@ -558,29 +503,24 @@ def sentinel_allocate_topk_solve_fn(mesh: Mesh, config: AllocateConfig,
     from kube_batch_tpu.ops.invariants import allocate_invariants
 
     impl = _impl(impl)
-    key = (mesh, config, "sentinel_topk", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        inner = allocate_topk_solve_fn(mesh, config, impl=impl)
-
-        from kube_batch_tpu.ops.invariants import eligibility_checksum
-
-        def fused(snap, pend_rows):
-            res = inner(snap, pend_rows)
-            verdict, hist = allocate_invariants(snap, res, config)
-            return res, verdict, hist, eligibility_checksum(snap)
-
-        fn = jax.jit(fused)
-        jitstats.register(f"sentinel_sharded_allocate_topk_solve[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
+    return _sentinel_fn(
+        (mesh, config, "sentinel_topk", impl),
+        f"sentinel_sharded_allocate_topk_solve[{impl}]",
+        lambda: allocate_topk_solve_fn(mesh, config, impl=impl),
+        allocate_invariants, config)
 
 
-def sentinel_sharded_allocate_topk_solve(snap, pend_rows, config, mesh,
-                                         impl=None):
-    fn = sentinel_allocate_topk_solve_fn(mesh, config, impl=impl)
-    with mesh:
-        return fn(snap, pend_rows)
+def sentinel_warm_allocate_solve_fn(mesh: Mesh, config: AllocateConfig,
+                                    k_min: int,
+                                    impl: Optional[str] = None):
+    from kube_batch_tpu.ops.invariants import allocate_invariants
+
+    impl = _impl(impl)
+    return _sentinel_fn(
+        (mesh, config, "sentinel_warm", k_min, impl),
+        f"sentinel_sharded_warm_allocate_solve[{impl}]",
+        lambda: warm_allocate_solve_fn(mesh, config, k_min, impl=impl),
+        allocate_invariants, config, carries_table=True)
 
 
 def sentinel_evict_solve_fn(mesh: Mesh, config: EvictConfig,
@@ -588,29 +528,55 @@ def sentinel_evict_solve_fn(mesh: Mesh, config: EvictConfig,
     from kube_batch_tpu.ops.invariants import evict_invariants
 
     impl = _impl(impl)
-    key = (mesh, config, "sentinel_evict", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        inner = evict_solve_fn(mesh, config, impl=impl)
-
-        from kube_batch_tpu.ops.invariants import eligibility_checksum
-
-        def fused(snap):
-            res = inner(snap)
-            verdict, hist = evict_invariants(snap, res, config)
-            return res, verdict, hist, eligibility_checksum(snap)
-
-        fn = jax.jit(fused)
-        jitstats.register(
-            f"sentinel_sharded_evict_solve[{config.mode},{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
+    return _sentinel_fn(
+        (mesh, config, "sentinel_evict", impl),
+        f"sentinel_sharded_evict_solve[{config.mode},{impl}]",
+        lambda: evict_solve_fn(mesh, config, impl=impl),
+        evict_invariants, config)
 
 
 def sentinel_sharded_evict_solve(snap, config, mesh, impl=None):
     fn = sentinel_evict_solve_fn(mesh, config, impl=impl)
     with mesh:
         return fn(snap)
+
+
+#: (kind, sentinel) -> the getter that memoizes the program on a mesh
+_MESH_ALLOCATE_GETTERS = {
+    ("full", False): allocate_solve_fn,
+    ("full", True): sentinel_allocate_solve_fn,
+    ("topk", False): allocate_topk_solve_fn,
+    ("topk", True): sentinel_allocate_topk_solve_fn,
+    ("warm", False): warm_allocate_solve_fn,
+    ("warm", True): sentinel_warm_allocate_solve_fn,
+}
+
+
+def allocate_program(kind: str, mesh: Optional[Mesh], impl: Optional[str],
+                     config: AllocateConfig, sentinel: bool, k_min: int = 0):
+    """THE lookup of an allocate dispatch's program: the memoized jitted
+    callable for ``kind`` ("full" | "topk" | "warm"), bare or with the
+    invariant tail fused behind it (``sentinel``).  On a ``mesh`` it is
+    what the ``*_solve_fn`` getter memoizes for (mesh, config, impl), with
+    ``config`` (and a warm program's ``k_min``) baked in; ``mesh=None`` is
+    the single-device program of ops/assignment.py or ops/invariants.py,
+    which takes them as static arguments at the call."""
+    if mesh is not None:
+        getter = _MESH_ALLOCATE_GETTERS[kind, sentinel]
+        if kind == "warm":
+            return getter(mesh, config, k_min, impl=impl)
+        return getter(mesh, config, impl=impl)
+    from kube_batch_tpu.ops import assignment, invariants
+
+    if kind == "warm":
+        return (invariants.warm_sentinel_solve_fn() if sentinel
+                else assignment.warm_solve_fn())
+    return {
+        ("full", False): assignment.allocate_solve,
+        ("full", True): invariants.allocate_sentinel_solve,
+        ("topk", False): assignment.allocate_topk_solve,
+        ("topk", True): invariants.allocate_topk_sentinel_solve,
+    }[kind, sentinel]
 
 
 def probe_solve_fn(mesh: Mesh, config: AllocateConfig,

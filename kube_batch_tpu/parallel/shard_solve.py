@@ -227,34 +227,21 @@ def _allocate_body(snap, *, config, node_shards, task_shards):
             pending if task_shards == 1
             else jax.lax.dynamic_slice_in_dim(pending, t0, T_blk, axis=0)
         )
-        if config.use_pallas:
-            from kube_batch_tpu.ops.pallas_kernels import (
-                interpret_mode,
-                masked_best_node_raw,
-            )
-
-            pick, lval, lkey, lchose = masked_best_node_raw(
-                score, static_ok, req_blk, idle_b, rel_b, pending_b,
-                quanta, t0=t0, n0=n0,
-                interpret=interpret_mode(),
-            )
-            lidx = pick + n0
-        else:
-            fit_idle = fits(req_blk, idle_b, quanta)
-            # per-shard zero-releasing skip: exact for solver outputs (see
-            # local_round_head), and finer-grained than the global test —
-            # a shard with no releasing budget skips its block fit alone
-            fit_rel = jax.lax.cond(
-                jnp.any(rel_b > 0.0),
-                lambda rel: fits(req_blk, rel, quanta),
-                lambda rel: jnp.zeros_like(fit_idle),
-                rel_b,
-            )
-            masked = jnp.where(
-                (fit_idle | fit_rel) & pending_b[:, None], score_static, NEG
-            )
-            lval, lkey, pick, lidx = _local_best(masked, tie_blk, n0)
-            lchose = jnp.take_along_axis(fit_idle, pick[:, None], axis=1)[:, 0]
+        fit_idle = fits(req_blk, idle_b, quanta)
+        # per-shard zero-releasing skip: exact for solver outputs (see
+        # local_round_head), and finer-grained than the global test —
+        # a shard with no releasing budget skips its block fit alone
+        fit_rel = jax.lax.cond(
+            jnp.any(rel_b > 0.0),
+            lambda rel: fits(req_blk, rel, quanta),
+            lambda rel: jnp.zeros_like(fit_idle),
+            rel_b,
+        )
+        masked = jnp.where(
+            (fit_idle | fit_rel) & pending_b[:, None], score_static, NEG
+        )
+        lval, lkey, pick, lidx = _local_best(masked, tie_blk, n0)
+        lchose = jnp.take_along_axis(fit_idle, pick[:, None], axis=1)[:, 0]
         vmax, best_b, chose_b = _combine_best(
             lval, lkey, lidx, lchose.astype(jnp.int32)
         )

@@ -96,9 +96,12 @@ def config_to_wire(cfg):
 def config_from_wire(obj):
     """Inverse of :func:`config_to_wire`."""
     if isinstance(obj, dict) and "__cfg__" in obj:
+        from kube_batch_tpu.ops.assignment import without_removed_fields
+
         cls = _config_registry()[obj["__cfg__"]]
         kwargs = {k: config_from_wire(v) for k, v in obj["fields"].items()}
-        return cls(**kwargs)
+        # a leader not yet upgraded still sends what this version removed
+        return cls(**without_removed_fields(kwargs))
     if isinstance(obj, dict) and "__tuple__" in obj:
         return tuple(config_from_wire(v) for v in obj["__tuple__"])
     return obj

@@ -245,10 +245,7 @@ class QueryPlane:
             default_mesh() if should_shard(snap.node_alloc.shape[0]) else None
         )
         dev = resident_snap(cols, snap, mesh=mesh)
-        # the probe never runs the Pallas head (bit-exact either way; G is
-        # far below the kernel tile) — strip the flag so serving shares one
-        # compile cache regardless of the write path's opt-in
-        config = session_allocate_config(ssn)._replace(use_pallas=False)
+        config = session_allocate_config(ssn)
         gates = victim_gates(ssn, "preempt")
         if not self._gate_gap_warned and gates & {"drf", "proportion"}:
             # a conf whose first voting preempt tier includes drf or

@@ -1,7 +1,6 @@
 """Longitudinal scheduling metrics over a simulated run.
 
-The in-process benchmark (testing/benchmark.py) measures one frozen cycle;
-these measure what only a timeline can: per-job queueing delay (arrival →
+A frozen cycle cannot show these; they measure what only a timeline can: per-job queueing delay (arrival →
 first bind) and completion time (arrival → last pod success), per-queue
 share-vs-entitlement over time, eviction/preemption churn, and makespan —
 all in VIRTUAL seconds, so they are properties of the scheduling policy,
@@ -16,7 +15,7 @@ from typing import Dict, List, Optional
 def nearest_rank(values: List[float], p: float) -> float:
     """Nearest-rank percentile: ceil(p*n)-1.  `int(p*n)` sat one rank high
     (p50 of a 2-sample read the max), overstating small-n tails — the ONE
-    shared definition (bench.py and testing/e2e.py call this too)."""
+    shared definition (testing/e2e.py calls this too)."""
     import math
 
     xs = sorted(values)

@@ -795,8 +795,8 @@ def run_density(master: str, n_pods: int = 3000, n_nodes: int = 100,
     (test/kubemark + test/e2e/benchmark.go:53-285): N hollow nodes, a
     minMember=`gang` gang, then `n_pods` 1m-cpu latency pods — all through
     the real apiserver protocol (watch in, Binding POSTs out), measuring
-    per-pod create→bind PodStartupLatency percentiles.  The in-process
-    testing/benchmark.py covers raw solve scale; this covers the wire."""
+    per-pod create→bind PodStartupLatency percentiles.  benchmark/run.py
+    covers the served path at scale; this covers the wire."""
     ns = "e2e-density"
     c = Cluster(master, **auth)
     c.apply_crds()
@@ -859,8 +859,8 @@ def run_density(master: str, n_pods: int = 3000, n_nodes: int = 100,
                     "--stub apiserver the protocol endpoint (pure-Python "
                     "HTTP on this host) bounds throughput, not the "
                     "scheduler — use a real/kind cluster for absolute "
-                    "numbers; the in-process matrix "
-                    "(testing/benchmark.py) isolates solve scale.",
+                    "numbers; benchmark/run.py measures the served "
+                    "path at scale.",
         }
 
 

@@ -715,7 +715,7 @@ class TestKBT009:
         assert "never read" in findings[0].message
 
     def test_sink_accumulation_is_clean(self):
-        # the allocate action's _PhaseMarks shape: the value flows into an
+        # an accumulating phase-mark shape: the value flows into an
         # ms sink and the next-mark attribute store
         src = """
         from kube_batch_tpu.utils import telemetry

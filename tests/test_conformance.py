@@ -1130,7 +1130,7 @@ class TestRealRequestBackfill:
 
         assert get_action("allocate").last_host_discards == 1
         # the control signal backfill consumed rides the SESSION, not the
-        # process-global action registry (ADVICE.md #5) — ≥1 because the
+        # process-global action registry (round-5 ADVICE #5) — ≥1 because the
         # backfill helper replay's own discards accumulate on it too
         assert ssn.host_discards >= 1
         # G discarded entirely; S backfilled into the freed capacity

@@ -475,6 +475,38 @@ class TestBundles:
             "atomic publish must leave only complete trip-* bundles"
         )
 
+    @pytest.mark.parametrize("flag", [False, True])
+    def test_bundle_from_before_the_pallas_removal(self, tmp_path, flag):
+        """A bundle directory is input from outside the program: one
+        written before PR 29 carries ``use_pallas`` in its config.  Off, it
+        replays as any other; on, it names a kernel that is gone, and the
+        replay says so."""
+        import json
+
+        from kube_batch_tpu.guard.bundle import replay_bundle
+
+        cache = _mk_cache(reserve_topk=True)
+        _add_gang(cache, 0)
+        _cycle(cache)
+        gp = cache.guard_plane
+        gp.bundle_dir = str(tmp_path)
+        _corrupt_ledger(cache)
+        _add_gang(cache, 1)
+        _cycle(cache)
+        path = gp.bundles[0]
+        want = replay_bundle(path)
+        meta_path = tmp_path / path.rsplit("/", 1)[-1] / "meta.json"
+        meta = json.loads(meta_path.read_text())
+        assert "use_pallas" not in meta["config"]
+        assert not any("PALLAS" in k for k in meta["knobs"])
+        meta["config"]["use_pallas"] = flag
+        meta_path.write_text(json.dumps(meta))
+        if flag:
+            with pytest.raises(ValueError, match="removed in PR 29"):
+                replay_bundle(path)
+        else:
+            assert replay_bundle(path) == want
+
 
 # ==========================================================================
 # sentinel invariant math (device-level units)

@@ -37,8 +37,8 @@ class TestRegistryClean:
         assert any("allocate_topk_solve" in n for n in names)
         assert any("evict_solve" in n for n in names)
         assert any("resident" in n for n in names)
-        assert any("pallas" in n for n in names)
-        assert any("masked_topk_blocks" in n for n in names)
+        assert any("warm_allocate_solve" in n for n in names)
+        assert any("warm_allocate_sentinel_solve" in n for n in names)
         assert any("enqueue_gate" in n for n in names)
         assert any("topk-inert" in n for n in names)
 
@@ -64,7 +64,7 @@ class TestRegistryClean:
 class TestPlantedBugs:
     def test_planted_f64_upcast_is_detected(self):
         # np.float64 scalar promotes the whole expression under x64 — the
-        # exact hazard class the pallas round head shipped (fixed this PR)
+        # exact hazard class a round-head kernel once shipped
         def build():
             fn = jax.jit(lambda x: x * np.float64(2.0))
             return fn, (_vec(),)

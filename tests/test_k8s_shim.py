@@ -693,7 +693,7 @@ class TestEventFuzz:
 
 
 class TestPvNodeAffinityFailClosed:
-    """ADVICE.md #1 regression: a PV whose REQUIRED nodeAffinity terms are
+    """round-5 ADVICE #1 regression: a PV whose REQUIRED nodeAffinity terms are
     unrecognized must translate as restrictive (reachable from no node),
     never as node=None (reachable from every node); metadata.name In
     expressions are a recognized single-node pin."""
@@ -766,7 +766,7 @@ class TestPvNodeAffinityFailClosed:
 
 
 class TestPvLedgerRetryQueue:
-    """ADVICE.md #2 regression: retry-queue overflow must release the
+    """round-5 ADVICE #2 regression: retry-queue overflow must release the
     dropped claimRef's ledger binding (so it re-derives), and queued
     retries must drain on a timer even when the scheduler goes idle."""
 

@@ -91,35 +91,3 @@ class TestPointerLifetime:
         assert back == spec
         r = back.build(100, scalars={"x.com/npu": 500})
         assert r.less_equal(back.build(200, scalars={"x.com/npu": 500}))
-
-
-class TestGoLoopNative:
-    def test_native_loop_matches_numpy_loop(self):
-        """The C go-loop (native/go_pass.c) must reproduce the numpy
-        re-creation's placements exactly — same control flow, same
-        arithmetic — in both pass modes; otherwise its time is not a valid
-        denominator for the speedup bracket."""
-        import numpy as np
-        import pytest
-
-        from kube_batch_tpu.testing.go_baseline import (
-            _workload,
-            go_loop_allocate,
-            go_loop_allocate_native,
-        )
-
-        (task_req, task_job, job_min, node_idle, node_alloc, quanta,
-         nt, nn) = _workload(800, 64, 4, 3)
-        base_assigned, base_stats = go_loop_allocate(
-            task_req, task_job, job_min, node_idle.copy(), node_alloc, quanta
-        )
-        for pooled in (False, True):
-            out = go_loop_allocate_native(
-                task_req, task_job, job_min, node_idle.copy(), node_alloc,
-                quanta, pooled=pooled, threads=4,
-            )
-            if out is None:
-                pytest.skip("native go_pass library unavailable")
-            assigned, stats = out
-            np.testing.assert_array_equal(assigned, base_assigned)
-            assert stats["placed"] == base_stats["placed"] > 0

@@ -210,6 +210,19 @@ class TestWireFormat:
         with pytest.raises(TypeError):
             stream.config_to_wire(object())
 
+    def test_config_from_a_leader_before_the_pallas_removal(self):
+        """A leader not yet upgraded still sends ``use_pallas``: off, the
+        follower builds the same config; on, it names a program that is
+        gone."""
+        from kube_batch_tpu.ops.assignment import AllocateConfig
+
+        wire = stream.config_to_wire(AllocateConfig())
+        wire["fields"]["use_pallas"] = False
+        assert stream.config_from_wire(wire) == AllocateConfig()
+        wire["fields"]["use_pallas"] = True
+        with pytest.raises(ValueError, match="removed in PR 29"):
+            stream.config_from_wire(wire)
+
     def test_meta_patch_round_trip(self):
         prev = {
             "task_keys": ["a/0", "a/1", "b/0"],

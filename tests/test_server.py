@@ -699,7 +699,7 @@ class TestRestartWithBindings:
 
 class TestTokenBucketConcurrency:
     def test_take_sleeps_outside_the_lock(self):
-        """ADVICE.md #3 regression: a waiter must reserve under the lock and
+        """round-5 ADVICE #3 regression: a waiter must reserve under the lock and
         sleep OUTSIDE it — a sleeper holding self._lock serializes the
         16-worker status pool and head-of-line blocks the bind loop."""
         from kube_batch_tpu.cmd.server import TokenBucket

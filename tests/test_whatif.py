@@ -873,7 +873,7 @@ class TestShardedProbe:
         ssn = open_session(cache, CONF.tiers)
         try:
             snap, meta = build_session_snapshot(ssn)
-            config = session_allocate_config(ssn)._replace(use_pallas=False)
+            config = session_allocate_config(ssn)
         finally:
             close_session(ssn)
         return snap, config
