@@ -599,9 +599,10 @@ class AllocateAction(Action):
                  sentinel[1] if sentinel is not None else None,
                  sentinel[2] if sentinel is not None else np.int32(0))
             )
-        sp_wait.set(rounds=int(rounds_run))
-        # convergence diagnostic (round-cap tuning)
+        # convergence diagnostic: how far into rounds x outer the solve went
         self.last_solve_rounds = int(rounds_run)
+        tracer.note_solve_rounds(
+            sp_wait, "allocate", self.last_solve_rounds, config.rounds)
         if topk_info is not None:
             topk_info = dict(
                 topk_info, exhausted=int(topk_exh), reentries=int(topk_reent)

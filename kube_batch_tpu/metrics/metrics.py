@@ -440,6 +440,20 @@ SOLVE_DISPATCHES = Counter(
     "warm: compacted, table carried | evict)",
     ("action", "mode", "program"),
 )
+# how far the allocate solve went into its rounds x outer budget: the sum
+# over solves, and the solves that went past their first pass
+SOLVE_ROUNDS = Counter(
+    f"{_SUBSYSTEM}_solve_rounds_total",
+    "Bidding rounds the device solves ran, by action",
+    ("action",),
+)
+SOLVE_OVER_BUDGET = Counter(
+    f"{_SUBSYSTEM}_solve_over_budget_total",
+    "Device solves that ran more bidding rounds than one pass has "
+    "(AllocateConfig.rounds): a pass ended with work left and another "
+    "carried on from what it had placed, by action",
+    ("action",),
+)
 DEVICE_PEAK_BYTES = Gauge(
     f"{_SUBSYSTEM}_device_peak_bytes",
     "peak_bytes_in_use of each local device, refreshed at most once a cycle",
@@ -451,6 +465,8 @@ SETTLE_SIGNALS.add(0.0)
 for _ended_by in ("quiet", "cap"):
     SETTLE_HOLDS.add(0.0, _ended_by)
 DECISIONS_LEFTOVER.add(0.0)
+SOLVE_ROUNDS.add(0.0, "allocate")
+SOLVE_OVER_BUDGET.add(0.0, "allocate")
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
@@ -511,6 +527,8 @@ METRICS = [
     JIT_COMPILE_SECONDS,
     JIT_COMPILES,
     SOLVE_DISPATCHES,
+    SOLVE_ROUNDS,
+    SOLVE_OVER_BUDGET,
     DEVICE_PEAK_BYTES,
 ]
 
@@ -724,6 +742,12 @@ def register_jit_compile(phase: str, seconds: float) -> None:
 
 def register_solve_dispatch(action: str, mode: str, program: str) -> None:
     SOLVE_DISPATCHES.inc(action, mode, program)
+
+
+def register_solve_rounds(action: str, rounds: int, over_budget: bool) -> None:
+    SOLVE_ROUNDS.add(rounds, action)
+    if over_budget:
+        SOLVE_OVER_BUDGET.inc(action)
 
 
 def refresh_device_peak_bytes() -> None:

@@ -527,6 +527,17 @@ class Tracer:
         span.set(mode=mode, engaged=list(engaged), program=program)
         metrics.register_solve_dispatch(action, mode, program)
 
+    def note_solve_rounds(self, span: Span, action: str, rounds: int,
+                          per_pass: int) -> None:
+        """Say on a ``device_wait`` span how many bidding rounds its solve
+        ran (``rounds``) and whether that is more than one pass has
+        (``over_budget``: a pass ended with work left and the next carried
+        on), and count both on ``/metrics`` (``volcano_solve_rounds_total``,
+        ``volcano_solve_over_budget_total``) from the same values."""
+        over_budget = rounds > per_pass
+        span.set(rounds=rounds, over_budget=over_budget)
+        metrics.register_solve_rounds(action, rounds, over_budget)
+
     def note_cycle_attr(self, key: str, value) -> None:
         if not self.enabled:
             return

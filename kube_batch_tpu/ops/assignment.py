@@ -15,10 +15,14 @@ Here the same semantics run as batched auction rounds on device:
           exhausted (a segmented prefix-sum over the rank-sorted bidders —
           the moral equivalent of "the PQ order reaches the node first");
           losers re-bid next round against updated budgets.
-  gang:   after the rounds, jobs whose allocated count (existing ready + new)
-          misses MinAvailable get every new placement reverted — the
-          vectorized Statement.Discard (statement.go:309-322); an outer
-          iteration then lets surviving tasks re-bid for the freed resources.
+  gang:   once bidding has stopped (a round placed nothing, or the
+          rounds x outer budget is spent), jobs whose allocated count
+          (existing ready + new) misses MinAvailable get every new placement
+          reverted — the vectorized Statement.Discard (statement.go:309-322);
+          an outer iteration then lets surviving tasks re-bid for the freed
+          resources.  A pass that only ran out of its `rounds` while still
+          placing discards nothing: it carries its placements, half-placed
+          gangs included, into the next pass, which re-ranks and bids on.
 
 Divergences from the sequential loop are the sanctioned ones (SURVEY.md
 §7.3): placement ties may resolve differently (the reference's
@@ -466,6 +470,17 @@ def allocate_rounds(
         )
         # inner loop capped while still placing? another outer pass continues
         rounds_capped = rounds_progress & (rounds_i >= config.rounds)
+        # bidding has STOPPED when the rounds ended for want of progress, the
+        # rounds x outer budget is spent, or nothing is left to bid; only
+        # then is a gang below MinAvailable a gang that cannot get there (the
+        # reference discards a Statement on what is free, never on a loop
+        # counter: allocate.go:192-196).  A pass that merely ran out of rounds
+        # carries its placements, whole and partial, into the next pass
+        settled = (
+            ~rounds_capped
+            | (o + 1 >= config.outer)
+            | ~jnp.any(eligible & (assigned < 0) & ~job_failed[snap.task_job])
+        )
         # ---- gang commit/discard (vectorized Statement) -----------------
         new_alloc_cnt = jax.ops.segment_sum(
             ((assigned >= 0) & ~pipelined).astype(jnp.int32),
@@ -484,8 +499,9 @@ def allocate_rounds(
         new_any = jax.ops.segment_sum(
             (assigned >= 0).astype(jnp.int32), snap.task_job, num_segments=J
         )
-        job_failed = job_failed | (~job_ok & (new_any > 0))
-        revert = (assigned >= 0) & ~job_ok[snap.task_job]
+        job_discard = settled & ~job_ok & (new_any > 0)
+        job_failed = job_failed | job_discard
+        revert = (assigned >= 0) & job_discard[snap.task_job]
         seg = jnp.where(revert, assigned, N)
         rev_req = jnp.where(revert[:, None], snap.task_resreq, 0.0)
         rev_alloc = jax.ops.segment_sum(

@@ -284,6 +284,86 @@ class TestOuterLoopContinuation:
         assert_no_overcommit(snap, res)
 
 
+def _herding_gangs(n_nodes, n_gangs, size=4):
+    """``n_gangs`` gangs of ``size`` one-core tasks over one-core nodes:
+    identical scores, so bidders herd, a node admits one of them a round,
+    and placing everyone takes several rounds however much room there is."""
+    return build_cluster(
+        nodes=[(f"n{i:03d}", 1000, 64 * GiB) for i in range(n_nodes)],
+        jobs=[(f"g{i:03d}", "default", size,
+               [(f"t{k}", 1000, GiB, 0) for k in range(size)])
+              for i in range(n_gangs)],
+    )
+
+
+def _members_placed(snap, meta, res):
+    """[n_jobs] placed members of each job."""
+    assigned = np.asarray(res.assigned)[: meta.n_tasks]
+    job_of = np.asarray(snap.task_job)[: meta.n_tasks]
+    return np.bincount(job_of[assigned >= 0], minlength=meta.n_jobs)
+
+
+class TestCappedPassCarriesItsPlacements:
+    """A pass whose bidding rounds ended only because the round counter ran
+    out, while the last round still placed, discards nothing: gangs that
+    are half way are carried into the next pass.  Only when bidding has
+    stopped (no progress, budget spent, nothing left to bid) is a gang
+    below MinAvailable reverted and failed for the cycle."""
+
+    def test_half_placed_gangs_finish_in_the_next_pass(self):
+        # room for all 8 gangs five times over; 2 rounds a pass place about
+        # three quarters of the 32 members, so the first pass ends capped
+        # with gangs half way (before: those were reverted AND failed, and
+        # the solve ended after one pass with 5 of 8 gangs placed)
+        snap, meta, res = solve(_herding_gangs(40, 8), rounds=2, outer=3)
+        assert (_members_placed(snap, meta, res) == 4).all()
+        assert np.asarray(res.committed)[: meta.n_jobs].all()
+        assert 2 < int(res.rounds_run) <= 6
+        assert_no_overcommit(snap, res)
+
+    def test_spent_budget_leaves_every_gang_whole_or_absent(self):
+        # the same herd with 2 rounds in all: the last pass is capped too,
+        # and it must still discard — no partial gang survives the budget
+        snap, meta, res = solve(_herding_gangs(40, 8), rounds=1, outer=2)
+        placed = _members_placed(snap, meta, res)
+        assert set(placed.tolist()) <= {0, 4}, placed
+        assert 0 < (placed == 4).sum() < 8, placed  # the budget did bite
+        np.testing.assert_array_equal(
+            np.asarray(res.committed)[: meta.n_jobs], placed == 4)
+        assert int(res.rounds_run) == 2
+        # what the reverted members had taken is free again
+        idle = np.asarray(res.node_idle)[:40, 0]
+        assert (idle == 0).sum() == placed.sum(), (idle, placed)
+        assert_no_overcommit(snap, res)
+
+    def test_unreachable_min_member_is_discarded_after_a_capped_pass(self):
+        # minMember 3 with 2 members: one round places both (so the pass
+        # ends capped, with progress) and leaves nothing pending.  The loop
+        # may not end on a pass that skipped the discard.
+        ci = build_cluster(
+            nodes=[(f"n{i}", 4000, 8 * GiB) for i in range(2)],
+            jobs=[("short", "default", 3,
+                   [(f"t{i}", 1000, GiB, 0) for i in range(2)])],
+        )
+        snap, meta, res = solve(ci, rounds=1, outer=3)
+        assert (np.asarray(res.assigned)[: meta.n_tasks] == -1).all()
+        assert not np.asarray(res.committed)[: meta.n_jobs].any()
+        np.testing.assert_allclose(
+            np.asarray(res.node_idle), np.asarray(snap.node_idle))
+
+    @pytest.mark.parametrize("rounds,outer", [
+        (1, 1), (1, 3), (2, 2), (2, 3), (3, 3), (6, 3)])
+    def test_rounds_run_stays_inside_the_budget(self, rounds, outer):
+        # more gangs than nodes can hold (12 x 4 members, 40 slots): some
+        # budget runs dry half way whatever it is
+        snap, meta, res = solve(_herding_gangs(40, 12), rounds=rounds,
+                                outer=outer)
+        assert 0 < int(res.rounds_run) <= rounds * outer
+        placed = _members_placed(snap, meta, res)
+        assert set(placed.tolist()) <= {0, 4}, placed
+        assert_no_overcommit(snap, res)
+
+
 def _prefer_last_node_row(snap):
     """Module-level custom score row (jit-cache friendly): strongly prefer
     the highest live node index."""

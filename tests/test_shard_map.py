@@ -40,9 +40,9 @@ def _env_guard():
             os.environ[k] = v
 
 
-def _mk_cache(seed=0):
+def _mk_cache(seed=0, n_tasks=N_TASKS, n_nodes=N_NODES):
     return synthetic_cluster(
-        n_tasks=N_TASKS, n_nodes=N_NODES, gang_size=4, n_queues=2, seed=seed
+        n_tasks=n_tasks, n_nodes=n_nodes, gang_size=4, n_queues=2, seed=seed
     )
 
 
@@ -96,8 +96,8 @@ def _run_cycles(cache, conf, cycles=4, seed=7):
     return binds, sorted(status)
 
 
-def _session_snapshot(seed=3):
-    cache = _mk_cache(seed)
+def _session_snapshot(seed=3, **size):
+    cache = _mk_cache(seed, **size)
     conf = load_scheduler_conf(None)
     ssn = open_session(cache, conf.tiers)
     try:
@@ -212,6 +212,49 @@ def test_forced_4_device_solves_bit_exact(_env_guard):
         for name in ev._fields:
             assert np.array_equal(getattr(ev, name), getattr(ev_sm, name)), (
                 f"shard_map evict[{mode}] {name} diverged")
+
+
+@pytest.mark.parametrize("kind", ["full", "topk"])
+def test_capped_pass_solves_bit_exact_on_the_test_mesh(kind):
+    """An input that meets the round cap while still placing (600 pods in
+    gangs of 4 over 48 nodes, 2 rounds a pass: the first pass ends with
+    gangs half way and carries them on): shard_map = pjit = single device
+    on the conftest's 8 devices, full matrix and compacted."""
+    import jax
+
+    from kube_batch_tpu.ops.assignment import allocate_solve
+    from kube_batch_tpu.parallel.mesh import (
+        allocate_solve_fn,
+        allocate_topk_solve_fn,
+        make_mesh,
+    )
+
+    snap, config = _session_snapshot(n_tasks=600, n_nodes=48)
+    config = config._replace(rounds=2)
+    local = jax.device_get(allocate_solve(snap, config))
+    assert 2 < int(local.rounds_run) <= 2 * config.outer
+    assert (local.assigned >= 0).sum() == 600
+    if kind == "topk":
+        config = config._replace(topk=4)
+        rows = np.full(1024, -1, np.int32)
+        pend = np.flatnonzero(np.asarray(snap.task_pending))
+        rows[: pend.size] = pend
+        args = (snap, rows)
+        solve_fn = allocate_topk_solve_fn
+    else:
+        args = (snap,)
+        solve_fn = allocate_solve_fn
+    mesh = make_mesh()
+    assert mesh.devices.size == 8
+    with mesh:
+        got = {impl: jax.device_get(solve_fn(mesh, config, impl=impl)(*args))
+               for impl in ("shard_map", "pjit")}
+    for impl, res in got.items():
+        for name in local._fields:
+            if name.startswith("topk_"):
+                continue
+            assert np.array_equal(getattr(local, name), getattr(res, name)), (
+                f"{impl} {kind} {name} diverged on a capped input")
 
 
 def test_enqueue_gate_mesh_matches_single():

@@ -781,6 +781,51 @@ class TestCompileListener:
         assert _counter(prom.JIT_COMPILES) == compiles0 + 1
 
 
+class TestSolveRounds:
+    @pytest.mark.parametrize("members,over", [(2, False), (9, True)])
+    def test_rounds_and_over_budget_follow_the_solve(self, members, over):
+        """Members that take a node each, over nodes whose scores differ:
+        every bidder wants the same best node and a node admits one, so the
+        gang takes a round a member.  Two fit in one pass of 6 rounds; nine
+        need a second pass, which is what ``over_budget`` counts."""
+        cache = SchedulerCache(
+            binder=FakeBinder(), evictor=FakeEvictor(),
+            status_updater=FakeStatusUpdater(),
+        )
+        cache.add_queue(Queue(name="q0", uid="uq0", weight=1))
+        for i in range(12):
+            cache.add_node(Node(
+                name=f"h{i}",
+                allocatable={"cpu": 6000.0 + 300 * i, "memory": 16 * GiB,
+                             "pods": 110.0},
+            ))
+        cache.add_pod_group(PodGroup(
+            name="wide", namespace="tr", uid="pg-wide", min_member=members,
+            queue="q0", creation_index=1,
+        ))
+        for k in range(members):
+            cache.add_pod(Pod(
+                name=f"wide-{k}", namespace="tr", uid=f"pod-wide-{k}",
+                requests={"cpu": 5000.0, "memory": 1 * GiB},
+                annotations={GROUP_NAME_ANNOTATION: "wide"},
+                phase=PodPhase.PENDING, creation_index=100 + k,
+            ))
+        sched = _mk_scheduler(cache)
+        rounds0 = _counter(prom.SOLVE_ROUNDS, "allocate")
+        over0 = _counter(prom.SOLVE_OVER_BUDGET, "allocate")
+        sched.run_once()
+        assert len(cache.binder.binds) == members
+        allocate = next(s for s in cache.flight_recorder.records()[-1].spans
+                        if s.name == "action:allocate")
+        wait = next(s for s in allocate.children if s.name == "device_wait")
+        rounds = wait.attrs["rounds"]
+        assert (rounds > 6) == over and rounds <= 18
+        assert wait.attrs["over_budget"] is over
+        assert _counter(prom.SOLVE_ROUNDS, "allocate") - rounds0 == rounds
+        assert _counter(prom.SOLVE_OVER_BUDGET, "allocate") - over0 == int(over)
+        cache.stop()
+
+
 class TestReadPlaneSpans:
     def test_flush_is_totalled_and_kept_out_of_the_records(self):
         from kube_batch_tpu.serve.plane import QueryPlane

@@ -227,12 +227,15 @@ def _cmp(full, got, tag):
             f"{tag}: diverged on {name}")
 
 
-def test_warm_solve_carry_merge_and_cut_bit_exact():
+@pytest.mark.parametrize("rounds", [6, 2])
+def test_warm_solve_carry_merge_and_cut_bit_exact(rounds):
     """The full solve-level life of a carried table: cold build → identity
     carry → displacement merge (a node's key improves and must enter) →
     hard erosion (a table node's budget zeroed: its entries are removed
     and the θ-cut must not resurrect anything) — each step bit-identical
-    to the full-matrix AND the cold compacted solve on that snapshot."""
+    to the full-matrix AND the cold compacted solve on that snapshot.
+    ``rounds=2`` meets the round cap while still placing, so every step
+    also runs passes that carry half-placed gangs on."""
     import jax
     import jax.numpy as jnp
 
@@ -243,10 +246,12 @@ def test_warm_solve_carry_merge_and_cut_bit_exact():
     )
 
     snap, config = _session_snapshot(400, 16, seed=7)
+    config = config._replace(rounds=rounds)
     P, K, W = 512, 4, 8
     rows = _pend_rows(snap, P)
     cfg_w = config._replace(topk=W)
     full = jax.device_get(allocate_solve(snap, config))
+    assert rounds < int(full.rounds_run) <= rounds * config.outer
     cold = jax.device_get(
         allocate_topk_solve(snap, rows, config._replace(topk=K)))
     _cmp(full, cold, "cold-topk")

@@ -139,6 +139,33 @@ class TestWhatifOracle:
             "probe promised member->node placement must bind verbatim"
         )
 
+    def test_gang_needing_more_nodes_than_a_pass_has_rounds(
+            self, plane_factory):
+        """Nine members that take a node each, over nodes whose scores
+        differ: every bidder wants the same best node, a node admits one,
+        so the gang needs nine rounds where a pass has six.  The pass that
+        runs out of rounds carries the six it placed (before: it reverted
+        them and failed the gang, in the probe and in the cycle alike), and
+        the probe's nodes are the nodes the committed solve then binds."""
+        cache = build_cache(
+            queues=[Queue(name="default", weight=1)],
+            nodes=[build_node(f"h{i}", cpu=6000 + 300 * i, mem=16 * GiB)
+                   for i in range(12)],
+        )
+        qp = plane_factory(cache)
+        _run(cache)
+        request = {"cpu": 5000, "memory": GiB}
+        resp = _probe(qp, {"queue": "default", "count": 9,
+                           "requests": request})
+        assert resp["feasible"] and resp["committed"]
+        assert len(set(resp["nodes"])) == 9
+        self._submit_gang(cache, 9, request)
+        _run(cache)
+        rounds = get_action("allocate").last_solve_rounds
+        assert 6 < rounds <= 18, rounds
+        binds = dict(cache.binder.binds)
+        assert [binds[f"c1/probe-{i}"] for i in range(9)] == resp["nodes"]
+
     def test_min_available_above_count_cannot_commit(self, plane_factory):
         """min_available > count is a gang that can never reach readiness:
         the commit gate must see the REAL value (no clamp to count), so
