@@ -603,6 +603,8 @@ class AllocateAction(Action):
         self.last_solve_rounds = int(rounds_run)
         tracer.note_solve_rounds(
             sp_wait, "allocate", self.last_solve_rounds, config.rounds)
+        tracer.note_topk_fallbacks(
+            sp_wait, "allocate", int(topk_exh), int(topk_reent))
         if topk_info is not None:
             topk_info = dict(
                 topk_info, exhausted=int(topk_exh), reentries=int(topk_reent)
@@ -660,8 +662,11 @@ class AllocateAction(Action):
                 hist_dev = self._dispatch_fit_histogram(cols, snap, p_rows)
             if unplaced:
                 fail_hist_dev = hist_dev
-        with tracer.span("host_replay"):
+        with tracer.span("host_replay") as sp_replay:
             self._replay(ssn, snap, meta, assigned, pipelined, task_job)
+            placed_per_job = np.bincount(task_job[assigned >= 0])
+            sp_replay.set(gangs=int(np.count_nonzero(placed_per_job)),
+                          largest_gang=int(placed_per_job.max(initial=0)))
         if fail_hist_dev is not None:
             # blocks only on whatever the device hasn't finished during the
             # replay; fit-error recording touches job diagnostic dicts the

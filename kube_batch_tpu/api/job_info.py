@@ -81,6 +81,10 @@ class JobInfo:
         self.pod_group: Optional[PodGroup] = None
         self.pdb = None  # legacy gang source (job_info.go:199-212 SetPDB)
         self.creation_index: int = 0
+        # the gang's own decision clock (cache/cache.py): the earliest
+        # arrival stamp among the members that arrived since the gang last
+        # had no undecided member, None while there is none
+        self.first_arrival: Optional[float] = None
         # ColumnStore binding (api/columns.py): when bound, the three ledger
         # Resources above are views into the store's [J, R] matrices and the
         # index choke points mirror per-status counts into j_counts
@@ -321,6 +325,12 @@ class JobInfo:
             if bucket is not None:
                 n += len(bucket)
         return n
+
+    def has_undecided(self) -> bool:
+        """Whether a member still waits for its bind decision."""
+        idx = self.task_status_index
+        return bool(idx.get(TaskStatus.PENDING)
+                    or idx.get(TaskStatus.PIPELINED))
 
     @property
     def ready_task_num(self) -> int:

@@ -623,10 +623,17 @@ class SchedulerCache:
         job = self._get_or_create_job(task, pod)
         self.dirty.note_pod(task._key)
         self.dirty.note_job(job.uid)
-        if task.node_name is None and task._key not in self._arrival_ts:
+        if task.node_name is None:
             # arrival→bind-decision latency clock starts at first ingest of
             # an unbound pod; kubelet status replays keep the original stamp
-            self._arrival_ts[task._key] = telemetry.perf_counter()
+            ts = self._arrival_ts.get(task._key)
+            if ts is None:
+                ts = self._arrival_ts[task._key] = telemetry.perf_counter()
+            # the gang's clock starts with its first undecided member's
+            if job.first_arrival is None or not job.has_undecided():
+                job.first_arrival = ts
+            else:
+                job.first_arrival = min(job.first_arrival, ts)
         job.add_task(task)
         self.columns.bind_task(task, job)
         if task.node_name:
@@ -679,9 +686,9 @@ class SchedulerCache:
             if self._owns(pod):
                 self._resolve_pod_priority(pod)
                 self.pods[pod.key()] = pod
-                self._add_task(TaskInfo(pod, self.spec), pod)
-                if t_arr is not None and pod.key() in self._arrival_ts:
+                if t_arr is not None and not pod.node_name:
                     self._arrival_ts[pod.key()] = t_arr
+                self._add_task(TaskInfo(pod, self.spec), pod)
 
     def delete_pod(self, pod: Pod) -> None:
         if self._stage(self.delete_pod, pod):
@@ -978,12 +985,14 @@ class SchedulerCache:
             # the right state; the caller (Statement/dispatch) finishes the
             # BINDING transition itself
             pod = self.pods.get(task.key())
-            t0 = None
+            t0, gangs = None, []
             if pod is not None:
                 self.binds_total += 1
                 t0 = self._arrival_ts.pop(task.key(), None)
+                if t0 is not None:
+                    gangs = self._gangs_decided_locked({task.job})
         if t0 is not None:
-            self._observe_decisions([t0], telemetry.perf_counter())
+            self._observe_decisions([t0], telemetry.perf_counter(), gangs)
         try:
             if pod is not None:
                 self.binder.bind(pod, hostname)
@@ -1032,19 +1041,21 @@ class SchedulerCache:
                 staged = [(t, h, t.pod) for t, h in tasks_hosts]
             else:
                 staged = self._bulk_bind_locked(tasks_hosts, job_sums, node_sums)
-            arrivals, now = self._note_bind_decisions_locked(staged)
-        self._observe_decisions(arrivals, now)
+            arrivals, gangs, now = self._note_bind_decisions_locked(staged)
+        self._observe_decisions(arrivals, now, gangs)
         self._dispatch_async(staged)
 
     def _note_bind_decisions_locked(self, staged) -> tuple:
         """Mark every staged dispatch in flight (update_pod's unacked-bind
         guard) and close the arrival→decision latency clocks; returns the
-        arrival stamps of the pods decided and the decision time (observed
+        arrival stamps of the pods decided, the gangs these binds completed
+        (:meth:`_gangs_decided_locked`) and the decision time (observed
         outside the lock)."""
         now = telemetry.perf_counter()
         pop_ts = self._arrival_ts.pop
         inflight = self._inflight_bind_hosts
         arrivals = []
+        jobs = set()
         binds = 0
         for task, hostname, pod in staged:
             if pod is None:
@@ -1054,8 +1065,23 @@ class SchedulerCache:
             t0 = pop_ts(task._key, None)
             if t0 is not None:
                 arrivals.append(t0)
+                jobs.add(task.job)
         self.binds_total += binds
-        return arrivals, now
+        return arrivals, self._gangs_decided_locked(jobs), now
+
+    def _gangs_decided_locked(self, job_ids) -> list:
+        """[(first arrival stamp, tasks)] of the gangs among ``job_ids``
+        that a bind has just left with no undecided member, and stops
+        their clocks (a member that arrives later starts a new one)."""
+        out = []
+        for job_id in job_ids:
+            job = self.jobs.get(job_id)
+            if job is None or job.first_arrival is None:
+                continue
+            if not job.has_undecided():
+                out.append((job.first_arrival, len(job.tasks)))
+                job.first_arrival = None
+        return out
 
     def left_schedulable_pending(self, binds_before: int) -> bool:
         """Whether bind decisions were made since ``binds_total`` read
@@ -1067,18 +1093,21 @@ class SchedulerCache:
             return (self.binds_total > binds_before
                     and self.columns.has_schedulable_pending())
 
-    def _observe_decisions(self, arrivals, now: float) -> None:
+    def _observe_decisions(self, arrivals, now: float, gangs=()) -> None:
         """The arrival→decision latency of the pods bound at ``now``, from
         their arrival stamps: the histogram (and the bench's exact-sample
         sink), its span-stamped twin on the cycle's trace record (an SLO
         breach arms a flight-recorder dump), and the tracer's split of it
-        into the wait for the deciding cycle and the rest."""
+        into the wait for the deciding cycle and the rest.  ``gangs`` are
+        the gangs these binds completed, each from its first arrival."""
         if not arrivals:
             return
         from kube_batch_tpu import metrics
 
         lat_ms = [(now - t0) * 1e3 for t0 in arrivals]
         metrics.observe_decision_latencies(lat_ms)
+        metrics.observe_gang_decision_latencies(
+            [((now - t0) * 1e3, size) for t0, size in gangs])
         tr = getattr(self, "tracer", None)
         if tr is not None:
             tr.note_decision_latencies(lat_ms)

@@ -454,6 +454,29 @@ SOLVE_OVER_BUDGET = Counter(
     "carried on from what it had placed, by action",
     ("action",),
 )
+# how often the compacted solve's candidate lists ran dry: the read-back
+# allocate already makes of AllocateResult.topk_exhausted / topk_reentries
+TOPK_EXHAUSTED = Counter(
+    f"{_SUBSYSTEM}_topk_exhausted_total",
+    "Task-rounds of the compacted solves in which a pending task's "
+    "candidate list held no node that still fit, by action",
+    ("action",),
+)
+TOPK_REENTRIES = Counter(
+    f"{_SUBSYSTEM}_topk_reentries_total",
+    "Bidding rounds of the compacted solves that re-entered the full "
+    "[T, N] matrix because a candidate list had run dry, by action",
+    ("action",),
+)
+# a gang's own clock beside the pods': from its first member's arrival to
+# the bind that left it no pending member, by the gang's size
+GANG_DECISION_LATENCY = Histogram(
+    f"{_SUBSYSTEM}_gang_decision_latency_milliseconds",
+    "A gang's first arrival to its last member's bind decision in "
+    "milliseconds, by size class (1 | 2-8 | 16-32 | 64+, named for the "
+    "powers of two in them: 9-32 tasks read 16-32, 33 and more read 64+)",
+    ("size_class",),
+)
 DEVICE_PEAK_BYTES = Gauge(
     f"{_SUBSYSTEM}_device_peak_bytes",
     "peak_bytes_in_use of each local device, refreshed at most once a cycle",
@@ -467,6 +490,8 @@ for _ended_by in ("quiet", "cap"):
 DECISIONS_LEFTOVER.add(0.0)
 SOLVE_ROUNDS.add(0.0, "allocate")
 SOLVE_OVER_BUDGET.add(0.0, "allocate")
+TOPK_EXHAUSTED.add(0.0, "allocate")
+TOPK_REENTRIES.add(0.0, "allocate")
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
@@ -529,6 +554,9 @@ METRICS = [
     SOLVE_DISPATCHES,
     SOLVE_ROUNDS,
     SOLVE_OVER_BUDGET,
+    TOPK_EXHAUSTED,
+    TOPK_REENTRIES,
+    GANG_DECISION_LATENCY,
     DEVICE_PEAK_BYTES,
 ]
 
@@ -748,6 +776,27 @@ def register_solve_rounds(action: str, rounds: int, over_budget: bool) -> None:
     SOLVE_ROUNDS.add(rounds, action)
     if over_budget:
         SOLVE_OVER_BUDGET.inc(action)
+
+
+def register_topk_fallbacks(action: str, exhausted: int,
+                            reentries: int) -> None:
+    TOPK_EXHAUSTED.add(exhausted, action)
+    TOPK_REENTRIES.add(reentries, action)
+
+
+def gang_size_class(size: int) -> str:
+    """The ``size_class`` label of a gang of ``size`` tasks."""
+    if size <= 1:
+        return "1"
+    if size <= 8:
+        return "2-8"
+    return "16-32" if size <= 32 else "64+"
+
+
+def observe_gang_decision_latencies(gangs) -> None:
+    """Record ``(ms, size)`` for each gang whose last member was decided."""
+    for ms, size in gangs:
+        GANG_DECISION_LATENCY.observe(ms, gang_size_class(size))
 
 
 def refresh_device_peak_bytes() -> None:

@@ -538,6 +538,17 @@ class Tracer:
         span.set(rounds=rounds, over_budget=over_budget)
         metrics.register_solve_rounds(action, rounds, over_budget)
 
+    def note_topk_fallbacks(self, span: Span, action: str, exhausted: int,
+                            reentries: int) -> None:
+        """Say on a ``device_wait`` span how often its solve's candidate
+        lists ran dry (``exhausted``: task-rounds with no candidate left
+        that fit; ``reentries``: rounds that went back to the full matrix
+        for it), and count both on ``/metrics``
+        (``volcano_topk_exhausted_total``, ``volcano_topk_reentries_total``)
+        from the same values."""
+        span.set(exhausted=exhausted, reentries=reentries)
+        metrics.register_topk_fallbacks(action, exhausted, reentries)
+
     def note_cycle_attr(self, key: str, value) -> None:
         if not self.enabled:
             return

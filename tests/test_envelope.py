@@ -57,8 +57,8 @@ class Served:
     """The cluster of ``CONFIG`` behind the served path, and the ledger of
     what was sent to it."""
 
-    def __init__(self, seed: int, audit_every: int = 0):
-        self.ledger = reference.Ledger(CONFIG, seed)
+    def __init__(self, seed: int, audit_every: int = 0, ledger=None):
+        self.ledger = ledger or reference.Ledger(CONFIG, seed)
         self.cache = cache = SchedulerCache(
             binder=FakeBinder(), evictor=FakeEvictor(),
             status_updater=FakeStatusUpdater())
@@ -80,15 +80,17 @@ class Served:
             self.cache.update_pod(serialize.pod_from_dict(pod))
         self.ledger.add(pgs, pods)
 
-    def burst(self) -> None:
-        """Delete the oldest gangs, post as many new ones."""
-        pgs, pods = self.ledger.oldest_gangs(BURST_GANGS)
+    def delete(self, pgs, pods) -> None:
         for pod in pods:
             self.cache.delete_pod(serialize.pod_from_dict(pod))
         for pg in pgs:
             self.cache.delete_pod_group(
                 serialize.pod_group_from_dict(pg).key())
         self.ledger.retire(pgs, pods)
+
+    def burst(self) -> None:
+        """Delete the oldest gangs, post as many new ones."""
+        self.delete(*self.ledger.oldest_gangs(BURST_GANGS))
         gang, mix = CONFIG["gang"], CONFIG["request_mix"]
         self.post(*self.ledger.make_gangs(
             BURST_GANGS, gang["size"], gang["min_member"],
@@ -104,10 +106,12 @@ class Served:
                 break
         return numbers
 
-    def counts(self) -> dict:
-        rows = [b for b in _bindings(self.cache)
+    def binds(self) -> list:
+        return [b for b in _bindings(self.cache)
                 if b["status"] in reference.BOUND_STATUSES]
-        return self.ledger.check_binds(rows)[0]
+
+    def counts(self) -> dict:
+        return self.ledger.check_binds(self.binds())[0]
 
     def dispatched(self) -> dict:
         """{(action, mode, program): dispatches of this drive}."""
