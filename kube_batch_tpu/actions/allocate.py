@@ -434,11 +434,13 @@ def dispatch_allocate_oracle(snap, config, cols, mode):
     return allocate_solve(resident_snap(cols, snap), oracle_cfg)
 
 
-def republish_query_lease(ssn, snap=None, meta=None, build=None) -> None:
+def republish_query_lease(ssn, snap=None, meta=None, build=None,
+                          version=None) -> bool:
     """THE guarded what-if lease publish — every publish path (allocate's
     solve and idle/empty cycles, reclaim/backfill/preempt's post-swap
-    re-arms) goes through here, so the gate, the version-token source, and
-    the failure policy live once.
+    re-arms, the cycle's re-arm at its commit) goes through here, so the
+    gate, the version-token source, and the failure policy live once.
+    Returns whether a lease was published.
 
     On donating backends EVERY resident swap retires the published lease
     (serve/lease.py) — and reclaim, backfill, and preempt all swap after
@@ -447,24 +449,31 @@ def republish_query_lease(ssn, snap=None, meta=None, build=None) -> None:
     allocate: the whole schedule period, exactly on the hardware serving
     targets.  ``resident_snap`` is memoized on the exact ``snap`` object
     the caller's dispatch used, so a re-arm is bookkeeping, not device
-    work.  ``build`` is the lazy (snap, meta) builder for the idle/empty
-    paths: the snapshot rebuild runs only when the publish is actually
+    work.  ``build`` is the lazy (snap, meta) builder for the paths with no
+    snapshot in hand: the rebuild runs only when the publish is actually
     owed (no plane attached, an isolated/object session, or a live lease
-    already covering the open's version — CPU: swaps never retire — all
-    skip it).  A publish failure degrades serving, never the cycle."""
+    already covering the version — CPU: swaps never retire — all skip it).
+
+    ``version`` is the dirty-tracker token the snapshot holds: the open's
+    (the default) for a snapshot taken before the cycle's own binds, the
+    tracker's own for one built after them, at the end of the session
+    (:meth:`Scheduler._rearm_lease`).  A publish failure degrades serving,
+    never the cycle."""
     qp = getattr(ssn.cache, "query_plane", None)
     if qp is None or ssn.columns is None:
-        return
+        return False
+    if version is None:
+        version = int(getattr(ssn.cache, "last_open_version", 0))
     try:
-        if not qp.needs_publish(
-            int(getattr(ssn.cache, "last_open_version", 0))
-        ):
-            return
+        if not qp.needs_publish(version):
+            return False
         if build is not None:
             snap, meta = build()
-        qp.publish_session(ssn, snap, meta)
+        qp.publish_session(ssn, snap, meta, version)
+        return True
     except Exception:  # noqa: BLE001 — the write path outranks serving
         logger.exception("whatif lease publication failed")
+        return False
 
 
 class AllocateAction(Action):

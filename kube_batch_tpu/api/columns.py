@@ -848,6 +848,21 @@ class ColumnStore:
     def has_schedulable_pending(self) -> bool:
         return bool(np.any(self.schedulable_pending_mask()))
 
+    def has_pending(self) -> bool:
+        """Whether any live task is Pending, BestEffort ones (backfill's)
+        and ones no solve could place included."""
+        return bool(np.any(
+            (self.t_status == int(TaskStatus.PENDING)) & self.t_valid))
+
+    def has_unsettled_phase(self) -> bool:
+        """Whether a session job's PodGroup is Pending (enqueue's
+        candidates) or Unknown: the phases the close-time status pass
+        visits and reports every cycle, whatever else moved."""
+        phases = self.j_phase[self.j_sess]
+        return bool(np.any(
+            (phases == PHASE_CODE[PodGroupPhase.PENDING])
+            | (phases == PHASE_CODE[PodGroupPhase.UNKNOWN])))
+
     def peek_task_rows(self, k: int) -> List[int]:
         """The task rows the next k ingested pods would occupy (no
         mutation) — the what-if probe's tie-hash oracle (ops/probe.py):

@@ -196,6 +196,13 @@ class SchedulerCache:
         # query plane's snapshot_version (serve/lease.py): a lease published
         # for cycle N reports exactly the ingest state that open consumed
         self.last_open_version = 0
+        # its twin at the other end of the session: the version as the last
+        # exclusive session handed the cache back, its own binds, evictions
+        # and close-time status stamps included, read before the deferred
+        # events apply (None until a session has closed).  The cycle stamps
+        # its re-armed what-if lease with it, and a floor wake that finds
+        # the tracker still there knows nothing was applied since
+        self.last_close_version: Optional[int] = None
         # the serve/ query plane, when one is attached (QueryPlane.__init__
         # sets it); the allocate action publishes its per-cycle lease here
         self.query_plane = None
@@ -316,6 +323,7 @@ class SchedulerCache:
         arrived during it, in order."""
         with self._lock:
             self._session_active = False
+            self.last_close_version = self.dirty.version
             deferred, self._deferred = self._deferred, []
             for fn, args in deferred:
                 try:
@@ -1092,6 +1100,34 @@ class SchedulerCache:
         with self._lock:
             return (self.binds_total > binds_before
                     and self.columns.has_schedulable_pending())
+
+    def owes_a_cycle(self) -> Optional[str]:
+        """Why a cycle started now would have something to do, or None for
+        a cache on which it would decide nothing and write nothing (what an
+        idle tick of the event-driven loop asks before it opens a session).
+        Nothing is owed when no mutation was applied since the last session
+        handed the cache back (any ingest, repair rebuild or deferred event
+        moves the tracker past ``last_close_version``: that session's close
+        derived every status after its own binds), no task is pending,
+        schedulable or not (one that fits nowhere keeps its retry and its
+        events every period), the open's gang gate dropped no job (each
+        open marks those Unschedulable again), and no session PodGroup is
+        in a phase the close reports every cycle or enqueue acts on
+        (Pending, Unknown).  Takes the big lock, like
+        :meth:`left_schedulable_pending`."""
+        with self._lock:
+            if self._session_active:
+                return "session"
+            if self.dirty.version != self.last_close_version:
+                return "churn"
+            cols = self.columns
+            if cols.has_pending():
+                return "pending"
+            if self.open_cache.gate_dropped_rows:
+                return "gang_invalid"
+            if cols.has_unsettled_phase():
+                return "phase"
+        return None
 
     def _observe_decisions(self, arrivals, now: float, gangs=()) -> None:
         """The arrival→decision latency of the pods bound at ``now``, from

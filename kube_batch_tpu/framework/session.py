@@ -1044,11 +1044,18 @@ def _count_gate_dropped(ssn: Session, qcounts: Dict[str, dict]) -> None:
         qc[(pg.phase or PodGroupPhase.PENDING).value.lower()] += 1
 
 
-def close_session(ssn: Session, stage_flush: bool = False):
+def close_session(ssn: Session, stage_flush: bool = False,
+                  release: bool = True):
     """Plugin close hooks then the job updater (framework.go:55-62 +
     job_updater.go:33-122, sans the 16-worker pool — the host loop is cold).
     Exclusive sessions additionally unwind Pipelined placements (session-only
     state, gone with a cloned session) and release the cache gate.
+
+    ``release=False`` leaves that last step, :func:`release_session`, to the
+    caller, who MUST make it: the pipelined cycle re-arms the what-if lease
+    in between, on the state every later reader will see (the status pass
+    has stamped, the session-only placements are unwound) and while nothing
+    else can move it.
 
     ``stage_flush=True`` is the pipelined cycle's close: the status pass
     still DERIVES everything synchronously (phase writes, dirty stamps,
@@ -1134,11 +1141,20 @@ def close_session(ssn: Session, stage_flush: bool = False):
                 drain = getattr(ssn.cache, "flush_binds", None)
                 if drain is not None:
                     drain()
-            ssn.cache.end_exclusive_session()
-        ssn.jobs = {}
-        ssn.nodes = {}
-        ssn.queues = {}
-        ssn.plugins = []
-        ssn.pipelined_tasks = []
-        ssn.allocated_tasks = []
+        if release:
+            release_session(ssn)
     return flush
+
+
+def release_session(ssn: Session) -> None:
+    """The last step of a close: an exclusive session hands the cache back
+    (the mutations deferred during the cycle apply, in order) and the
+    session lets go of everything it held."""
+    if ssn.exclusive:
+        ssn.cache.end_exclusive_session()
+    ssn.jobs = {}
+    ssn.nodes = {}
+    ssn.queues = {}
+    ssn.plugins = []
+    ssn.pipelined_tasks = []
+    ssn.allocated_tasks = []

@@ -316,6 +316,23 @@ SETTLE_HELD = Summary(
     f"{_SUBSYSTEM}_cycle_settle_milliseconds",
     "Time ingest wakes were held for the rest of their burst (ms)",
 )
+# the idle tick (Scheduler._idle_tick) and the lease re-arm that lets it
+# find nothing owed (Scheduler._rearm_lease)
+QUIESCENT_TICKS = Counter(
+    f"{_SUBSYSTEM}_cycle_quiescent_ticks_total",
+    "Floor wakes that found nothing owed and opened no session (each also "
+    "counted on cycle_trigger_wakes_total under trigger=floor; over those: "
+    "the share of idle ticks that cost no cycle)",
+)
+LEASE_REARMS = Counter(
+    f"{_SUBSYSTEM}_whatif_lease_rearms_total",
+    "What-if lease re-arms at a cycle's commit, by outcome (published: "
+    "the cycle moved the cache past the lease, which was "
+    "published again on the state the cycle left | ingest_pending: "
+    "skipped, the next cycle starts at once and publishes from its open | "
+    "not_owed: the lease already covered that state | failed)",
+    ("outcome",),
+)
 PIPELINE_OVERLAP = Histogram(
     f"{_SUBSYSTEM}_pipeline_writeback_overlap_milliseconds",
     "Writeback-stage time overlapped behind the next cycle (ms)",
@@ -487,6 +504,9 @@ SELF_WAKES.add(0.0)
 SETTLE_SIGNALS.add(0.0)
 for _ended_by in ("quiet", "cap"):
     SETTLE_HOLDS.add(0.0, _ended_by)
+QUIESCENT_TICKS.add(0.0)
+for _outcome in ("published", "ingest_pending", "not_owed"):
+    LEASE_REARMS.add(0.0, _outcome)
 DECISIONS_LEFTOVER.add(0.0)
 SOLVE_ROUNDS.add(0.0, "allocate")
 SOLVE_OVER_BUDGET.add(0.0, "allocate")
@@ -530,6 +550,8 @@ METRICS = [
     SETTLE_HOLDS,
     SETTLE_SIGNALS,
     SETTLE_HELD,
+    QUIESCENT_TICKS,
+    LEASE_REARMS,
     PIPELINE_OVERLAP,
     STAGED_INGEST,
     QUEUE_SHARE,
@@ -831,6 +853,17 @@ def register_settle_hold(ended_by: str, signals: int, held_ms: float) -> None:
     SETTLE_HOLDS.inc(ended_by)
     SETTLE_SIGNALS.add(signals)
     SETTLE_HELD.observe_many(held_ms, 1)
+
+
+def register_quiescent_tick() -> None:
+    """One floor wake that opened no session."""
+    QUIESCENT_TICKS.inc()
+
+
+def register_lease_rearm(outcome: str) -> None:
+    """One look at the what-if lease at a cycle's commit, by what came of
+    it."""
+    LEASE_REARMS.inc(outcome)
 
 
 def observe_pipeline_overlap(ms: float) -> None:

@@ -119,9 +119,13 @@ class ReplicationPublisher:
         lease_wire = _lease_wire(lease)
         version = int(lease.version)
         hint = dict(delta_hint) if hint_ok else None
+        # the encode's span goes on the record of the cycle that published
+        # (as the writeback's does): the cycle's last publish, the lease
+        # re-arm, is encoded after the cycle has ended
+        record = self.tracer.current if self.tracer is not None else None
         self._pending = self._pool.submit(
             self._encode_cycle, seq, version, fields, tables, lease_wire,
-            hint)
+            hint, record)
         return seq
 
     def barrier(self) -> None:
@@ -147,9 +151,11 @@ class ReplicationPublisher:
             self._full_cache = None
 
     # ---- worker ----------------------------------------------------------
-    def _encode_cycle(self, seq, version, fields, tables, lease_wire, hint):
+    def _encode_cycle(self, seq, version, fields, tables, lease_wire, hint,
+                      record=None):
         try:
-            span = (self.tracer.span("replicate_encode", seq=seq)
+            span = (self.tracer.cycle_span("replicate_encode", record,
+                                           seq=seq)
                     if self.tracer is not None else None)
             if span is not None:
                 with span:

@@ -24,8 +24,25 @@ the first wakes the parked loop: the trigger therefore lets the burst
 finish arriving (a settle hold of at most an eighth of a cycle's cost
 without a further signal, half a cycle's in all) before it starts the ONE
 cycle that decides it, where the first request's cycle used to bind
-nothing and make the rest wait for a second.  Knobs: ``KB_PIPELINE=0``
-restores the serial wait.Until loop (the bit-exactness oracle),
+nothing and make the rest wait for a second.
+
+A cycle leaves nothing owed, and an idle tick that finds nothing owed opens
+no session.  The last act of a pipelined cycle, while its session still
+owns the cache, is to publish the what-if lease again on the state its
+binds left (:meth:`Scheduler._rearm_lease`), so what-ifs see a commit at
+once and the tick after it has no lease to repair.  A floor wake then
+drains what is staged, runs the resync queue, looks at the conf file, and
+if none of that applied anything, no task is pending and no PodGroup is in
+a phase the close reports every cycle (:meth:`SchedulerCache.owes_a_cycle`)
+it runs no session and no action (:meth:`Scheduler._idle_tick`): a
+fraction of a millisecond where a cycle that decides nothing cost tens to
+hundreds, in the way of whatever burst arrived while it ran.  The tick
+still counts as a floor wake and still advances the guard's cycle clock;
+it feeds neither the cost EWMA nor the rate floor, which count from cycles
+that opened a session.  Anything owed means the whole cycle, as before.
+
+Knobs: ``KB_PIPELINE=0`` restores the serial wait.Until loop (the
+bit-exactness oracle),
 ``KB_PERIOD_MIN`` pins the minimum spacing between cycle starts (rate
 floor for bursts; unset, the floor ADAPTS to an EWMA of the cycle's own
 measured cost — see :meth:`Scheduler._note_cycle_cost`), ``KB_PERIOD_MAX``
@@ -42,12 +59,20 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional
 
 from kube_batch_tpu import actions as _actions  # registers actions
+from kube_batch_tpu.actions.allocate import (
+    build_session_snapshot,
+    republish_query_lease,
+)
 from kube_batch_tpu import plugins as _plugins  # registers plugin builders
 from kube_batch_tpu.cache.cache import SchedulerCache
 from kube_batch_tpu.framework.conf import SchedulerConfiguration, load_scheduler_conf
 from kube_batch_tpu.framework.interface import Action, get_action
 from kube_batch_tpu.envutil import env_flag
-from kube_batch_tpu.framework.session import close_session, open_session
+from kube_batch_tpu.framework.session import (
+    close_session,
+    open_session,
+    release_session,
+)
 from kube_batch_tpu import metrics
 from kube_batch_tpu.obs.alerts import alerts_of
 from kube_batch_tpu.obs.trace import tracer_of
@@ -92,7 +117,14 @@ class CycleTrigger:
     :meth:`wait_for_work` are root spans on the loop thread, ``park:floor``
     (the rate floor) and ``park:event`` (nothing pending, until a signal or
     the idle tick, then the settle hold as its child span ``settle``),
-    kept on the record of the cycle they precede."""
+    kept on the record of the cycle they precede.
+
+    The two deadlines count from different starts.  The rate floor spaces
+    CYCLES: it counts from the start of the last cycle that opened a
+    session, so a quiescent tick (a floor wake that found nothing owed and
+    opened none) neither restarts it nor makes the event after it wait.
+    The idle deadline counts from the loop's last wake of either kind, so
+    ticks stay ``max_period`` apart whether or not they ran a cycle."""
 
     def __init__(self, clock=None, tracer=None):
         self.clock = clock if clock is not None else time
@@ -140,6 +172,14 @@ class CycleTrigger:
                 self._stopping = True
             self._cond.notify_all()
 
+    def ingest_pending(self) -> bool:
+        """Whether an ingest signal is waiting for the next cycle (not
+        consumed): what the cycle asks before it re-arms the what-if lease
+        at its commit, since the cycle that signal starts at once publishes
+        from its own open."""
+        with self._cond:
+            return self._pending == "ingest"
+
     def poll(self) -> bool:
         """Consume a pending signal without waiting (the sim's virtual-time
         pacing asks 'would the trigger fire now?' instead of blocking)."""
@@ -155,7 +195,8 @@ class CycleTrigger:
         return reason
 
     def wait_for_work(self, cycle_start: float, min_period: float,
-                      max_period: float, settle=None) -> str:
+                      max_period: float, settle=None,
+                      idle_from: Optional[float] = None) -> str:
         """Block until the next cycle should start; returns the wake reason
         (``"ingest"`` — signalled arrival churn; ``"leftover"`` — only the
         loop's own signal, for what its last cycle left pending;
@@ -164,15 +205,25 @@ class CycleTrigger:
         a hot ingest stream nor a chain of self-wakes can busy-spin the
         solve.  ``settle`` is the ``(quiet gap, cap)`` in seconds of the
         hold an ingest wake pays for the rest of its burst (``None``: no
-        hold)."""
+        hold).
+
+        ``cycle_start`` is when the last cycle that opened a session
+        started, and the rate floor counts from it alone: a quiescent tick
+        (:meth:`Scheduler._idle_tick`) is no cycle to space the next one
+        from, and an event that lands just after one starts its cycle at
+        once.  ``idle_from`` is the loop's last wake of either kind
+        (default ``cycle_start``), and the idle deadline counts from it, so
+        quiescent ticks stay ``max_period`` apart and never spin."""
         clock = self.clock
         floor_sp = None
         floor_rem = min_period - (clock.monotonic() - cycle_start)
         if floor_rem > 0:
             with self._parked("park:floor") as floor_sp:
                 clock.sleep(floor_rem)
+        if idle_from is None:
+            idle_from = cycle_start
         with self._parked("park:event") as event_sp:
-            if not self._await_signal(cycle_start + max_period):
+            if not self._await_signal(idle_from + max_period):
                 reason, signalled_ms = "floor", 0.0
             else:
                 if settle is not None:
@@ -328,6 +379,9 @@ class Scheduler:
         # cycle computes; _await_writeback is the stage barrier
         self._wb_pool: Optional[ThreadPoolExecutor] = None
         self._wb_future = None
+        # the last cycle raised: what it left (and the loop's re-list
+        # recovery) is for a whole cycle to look at, whatever woke the loop
+        self._cycle_failed = False
 
     def _stat_conf(self) -> Optional[float]:
         if not self._conf_path:
@@ -371,25 +425,160 @@ class Scheduler:
         this form stays the bit-exactness oracle (KB_PIPELINE=0)."""
         self._cycle(pipelined=False)
 
-    def run_once_pipelined(self) -> None:
+    def run_once_pipelined(self, wake: Optional[str] = None) -> bool:
         """One pipelined cycle: staged ingest drains under one lock, the
         session opens/solves/replays on this thread, the close DERIVES the
         status pass synchronously but hands the egress half (status flush +
         async binder drain) to the writeback worker — overlapped with the
         caller's next cycle.  :meth:`drain_pipeline` (or the next cycle's
-        stage barrier) joins it."""
-        self._cycle(pipelined=True)
+        stage barrier) joins it.
 
-    def _cycle(self, pipelined: bool) -> None:
+        ``wake`` is the event-driven loop's wake reason.  On ``"floor"``
+        (the idle period elapsed with no signal) the cycle first looks
+        whether anything is owed (:meth:`_idle_tick`) and, where nothing
+        is, opens no session: it returns False, a quiescent tick.  Every
+        other call, ``run_once_pipelined()`` among them, runs the whole
+        cycle and returns True."""
+        return self._cycle(pipelined=True, wake=wake)
+
+    def _cycle(self, pipelined: bool, wake: Optional[str] = None) -> bool:
+        if pipelined and wake == "floor" and self._idle_tick():
+            return False
         tracer = self.tracer
         # the cycle's trace record: every stage below runs inside a span;
         # the pipelined writeback attaches to THIS record from its worker
         # thread, so the exported trace shows the overlap structure
         record = tracer.begin_cycle("pipelined" if pipelined else "serial")
+        self._cycle_failed = True  # until the body has returned
         try:
             self._cycle_body(pipelined, record)
         finally:
             tracer.end_cycle()
+        self._cycle_failed = False
+        return True
+
+    def _idle_tick(self) -> bool:
+        """A floor wake's look at whether anything is owed; True when
+        nothing is, and the tick is then all there is of this cycle.
+
+        A cycle leaves nothing owed: its close derived every status after
+        its own binds, and its last act was to re-arm the what-if lease on
+        the state it left (:meth:`_rearm_lease`).  So a floor wake (no
+        ingest has signalled since) that applies nothing here (the staged
+        ingest, the resync queue and the conf file are looked at as at the
+        head of any cycle) and finds the cache as the last session left it,
+        with nothing to decide or report (:meth:`SchedulerCache.
+        owes_a_cycle`) and the lease still covering that state, opens no
+        session and runs no action: the cycle it replaces would have
+        decided nothing and written nothing
+        (tests/test_pipeline.py::TestQuiescentTick).  It still advances
+        the guard's cycle clock, whose cooldowns count cycles.  Anything
+        else means the whole cycle, exactly as before; the span's ``owed``
+        says what.
+
+        The tick's time is a root span of the loop thread, kept like the
+        parked time on the record of the cycle it precedes."""
+        tracer = self.tracer
+        with tracer.park_span(
+                "idle_tick", before_cycle=tracer.next_cycle_number()) as sp:
+            owed = self._owed()
+            sp.set(quiescent=owed is None)
+            if owed is not None:
+                sp.set(owed=owed)
+                return False
+            self._end_cycle_clocks()
+        metrics.register_quiescent_tick()
+        return True
+
+    def _owed(self) -> Optional[str]:
+        """What makes this floor wake a whole cycle, or None."""
+        cache = self.cache
+        owes = getattr(cache, "owes_a_cycle", None)
+        if owes is None:
+            return "cache"
+        if self._cycle_failed:
+            return "failed_cycle"
+        drain = getattr(cache, "drain_staged_ingest", None)
+        if drain is not None:
+            n_staged = drain()
+            if n_staged:
+                # applied here: the cycle's own drain will find none
+                metrics.register_staged_ingest(n_staged)
+                return "staged"
+        resync = getattr(cache, "process_resync_tasks", None)
+        if resync is not None:
+            resync()  # a repair it applied moves the tracker: "churn"
+        conf = self.conf
+        self._maybe_reload_conf()
+        if self.conf is not conf:
+            return "conf"
+        owed = owes()
+        if owed is not None:
+            return owed
+        qp = getattr(cache, "query_plane", None)
+        if qp is not None and qp.needs_publish(cache.last_close_version):
+            return "lease"
+        return None
+
+    def _close_pipelined(self, ssn, rearm: bool):
+        """The pipelined close, which hands the cache back in two steps
+        with the lease re-arm between them: after the status pass has
+        stamped the tracker and the session-only placements are unwound,
+        before the deferred ingest applies.  Returns the staged flush.
+        One stage, ``status_derive``, as the close has always been; the
+        re-arm is its child span."""
+        with self.tracer.span("status_derive"):
+            try:
+                flush = close_session(ssn, stage_flush=True, release=False)
+                if rearm:
+                    self._rearm_lease(ssn)
+                return flush
+            finally:
+                release_session(ssn)
+
+    def _rearm_lease(self, ssn) -> None:
+        """The re-arm at the commit: the last thing a pipelined cycle does
+        while its session still owns the cache.  If what the cycle did
+        moved the tracker past the published lease (what its binds and
+        evictions did to the statuses the close derives: a bind decision
+        stamps nothing by itself) the lease is published again from a
+        snapshot of the state the cycle leaves, stamped with the version
+        read here; staged ingest is in neither, it waits in the staging
+        buffer for the next drain.  So what-ifs see a commit at once and
+        not an idle tick later, and that tick finds nothing owed.  With an
+        ingest signal already pending the next cycle starts at once and
+        publishes from its own open, so the re-arm is skipped."""
+        cache = self.cache
+        qp = getattr(cache, "query_plane", None)
+        if qp is None or ssn.columns is None or not ssn.exclusive:
+            return
+        with self.tracer.span("lease_rearm") as sp:
+            version = int(cache.dirty.version)
+            if not qp.needs_publish(version):
+                outcome = "not_owed"
+            elif self.trigger.ingest_pending():
+                outcome = "ingest_pending"
+            elif republish_query_lease(
+                    ssn, build=lambda: build_session_snapshot(ssn),
+                    version=version):
+                outcome = "published"
+            else:
+                outcome = "failed"  # logged where it failed, never raised
+            sp.set(outcome=outcome)
+        metrics.register_lease_rearm(outcome)
+
+    def _end_cycle_clocks(self) -> None:
+        """What counts cycles, quiescent ticks included."""
+        # guard-plane breaker clock: demotion cooldowns and half-open
+        # probes count in SCHEDULING CYCLES, not wall seconds, so the
+        # state machine is deterministic under the sim's virtual clock
+        guard = getattr(self.cache, "guard_plane", None)
+        if guard is not None:
+            guard.end_cycle()
+            # trip-rate SLO alerting rides the same deterministic clock
+            alerts_of(self.cache).evaluate(guard)
+        # how full each device has been: once a cycle, never per scrape
+        metrics.refresh_device_peak_bytes()
 
     def _cycle_body(self, pipelined: bool, record) -> None:
         tracer = self.tracer
@@ -425,6 +614,7 @@ class Scheduler:
         # what runs after them (reclaim's idle-fit claimant gate)
         ssn.action_names = [a.name for a in self.actions]
         staged_flush = None
+        acted = False  # every action ran to its end
         try:
             for action in self.actions:
                 # the span IS the measurement (rule KBT014): the action
@@ -433,6 +623,7 @@ class Scheduler:
                 with tracer.span("action:" + action.name) as sp:
                     action.execute(ssn)
                 metrics.observe_action_latency(action.name, sp.dur_us)
+            acted = True
         finally:
             shed = (
                 self.cycle_budget > 0
@@ -454,8 +645,11 @@ class Scheduler:
                 # pipelined: the close stages the flush (degraded verdict
                 # captured NOW, while the shed flag is visible) and skips
                 # the inline binder drain — both run on the writeback worker
-                with tracer.span("status_derive"):
-                    staged_flush = close_session(ssn, stage_flush=pipelined)
+                if pipelined:
+                    staged_flush = self._close_pipelined(ssn, rearm=acted)
+                else:
+                    with tracer.span("status_derive"):
+                        staged_flush = close_session(ssn)
             finally:
                 if shed:
                     self.cache.shed_status_writes = False
@@ -483,16 +677,7 @@ class Scheduler:
             if flush is not None:
                 with tracer.span("bind_drain"):
                     flush()
-        # guard-plane breaker clock: demotion cooldowns and half-open
-        # probes count in SCHEDULING CYCLES, not wall seconds, so the
-        # state machine is deterministic under the sim's virtual clock
-        guard = getattr(self.cache, "guard_plane", None)
-        if guard is not None:
-            guard.end_cycle()
-            # trip-rate SLO alerting rides the same deterministic clock
-            alerts_of(self.cache).evaluate(guard)
-        # how full each device has been: once a cycle, never per scrape
-        metrics.refresh_device_peak_bytes()
+        self._end_cycle_clocks()
         if self.on_cycle_end is not None:
             self.on_cycle_end()
 
@@ -650,7 +835,16 @@ class Scheduler:
         each cycle that made progress and never a spin; every link binds at
         least one pod, so a chain is no longer than the backlog.  Evictions
         are no progress here: a victim's termination arrives as an ingest
-        event."""
+        event.
+
+        The wake reason goes to the cycle it starts
+        (:meth:`run_once_pipelined`): a ``"floor"`` wake that finds nothing
+        owed is a quiescent tick and opens no session.  Only a cycle that
+        did open one feeds the cost EWMA (a tick's fraction of a
+        millisecond would shrink the floor and the settle hold's quiet gap
+        to their clamps and split bursts over two cycles again), restarts
+        the rate floor and can leave something behind; every wake, a
+        tick's too, restarts the idle deadline."""
         cache = self.cache
         left_behind = getattr(cache, "left_schedulable_pending", None)
         enable = getattr(cache, "enable_ingest_staging", None)
@@ -664,26 +858,33 @@ class Scheduler:
             "max_period=%.3fs (KB_PIPELINE=0 for the serial oracle)",
             self.min_period, self.max_period,
         )
+        wake = None  # what woke the loop for the cycle to come (start-up: nothing)
+        floor_from = self.clock.monotonic()
         try:
             while not self._stop:
                 tick = self.clock.monotonic()
                 binds = getattr(cache, "binds_total", 0)
                 try:
-                    self.run_once_pipelined()
-                    # successful cycles only: a fast-CRASHING cycle must
-                    # not drag the adaptive floor down and turn the loop
-                    # into a high-frequency crash retry
-                    self._note_cycle_cost(self.clock.monotonic() - tick)
-                    if left_behind is not None and left_behind(binds):
-                        self.trigger.notify(leftover=True)
+                    if self.run_once_pipelined(wake):
+                        floor_from = tick
+                        # successful cycles only: a fast-CRASHING cycle
+                        # must not drag the adaptive floor down and turn
+                        # the loop into a high-frequency crash retry.  And
+                        # cycles only: a quiescent tick's fraction of a
+                        # millisecond would shrink the floor and the settle
+                        # hold's quiet gap to their clamps
+                        self._note_cycle_cost(self.clock.monotonic() - tick)
+                        if left_behind is not None and left_behind(binds):
+                            self.trigger.notify(leftover=True)
                 except Exception:  # noqa: BLE001 — next cycle self-corrects
                     logger.exception("scheduling cycle failed")
+                    floor_from = tick
                     self._recover_failed_cycle()
-                reason = self.trigger.wait_for_work(
-                    tick, self.min_period, self.max_period,
-                    self.settle_window(),
+                wake = self.trigger.wait_for_work(
+                    floor_from, self.min_period, self.max_period,
+                    self.settle_window(), tick,
                 )
-                metrics.register_trigger_wake(reason)
+                metrics.register_trigger_wake(wake)
         finally:
             # shutdown drain: join the in-flight writeback, apply staged
             # ingest, and detach the trigger so a re-armed run_forever (the

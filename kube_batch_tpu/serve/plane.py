@@ -227,11 +227,12 @@ class QueryPlane:
         lease = self.broker.current()
         return lease is None or lease.version < version
 
-    def publish_session(self, ssn, snap, meta) -> None:
+    def publish_session(self, ssn, snap, meta, version: int) -> None:
         """Publish the lease for this cycle: the device-resident snapshot
         the solve consumed (memoized — the swap already ran for the solve
         dispatch), the session's solve configs, the dirty-tracker version
-        token, and the row-allocator peek that keys the tie-hash oracle."""
+        token ``version`` that ``snap`` holds, and the row-allocator peek
+        that keys the tie-hash oracle."""
         cols = ssn.columns
         if cols is None:
             return  # isolated/object session — nothing resident to lease
@@ -281,7 +282,7 @@ class QueryPlane:
         lease = SnapshotLease(
             snap=dev,
             meta=meta,
-            version=int(getattr(ssn.cache, "last_open_version", 0)),
+            version=int(version),
             config=config,
             evict_config=evict_config,
             mesh=mesh,

@@ -89,7 +89,9 @@ class PacedCondition:
     the clock on — to the wait's end, or only as far as the next scripted
     signal that falls inside it, which it then raises.  ``install`` puts it
     in the trigger's place; ``script`` is ``[(virtual time, notify()'s
-    keywords)]``."""
+    keywords)]``, or in the keywords' place a callable that raises the
+    signal itself (an ingest through a cache whose signal is the trigger's
+    ``notify``, a ``Scheduler.stop``)."""
 
     def __init__(self, trigger, script=()):
         self.trigger, self.clock = trigger, trigger.clock
@@ -117,6 +119,9 @@ class PacedCondition:
         if self.script and self.script[0][0] <= end:
             at, kwargs = self.script.pop(0)
             self.clock.advance_to(at)
-            self.trigger.notify(**kwargs)
+            if callable(kwargs):
+                kwargs()
+            else:
+                self.trigger.notify(**kwargs)
         else:
             self.clock.advance_to(end)

@@ -11,6 +11,7 @@ state.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 
@@ -716,9 +717,9 @@ class TestLeftoverWake:
         log, parked = [], threading.Semaphore(0)
         cycle, wait = sched.run_once_pipelined, sched.trigger.wait_for_work
 
-        def run_once_pipelined():
+        def run_once_pipelined(*wake):
             try:
-                cycle()
+                return cycle(*wake)
             finally:
                 log.append(("cycle", time.monotonic()))
 
@@ -797,14 +798,15 @@ class TestLeftoverWake:
         sched, log, parked = self._loop(cache, max_period=0.05)
         cycle, state = sched.run_once_pipelined, {"n": 0}
 
-        def failing_after_its_binds():
-            cycle()
+        def failing_after_its_binds(*wake):
+            opened = cycle(*wake)
             state["n"] += 1
             if state["n"] == 1:
                 # the bind is acknowledged before the recovery's re-list,
                 # so no later cycle binds `fits` a second time
                 sched.drain_pipeline()
                 raise RuntimeError("planted: the cycle dies after binding")
+            return opened
 
         sched.run_once_pipelined = failing_after_its_binds
         self_wakes0 = _self_wakes()
@@ -934,6 +936,325 @@ class TestOneCycleABurst:
         assert (hold.name, hold.attrs) == ("settle", {
             "q_ms": 25.0, "signals": 4, "ended_by": "quiet",
             "widest_gap_ms": pytest.approx(1.0)})
+
+
+def _quiescent_ticks() -> float:
+    return prom_metrics.metrics.QUIESCENT_TICKS._values.get((), 0.0)
+
+
+def _rearms() -> dict:
+    return {k[0]: v
+            for k, v in prom_metrics.metrics.LEASE_REARMS._values.items()}
+
+
+SHIPPED_CONF = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "config", "kube-batch-tpu-conf.yaml")
+
+
+class TestQuiescentTick:
+    """A cycle leaves nothing owed, so a floor wake that finds nothing owed
+    opens no session: after a deciding cycle the idle ticks cost no cycle,
+    stay one idle period apart, and leave the adaptive floor, the settle
+    window and the rate floor alone; anything owed means the whole cycle."""
+
+    def _gang(self, cache, name, cpu=500.0, members=1, queue="q0"):
+        cache.add_pod_group(PodGroup(
+            name=name, namespace="ns", uid=f"pg-{name}", min_member=members,
+            queue=queue, creation_index=1,
+        ))
+        for k in range(members):
+            cache.add_pod(Pod(
+                name=f"{name}-{k}", namespace="ns", uid=f"u-{name}-{k}",
+                requests={"cpu": cpu}, phase=PodPhase.PENDING,
+                annotations={GROUP_NAME_ANNOTATION: name}, creation_index=2,
+            ))
+
+    def test_floor_wakes_after_a_deciding_cycle_open_no_session(self):
+        """The real loop on one thread and a clock only its waits move
+        (``PacedCondition``): a cycle costs 200 ms of it, a tick half a
+        millisecond."""
+        from kube_batch_tpu.guard import guard_of
+        from kube_batch_tpu.serve.plane import QueryPlane
+        from kube_batch_tpu.sim.clock import VirtualClock
+        from tests.fixtures import PacedCondition
+
+        cache = _mk_cache()
+        qp = QueryPlane(cache, start_thread=False)
+        self._gang(cache, "a")
+        clock = VirtualClock(start=100.0)
+        sched = Scheduler(cache, conf=load_scheduler_conf(None),
+                          schedule_period=1.0, clock=clock)
+        sched.pipelined = True
+        body, tick = sched._cycle_body, sched._idle_tick
+        cycle, log = sched.run_once_pipelined, []
+
+        def _cycle_body(*args):
+            clock.sleep(0.2)
+            return body(*args)
+
+        def _idle_tick():
+            clock.sleep(0.0005)
+            return tick()
+
+        def run_once_pipelined(wake):
+            log.append((wake, round(clock.monotonic(), 4), cycle(wake)))
+            return log[-1][2]
+
+        sched._cycle_body, sched._idle_tick = _cycle_body, _idle_tick
+        sched.run_once_pipelined = run_once_pipelined
+        # 10 ms after the third tick a gang arrives (staged: the cache's
+        # signal is the trigger's notify); the loop is stopped mid-park
+        PacedCondition.install(sched.trigger, [
+            (103.010, lambda: self._gang(cache, "b")),
+            (106.5, sched.stop),
+        ])
+        guard = guard_of(cache)
+        opens0 = sched.tracer.span_counts.get("session_open", 0)
+        ticks0, floor0 = _quiescent_ticks(), _wakes("floor")
+        rearms0, guard0 = _rearms(), guard.cycle
+        try:
+            sched.run_forever()
+        finally:
+            qp.close()
+        # the start-up cycle decides `a`; three ticks, one idle period
+        # apart from each other and from that cycle's START, open nothing;
+        # the arrival is held for its quiet gap (an eighth of 200 ms) and
+        # decided at once: the floor counts from the last cycle that opened
+        # a session, 3 s ago, not from the tick 10 ms ago; three more ticks
+        assert log == [
+            (None, 100.0, True),
+            ("floor", 101.0, False), ("floor", 102.0, False),
+            ("floor", 103.0, False),
+            ("ingest", 103.035, True),
+            ("floor", 104.035, False), ("floor", 105.035, False),
+            ("floor", 106.035, False),
+        ]
+        assert sorted(cache.binder.binds) == ["ns/a-0", "ns/b-0"]
+        counts = sched.tracer.span_counts
+        assert counts["session_open"] == opens0 + 2
+        assert counts["idle_tick"] == 6
+        assert counts["lease_rearm"] == 2
+        assert "park:floor" not in counts, "a tick restarted the rate floor"
+        assert _quiescent_ticks() == ticks0 + 6
+        assert _wakes("floor") == floor0 + 6, "ticks are floor wakes still"
+        assert guard.cycle == guard0 + 8, "cooldowns count ticks as cycles"
+        grown = {k: v - rearms0.get(k, 0.0) for k, v in _rearms().items()}
+        assert grown == {"published": 2.0, "ingest_pending": 0.0,
+                         "not_owed": 0.0}
+        # only the two cycles fed the EWMA (200 ms each on this clock), so
+        # the floor and the hold's window are a 200 ms cycle's
+        assert sched.cycle_cost_ewma == pytest.approx(0.2)
+        assert sched.min_period == pytest.approx(0.2)
+        assert sched.settle_window() == pytest.approx((0.025, 0.1))
+        # the record of the cycle that decided `b` leads with the ticks
+        # and the park before it, and holds the re-arm inside its close
+        record = next(r for r in sched.tracer.recorder.records()
+                      if any(s.name == "ingest_drain"
+                             and s.attrs.get("events") == 2
+                             for s in r.spans))
+        names = [s.name for s in record.spans]
+        assert names[:names.index("ingest_drain")] == [
+            "park:event", "idle_tick", "park:event", "idle_tick",
+            "park:event"][-names.index("ingest_drain"):]
+        tick_span = next(s for s in record.spans if s.name == "idle_tick")
+        assert tick_span.attrs["quiescent"] is True
+        close = next(s for s in record.spans if s.name == "status_derive")
+        rearm = close.children[-1]
+        assert (rearm.name, rearm.attrs) == (
+            "lease_rearm", {"outcome": "published"})
+        assert "lease_rearm" not in names, "a stage of its own"
+        assert qp.broker.current().version == cache.last_close_version \
+            == cache.dirty.version
+
+    # what is planted after the deciding cycle, and what the tick's span
+    # then says is owed (None: nothing, the control)
+    CASES = {
+        "nothing-owed": (True, None),
+        "nothing-owed-no-plane": (False, None),
+        "staged-event": (True, "staged"),
+        "resync-item": (True, "churn"),
+        "conf-file-changed": (True, "conf"),
+        "pod-that-fits-nowhere": (True, "pending"),
+        "pending-phase-podgroup": (True, "phase"),
+        "gang-invalid-podgroup": (True, "gang_invalid"),
+        "cycle-that-raised": (True, "failed_cycle"),
+        "lease-owed": (True, "lease"),
+        "no-plane-pending-work": (False, "pending"),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_anything_owed_means_the_whole_cycle(self, case, tmp_path):
+        from kube_batch_tpu.serve.plane import QueryPlane
+
+        plane, owed = self.CASES[case]
+        cache = _mk_cache()
+        qp = QueryPlane(cache, start_thread=False) if plane else None
+        conf_file = tmp_path / "conf.yaml"
+        with open(SHIPPED_CONF) as f:
+            conf_file.write_text(f.read())
+        sched = Scheduler(cache, conf_path=str(conf_file))
+        try:
+            self._gang(cache, "a", members=2)
+            if owed == "pending":
+                self._gang(cache, "unfit", cpu=10_000_000.0)
+            if owed == "gang_invalid":
+                cache.add_pod_group(PodGroup(
+                    name="empty", namespace="ns", uid="pg-empty",
+                    min_member=1, queue="q0", creation_index=1))
+            assert sched.run_once_pipelined() is True
+            sched.drain_pipeline()
+            assert len(cache.binder.binds) == 2
+            if owed == "staged":
+                cache.enable_ingest_staging()
+                self._gang(cache, "late")
+            elif case == "resync-item":
+                cache.resync_task(cache.jobs["ns/a"].tasks["ns/a-0"])
+            elif owed == "conf":
+                stamp = os.path.getmtime(conf_file) + 5
+                os.utime(conf_file, (stamp, stamp))
+            elif owed == "phase":
+                # a gang of two with one member Running and one Succeeded
+                # stays valid, has nothing pending, and falls back to
+                # Pending: the close reports it every cycle
+                for key in ("ns/a-0", "ns/a-1"):
+                    kl.set_running(cache, key, cache.pods[key].node_name)
+                kl.set_succeeded(cache, "ns/a-1")
+                # the shipped conf's enqueue would promote it: without it
+                sched.actions = [a for a in sched.actions
+                                 if a.name != "enqueue"]
+                assert sched.run_once_pipelined("floor") is True  # churn
+                sched.drain_pipeline()
+            elif owed == "failed_cycle":
+                def boom(ssn):
+                    raise RuntimeError("planted: the action dies")
+
+                sched.actions = list(sched.actions)
+                real, sched.actions[0] = sched.actions[0], type(
+                    "Boom", (), {"name": "boom", "execute": staticmethod(
+                        boom)})()
+                with pytest.raises(RuntimeError):
+                    sched.run_once_pipelined()
+                sched.actions[0] = real
+                sched.drain_pipeline()
+            elif owed == "lease":
+                qp.broker.retire()
+            opens0 = sched.tracer.span_counts["session_open"]
+            ticks0 = _quiescent_ticks()
+            opened = sched.run_once_pipelined("floor")
+            sched.drain_pipeline()
+            span = sched.tracer._preceding[-1] if owed is None else next(
+                s for s in sched.tracer.recorder.last_record().spans
+                if s.name == "idle_tick" and not s.attrs["quiescent"])
+            assert span.name == "idle_tick"
+            assert span.attrs.get("owed") == owed
+            assert opened is (owed is not None)
+            assert sched.tracer.span_counts["session_open"] == \
+                opens0 + (owed is not None)
+            assert _quiescent_ticks() == ticks0 + (owed is None)
+            if owed in ("staged", "churn", "conf", "failed_cycle", "lease"):
+                # what was owed is settled: the next tick is quiescent
+                assert sched.run_once_pipelined("floor") is False
+            assert cache.columns.check_consistency(cache) == []
+        finally:
+            sched.close()
+            if qp is not None:
+                qp.close()
+
+
+def _egress(cache) -> dict:
+    """What left the cache: bind dispatches, status writes and pod
+    conditions in order; events as a multiset (the binder's dispatch pool
+    and the writeback worker both append, in no fixed order)."""
+    return {
+        "binds": list(cache.binder.channel),
+        "pod_groups": [(pg.namespace, pg.name, pg.phase, pg.running,
+                        pg.failed, pg.succeeded, len(pg.conditions))
+                       for pg in cache.status_updater.pod_groups],
+        "pod_conditions": list(cache.status_updater.pod_conditions),
+        "events": sorted(cache.events),
+        "evicts": list(cache.evictor.evicts),
+    }
+
+
+class TestQuiescentPremise:
+    """The premise the quiescent tick rests on: the cycle it stands in for
+    would have decided nothing and written nothing.  Two caches take the
+    same randomized churn; after every deciding cycle one gets N floor
+    wakes, the other N whole cycles.  They end in the same binds, pod and
+    PodGroup statuses, conditions, queue writes and egress, and every whole
+    cycle that stood where a tick was quiescent staged an empty flush."""
+
+    TICKS = 2
+
+    @pytest.mark.parametrize("seed, shipped, plane", [
+        (0, False, False), (11, True, True), (42, True, False),
+        (7, False, True),
+    ])
+    def test_ticks_and_whole_cycles_end_in_the_same_state(
+            self, seed, shipped, plane):
+        from kube_batch_tpu.serve.plane import QueryPlane
+
+        sides = []
+        for _ in range(2):
+            cache = _mk_cache()
+            sides.append((
+                cache,
+                Scheduler(cache, conf=load_scheduler_conf(
+                    SHIPPED_CONF if shipped else None)),
+                _Churner(cache, seed),
+                QueryPlane(cache, start_thread=False) if plane else None,
+            ))
+        (c_tick, s_tick, ch_tick, _), (c_full, s_full, ch_full, _) = sides
+        flushes = []
+        stage = c_full.stage_status_flush
+
+        def stage_status_flush(*args):
+            flushes.append(stage(*args))
+            return flushes[-1]
+
+        c_full.stage_status_flush = stage_status_flush
+        quiescent = full_instead = 0
+        try:
+            for ch in (ch_tick, ch_full):
+                for _ in range(3):
+                    ch.add_gang()
+            for cycle in range(12):
+                for _, sched, ch, _ in sides:
+                    ch.step()
+                    sched.run_once_pipelined()
+                    sched.drain_pipeline()
+                for _ in range(self.TICKS):
+                    opened = s_tick.run_once_pipelined("floor")
+                    s_tick.drain_pipeline()
+                    del flushes[:]
+                    s_full.run_once_pipelined()
+                    s_full.drain_pipeline()
+                    flush, = flushes
+                    if opened:
+                        full_instead += 1
+                        continue
+                    quiescent += 1
+                    wrote = (flush.to_write, flush.ops, flush.qwrites,
+                             flush.shed_queues)
+                    assert wrote == ([], [], [], 0), (
+                        f"seed={seed} cycle={cycle}: a whole cycle in a "
+                        f"quiescent tick's place wrote {wrote}")
+                want, got = _observable_state(c_full), _observable_state(
+                    c_tick)
+                for field in want:
+                    assert got[field] == want[field], (
+                        f"seed={seed} cycle={cycle}: {field} diverged")
+                assert _egress(c_tick) == _egress(c_full)
+            # the churn met both kinds of tick
+            assert quiescent >= 6 and quiescent + full_instead == 24
+            for cache, *_ in sides:
+                assert cache.columns.check_consistency(cache) == []
+        finally:
+            for _, sched, _, qp in sides:
+                sched.close()
+                if qp is not None:
+                    qp.close()
 
 
 class TestBudgetShedOverlappedClose:

@@ -1182,6 +1182,155 @@ class TestWhatifHTTP:
 
 
 # ==========================================================================
+# the re-arm at the commit: a cycle leaves the lease on the state it left
+# ==========================================================================
+
+
+class TestLeaseRearm:
+    """A pipelined cycle's last act under its session is to publish the
+    lease again on the state its binds left, stamped with the version the
+    cache has as the session hands it back: what-ifs see a commit at once,
+    not an idle tick later."""
+
+    BIG = {"cpu": 6000, "memory": 2 * GiB}
+
+    def _cluster(self, n_nodes):
+        """One node that holds a ``BIG`` pod, and n - 1 that never could."""
+        nodes = [build_node("big", cpu=8000, mem=16 * GiB)] + [
+            build_node(f"s{i}", cpu=1000, mem=2 * GiB)
+            for i in range(n_nodes - 1)]
+        return build_cache(queues=[Queue(name="default", weight=1)],
+                           nodes=nodes)
+
+    def _big_pod(self, cache, name):
+        cache.add_pod_group(PodGroup(
+            name=name, namespace="c1", min_member=1, queue="default"))
+        cache.add_pod(build_pod("c1", name, None, PodPhase.PENDING,
+                                dict(self.BIG), group_name=name))
+
+    @staticmethod
+    def _rearms():
+        from kube_batch_tpu.metrics import metrics as m
+
+        return {k[0]: v for k, v in m.LEASE_REARMS._values.items()}
+
+    @pytest.mark.parametrize("n_nodes", [
+        pytest.param(3, id="one-device"),
+        # 160 nodes pad to the 256 at which the 8-device test mesh shards
+        pytest.param(160, id="mesh"),
+    ])
+    def test_a_probe_sees_the_commit_at_once(self, plane_factory, n_nodes):
+        from kube_batch_tpu.scheduler import Scheduler
+
+        cache = self._cluster(n_nodes)
+        qp = plane_factory(cache)
+        sched = Scheduler(cache, conf=CONF)
+        try:
+            sched.run_once_pipelined()  # an empty cluster's lease
+            sched.drain_pipeline()
+            ask = {"queue": "default", "count": 1, "requests": self.BIG}
+            before = _probe(qp, ask)
+            assert before["feasible"] and before["nodes"] == ["big"]
+            self._big_pod(cache, "p0")
+            rearms0 = self._rearms()
+            sched.run_once_pipelined()  # binds p0 where the probe said
+            sched.drain_pipeline()
+            assert dict(cache.binder.binds) == {"c1/p0": "big"}
+            # no tick and no second cycle: the capacity is gone already
+            after = _probe(qp, ask)
+            assert after["feasible"] is False, (
+                "the lease still shows the node as it was before the bind")
+            lease = qp.broker.current()
+            assert after["snapshot_version"] == lease.version
+            assert lease.version == cache.last_close_version \
+                == cache.dirty.version > cache.last_open_version
+            assert (lease.mesh is not None) == (n_nodes >= 129)
+            assert self._rearms()["published"] == rearms0["published"] + 1
+            # and the tick after it owes nothing
+            assert sched.run_once_pipelined("floor") is False
+        finally:
+            sched.close()
+
+    def test_a_bind_that_moves_no_status_waits_for_the_next_ingest(
+            self, plane_factory):
+        """What the re-arm does NOT cover, pinned so that nobody reads more
+        into it: bind decisions stamp nothing by themselves, so a pod bound
+        into a gang that is already Running (min_member long met) leaves
+        the tracker where the open found it, the re-arm reads ``not_owed``
+        and the lease shows the node as it was, as before the re-arm
+        existed, until the next ingest (in a cluster: the kubelet's Running
+        update) starts a cycle that publishes from its open."""
+        from kube_batch_tpu.scheduler import Scheduler
+        from kube_batch_tpu.sim import kubelet as kl
+
+        cache = self._cluster(3)
+        qp = plane_factory(cache)
+        sched = Scheduler(cache, conf=CONF)
+        try:
+            cache.add_pod_group(PodGroup(
+                name="pg", namespace="c1", min_member=1, queue="default"))
+            cache.add_pod(build_pod(
+                "c1", "small", None, PodPhase.PENDING,
+                {"cpu": 500, "memory": GiB}, group_name="pg"))
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+            assert dict(cache.binder.binds) == {"c1/small": "big"}
+            cache.add_pod(build_pod("c1", "p1", None, PodPhase.PENDING,
+                                    dict(self.BIG), group_name="pg"))
+            rearms0 = self._rearms()
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+            assert dict(cache.binder.binds)["c1/p1"] == "big"
+            assert cache.last_close_version == cache.last_open_version
+            assert self._rearms()["not_owed"] == rearms0["not_owed"] + 1
+            ask = {"queue": "default", "count": 1, "requests": self.BIG}
+            assert _probe(qp, ask)["feasible"] is True  # the gap
+            kl.set_running(cache, "c1/p1", "big")
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+            assert _probe(qp, ask)["feasible"] is False
+        finally:
+            sched.close()
+
+    def test_a_pending_ingest_signal_skips_the_rearm(self, plane_factory):
+        """The cycle that signal starts at once publishes from its own
+        open, as every cycle did before: nothing is lost but one publish."""
+        from kube_batch_tpu.scheduler import Scheduler
+
+        cache = self._cluster(3)
+        qp = plane_factory(cache)
+        sched = Scheduler(cache, conf=CONF)
+        cache.set_ingest_signal(sched.trigger.notify)
+        try:
+            self._big_pod(cache, "p0")  # signals; nothing consumes it
+            assert sched.trigger.ingest_pending()
+            rearms0 = self._rearms()
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+            assert dict(cache.binder.binds) == {"c1/p0": "big"}
+            assert self._rearms()["ingest_pending"] == \
+                rearms0["ingest_pending"] + 1
+            stale = qp.broker.current()
+            assert stale.version == cache.last_open_version \
+                < cache.last_close_version
+            assert sched.trigger.poll()  # the loop's wake takes the signal
+            sched.run_once_pipelined()   # the cycle it starts
+            sched.drain_pipeline()
+            lease = qp.broker.current()
+            assert lease.version == cache.last_close_version \
+                == cache.dirty.version
+            after = _probe(qp, {"queue": "default", "count": 1,
+                                "requests": self.BIG})
+            assert after["feasible"] is False
+            grown = {k: v - rearms0[k] for k, v in self._rearms().items()}
+            assert grown == {"published": 0.0, "ingest_pending": 1.0,
+                             "not_owed": 1.0}
+        finally:
+            cache.set_ingest_signal(None)
+            sched.close()
+
+
+# ==========================================================================
 # verdict honesty: per-response `unmodeled: [...]` (guard-plane PR satellite)
 # ==========================================================================
 
