@@ -507,6 +507,21 @@ class AllocateAction(Action):
         self._fit_histograms_seen: set = set()
 
     def execute(self, ssn) -> None:
+        # a solve that spent its whole rounds x outer budget while it was
+        # still placing, and left pods unplaced, runs on once: on a packed
+        # cluster a node offers one slot, equal pods bid for the same
+        # best-scored nodes and a node has one winner a round, so 18 rounds
+        # place 50-80 pods of a hundred with room left for the rest.  The
+        # reference's sequential loop has no round budget: it would have
+        # reached that room in this cycle.  What a second solve leaves
+        # waits for the next cycle, as before (the loop wakes itself)
+        if self._solve_and_replay(ssn):
+            metrics.register_allocate_runs_on()
+            self._solve_and_replay(ssn)
+
+    def _solve_and_replay(self, ssn) -> bool:
+        """One dispatch, its readback and its replay; True when the solve
+        ran out of rounds while still placing and left pods unplaced."""
         self.last_fallback = {}
         self.last_host_discards = 0
         self.last_solve_rounds = 0
@@ -528,7 +543,7 @@ class AllocateAction(Action):
             republish_query_lease(
                 ssn, build=lambda: build_session_snapshot(ssn)
             )
-            return
+            return False
 
         from kube_batch_tpu.obs.trace import solve_program, tracer_of
 
@@ -548,7 +563,7 @@ class AllocateAction(Action):
             republish_query_lease(
                 ssn, build=lambda: build_session_snapshot(ssn)
             )
-            return
+            return False
         with tracer.span("snapshot_build"):
             snap, meta = build_session_snapshot(ssn)
         # multi-chip parts shard the node axis over the ICI mesh — the
@@ -571,7 +586,7 @@ class AllocateAction(Action):
             # a demotion whose target cannot hold the cluster: no program
             # ran, nothing below runs (no replay, no binds, no fit errors)
             gp.fail_closed("allocate", str(e))
-            return
+            return False
         tracer.note_solve_dispatch(
             sp_solve, "allocate", self.last_solve_mode, ginfo["engaged"],
             program=solve_program(
@@ -633,7 +648,7 @@ class AllocateAction(Action):
             # below this line runs: no replay, no binds, no fit errors.
             # The guard has already demoted the engaged fast paths, healed
             # the resident cache, and dumped the diagnostics bundle.
-            return
+            return False
         task_job = np.asarray(snap.task_job)[: meta.n_tasks]
         # fit errors only for tasks of jobs that are IN this session (the
         # columnar row space also carries rows of jobs the session dropped —
@@ -701,6 +716,8 @@ class AllocateAction(Action):
                 ssn, gp, snap, config, ginfo, audit_dev, assigned, pipelined,
                 meta,
             )
+        return (unplaced and bool((assigned >= 0).any())
+                and self.last_solve_rounds >= config.rounds * config.outer)
 
     # ------------------------------------------------------------------
     # fit-error histogram (the lazy [P, N] / [T, N] predicate re-walk)

@@ -23,7 +23,7 @@ import logging
 from collections import defaultdict
 from typing import Callable, Dict, List, Tuple
 
-from kube_batch_tpu.actions.reclaim import find_task, solve_claims
+from kube_batch_tpu.actions.reclaim import ReplayTally, find_task, solve_claims
 from kube_batch_tpu.api.task_info import TaskInfo
 from kube_batch_tpu.api.types import PodGroupPhase, TaskStatus
 from kube_batch_tpu.framework.interface import Action
@@ -43,18 +43,28 @@ class PreemptAction(Action):
     # ---- phase 1: inter-job within queue (device-solved) ---------------
     def _phase1(self, ssn) -> None:
         claims, _ = solve_claims(ssn, "preempt")
+        if not claims:
+            return
+        with ReplayTally.replaying(ssn, "preempt", claims) as tally:
+            self._replay(ssn, tally, claims)
+
+    def _replay(self, ssn, tally, claims) -> None:
         # group claims by preemptor job — the Statement boundary
         by_job: Dict[str, List[Tuple[TaskInfo, str, List[tuple]]]] = defaultdict(list)
         for claimant_ref, node_name, victim_refs in claims:
             task = find_task(ssn, claimant_ref)
             if task is not None and victim_refs:
                 by_job[task.job].append((task, node_name, victim_refs))
+            else:
+                tally.host_rejected += 1
 
         for job_uid, job_claims in by_job.items():
             job = ssn.jobs.get(job_uid)
             if job is None:
+                tally.host_rejected += len(job_claims)
                 continue
             stmt = ssn.statement()
+            staged = []  # (task, victims evicted) of this Statement
             for task, node_name, victim_refs in job_claims:
                 # host predicate re-check (preempt.go:191), only for
                 # host-only constraints (see allocate replay)
@@ -65,6 +75,7 @@ class PreemptAction(Action):
                     ):
                         ssn.predicate(task, node)
                 except FitFailure:
+                    tally.host_rejected += 1
                     continue
                 preemptees = [
                     v.clone() for v in (find_task(ssn, r) for r in victim_refs)
@@ -72,28 +83,36 @@ class PreemptAction(Action):
                 ]
                 victims = ssn.preemptable(task, preemptees)
                 if not victims:
+                    tally.host_rejected += 1
                     continue
                 total = ssn.spec.empty()
                 for v in victims:
                     total.add_(v.resreq)
                 if not task.init_resreq.less_equal(total):
+                    tally.uncovered += 1
                     continue  # victims must cover every dimension
                 # evict lowest-task-order first (preempt.go:219-237)
                 vq = PriorityQueue(less=lambda l, r: not ssn.task_order_fn(l, r))
                 for v in victims:
                     vq.push(v)
                 preempted = ssn.spec.empty()
+                evicted = 0
                 while vq:
                     victim = vq.pop()
-                    stmt.evict(victim, "preempt")
+                    stmt.evict(victim, "preempt", claimant=task)
+                    evicted += 1
                     preempted.add_(victim.resreq)
                     if task.init_resreq.less_equal(preempted):
                         break
                 stmt.pipeline(task, node_name)
+                staged.append((task, evicted))
             if ssn.job_pipelined(job):
                 stmt.commit()
+                for task, evicted in staged:
+                    tally.commit(task, evicted)
             else:
                 stmt.discard()
+                tally.host_rejected += len(staged)
 
     # ---- phase 2: intra-job (host, guarded) ----------------------------
     def _phase2(self, ssn) -> None:

@@ -11,6 +11,7 @@ pipelines onto the freed resources."""
 
 from __future__ import annotations
 
+import contextlib
 import logging
 from collections import defaultdict
 from typing import Dict, List
@@ -18,6 +19,7 @@ from typing import Dict, List
 import jax
 import numpy as np
 
+from kube_batch_tpu import metrics
 from kube_batch_tpu.api.cluster_info import ClusterInfo
 from kube_batch_tpu.api.snapshot import build_snapshot
 from kube_batch_tpu.framework.interface import Action
@@ -102,9 +104,21 @@ def solve_claims(ssn, mode: str):
         and "reclaim" in names
         and names.index("allocate") > names.index("reclaim")
     )
+    # the releasing gate (PARITY "known divergences"): a claimant that fits a
+    # node's idle plus what that node's RELEASING victims have promised is
+    # left to allocate, in both modes — sound under the same conditions as
+    # the idle gate, but wherever allocate stands in the pipeline: what it
+    # cannot pipeline this cycle it binds once the victims' DELETE drained
+    releasing_gate = (
+        not ssn.conf_flag(f"{mode}.referenceExact")
+        and not ssn.host_only_predicates
+        and names is not None
+        and "allocate" in names
+    )
     config = EvictConfig(
         mode=mode,
         idle_gate=idle_gate,
+        releasing_gate=releasing_gate,
         gang=ssn.plugin_enabled("gang"),
         drf=ssn.plugin_enabled("drf"),
         proportion=ssn.plugin_enabled("proportion"),
@@ -178,10 +192,12 @@ def solve_claims(ssn, mode: str):
     # (three per-field np.asarray reads were three blocking transfers;
     # flagged by KBT010's first dogfood run); the guard sentinel's verdict
     # + histogram ride it
-    with tracer.device_span("device_wait", action=mode):
-        claim_node, evicted, victim_claimant, verdict, vhist, echeck = (
+    with tracer.device_span("device_wait", action=mode) as sp_wait:
+        (claim_node, evicted, victim_claimant, rounds_run, gated, verdict,
+         vhist, echeck) = (
             jax.device_get(  # kbt: allow[KBT010] the annotated choke point ^
                 (result.claim_node, result.evicted, result.victim_claimant,
+                 result.rounds_run, result.gated_releasing,
                  sentinel[0] if sentinel is not None else np.int32(0),
                  sentinel[1] if sentinel is not None else None,
                  sentinel[2] if sentinel is not None else np.int32(0))
@@ -190,6 +206,11 @@ def solve_claims(ssn, mode: str):
     claim_node = claim_node[: meta.n_tasks]
     evicted = evicted[: meta.n_tasks]
     victim_claimant = victim_claimant[: meta.n_tasks]
+    tracer.note_evict_solve(
+        sp_wait, mode, int(rounds_run), int(np.sum(claim_node >= 0)),
+        int(np.sum(evicted)),
+    )
+    metrics.register_evict_claims(mode, "gated_releasing", int(gated))
 
     if sentinel is not None:
         from kube_batch_tpu.api.types import TaskStatus as _TS
@@ -260,6 +281,40 @@ def solve_claims(ssn, mode: str):
     return claims, meta
 
 
+class ReplayTally:
+    """What one action's replay made of the solve's claims: the
+    ``evict_replay`` span's attributes and ``volcano_evict_claims_total``,
+    set from the same counts when :meth:`replaying` ends."""
+
+    def __init__(self, ssn, mode: str, claims: int):
+        self.ssn, self.mode, self.claims = ssn, mode, claims
+        self.committed = self.host_rejected = self.uncovered = 0
+        self.victims = 0
+
+    @classmethod
+    @contextlib.contextmanager
+    def replaying(cls, ssn, mode: str, claims: list):
+        """The ``evict_replay`` span around one action's replay."""
+        from kube_batch_tpu.obs.trace import tracer_of
+
+        tally = cls(ssn, mode, len(claims))
+        with tracer_of(ssn.cache).span("evict_replay") as span:
+            yield tally
+            tally.close(span)
+
+    def commit(self, task, n_victims: int) -> None:
+        self.committed += 1
+        self.victims += n_victims
+        self.ssn.cache.note_evict_claim(task.key(), n_victims)
+
+    def close(self, span) -> None:
+        span.set(claims=self.claims, victims=self.victims,
+                 rejected=self.host_rejected + self.uncovered)
+        for outcome in ("committed", "host_rejected", "uncovered"):
+            metrics.register_evict_claims(
+                self.mode, outcome, getattr(self, outcome))
+
+
 def find_task(ssn, ref: tuple):
     """(job_uid, task_key) → session TaskInfo, O(1)."""
     job = ssn.jobs.get(ref[0])
@@ -271,48 +326,61 @@ class ReclaimAction(Action):
 
     def execute(self, ssn) -> None:
         claims, _ = solve_claims(ssn, "reclaim")
-        for claimant_ref, node_name, victim_refs in claims:
-            task = find_task(ssn, claimant_ref)
-            if task is None or not victim_refs:
-                continue
-            # host predicate re-check (reclaim.go:124), only for constraints
-            # the device mask approximates (rich affinity / host ports /
-            # pressure gates)
-            node = ssn.nodes.get(node_name)
-            try:
-                if node is not None and (
-                    task.needs_host_predicate or ssn.host_only_predicates
-                ):
-                    ssn.predicate(task, node)
-            except FitFailure as e:
-                logger.info("reclaim claim %s→%s rejected by host predicate: %s",
-                            claimant_ref, node_name, e.reason)
-                continue
-            preemptees = [
-                v.clone() for v in (find_task(ssn, r) for r in victim_refs)
-                if v is not None
-            ]
-            # host validation net: the real tier-intersected verdict
-            # (proportion deserved, gang survival, conformance) on the
-            # device-selected set only — O(claims), not O(T × N)
-            victims = ssn.reclaimable(task, preemptees)
-            if not victims:
-                continue
-            total = ssn.spec.empty()
-            for v in victims:
-                total.add_(v.resreq)
-            # sufficiency: victims must cover the claimant in EVERY dimension
-            # (reclaim.go:150-163) — checked before any eviction happens
-            if not task.init_resreq.less_equal(total):
-                logger.info(
-                    "reclaim claim %s→%s lost victims to host validation, skipped",
-                    claimant_ref, node_name,
-                )
-                continue
-            reclaimed = ssn.spec.empty()
-            for victim in victims:  # immediate evict, no Statement
-                ssn.evict(victim, "reclaim")
-                reclaimed.add_(victim.resreq)
-                if task.init_resreq.less_equal(reclaimed):
-                    break
-            ssn.pipeline(task, node_name)
+        if not claims:
+            return
+        with ReplayTally.replaying(ssn, "reclaim", claims) as tally:
+            for claim in claims:
+                self._replay(ssn, tally, *claim)
+
+    def _replay(self, ssn, tally, claimant_ref, node_name, victim_refs):
+        task = find_task(ssn, claimant_ref)
+        if task is None or not victim_refs:
+            tally.host_rejected += 1
+            return
+        # host predicate re-check (reclaim.go:124), only for constraints
+        # the device mask approximates (rich affinity / host ports /
+        # pressure gates)
+        node = ssn.nodes.get(node_name)
+        try:
+            if node is not None and (
+                task.needs_host_predicate or ssn.host_only_predicates
+            ):
+                ssn.predicate(task, node)
+        except FitFailure as e:
+            logger.info("reclaim claim %s→%s rejected by host predicate: %s",
+                        claimant_ref, node_name, e.reason)
+            tally.host_rejected += 1
+            return
+        preemptees = [
+            v.clone() for v in (find_task(ssn, r) for r in victim_refs)
+            if v is not None
+        ]
+        # host validation net: the real tier-intersected verdict
+        # (proportion deserved, gang survival, conformance) on the
+        # device-selected set only — O(claims), not O(T × N)
+        victims = ssn.reclaimable(task, preemptees)
+        if not victims:
+            tally.host_rejected += 1
+            return
+        total = ssn.spec.empty()
+        for v in victims:
+            total.add_(v.resreq)
+        # sufficiency: victims must cover the claimant in EVERY dimension
+        # (reclaim.go:150-163) — checked before any eviction happens
+        if not task.init_resreq.less_equal(total):
+            logger.info(
+                "reclaim claim %s→%s lost victims to host validation, skipped",
+                claimant_ref, node_name,
+            )
+            tally.uncovered += 1
+            return
+        reclaimed = ssn.spec.empty()
+        evicted = 0
+        for victim in victims:  # immediate evict, no Statement
+            ssn.evict(victim, "reclaim", claimant=task)
+            evicted += 1
+            reclaimed.add_(victim.resreq)
+            if task.init_resreq.less_equal(reclaimed):
+                break
+        ssn.pipeline(task, node_name)
+        tally.commit(task, evicted)

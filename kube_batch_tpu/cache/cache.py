@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import logging
 import threading
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -210,6 +210,16 @@ class SchedulerCache:
         self.resolve_priority = resolve_priority
         self.binder = binder if binder is not None else FakeBinder()
         self.evictor = evictor if evictor is not None else FakeEvictor()
+        # the standalone deployment's eviction feed (cache/evictions.py),
+        # when the entry point installed one: GET /v1/evictions serves it
+        self.eviction_log = None
+        # observation of evictions in flight: when each was ordered and for
+        # whom (victim key → (perf_counter, claimant key), closed by the
+        # victim's DELETE), how many victims of each claimant are in flight,
+        # and the claimants that were ever given victims
+        self._evict_ordered_at: Dict[str, Tuple[float, str]] = {}
+        self._evict_in_flight: Dict[str, int] = {}
+        self._evict_claimants: Set[str] = set()
         self.status_updater = status_updater or FakeStatusUpdater()
         self.volume_binder = volume_binder or FakeVolumeBinder()
         self._lock = threading.RLock()
@@ -705,6 +715,21 @@ class SchedulerCache:
             if self._gate(self.delete_pod, pod):
                 return
             self._delete_pod_locked(pod)
+            # a real DELETE (not a status replay's or a repair's rebuild):
+            # if the pod was a victim, its order has been released
+            self._evict_claimants.discard(pod.key())
+            ordered = self._evict_ordered_at.pop(pod.key(), None)
+            if ordered is not None:
+                left = self._evict_in_flight.get(ordered[1], 0) - 1
+                if left > 0:
+                    self._evict_in_flight[ordered[1]] = left
+                else:
+                    self._evict_in_flight.pop(ordered[1], None)
+        if ordered is not None:
+            from kube_batch_tpu import metrics
+
+            metrics.observe_eviction_release_latency(
+                (telemetry.perf_counter() - ordered[0]) * 1e3)
 
     def _delete_pod_locked(self, pod: Pod, retire_placeholder: bool = True,
                            forget_resync: bool = True) -> None:
@@ -1361,8 +1386,28 @@ class SchedulerCache:
                 f for f in self._dispatch_futures if not f.done()
             ]
 
-    def evict(self, task: TaskInfo, reason: str) -> None:
-        """(cache.go:404-444)"""
+    def note_evict_claim(self, claimant_key: str, n_victims: int) -> None:
+        """A claim of ``claimant_key`` was committed this cycle (its
+        ``n_victims`` victims are evicted, it is pipelined).  A claimant
+        that was given victims in an earlier cycle already is counted on
+        ``volcano_evict_repeat_claims_total{earlier}``: ``in_flight`` while
+        a victim of the earlier claim is still to be deleted (an eviction
+        ordered again while the first was in flight), ``released`` once
+        they all went (the room they left went to another pod)."""
+        from kube_batch_tpu import metrics
+
+        with self._lock:
+            seen = claimant_key in self._evict_claimants
+            self._evict_claimants.add(claimant_key)
+            earlier = self._evict_in_flight.get(claimant_key, 0) - n_victims
+        if seen:
+            metrics.register_evict_repeat_claim(
+                "in_flight" if earlier > 0 else "released")
+
+    def evict(self, task: TaskInfo, reason: str,
+              claimant: Optional[TaskInfo] = None) -> None:
+        """(cache.go:404-444)  ``reason`` is the action that ordered it,
+        ``claimant`` the task it makes room for."""
         with self._lock:
             if not self._session_active:
                 own = self._own_task(task)
@@ -1381,6 +1426,7 @@ class SchedulerCache:
             if pod is not None:
                 self.evictor.evict(pod)
                 self.events.append(("Evict", task.key(), reason))
+                self._note_eviction(task, reason, claimant)
         except CircuitOpenError:
             logger.warning("evict of %s parked: egress breaker open",
                            task.key())
@@ -1388,6 +1434,24 @@ class SchedulerCache:
         except Exception as e:  # noqa: BLE001
             logger.error("evict of %s failed: %s", task.key(), e)
             self.resync_task(task)
+
+    def _note_eviction(self, task: TaskInfo, action: str,
+                       claimant: Optional[TaskInfo]) -> None:
+        """An eviction went out: count it, start its release clock, and
+        append it to the standalone feed if there is one."""
+        from kube_batch_tpu import metrics
+
+        metrics.register_eviction(action)
+        whose = claimant.key() if claimant is not None else ""
+        with self._lock:
+            if task.key() not in self._evict_ordered_at:
+                self._evict_ordered_at[task.key()] = (
+                    telemetry.perf_counter(), whose)
+                self._evict_in_flight[whose] = (
+                    self._evict_in_flight.get(whose, 0) + 1)
+        log = self.eviction_log
+        if log is not None:
+            log.record(task.key(), task.node_name or "", action, whose)
 
     # volume seams (cache.go:189-209; real ledger in cache/volume.py,
     # no-op fake by default)

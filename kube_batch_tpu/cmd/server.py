@@ -38,6 +38,10 @@ The reference serves Prometheus `/metrics` (+ pprof) on --listen-address
 - POST /v1/whatif/sweep        — server-side capacity sweep: binary-search
                                  the largest feasible replica count against
                                  ONE snapshot lease
+- GET  /v1/evictions?since=N   — the standalone eviction feed
+                                 (cache/evictions.py): what the scheduler
+                                 ordered evicted, for the client (the
+                                 kubelet) to terminate with DELETE /v1/pods
 - GET  /v1/replicate?since=N   — the replication stream (replicate/): the
                                  leader's KBR1 frame for record N+1, a
                                  synthesized full snapshot when N fell off
@@ -290,6 +294,10 @@ def make_handler(cache: SchedulerCache, query_plane=None):
                 "/v1/replicate?"
             ):
                 self._replicate()
+            elif self.path == "/v1/evictions" or self.path.startswith(
+                "/v1/evictions?"
+            ):
+                self._evictions()
             elif self.path == "/v1/alerts":
                 # guard trip-rate SLO alerts (obs/alerts): firing state,
                 # windowed trip counts, thresholds
@@ -308,21 +316,42 @@ def make_handler(cache: SchedulerCache, query_plane=None):
             per pull, chosen by the follower's applied cursor (heartbeat
             when caught up, a synthesized full snapshot when the cursor
             fell off the ring — the delta-gap escalation)."""
-            from urllib.parse import parse_qs, urlparse
-
             pub = getattr(cache, "replication", None)
             if pub is None:
                 self._send(503, json.dumps(
                     {"error": "replication not enabled"}))
                 return
+            since = self._since(-1)
+            if since is not None:
+                self._send_bytes(200, pub.record_for(since))
+
+        def _evictions(self):
+            """The standalone deployment's eviction feed: what the
+            scheduler ordered evicted since the client's cursor
+            (cache/evictions.py).  No cache lock: a kubelet stand-in polls
+            this every few milliseconds."""
+            log = cache.eviction_log
+            if log is None:
+                self._send(503, json.dumps({
+                    "error": "no eviction feed: with --master an eviction "
+                             "is a pod DELETE at the apiserver"}))
+                return
+            since = self._since(0)
+            if since is not None:
+                self._send(200, json.dumps(log.since(since)))
+
+        def _since(self, default: int) -> Optional[int]:
+            """The ``?since=N`` cursor of a feed; answers 400 and returns
+            None where it is no integer."""
+            from urllib.parse import parse_qs, urlparse
+
             q = parse_qs(urlparse(self.path).query)
             try:
-                since = int(q.get("since", ["-1"])[0])
+                return int(q.get("since", [str(default)])[0])
             except ValueError:
                 self._send(400, json.dumps(
                     {"error": "since must be an integer"}))
-                return
-            self._send_bytes(200, pub.record_for(since))
+                return None
 
         def _trace_dumps(self):
             """Flight-recorder dump streaming: the index lists every dump
@@ -650,13 +679,15 @@ def run(opt: ServerOption) -> None:
     logger.info("runtime: %s", json.dumps(runtime_report()))
     if opt.follower:
         return run_follower(opt)
-    from kube_batch_tpu.cache.fake import FakeBinder, FakeEvictor
+    from kube_batch_tpu.cache.evictions import EvictionLog
+    from kube_batch_tpu.cache.fake import FakeBinder
 
     from kube_batch_tpu.cache.volume import StandalonePVBinder
 
     # with a k8s front end (--master), binds/evictions write back to the
     # apiserver (pods/binding POST, pod DELETE); standalone deployments keep
-    # the recording fakes behind the ingest API
+    # the recording fake binder behind the ingest API and serve what was
+    # ordered evicted on GET /v1/evictions for the client to terminate
     k8s_mode = opt.master.startswith("http")
     # one bucket for ALL egress (binds + evictions + status writes): the
     # reference's writes share a single throttled rest.Config (server.go:69-70)
@@ -668,7 +699,7 @@ def run(opt: ServerOption) -> None:
 
         auth = in_cluster_auth()
         backend = K8sBackend(opt.master, **auth)
-        binder, evictor = backend, backend
+        binder, evictor, eviction_log = backend, backend, None
         status_updater = RateLimitedStatusUpdater(backend, bucket=bucket)
         # pv/pvc/storageclass watches feed this ledger; its claimRef /
         # selected-node PATCHes ride the backend's own transport AND the
@@ -679,7 +710,8 @@ def run(opt: ServerOption) -> None:
             bucket=bucket,
         )
     else:
-        binder, evictor = FakeBinder(), FakeEvictor()
+        eviction_log = EvictionLog()
+        binder, evictor = FakeBinder(), eviction_log
         status_updater = None  # cache default: recording fake
         # real PV ledger behind /v1/persistentvolumes
         volume_binder = StandalonePVBinder()
@@ -692,6 +724,7 @@ def run(opt: ServerOption) -> None:
         volume_binder=volume_binder,
         resolve_priority=opt.enable_priority_class,
     )
+    cache.eviction_log = eviction_log
     on_cycle_end = None
     if opt.state_file:
         from kube_batch_tpu.cache.persistence import load_state, save_state

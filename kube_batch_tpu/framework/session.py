@@ -496,8 +496,9 @@ class Session:
         if job is not None:
             job.update_task_status(task, TaskStatus.BINDING)
 
-    def evict(self, task: TaskInfo, reason: str) -> None:
-        self.cache.evict(task, reason)
+    def evict(self, task: TaskInfo, reason: str,
+              claimant: Optional[TaskInfo] = None) -> None:
+        self.cache.evict(task, reason, claimant)
         job = self.jobs.get(task.job)
         if job is not None:
             job.update_task_status(task, TaskStatus.RELEASING)
@@ -542,7 +543,8 @@ class Statement:
         self.operations: List[Tuple[str, tuple]] = []
 
     # -- session-visible verbs -------------------------------------------
-    def evict(self, reclaimee: TaskInfo, reason: str) -> None:
+    def evict(self, reclaimee: TaskInfo, reason: str,
+              claimant: Optional[TaskInfo] = None) -> None:
         job = self.ssn.jobs.get(reclaimee.job)
         if job is not None:
             job.update_task_status(reclaimee, TaskStatus.RELEASING)
@@ -550,7 +552,7 @@ class Statement:
         if node is not None:
             node.update_task(reclaimee)
         self.ssn._fire(False, reclaimee)
-        self.operations.append(("evict", (reclaimee, reason)))
+        self.operations.append(("evict", (reclaimee, reason, claimant)))
 
     def pipeline(self, task: TaskInfo, hostname: str) -> None:
         job = self.ssn.jobs.get(task.job)
@@ -597,8 +599,7 @@ class Statement:
             return
         for name, args in self.operations:
             if name == "evict":
-                task, reason = args
-                self.ssn.cache.evict(task, reason)
+                self.ssn.cache.evict(*args)
             elif name == "pipeline":
                 pass  # session-only state (statement.go pipeline no-ops on commit)
             elif name == "allocate":
@@ -613,8 +614,7 @@ class Statement:
     def discard(self) -> None:
         for name, args in reversed(self.operations):
             if name == "evict":
-                task, _ = args
-                self._unevict(task)
+                self._unevict(args[0])
             elif name == "pipeline":
                 task, _ = args
                 self._unpipeline(task)

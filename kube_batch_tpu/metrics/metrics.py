@@ -471,6 +471,11 @@ SOLVE_OVER_BUDGET = Counter(
     "carried on from what it had placed, by action",
     ("action",),
 )
+ALLOCATE_RUNS_ON = Counter(
+    f"{_SUBSYSTEM}_allocate_runs_on_total",
+    "Allocate solves that spent their whole rounds x outer budget while "
+    "still placing and were followed by a second solve in the same cycle",
+)
 # how often the compacted solve's candidate lists ran dry: the read-back
 # allocate already makes of AllocateResult.topk_exhausted / topk_reentries
 TOPK_EXHAUSTED = Counter(
@@ -494,6 +499,38 @@ GANG_DECISION_LATENCY = Histogram(
     "powers of two in them: 9-32 tasks read 16-32, 33 and more read 64+)",
     ("size_class",),
 )
+# the evict path (reclaim, preempt), end to end: what the solves' claims
+# came to on the host, what was evicted, how long a victim took to go, and
+# whether an eviction in flight was ever ordered again
+EVICTIONS = Counter(
+    f"{_SUBSYSTEM}_evictions_total",
+    "Evictions ordered (the victim went Releasing and the order went out), "
+    "by the action that ordered them",
+    ("action",),
+)
+EVICT_CLAIMS = Counter(
+    f"{_SUBSYSTEM}_evict_claims_total",
+    "Claimants of the evict solves, by action and outcome (committed: "
+    "victims evicted and the claimant pipelined | host_rejected: a host "
+    "predicate, the tiered victim verdict or preempt's Statement gate said "
+    "no | uncovered: the validated victims no longer cover it | "
+    "gated_releasing: kept out of the solve because it fits a node's idle "
+    "plus what that node's releasing victims have promised)",
+    ("action", "outcome"),
+)
+EVICTION_RELEASE_LATENCY = Histogram(
+    f"{_SUBSYSTEM}_eviction_release_latency_milliseconds",
+    "An eviction's order to the drain of its victim's DELETE in milliseconds",
+)
+EVICT_REPEAT_CLAIMS = Counter(
+    f"{_SUBSYSTEM}_evict_repeat_claims_total",
+    "Committed claims of a claimant that was given victims in an earlier "
+    "cycle already, by what had become of those (in_flight: one is still to "
+    "be deleted, so an eviction was ordered again while the first was in "
+    "flight | released: all were deleted and the room they left went to "
+    "another pod)",
+    ("earlier",),
+)
 DEVICE_PEAK_BYTES = Gauge(
     f"{_SUBSYSTEM}_device_peak_bytes",
     "peak_bytes_in_use of each local device, refreshed at most once a cycle",
@@ -510,8 +547,16 @@ for _outcome in ("published", "ingest_pending", "not_owed"):
 DECISIONS_LEFTOVER.add(0.0)
 SOLVE_ROUNDS.add(0.0, "allocate")
 SOLVE_OVER_BUDGET.add(0.0, "allocate")
+ALLOCATE_RUNS_ON.add(0.0)
 TOPK_EXHAUSTED.add(0.0, "allocate")
 TOPK_REENTRIES.add(0.0, "allocate")
+for _earlier in ("in_flight", "released"):
+    EVICT_REPEAT_CLAIMS.add(0.0, _earlier)
+for _action in ("reclaim", "preempt"):
+    EVICTIONS.add(0.0, _action)
+    for _outcome in ("committed", "host_rejected", "uncovered",
+                     "gated_releasing"):
+        EVICT_CLAIMS.add(0.0, _action, _outcome)
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
@@ -576,10 +621,15 @@ METRICS = [
     SOLVE_DISPATCHES,
     SOLVE_ROUNDS,
     SOLVE_OVER_BUDGET,
+    ALLOCATE_RUNS_ON,
     TOPK_EXHAUSTED,
     TOPK_REENTRIES,
     GANG_DECISION_LATENCY,
     DEVICE_PEAK_BYTES,
+    EVICTIONS,
+    EVICT_CLAIMS,
+    EVICTION_RELEASE_LATENCY,
+    EVICT_REPEAT_CLAIMS,
 ]
 
 
@@ -800,10 +850,31 @@ def register_solve_rounds(action: str, rounds: int, over_budget: bool) -> None:
         SOLVE_OVER_BUDGET.inc(action)
 
 
+def register_allocate_runs_on() -> None:
+    ALLOCATE_RUNS_ON.inc()
+
+
 def register_topk_fallbacks(action: str, exhausted: int,
                             reentries: int) -> None:
     TOPK_EXHAUSTED.add(exhausted, action)
     TOPK_REENTRIES.add(reentries, action)
+
+
+def register_eviction(action: str) -> None:
+    EVICTIONS.inc(action)
+
+
+def register_evict_claims(action: str, outcome: str, n: int) -> None:
+    if n:
+        EVICT_CLAIMS.add(n, action, outcome)
+
+
+def register_evict_repeat_claim(earlier: str) -> None:
+    EVICT_REPEAT_CLAIMS.inc(earlier)
+
+
+def observe_eviction_release_latency(ms: float) -> None:
+    EVICTION_RELEASE_LATENCY.observe(ms)
 
 
 def gang_size_class(size: int) -> str:

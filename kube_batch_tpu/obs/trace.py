@@ -538,6 +538,17 @@ class Tracer:
         span.set(rounds=rounds, over_budget=over_budget)
         metrics.register_solve_rounds(action, rounds, over_budget)
 
+    def note_evict_solve(self, span: Span, action: str, rounds: int,
+                         claims: int, victims: int) -> None:
+        """Say on an evict solve's ``device_wait`` span how many bidding
+        rounds it ran and what it proposed (``claims`` claimants given a
+        node, ``victims`` tasks to evict for them), and count the rounds on
+        ``/metrics`` (``volcano_solve_rounds_total{action}``) from the same
+        value.  What the host made of the claims is counted at the replay
+        (``volcano_evict_claims_total``)."""
+        span.set(rounds=rounds, claims=claims, victims=victims)
+        metrics.register_solve_rounds(action, rounds, False)
+
     def note_topk_fallbacks(self, span: Span, action: str, exhausted: int,
                             reentries: int) -> None:
         """Say on a ``device_wait`` span how often its solve's candidate

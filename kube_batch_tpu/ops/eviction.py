@@ -55,13 +55,16 @@ from kube_batch_tpu.api.snapshot import DeviceSnapshot
 from kube_batch_tpu.api.types import TaskStatus
 from kube_batch_tpu.ops import fairness, ordering
 from kube_batch_tpu.ops.assignment import _best_node, _tie_break_hash
-from kube_batch_tpu.ops.feasibility import fits, static_predicates
+from kube_batch_tpu.ops.feasibility import static_predicates
 from kube_batch_tpu.ops.ordering import segmented_prefix
 from kube_batch_tpu.ops.scoring import ScoreWeights, score_matrix
 
 NEG = jnp.float32(-3.0e38)
 BIG = jnp.int32(1 << 30)
 SHARE_DELTA = 1e-6  # drf.go:23 shareDelta
+# the gates count at most this many tasks a node (every task takes a pod
+# slot, so a node's pod capacity binds long before; keeps the sum in i32)
+NODE_SLOTS = 1 << 10
 
 
 class EvictConfig(NamedTuple):
@@ -76,10 +79,15 @@ class EvictConfig(NamedTuple):
 
     mode: str = "reclaim"     # "reclaim" (cross-queue) | "preempt" (same-queue)
     rounds: int = 8
-    # reclaim-only: skip claimants that fit free Idle (allocate places them
-    # later this cycle) — set by the action layer ONLY when allocate is
+    # reclaim-only: skip as many claimants as fit free Idle (allocate places
+    # them later this cycle) — set by the action layer ONLY when allocate is
     # actually configured after reclaim and host predicates are exact
     idle_gate: bool = False
+    # both modes: skip as many claimants as fit the nodes' Idle plus the
+    # capacity their RELEASING victims have promised (an eviction in flight
+    # is not ordered again) — set by the action layer only when allocate is
+    # in the pipeline to place them and host predicates are exact
+    releasing_gate: bool = False
     # ordering / gating (claimant side)
     gang: bool = True
     drf: bool = True
@@ -96,6 +104,10 @@ class EvictResult(NamedTuple):
     claim_node: jnp.ndarray       # [T] i32 — node the claimant pipelines onto, -1
     evicted: jnp.ndarray          # [T] bool — task chosen as victim
     victim_claimant: jnp.ndarray  # [T] i32 — claimant task index a victim serves, -1
+    rounds_run: jnp.ndarray       # [] i32 — bidding rounds the solve ran
+    # [] i32 — claimants the gates left to allocate beyond what Idle alone
+    # holds: the releasing gate's (volcano_evict_claims_total)
+    gated_releasing: jnp.ndarray
 
 
 # ---- the victim machinery shared by every eviction path ------------------
@@ -173,53 +185,57 @@ def pick_victims(snap: DeviceSnapshot, vmask, node_req, node_has_claim,
     Q = snap.queue_weight.shape[0]
     vn = jnp.clip(snap.task_node, 0, N - 1)
 
-    seg = jnp.where(vmask, snap.task_node, N)
-    order = ordering.sort_by_segment_then_rank(seg, victim_rank, N + 1)
-    seg_s = seg[order]
-    req_s = jnp.where(vmask[order, None], snap.task_resreq[order], 0.0)
-    is_start = jnp.concatenate([jnp.array([True]), seg_s[1:] != seg_s[:-1]])
-    prefix = segmented_prefix(req_s, is_start)                   # exclusive
-    need_s = node_req[jnp.clip(seg_s, 0, N - 1)]
-    covered_before = jnp.all(prefix >= need_s - snap.quanta, axis=-1)
-    take_s = vmask[order] & (seg_s < N) & ~covered_before
-    take = jnp.zeros(T, bool).at[order].set(take_s)
+    # named for trace_reduce.py's programs: the selection scan, then the caps
+    with jax.named_scope("evict_pick"):
+        seg = jnp.where(vmask, snap.task_node, N)
+        order = ordering.sort_by_segment_then_rank(seg, victim_rank, N + 1)
+        seg_s = seg[order]
+        req_s = jnp.where(vmask[order, None], snap.task_resreq[order], 0.0)
+        is_start = jnp.concatenate(
+            [jnp.array([True]), seg_s[1:] != seg_s[:-1]])
+        prefix = segmented_prefix(req_s, is_start)               # exclusive
+        need_s = node_req[jnp.clip(seg_s, 0, N - 1)]
+        covered_before = jnp.all(prefix >= need_s - snap.quanta, axis=-1)
+        take_s = vmask[order] & (seg_s < N) & ~covered_before
+        take = jnp.zeros(T, bool).at[order].set(take_s)
 
-    if config.victim_gang:
-        # position among taken victims of the same job < remaining slack
-        jorder = ordering.sort_by_segment_then_rank(
-            jnp.where(take, snap.task_job, J), victim_rank, J + 1
-        )
-        js = jnp.where(take, snap.task_job, J)[jorder]
-        j_start = jnp.concatenate([jnp.array([True]), js[1:] != js[:-1]])
-        pos = segmented_prefix(
-            take[jorder].astype(jnp.float32)[:, None], j_start
-        )[:, 0].astype(jnp.int32)
-        keep_j = take[jorder] & (pos < slack_rem[jnp.clip(js, 0, J - 1)])
-        take = jnp.zeros(T, bool).at[jorder].set(keep_j)
-    if qbudget_rem is not None:
-        # cumulative eviction per victim queue ≤ remaining budget
-        qorder = ordering.sort_by_segment_then_rank(
-            jnp.where(take, task_queue, Q), victim_rank, Q + 1
-        )
-        qs = jnp.where(take, task_queue, Q)[qorder]
-        q_start = jnp.concatenate([jnp.array([True]), qs[1:] != qs[:-1]])
-        qreq_s = jnp.where(take[qorder, None], snap.task_resreq[qorder], 0.0)
-        qprefix = segmented_prefix(qreq_s, q_start)
-        fits_budget = jnp.all(
-            qprefix + qreq_s
-            <= qbudget_rem[jnp.clip(qs, 0, Q - 1)] + snap.quanta,
-            axis=-1,
-        )
-        take = jnp.zeros(T, bool).at[qorder].set(take[qorder] & fits_budget)
+    with jax.named_scope("evict_cap"):
+        if config.victim_gang:
+            # position among taken victims of the same job < remaining slack
+            jorder = ordering.sort_by_segment_then_rank(
+                jnp.where(take, snap.task_job, J), victim_rank, J + 1
+            )
+            js = jnp.where(take, snap.task_job, J)[jorder]
+            j_start = jnp.concatenate([jnp.array([True]), js[1:] != js[:-1]])
+            pos = segmented_prefix(
+                take[jorder].astype(jnp.float32)[:, None], j_start
+            )[:, 0].astype(jnp.int32)
+            keep_j = take[jorder] & (pos < slack_rem[jnp.clip(js, 0, J - 1)])
+            take = jnp.zeros(T, bool).at[jorder].set(keep_j)
+        if qbudget_rem is not None:
+            # cumulative eviction per victim queue ≤ remaining budget
+            qorder = ordering.sort_by_segment_then_rank(
+                jnp.where(take, task_queue, Q), victim_rank, Q + 1
+            )
+            qs = jnp.where(take, task_queue, Q)[qorder]
+            q_start = jnp.concatenate([jnp.array([True]), qs[1:] != qs[:-1]])
+            qreq_s = jnp.where(take[qorder, None], snap.task_resreq[qorder], 0.0)
+            qprefix = segmented_prefix(qreq_s, q_start)
+            fits_budget = jnp.all(
+                qprefix + qreq_s
+                <= qbudget_rem[jnp.clip(qs, 0, Q - 1)] + snap.quanta,
+                axis=-1,
+            )
+            take = jnp.zeros(T, bool).at[qorder].set(take[qorder] & fits_budget)
 
-    # coverage recheck after caps; cancel uncovered claims
-    got = jax.ops.segment_sum(
-        jnp.where(take[:, None], snap.task_resreq, 0.0),
-        jnp.where(take, snap.task_node, N),
-        num_segments=N + 1,
-    )[:N]
-    covered = node_has_claim & jnp.all(got >= node_req - snap.quanta, axis=-1)
-    return take & covered[vn], covered
+        # coverage recheck after caps; cancel uncovered claims
+        got = jax.ops.segment_sum(
+            jnp.where(take[:, None], snap.task_resreq, 0.0),
+            jnp.where(take, snap.task_node, N),
+            num_segments=N + 1,
+        )[:N]
+        covered = node_has_claim & jnp.all(got >= node_req - snap.quanta, axis=-1)
+        return take & covered[vn], covered
 
 
 def local_evict_bids(snap: DeviceSnapshot, config: EvictConfig):
@@ -291,22 +307,68 @@ def local_evict_bids(snap: DeviceSnapshot, config: EvictConfig):
     return bids
 
 
-def local_idle_fit_any(snap: DeviceSnapshot):
-    """[T] bool — task fits some schedulable node's cycle-start Idle (the
-    reclaim idle gate's [T, N] probe; the shard_map path computes it
-    blockwise with a psum over the node shards)."""
-    return jnp.any(
-        fits(snap.task_req, snap.node_idle, snap.quanta)
-        & static_predicates(snap),
-        axis=1,
-    )
+def gate_room_local(task_req, static_ok, snap: DeviceSnapshot,
+                    config: EvictConfig):
+    """[T', 2] i32 — how many tasks like this one ``snap``'s node block holds
+    without a further eviction (column 1), and how many of those its Idle
+    alone holds where the idle gate is on (column 0; what the releasing
+    gate adds is the difference): the claimant gates' [T, N] probe, shared
+    by the single program and the shard_map body (which psums it over the
+    node shards).  A node counts by its cycle-start Idle (the idle gate,
+    reclaim only), and a node with RELEASING capacity by its Idle plus that
+    capacity (the releasing gate, both modes): the reference's
+    ``FutureIdle`` (node_info.go: idle + releasing - pipelined; the
+    snapshot's ``node_releasing`` is already net of what is pipelined
+    there, api/node_info.py).  With the releasing gate alone only the
+    nodes that have something releasing count.  One pass whichever gates
+    are on."""
+    idle_gate = config.idle_gate and config.mode != "preempt"
+    promised = jnp.any(snap.node_releasing > 0.0, axis=-1)           # [N]
+
+    def held(budget, nodes=None):
+        # [T] how many such tasks the nodes' budgets hold (a node that
+        # holds one passes ``fits``; ``nodes`` [N] bool: those that count);
+        # dimensions the task does not ask for do not bind
+        need = task_req[:, None, :]                                  # [T, 1, R]
+        per_dim = jnp.where(
+            need > 0.0,
+            jnp.floor((budget[None] + snap.quanta) / jnp.maximum(need, 1e-9)),
+            jnp.float32(NODE_SLOTS),
+        )
+        slots = jnp.clip(
+            jnp.min(per_dim, axis=-1), 0, NODE_SLOTS).astype(jnp.int32)
+        ok = static_ok if nodes is None else static_ok & nodes[None, :]
+        return jnp.sum(jnp.where(ok, slots, 0), axis=1)
+
+    def count():
+        by_idle = (held(snap.node_idle) if idle_gate
+                   else jnp.zeros(task_req.shape[0], jnp.int32))
+        if not config.releasing_gate:
+            return jnp.stack([by_idle, by_idle], axis=1)
+        budget = snap.node_idle + jnp.where(
+            promised[:, None], snap.node_releasing, 0.0)
+        # with the releasing gate alone only the promised nodes count
+        return jnp.stack(
+            [by_idle, held(budget, None if idle_gate else promised)], axis=1)
+
+    if idle_gate:
+        return count()
+    # the releasing gate alone (preempt): nothing releasing, nothing to count
+    return jax.lax.cond(
+        jnp.any(promised), count,
+        lambda: jnp.zeros((task_req.shape[0], 2), jnp.int32))
+
+
+def gates_on(config: EvictConfig) -> bool:
+    return (config.idle_gate and config.mode != "preempt") \
+        or config.releasing_gate
 
 
 def evict_rounds(
     snap: DeviceSnapshot,
     config: EvictConfig,
     bids_fn,
-    fits_idle_any=None,
+    gate_room=None,
     n_nodes=None,
     claimant_mask=None,
 ) -> EvictResult:
@@ -314,8 +376,9 @@ def evict_rounds(
     eligibility, ranks, winner-per-node selection, victim picking, global
     caps, coverage, and the commit gate — everything that reads only the
     task/job/queue-axis vectors (replicated under shard_map).  The [T, N]-
-    scale bids come from ``bids_fn``; ``fits_idle_any`` is the idle-gate
-    probe (required iff ``config.idle_gate`` on reclaim).  ``n_nodes``
+    scale bids come from ``bids_fn``; ``gate_room`` ([T]) is the
+    claimant gates' probe, ``[T, 2]`` (:func:`gate_room_local` summed over
+    the node shards; required iff :func:`gates_on`).  ``n_nodes``
     overrides the GLOBAL node count when ``snap``'s node arrays are
     shard-local blocks (the shard_map body).  ``claimant_mask`` ([T] bool)
     restricts claimants beyond the standard eligibility — callers probing
@@ -348,18 +411,45 @@ def evict_rounds(
     )
     if claimant_mask is not None:
         claimant_base &= claimant_mask
-    if config.idle_gate and not preempt:
+    if gates_on(config):
         # IMPROVEMENT over reclaim.go (which never looks at Idle and will
         # evict cross-queue victims for a task free capacity could satisfy):
         # a claimant that fits some schedulable node's cycle-start Idle is
         # left to the allocate action — eviction is for capacity that must
-        # be TAKEN, not capacity that's already free.  The action layer
-        # enables this only when allocate really runs after reclaim;
-        # claimants with host-only constraints are exempt (their device fit
-        # is approximate — allocate's host re-check might reject the node
-        # and strand them).  Preempt never gates: it runs after allocate,
-        # so its claimants already failed idle placement this cycle.
-        claimant_base &= ~(fits_idle_any & ~snap.task_needs_host)
+        # be TAKEN, not capacity that's already free (the idle gate; the
+        # action layer enables it only when allocate really runs after
+        # reclaim.  Preempt runs after allocate, so its claimants already
+        # failed idle placement this cycle).  The releasing gate, in both
+        # modes: an eviction in flight is not ordered twice (PARITY "known
+        # divergences").  A node's RELEASING victims have promised their
+        # capacity; a claimant that fits that node's Idle plus the promise
+        # already has its room on the way — the allocate that follows
+        # pipelines it, or binds it once the victims' DELETE has drained.
+        # reclaim.go / preempt.go look at neither and evict again every
+        # cycle until the kubelet is done.
+        #
+        # Both gates COUNT: the room that needs no further eviction holds
+        # ``gate_room[t]`` tasks like t, so of the claimants with room
+        # anywhere only the first ``gate_room[t]``, the least roomy first,
+        # are left to allocate.  An existential gate ("fits somewhere")
+        # lets two free slots keep a hundred claimants away from the
+        # victims they need, for ever where they are gangs that allocate
+        # cannot complete on two slots.  Claimants with host-only
+        # constraints are exempt (their device fit is approximate —
+        # allocate's host re-check might reject the node and strand them).
+        room = gate_room[:, 1]
+        cand = claimant_base & (room > 0) & ~snap.task_needs_host
+        # most constrained first: a node with room for a large claimant
+        # has room for a smaller one, so the candidates with the least room
+        # are counted against it before those that could go elsewhere
+        ahead = ordering.multisort_ranks(
+            [jnp.where(cand, room, BIG), subrank])
+        gated = cand & (ahead < room)
+        claimant_base &= ~gated
+        gated_releasing = jnp.sum(
+            gated & (ahead >= gate_room[:, 0])).astype(jnp.int32)
+    else:
+        gated_releasing = jnp.int32(0)
 
     def round_body(state):
         claim_node, evicted, victim_claimant, i, _ = state
@@ -441,7 +531,8 @@ def evict_rounds(
         )
 
         # ---- victim-capacity bids ([T, N]-scale, path-specific head) -
-        best, has = bids_fn(victim_ok, claimant_ok)
+        with jax.named_scope("evict_bid"):
+            best, has = bids_fn(victim_ok, claimant_ok)
         has &= claimant_ok
 
         # ---- one winner per node: lowest claimant rank ---------------
@@ -497,7 +588,7 @@ def evict_rounds(
         *_, i, progress = state
         return (i < config.rounds) & progress
 
-    claim_node, evicted, victim_claimant, _, _ = jax.lax.while_loop(
+    claim_node, evicted, victim_claimant, rounds_run, _ = jax.lax.while_loop(
         round_cond,
         round_body,
         (
@@ -526,13 +617,16 @@ def evict_rounds(
         victim_claimant = jnp.where(victim_revert, -1, victim_claimant)
 
     return EvictResult(
-        claim_node=claim_node, evicted=evicted, victim_claimant=victim_claimant
+        claim_node=claim_node, evicted=evicted,
+        victim_claimant=victim_claimant, rounds_run=rounds_run,
+        gated_releasing=gated_releasing,
     )
 
 
 @partial(jax.jit, static_argnames=("config",))
 def evict_solve(snap: DeviceSnapshot, config: EvictConfig) -> EvictResult:
-    fia = None
-    if config.idle_gate and config.mode != "preempt":
-        fia = local_idle_fit_any(snap)
-    return evict_rounds(snap, config, local_evict_bids(snap, config), fia)
+    room = None
+    if gates_on(config):
+        room = gate_room_local(
+            snap.task_req, static_predicates(snap), snap, config)
+    return evict_rounds(snap, config, local_evict_bids(snap, config), room)

@@ -422,7 +422,8 @@ def evict_solve_fn(mesh: Mesh, config: EvictConfig,
             in_shardings = snapshot_shardings(mesh)
             repl = NamedSharding(mesh, P())
             out_shardings = EvictResult(
-                claim_node=repl, evicted=repl, victim_claimant=repl
+                claim_node=repl, evicted=repl, victim_claimant=repl,
+                rounds_run=repl, gated_releasing=repl,
             )
             fn = jax.jit(
                 partial(_evict, config=config),

@@ -608,16 +608,13 @@ def _evict_body(snap, *, config, node_shards, task_shards):
         has = _gather_tasks(vmax > NEG, task_shards)
         return best, has
 
-    fia = None
-    if config.idle_gate and not preempt:
-        any_l = jnp.any(
-            fits(view.task_req, snap.node_idle, snap.quanta) & static_ok,
-            axis=1,
-        )
+    room = None
+    if evi.gates_on(config):
+        room_l = evi.gate_room_local(view.task_req, static_ok, snap, config)
         with jax.named_scope("xchip_any_bid"):
-            any_g = jax.lax.psum(any_l.astype(jnp.int32), NODE_AXIS) > 0
-        fia = _gather_tasks(any_g, task_shards)
-    return evi.evict_rounds(snap, config, bids, fia, n_nodes=N)
+            room_g = jax.lax.psum(room_l, NODE_AXIS)
+        room = _gather_tasks(room_g, task_shards)
+    return evi.evict_rounds(snap, config, bids, room, n_nodes=N)
 
 
 # --------------------------------------------------------------------------
@@ -715,7 +712,8 @@ def evict_shard_map(mesh, config):
 
     task_shards, node_shards = _axis_sizes(mesh)
     out_specs = EvictResult(
-        claim_node=P(), evicted=P(), victim_claimant=P()
+        claim_node=P(), evicted=P(), victim_claimant=P(), rounds_run=P(),
+        gated_releasing=P(),
     )
     body = partial(_evict_body, config=config,
                    node_shards=node_shards, task_shards=task_shards)

@@ -1161,3 +1161,64 @@ tiers:
         run_actions(cache, conf_text=conf_off,
                     action_names=["allocate", "backfill"])
         assert not cache.binder.binds  # s-0 stranded until next cycle
+
+
+class TestAllocateRunsOn:
+    """A solve that spent its whole rounds x outer budget while still
+    placing runs on once in the same cycle.  A packed cluster: every node
+    has room for ONE of the pending pods and no two nodes score the same,
+    so equal pods all bid for the one best-scored node and a round places
+    one pod; 18 rounds cannot place more than 18 pods of the 32, with room
+    for all of them free, and the reference's sequential loop, which has no
+    round budget, would have reached that room in this cycle."""
+
+    N = 32
+
+    def _cache(self):
+        nodes, pods, groups = [], [], []
+        for i in range(self.N):
+            nodes.append(build_node(f"n{i}", cpu=8000, mem=64 * GiB))
+            # distinct scores: a filler of 100 m more on each node
+            pods.append(build_pod(
+                "c1", f"fill-{i}", f"n{i}", PodPhase.RUNNING,
+                {"cpu": 1100 + 100 * i, "memory": GiB}, group_name="fill"))
+        groups.append(PodGroup(name="fill", namespace="c1", min_member=1,
+                               queue="default", creation_index=0))
+        for g in range(8):
+            groups.append(PodGroup(name=f"g{g}", namespace="c1",
+                                   min_member=4, queue="default",
+                                   creation_index=1 + g))
+            for i in range(4):
+                pods.append(build_pod(
+                    "c1", f"g{g}-{i}", None, PodPhase.PENDING,
+                    {"cpu": 3500, "memory": GiB}, group_name=f"g{g}"))
+        return build_cache(queues=["default"], pod_groups=groups,
+                           nodes=nodes, pods=pods)
+
+    @staticmethod
+    def _allocate_dispatches() -> float:
+        from kube_batch_tpu.metrics import metrics as m
+
+        return sum(v for k, v in m.SOLVE_DISPATCHES._values.items()
+                   if k[0] == "allocate")
+
+    def test_a_solve_out_of_rounds_runs_on_once(self):
+        cache = self._cache()
+        before = self._allocate_dispatches()
+        run_actions(cache, action_names=["allocate"])
+        assert self._allocate_dispatches() - before == 2
+        from kube_batch_tpu.metrics import metrics as m
+
+        assert m.ALLOCATE_RUNS_ON._values[()] >= 1
+        # whole gangs only, one pod a node, and more than 18 rounds place
+        binds = cache.binder.binds
+        assert 18 < len(binds) <= 32 and len(binds) % 4 == 0
+        assert len(set(binds.values())) == len(binds)
+        errs = cache.columns.check_consistency(cache)
+        assert not errs, errs[:3]
+
+    def test_a_solve_inside_its_budget_is_not_repeated(self):
+        cache = TestRealRequestBackfill()._cache()
+        before = self._allocate_dispatches()
+        run_actions(cache, action_names=["allocate"])
+        assert self._allocate_dispatches() - before == 1
