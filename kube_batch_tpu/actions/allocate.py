@@ -170,31 +170,41 @@ def topk_bucket_for(capT: int):
     return fit[-1] if fit else None
 
 
+def plan_pend_bucket(snap):
+    """The pending-axis compaction every bidding program shares (allocate's
+    top-K and warm solves, the evict solves): ``(pend_rows, pending,
+    bucket)`` — the pending task rows of the host-backed ``snap`` in
+    ascending order, padded with -1 to the ONE bucket its task axis
+    compacts into (:func:`topk_bucket_for`), or None where the full-axis
+    program should run: a task bucket too small to carry a compaction
+    rung (``bucket`` None), no pending row, or a pending set past the
+    bucket (the cold-start regime — the full program IS the right shape
+    there).  The bucket is a pure function of the task-capacity shape, so
+    a compacted program's shapes can only change when the cache's own
+    shape buckets do — zero steady-state retraces by construction."""
+    bucket = topk_bucket_for(int(snap.task_req.shape[0]))
+    rows = np.flatnonzero(np.asarray(snap.task_pending))
+    if bucket is None or rows.size == 0 or rows.size > bucket:
+        return None, int(rows.size), bucket
+    pend_rows = np.full(bucket, -1, np.int32)
+    pend_rows[: rows.size] = rows.astype(np.int32)
+    return pend_rows, int(rows.size), bucket
+
+
 def plan_topk_bucket(snap, cols, k: int):
     """The dispatch's compaction plan: (pend_rows [P] np.int32, K) or
     (None, 0) when the full-matrix program should run.
 
-    Compaction is declined when it cannot win: no pending rows (idle
-    cycles are skipped upstream anyway), K no smaller than the node
-    bucket, a task bucket too small to carry a compaction rung, or a
-    pending set past the bucket (the cold-start regime — the full
-    program IS the right shape there).  The bucket itself is a pure
-    function of the task-capacity shape (:func:`topk_bucket_for`), so
-    the compacted program's shapes can only change when the cache's own
-    shape buckets do — zero steady-state retraces by construction."""
+    Compaction is declined when it cannot win: K no smaller than the node
+    bucket, or no bucket for the pending set (:func:`plan_pend_bucket`:
+    idle cycles are skipped upstream anyway)."""
     del cols  # the bucket is shape-derived; no per-cache state
-    capT = int(snap.task_req.shape[0])
     capN = int(snap.node_idle.shape[0])
     if k <= 0 or k >= capN:
         return None, 0
-    bucket = topk_bucket_for(capT)
-    if bucket is None:
+    pend_rows, _, _ = plan_pend_bucket(snap)
+    if pend_rows is None:
         return None, 0
-    rows = np.flatnonzero(np.asarray(snap.task_pending))
-    if rows.size == 0 or rows.size > bucket:
-        return None, 0
-    pend_rows = np.full(bucket, -1, np.int32)
-    pend_rows[: rows.size] = rows.astype(np.int32)
     return pend_rows, k
 
 

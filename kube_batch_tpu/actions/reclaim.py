@@ -128,6 +128,10 @@ def solve_claims(ssn, mode: str):
         victim_drf="drf" in gates,
         weights=ssn.score_weights,
     )
+    from kube_batch_tpu.actions.allocate import (
+        plan_pend_bucket,
+        republish_query_lease,
+    )
     from kube_batch_tpu.api.columns import resident_snap
     from kube_batch_tpu.guard import guard_of
     from kube_batch_tpu.obs.trace import tracer_of
@@ -144,11 +148,16 @@ def solve_claims(ssn, mode: str):
     audit_dev = None
     engaged: List[str] = []
     mesh = None
+    # the claimant axis of the bids: the pending bucket wherever the pending
+    # set fits the one bucket the task axis' shape gives (allocate's rule,
+    # shared), else the whole task axis — what the input shows, no knob
+    pend_rows, claimants, bucket = plan_pend_bucket(snap)
     # device-resident feature cache (see allocate's dispatch): the decode
     # below keeps reading the ORIGINAL host-backed snap
     with tracer.device_span("solve_dispatch", cols=cols, action=mode) as sp:
         if should_shard(snap.node_alloc.shape[0]):
             mesh = default_mesh()
+            pend_rows = None  # the sharded bodies bid on the task axis
             from kube_batch_tpu.parallel.mesh import _impl as _resolve_impl
 
             # demotion-aware path selection: a tripped shard_map path runs
@@ -174,19 +183,18 @@ def solve_claims(ssn, mode: str):
             if gp.enabled:
                 from kube_batch_tpu.ops.invariants import evict_sentinel_solve
 
-                result, v_dev, h_dev, e_dev = evict_sentinel_solve(dev, config)
+                result, v_dev, h_dev, e_dev = evict_sentinel_solve(
+                    dev, config, pend_rows)
                 sentinel = (v_dev, h_dev, e_dev)
             else:
-                result = evict_solve(dev, config)
-    tracer.note_solve_dispatch(
+                result = evict_solve(dev, config, pend_rows)
+    tracer.note_evict_dispatch(
         sp, mode, "sharded" if mesh is not None else "single", engaged,
-        program="evict",
+        compact=pend_rows is not None, claimants=claimants, bucket=bucket,
     )
     # this swap retired the what-if lease on donating backends — re-arm it
     # off the same (memoized) resident snapshot so serving doesn't stay
     # dark until the next cycle's allocate
-    from kube_batch_tpu.actions.allocate import republish_query_lease
-
     republish_query_lease(ssn, snap, meta)
     # kbt: allow[KBT010] the evict pass's ONE sanctioned readback — batched
     # (three per-field np.asarray reads were three blocking transfers;
@@ -229,7 +237,7 @@ def solve_claims(ssn, mode: str):
         )
         if not consume_sentinel(
             gp, mode, ssn, snap, dev, config, int(verdict), vhist,
-            int(echeck), engaged, host_bad=host_bad,
+            int(echeck), engaged, host_bad=host_bad, pend_rows=pend_rows,
         ):
             # condemned solve → fail closed: NO evictions from it
             return [], None

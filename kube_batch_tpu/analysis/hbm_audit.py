@@ -38,10 +38,11 @@ with mandatory reasons — see HBM_ALLOWLIST; stale entries fail the audit):
   profile name) at a declared shape point.
 - **KBT202 full-matrix temporary** — a program declared steady-path
   (EntryPoint.steady) materializes a task-axis × node-axis plane.  This
-  is the rule that permanently pins ROADMAP 1.(1) (evict full-matrix
-  bids) and 1.(2) (shard_map exhaustion fallback): those corners live in
-  the allowlist with ROADMAP cross-references until fixed — the
-  allowlist IS the burn-down list.
+  is the rule that permanently pins ROADMAP 1.(1) (evict's bid planes:
+  [P, N] on the pending bucket, [T, N] in the full-axis fallback) and
+  1.(2) (shard_map exhaustion fallback): those corners live in the
+  allowlist with ROADMAP cross-references until fixed — the allowlist IS
+  the burn-down list.
 - **KBT203 unrealized donation** — the registry declares a donated
   argument but no output of the traced jaxpr can alias it (shape+dtype
   match): the savings the budget model credits would not materialize,
@@ -656,27 +657,34 @@ def audit_entry_at(entry: EntryPoint, sp: ShapePoint,
 #: ROADMAP sub-item that deletes it.  Stale entries (nothing matched) fail
 #: the audit, so a fix can't leave its waiver behind.
 HBM_ALLOWLIST: Dict[Tuple[str, str, str], str] = {
-    # -- ROADMAP 1.(1): evict still scores full-matrix [T, N] bid planes --
-    # (single-device, sentinel-fused, and both sharded impls inherit them;
-    # the sharded bodies hold [T, N/shards] per device — same verdict)
+    # -- ROADMAP 1.(1): the evict bids are [claimant, node] planes --------
+    # (PR 36: the single-device programs bid on the pending bucket, [P, N],
+    # wherever the pending set fits it — the ``[*,compact]`` entries — and
+    # on [T, N] in the full-axis fallback; the sharded bodies keep the task
+    # axis and hold [T, N/shards] per device.  One glob covers a program's
+    # both shapes: same rule, same burn-down item)
     ("ops.eviction.evict_solve[*]", "KBT202", "*"):
-        "ROADMAP 1.(1): eviction scores full [T, N] bid planes; the "
-        "candidate-table + warm-carry rebuild over per-(queue, node) "
-        "capacity keys is the planned fix",
+        "ROADMAP 1.(1): eviction scores [P, N] bid planes on the pending "
+        "bucket (as allocate's table build does, 1.(2)) and full [T, N] "
+        "planes in the full-axis fallback (a pending set past the bucket, "
+        "a task axis too small to have one); the candidate-table + "
+        "warm-carry rebuild over per-(queue, node) capacity keys is the "
+        "planned fix",
     ("ops.eviction.evict_solve[*]", "KBT201", "northstar-1m"):
-        "ROADMAP 1.(1): the full-matrix bid planes blow the v5e budget at "
-        "1M\u00d7100k; evict is gated to \u2264headline scale until sparse "
-        "eviction lands",
+        "ROADMAP 1.(1): the bid planes blow the v5e budget at "
+        "1M\u00d7100k, [P, N] at P=65536 (~26 GiB a plane) as well as the "
+        "fallback's [T, N]; evict is gated to \u2264headline scale until "
+        "sparse eviction lands",
     ("ops.invariants.evict_sentinel_solve[*]", "KBT202", "*"):
         "ROADMAP 1.(1): sentinel-fused evict inherits the bare solve's "
-        "full-matrix bid planes",
+        "bid planes, [P, N] on the bucket and [T, N] in the fallback",
     ("ops.invariants.evict_sentinel_solve[*]", "KBT201", "northstar-1m"):
         "ROADMAP 1.(1): sentinel-fused evict inherits the bare solve's "
-        "over-budget planes at 1M\u00d7100k",
+        "over-budget planes at 1M\u00d7100k, both shapes",
     ("parallel.mesh.*sharded_evict_solve[*]", "KBT202", "*"):
         "ROADMAP 1.(1): sharded evict (both impls, sentinel-fused "
-        "included) shards the bid planes over nodes but still holds "
-        "[T, N/shards] per device",
+        "included) bids on the whole task axis: it shards the bid planes "
+        "over nodes but still holds [T, N/shards] per device",
     ("parallel.mesh.*sharded_evict_solve[*]", "KBT201", "northstar-1m"):
         "ROADMAP 1.(1): [T, N/8] per device is ~200 GiB at 1M\u00d7100k "
         "\u2014 sharding alone cannot absorb a full-matrix plane",
@@ -745,10 +753,12 @@ HBM_ALLOWLIST: Dict[Tuple[str, str, str], str] = {
         "histogram runs as sharded_failure_histogram there",
     ("ops.eviction.evict_solve[*]", "KBT201", "envelope-150k"):
         "ROADMAP R2: this deployment lives on 4 chips; reclaim/preempt "
-        "run as sharded_evict_solve there",
+        "run as sharded_evict_solve there (this covers the full-axis "
+        "fallback alone: on the pending bucket the program fits one chip)",
     ("ops.invariants.evict_sentinel_solve[*]", "KBT201", "envelope-150k"):
         "ROADMAP R2: this deployment lives on 4 chips; sentinel-fused "
-        "evict, same verdict as the bare program",
+        "evict, same verdict as the bare program (the full-axis fallback "
+        "alone)",
     # -- cold oracles + diagnostics: not steady-path (no KBT202 claim),
     #    but their full-matrix peaks are on the same ROADMAP 1 burn-down --
     ("ops.assignment.allocate_solve", "KBT201", "northstar-1m"):

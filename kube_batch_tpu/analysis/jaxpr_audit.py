@@ -325,18 +325,12 @@ def _build_topk_probe(sp: Optional[ShapePoint] = None):
     )
 
 
-def _build_evict_reclaim(sp: Optional[ShapePoint] = None):
+def _build_evict(mode, compact, sp: Optional[ShapePoint] = None):
     from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
 
     ax = sp or _AUDIT_POINT
-    return evict_solve, (_snap(ax), EvictConfig(mode="reclaim"))
-
-
-def _build_evict_preempt(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
-
-    ax = sp or _AUDIT_POINT
-    return evict_solve, (_snap(ax), EvictConfig(mode="preempt"))
+    rows = (_abstract_pend_rows(ax.P),) if compact else ()
+    return evict_solve, (_snap(ax), EvictConfig(mode=mode)) + rows
 
 
 def _build_resident_scatter(sp: Optional[ShapePoint] = None):
@@ -433,13 +427,13 @@ def _build_sentinel_topk(sp: Optional[ShapePoint] = None):
     )
 
 
-def _build_sentinel_evict(mode, sp: Optional[ShapePoint] = None):
+def _build_sentinel_evict(mode, compact, sp: Optional[ShapePoint] = None):
     from kube_batch_tpu.ops.eviction import EvictConfig
     from kube_batch_tpu.ops.invariants import evict_sentinel_solve
 
     ax = sp or _AUDIT_POINT
-    return evict_sentinel_solve, (
-        _snap(ax), EvictConfig(mode=mode))
+    rows = (_abstract_pend_rows(ax.P),) if compact else ()
+    return evict_sentinel_solve, (_snap(ax), EvictConfig(mode=mode)) + rows
 
 
 def _build_sentinel_gate(sp: Optional[ShapePoint] = None):
@@ -468,10 +462,20 @@ REGISTRY: Tuple[EntryPoint, ...] = (
     EntryPoint("ops.assignment.failure_histogram_bucket_solve",
                _build_bucket_histogram),
     # eviction runs inside production cycles — steady, so KBT202 pins the
-    # known full-matrix bid planes (ROADMAP 1.(1)) via the allowlist
-    EntryPoint("ops.eviction.evict_solve[reclaim]", _build_evict_reclaim,
+    # known bid planes (ROADMAP 1.(1)) via the allowlist: [P, N] on the
+    # pending bucket (what actions/reclaim.py dispatches wherever the
+    # pending set fits it), [T, N] in the full-axis fallback
+    EntryPoint("ops.eviction.evict_solve[reclaim]",
+               lambda sp=None: _build_evict("reclaim", False, sp),
                steady=True),
-    EntryPoint("ops.eviction.evict_solve[preempt]", _build_evict_preempt,
+    EntryPoint("ops.eviction.evict_solve[preempt]",
+               lambda sp=None: _build_evict("preempt", False, sp),
+               steady=True),
+    EntryPoint("ops.eviction.evict_solve[reclaim,compact]",
+               lambda sp=None: _build_evict("reclaim", True, sp),
+               steady=True),
+    EntryPoint("ops.eviction.evict_solve[preempt,compact]",
+               lambda sp=None: _build_evict("preempt", True, sp),
                steady=True),
     EntryPoint("api.resident.scatter", _build_resident_scatter,
                donate=_scatter_donation(), steady=True),
@@ -487,10 +491,16 @@ REGISTRY: Tuple[EntryPoint, ...] = (
     EntryPoint("ops.invariants.warm_allocate_sentinel_solve",
                _build_warm_sentinel, donate=_warm_donation(), steady=True),
     EntryPoint("ops.invariants.evict_sentinel_solve[reclaim]",
-               lambda sp=None: _build_sentinel_evict("reclaim", sp),
+               lambda sp=None: _build_sentinel_evict("reclaim", False, sp),
                steady=True),
     EntryPoint("ops.invariants.evict_sentinel_solve[preempt]",
-               lambda sp=None: _build_sentinel_evict("preempt", sp),
+               lambda sp=None: _build_sentinel_evict("preempt", False, sp),
+               steady=True),
+    EntryPoint("ops.invariants.evict_sentinel_solve[reclaim,compact]",
+               lambda sp=None: _build_sentinel_evict("reclaim", True, sp),
+               steady=True),
+    EntryPoint("ops.invariants.evict_sentinel_solve[preempt,compact]",
+               lambda sp=None: _build_sentinel_evict("preempt", True, sp),
                steady=True),
     EntryPoint("ops.invariants.enqueue_gate_sentinel", _build_sentinel_gate,
                steady=True),

@@ -190,7 +190,9 @@ def replay_bundle(path: str) -> Dict:
         return {n: int(c) for n, c in zip(INVARIANT_NAMES, h) if c}
 
     if meta["config_kind"] == "EvictConfig":
-        res, v, h, _e = evict_sentinel_solve(snap, config)
+        res, v, h, _e = evict_sentinel_solve(
+            snap, config,
+            None if pend_rows is None else jax.numpy.asarray(pend_rows))
         claim, evicted, verdict = jax.device_get(
             (res.claim_node, res.evicted, v)
         )

@@ -518,6 +518,13 @@ EVICT_CLAIMS = Counter(
     "plus what that node's releasing victims have promised)",
     ("action", "outcome"),
 )
+EVICT_SOLVE_COMPACTED = Counter(
+    f"{_SUBSYSTEM}_evict_solve_compacted_total",
+    "Evict solve dispatches, by action and whether the bids ran on the "
+    "pending bucket (true) or on the whole task axis (false: a pending set "
+    "past the bucket, a task axis too small to have one, the sharded path)",
+    ("action", "compacted"),
+)
 EVICTION_RELEASE_LATENCY = Histogram(
     f"{_SUBSYSTEM}_eviction_release_latency_milliseconds",
     "An eviction's order to the drain of its victim's DELETE in milliseconds",
@@ -557,6 +564,8 @@ for _action in ("reclaim", "preempt"):
     for _outcome in ("committed", "host_rejected", "uncovered",
                      "gated_releasing"):
         EVICT_CLAIMS.add(0.0, _action, _outcome)
+    for _compacted in ("true", "false"):
+        EVICT_SOLVE_COMPACTED.add(0.0, _action, _compacted)
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
@@ -628,6 +637,7 @@ METRICS = [
     DEVICE_PEAK_BYTES,
     EVICTIONS,
     EVICT_CLAIMS,
+    EVICT_SOLVE_COMPACTED,
     EVICTION_RELEASE_LATENCY,
     EVICT_REPEAT_CLAIMS,
 ]
@@ -867,6 +877,10 @@ def register_eviction(action: str) -> None:
 def register_evict_claims(action: str, outcome: str, n: int) -> None:
     if n:
         EVICT_CLAIMS.add(n, action, outcome)
+
+
+def register_evict_solve_compacted(action: str, compacted: bool) -> None:
+    EVICT_SOLVE_COMPACTED.inc(action, "true" if compacted else "false")
 
 
 def register_evict_repeat_claim(earlier: str) -> None:

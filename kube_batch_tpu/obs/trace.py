@@ -527,6 +527,21 @@ class Tracer:
         span.set(mode=mode, engaged=list(engaged), program=program)
         metrics.register_solve_dispatch(action, mode, program)
 
+    def note_evict_dispatch(self, span: Span, action: str, mode: str,
+                            engaged, *, compact: bool, claimants: int,
+                            bucket: Optional[int]) -> None:
+        """:meth:`note_solve_dispatch` for an evict solve
+        (``program="evict"``), and on which claimant axis its bids ran:
+        ``compact`` (the pending bucket, not the whole task axis),
+        ``claimants`` (pending rows) and ``bucket`` (the one bucket the
+        task axis compacts into; 0 where it is too small to have one) on
+        the span, and ``volcano_evict_solve_compacted_total{action,
+        compacted}`` from the same value."""
+        self.note_solve_dispatch(span, action, mode, engaged,
+                                 program="evict")
+        span.set(compact=compact, claimants=claimants, bucket=bucket or 0)
+        metrics.register_evict_solve_compacted(action, compact)
+
     def note_solve_rounds(self, span: Span, action: str, rounds: int,
                           per_pass: int) -> None:
         """Say on a ``device_wait`` span how many bidding rounds its solve

@@ -33,14 +33,22 @@ claim with the real plugin callbacks on the (small) selected sets — the
 device narrows O(tasks × nodes × victims) to O(claims), the host stays
 authoritative for semantics.
 
-Memory footprint: the bidding rounds still score FULL [tasks, nodes] bid
-planes, which blows the v5e HBM budget at the 1M×100k north star — the
-tier-C HBM audit (analysis/hbm_audit.py) flags every evict variant under
-KBT201/KBT202 and waives it in ``HBM_ALLOWLIST`` under ROADMAP 1.(1);
-the sparse rebuild (candidate table over per-(queue, node) capacity keys,
-with re-rank-on-growth since evictions grow capacity within a pass)
-deletes those waivers, and the audit fails on the stale entries if this
-file gets fixed without removing them.
+Memory footprint: every [claimant, node] plane (static predicates, score,
+tie hash, the gates' probe, the per-round capacity matmuls and the masked
+argmax) is built over the claimant axis the caller hands in.  The action
+(actions/reclaim.py) hands in the pending bucket ``pend_rows`` [P] whenever
+the pending set fits the one bucket the task axis' shape gives
+(actions/allocate.py ``topk_bucket_for``): [P, N] planes, 168 MB each at
+P=8,192 x N=5,120 against 1.03 GB at T=50,176.  Victims only ever need
+task-axis vectors.  With ``pend_rows=None`` (a pending set past the bucket,
+an axis too small to have one, the sharded bodies) the claimant axis is the
+whole task axis and the planes are [T, N], as before.  The tier-C HBM audit
+(analysis/hbm_audit.py) traces both: a [P, N] plane is still a task-axis x
+node-axis temporary (KBT202, as allocate's table build is, ROADMAP 1.(2)),
+and both blow the v5e budget at the 1M x 100k north star (KBT201); the
+waivers in ``HBM_ALLOWLIST`` say which program each covers, and the sparse
+rebuild (candidate table over per-(queue, node) capacity keys, with
+re-rank-on-growth since evictions grow capacity within a pass) deletes them.
 """
 
 from __future__ import annotations
@@ -54,7 +62,12 @@ import jax.numpy as jnp
 from kube_batch_tpu.api.snapshot import DeviceSnapshot
 from kube_batch_tpu.api.types import TaskStatus
 from kube_batch_tpu.ops import fairness, ordering
-from kube_batch_tpu.ops.assignment import _best_node, _tie_break_hash
+from kube_batch_tpu.ops.assignment import (
+    _best_node,
+    _tie_break_hash,
+    pend_view,
+    tie_break_hash_rows,
+)
 from kube_batch_tpu.ops.feasibility import static_predicates
 from kube_batch_tpu.ops.ordering import segmented_prefix
 from kube_batch_tpu.ops.scoring import ScoreWeights, score_matrix
@@ -238,21 +251,36 @@ def pick_victims(snap: DeviceSnapshot, vmask, node_req, node_has_claim,
         return take & covered[vn], covered
 
 
-def local_evict_bids(snap: DeviceSnapshot, config: EvictConfig):
+def local_evict_bids(snap: DeviceSnapshot, config: EvictConfig,
+                     pend_rows=None, view=None):
     """Build the single-program bids head: ``bids(victim_ok, claimant_ok)
-    -> (best, has)`` — the per-round [T, N]-scale victim-capacity /
+    -> (best, has)`` — the per-round [C, N]-scale victim-capacity /
     feasibility / masked-argmax block, computed from the full matrices in
-    one logical program.  The shard_map path substitutes the explicit-
-    collective block head (parallel/shard_solve.py); the rest of the solve
-    is the SHARED :func:`evict_rounds` machinery."""
-    T, R = snap.task_req.shape
+    one logical program.  The claimant axis C is the task axis, or the
+    pending bucket where ``pend_rows`` ([P] i32 global task rows, -1
+    padding; ``view`` its :func:`~ops.assignment.pend_view`) is given:
+    ``victim_ok`` stays [T] either way, ``claimant_ok`` / ``best`` /
+    ``has`` are [C], and a bucket row's tie hash is that of its GLOBAL
+    task index, so every tie falls as on the full axis.  The shard_map path
+    substitutes the explicit-collective block head
+    (parallel/shard_solve.py); the rest of the solve is the SHARED
+    :func:`evict_rounds` machinery."""
+    R = snap.task_req.shape[1]
     N = snap.node_alloc.shape[0]
     Q = snap.queue_weight.shape[0]
     preempt = config.mode == "preempt"
     task_queue = snap.job_queue[snap.task_job]                      # [T]
-    static_ok = static_predicates(snap)
-    score = score_matrix(snap, config.weights)
-    tie_hash = _tie_break_hash(T, N)
+    if pend_rows is None:
+        view, claimant_queue = snap, task_queue
+    else:
+        claimant_queue = view.job_queue[view.task_job]              # [P]
+    static_ok = static_predicates(view)
+    score = score_matrix(view, config.weights)
+    if pend_rows is None:
+        tie_hash = _tie_break_hash(snap.task_req.shape[0], N)
+    else:
+        tie_hash = tie_break_hash_rows(
+            jnp.maximum(pend_rows, 0), jnp.arange(N, dtype=jnp.int32))
 
     def bids(victim_ok, claimant_ok):
         # ---- per-(queue, node) evictable capacity --------------------
@@ -272,20 +300,20 @@ def local_evict_bids(snap: DeviceSnapshot, config: EvictConfig):
         # ---- bids ----------------------------------------------------
         # feasible[t, n] iff claimant t's InitResreq fits cap[queue_t, n].
         # Each claimant's queue-specific capacity row is gathered with a
-        # one-hot matmul over the queue axis ([T,Q]@[Q,N] on the MXU, one
+        # one-hot matmul over the queue axis ([C,Q]@[Q,N] on the MXU, one
         # per resource dim): compile cost and kernel count stay flat as the
         # queue bucket grows, unlike the unrolled per-queue fits pass this
-        # replaces (Q=128 would mean 128 full [T,N] passes). The one-hot
+        # replaces (Q=128 would mean 128 full [C,N] passes). The one-hot
         # contraction selects exactly one row, so it is exact, not a sum.
-        onehot_q = (task_queue[:, None] == jnp.arange(Q)[None, :]).astype(
+        onehot_q = (claimant_queue[:, None] == jnp.arange(Q)[None, :]).astype(
             jnp.float32
-        )                                                            # [T, Q]
+        )                                                            # [C, Q]
         # a queue index outside [0, Q) gathers an all-zero capacity row from
         # the one-hot contraction; a near-zero request could still pass the
         # epsilon compare against it — make such tasks categorically
         # infeasible rather than relying on claimant_ok to exclude them
         feas = static_ok & claimant_ok[:, None]
-        feas &= ((task_queue >= 0) & (task_queue < Q))[:, None]
+        feas &= ((claimant_queue >= 0) & (claimant_queue < Q))[:, None]
         for r in range(R):  # R is the small static resource dim
             # HIGHEST precision: TPU default matmul truncates the f32
             # capacity operand to bf16 (~2^-8 relative), which at byte-unit
@@ -297,8 +325,8 @@ def local_evict_bids(snap: DeviceSnapshot, config: EvictConfig):
             # graph, zero per-iteration host dispatch
             cap_tr = jnp.matmul(
                 onehot_q, cap[:, :, r], precision=jax.lax.Precision.HIGHEST
-            )                                                        # [T, N]
-            feas &= snap.task_req[:, r, None] <= cap_tr + snap.quanta[r]
+            )                                                        # [C, N]
+            feas &= view.task_req[:, r, None] <= cap_tr + snap.quanta[r]
         masked = jnp.where(feas, score, NEG)
         # tie-hash spread: without it every equal-score claimant bids the
         # same argmax node and only one claim lands per round
@@ -371,24 +399,58 @@ def evict_rounds(
     gate_room=None,
     n_nodes=None,
     claimant_mask=None,
+    pend_rows=None,
 ) -> EvictResult:
     """The eviction machinery shared by every solve path: victim/claimant
     eligibility, ranks, winner-per-node selection, victim picking, global
     caps, coverage, and the commit gate — everything that reads only the
-    task/job/queue-axis vectors (replicated under shard_map).  The [T, N]-
-    scale bids come from ``bids_fn``; ``gate_room`` ([T]) is the
-    claimant gates' probe, ``[T, 2]`` (:func:`gate_room_local` summed over
-    the node shards; required iff :func:`gates_on`).  ``n_nodes``
-    overrides the GLOBAL node count when ``snap``'s node arrays are
-    shard-local blocks (the shard_map body).  ``claimant_mask`` ([T] bool)
-    restricts claimants beyond the standard eligibility — callers probing
-    a SUBSET of the pending work (a single job's what-if, a drained queue)
-    share this machinery instead of forking it."""
+    task/job/queue-axis vectors (replicated under shard_map).  The [C, N]-
+    scale bids come from ``bids_fn``; ``gate_room`` ([C, 2]) is the
+    claimant gates' probe (:func:`gate_room_local` summed over the node
+    shards; required iff :func:`gates_on`).  The claimant axis C is the
+    task axis, or the pending bucket where ``pend_rows`` ([P] i32 global
+    task rows in ascending order, -1 padding, covering EVERY pending row)
+    is given: eligibility is computed on [T] and gathered to the bucket,
+    the claimants' virtual rank, the bid and the winner selection run on
+    the bucket, a winner's bucket slot is mapped back to its task row
+    before it indexes anything, and the probe and the claims are scattered
+    to [T] — so the result, and all the victim machinery, is on the task
+    axis whichever axis the bids ran on, and is the same result (a row
+    that is not pending can never bid).
+    ``n_nodes`` overrides the GLOBAL node count when ``snap``'s node arrays
+    are shard-local blocks (the shard_map body).  ``claimant_mask`` ([T]
+    bool) restricts claimants beyond the standard eligibility — callers
+    probing a SUBSET of the pending work (a single job's what-if, a drained
+    queue) share this machinery instead of forking it."""
     T, R = snap.task_req.shape
     N = n_nodes if n_nodes is not None else snap.node_alloc.shape[0]
     J = snap.job_min_avail.shape[0]
     Q = snap.queue_weight.shape[0]
     preempt = config.mode == "preempt"
+
+    if pend_rows is None:
+        def to_claimants(x):
+            return x
+
+        def to_tasks(x, fill):
+            del fill
+            return x
+    else:
+        live = pend_rows >= 0
+        safe = jnp.clip(pend_rows, 0, T - 1)
+        # padding slots land in the dropped T slot of a [T+1] buffer
+        # (negative indices must never reach a scatter)
+        scat = jnp.where(live, pend_rows, T)
+
+        def to_claimants(x):
+            """[T] -> [P]: the bucket's rows of a task-axis vector."""
+            g = x[safe]
+            return g & live if g.dtype == jnp.bool_ else g
+
+        def to_tasks(x, fill):
+            """[P, ...] -> [T, ...]: ``fill`` where the bucket has no row."""
+            buf = jnp.full((T + 1,) + x.shape[1:], fill, x.dtype)
+            return buf.at[scat].set(x)[:T]
 
     task_queue = snap.job_queue[snap.task_job]                      # [T]
     running = victim_running(snap)
@@ -437,6 +499,9 @@ def evict_rounds(
         # cannot complete on two slots.  Claimants with host-only
         # constraints are exempt (their device fit is approximate —
         # allocate's host re-check might reject the node and strand them).
+        # the bucket's probe on the task axis: a row outside the bucket is
+        # not pending, so it was no candidate with any room either
+        gate_room = to_tasks(gate_room, 0)
         room = gate_room[:, 1]
         cand = claimant_base & (room > 0) & ~snap.task_needs_host
         # most constrained first: a node with room for a large claimant
@@ -450,6 +515,23 @@ def evict_rounds(
             gated & (ahead >= gate_room[:, 0])).astype(jnp.int32)
     else:
         gated_releasing = jnp.int32(0)
+
+    # the claimants' side of the rank, on the claimant axis: on the bucket
+    # the virtual order is computed among the bucket's rows alone (a third
+    # of a round's device time on [T]; its three sorts, prefix scans and
+    # gathers shrink with the axis).  Only the ORDER among eligible
+    # claimants is read (claim_winners), and it is the task axis' order:
+    # a row outside the bucket is no claimant, so it adds 0.0 to every
+    # prefix and never stands between two claimants' keys, and the bucket
+    # ascends, so every stable-sort tie falls as on [T] (the exactness
+    # story of ops/assignment.py scatter_bucket_result).  The subrank is
+    # taken among the bucket's rows: sort_by_segment_then_rank packs it
+    # under the axis' own length.
+    c_resreq = to_claimants(snap.task_resreq)
+    c_job = to_claimants(snap.task_job)
+    c_queue = to_claimants(task_queue)
+    c_subrank = subrank if pend_rows is None else ordering.task_subranks(
+        to_claimants(snap.task_prio), to_claimants(snap.task_creation))
 
     def round_body(state):
         claim_node, evicted, victim_claimant, i, _ = state
@@ -511,12 +593,13 @@ def evict_rounds(
             # reclaim skips overused claimant queues (reclaim.go:112-116)
             q_overused = fairness.overused(deserved, queue_alloc_now, snap.quanta)
             claimant_ok &= ~q_overused[task_queue]
+        bidder_ok = to_claimants(claimant_ok)                        # [C]
         rank = ordering.virtual_task_ranks(
-            claimant_ok,
-            snap.task_resreq,
-            snap.task_job,
-            task_queue,
-            subrank,
+            bidder_ok,
+            c_resreq,
+            c_job,
+            c_queue,
+            c_subrank,
             snap.job_prio,
             job_pipelined_now,
             snap.job_creation,
@@ -528,17 +611,22 @@ def evict_rounds(
             gang_enabled=config.gang,
             drf_enabled=config.drf,
             proportion_enabled=config.proportion,
-        )
+        )                                                            # [C]
 
-        # ---- victim-capacity bids ([T, N]-scale, path-specific head) -
+        # ---- victim-capacity bids ([C, N]-scale, path-specific head) -
         with jax.named_scope("evict_bid"):
-            best, has = bids_fn(victim_ok, claimant_ok)
-        has &= claimant_ok
+            best, has = bids_fn(victim_ok, bidder_ok)
+        has &= bidder_ok
 
         # ---- one winner per node: lowest claimant rank ---------------
         is_winner, winner_task, node_has_claim = claim_winners(
             has, best, rank, N
         )
+        if pend_rows is not None:
+            # bucket slot -> task row (the bucket ascends, so the largest
+            # slot among equal ranks is the largest row, as on [T])
+            winner_task = jnp.where(
+                node_has_claim, pend_rows[jnp.maximum(winner_task, 0)], -1)
         node_req = jnp.where(
             node_has_claim[:, None], snap.task_req[jnp.maximum(winner_task, 0)], jnp.inf
         )                                                            # [N, R]
@@ -576,8 +664,9 @@ def evict_rounds(
         )
 
         # ---- apply ---------------------------------------------------
-        new_claim = is_winner & covered[jnp.clip(best, 0, N - 1)]
-        claim_node = jnp.where(new_claim, best, claim_node)
+        new_claim = is_winner & covered[jnp.clip(best, 0, N - 1)]   # [C]
+        claim_node = jnp.where(
+            to_tasks(new_claim, False), to_tasks(best, -1), claim_node)
         evicted = evicted | final_take
         victim_claimant = jnp.where(
             final_take, winner_task[vn], victim_claimant
@@ -624,9 +713,17 @@ def evict_rounds(
 
 
 @partial(jax.jit, static_argnames=("config",))
-def evict_solve(snap: DeviceSnapshot, config: EvictConfig) -> EvictResult:
+def evict_solve(snap: DeviceSnapshot, config: EvictConfig,
+                pend_rows=None) -> EvictResult:
+    """The eviction solve on one device.  ``pend_rows`` ([P] i32, the
+    pending bucket as actions/allocate.py ``plan_pend_bucket`` plans it)
+    runs every [claimant, node] plane on [P, N]; None runs them on [T, N].
+    One function, both shapes: the result is bit-identical."""
+    view = snap if pend_rows is None else pend_view(snap, pend_rows)
     room = None
     if gates_on(config):
         room = gate_room_local(
-            snap.task_req, static_predicates(snap), snap, config)
-    return evict_rounds(snap, config, local_evict_bids(snap, config), room)
+            view.task_req, static_predicates(view), snap, config)
+    return evict_rounds(
+        snap, config, local_evict_bids(snap, config, pend_rows, view), room,
+        pend_rows=pend_rows)

@@ -434,9 +434,13 @@ def warm_sentinel_solve_fn():
 
 
 @partial(jax.jit, static_argnames=("config",))
-def evict_sentinel_solve(snap: DeviceSnapshot, config: EvictConfig):
-    """evict_solve (reclaim/preempt) with the fused invariant tail."""
-    res = evict_solve.__wrapped__(snap, config)
+def evict_sentinel_solve(snap: DeviceSnapshot, config: EvictConfig,
+                         pend_rows=None):
+    """evict_solve (reclaim/preempt; on the pending bucket where
+    ``pend_rows`` is given) with the fused invariant tail: the invariants
+    run on the [T] result the bucket's claims were scattered to, so a
+    mis-scatter is in scope, not just the rounds."""
+    res = evict_solve.__wrapped__(snap, config, pend_rows)
     verdict, hist = evict_invariants(snap, res, config)
     return res, verdict, hist, eligibility_checksum(snap)
 

@@ -105,6 +105,21 @@ class TestSelfEnforcement:
                 f"cross-reference: {key}"
 
 
+    def test_the_compact_evict_programs_brought_no_waiver(self):
+        """PR 36 registered the evict programs' pending-bucket shape beside
+        the full-axis fallback: eight evict waivers before, eight after,
+        and the single-device ones say which shape they cover."""
+        evict = {k: v for k, v in HBM_ALLOWLIST.items() if "evict" in k[0]}
+        assert len(evict) == 8
+        for (e_pat, rule, point), reason in evict.items():
+            if e_pat.startswith("parallel."):
+                continue
+            assert "fallback" in reason or "both shapes" in reason, (
+                e_pat, rule, point)
+            if point == "envelope-150k":
+                assert "alone" in reason    # the bucket's shape fits there
+
+
 class TestEnvelope:
     """Kubernetes' published envelope (150,000 pods on 5,000 nodes:
     ``benchmark/configs/k8s-envelope-150k-5k.json``) on the four chips of
@@ -166,6 +181,22 @@ class TestEnvelope:
                      "ops.assignment.warm_allocate_solve",
                      "ops.invariants.warm_allocate_sentinel_solve"):
             assert "KBT201" not in _rules(reports[name]), name
+
+    @pytest.mark.parametrize("mode", ("reclaim", "preempt"))
+    @pytest.mark.parametrize("entry", ("ops.eviction.evict_solve",
+                                       "ops.invariants.evict_sentinel_solve"))
+    def test_on_the_pending_bucket_evict_fits_one_device(self, reports,
+                                                         point, entry, mode):
+        """The evict solves bid on the pending bucket (PR 36): [P, N] planes
+        at P = 32,768 fit one chip where the full-axis fallback's [T, N]
+        do not, under the waivers the fallback already had (a [P, N] plane
+        is still a task-axis x node-axis temporary: KBT202 stays)."""
+        compact = reports[f"{entry}[{mode},compact]"]
+        full = reports[f"{entry}[{mode}]"]
+        assert point.P == 32_768
+        assert compact.traced and _rules(compact) == ["KBT202"]
+        assert compact.peak_bytes < 8 * GIB < 16 * GIB < full.peak_bytes
+        assert full.peak_bytes > 4 * compact.peak_bytes
 
     def test_the_oracle_is_charged_by_its_node_shards(self, point):
         """A pjit's intermediates carry no specs: a value with the global
@@ -351,6 +382,9 @@ class TestAllowlist:
         assert _glob_match("ops.eviction.evict_solve[reclaim]",
                            "ops.eviction.evict_solve[*]")
         assert _glob_match("ops.eviction.evict_solve[preempt]",
+                           "ops.eviction.evict_solve[*]")
+        # one waiver covers a program's both shapes (PR 36)
+        assert _glob_match("ops.eviction.evict_solve[reclaim,compact]",
                            "ops.eviction.evict_solve[*]")
         assert not _glob_match("ops.eviction.evict_solver",
                                "ops.eviction.evict_solve[*]")

@@ -36,6 +36,12 @@ class TestRegistryClean:
         assert any("allocate_solve" in n for n in names)
         assert any("allocate_topk_solve" in n for n in names)
         assert any("evict_solve" in n for n in names)
+        # both shapes of every evict program: the pending bucket's and the
+        # full-axis fallback's (PR 36)
+        for entry in ("ops.eviction.evict_solve",
+                      "ops.invariants.evict_sentinel_solve"):
+            for mode in ("reclaim", "preempt"):
+                assert {f"{entry}[{mode}]", f"{entry}[{mode},compact]"} <= names
         assert any("resident" in n for n in names)
         assert any("warm_allocate_solve" in n for n in names)
         assert any("warm_allocate_sentinel_solve" in n for n in names)

@@ -66,3 +66,34 @@ def test_the_pjit_oracle_at_the_envelope_fits_as_the_audit_models_it(topo):
     # XLA keeps the [150528, 5120] planes node-sharded: about one quarter
     # plane set a device, where a replicated plane alone is 2.9 GiB
     assert GIB < compiled <= modelled <= 16 * GIB, (compiled, modelled)
+
+
+def test_on_the_pending_bucket_the_evict_solve_holds_a_sixth_of_the_planes(
+        topo):
+    """``overcommit-50k-5k``'s evict program (reclaim, both claimant gates,
+    the guard's sentinel fused) at 50,176 x 5,120 on one chip: bidding on
+    the pending bucket of 8,192 rows the compiler allocates under a GiB of
+    temporaries, where the full-axis fallback takes over four (the cell's
+    whole ``peak_bytes_reserved`` before PR 36)."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from kube_batch_tpu.actions.allocate import topk_bucket_for
+    from kube_batch_tpu.analysis.jaxpr_audit import abstract_snapshot
+    from kube_batch_tpu.ops.eviction import EvictConfig
+    from kube_batch_tpu.ops.invariants import evict_sentinel_solve
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    T, N = 50_176, 5_120
+    assert topk_bucket_for(T) == 8_192
+    snap = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        abstract_snapshot(T=T, N=N, J=13_312, Q=8, R=4, W=4, K=4))
+    rows = jax.ShapeDtypeStruct((8_192,), jnp.int32, sharding=one_chip)
+    ec = EvictConfig(mode="reclaim", idle_gate=True, releasing_gate=True)
+    temp = {}
+    for name, args in (("bucket", (snap, ec, rows)), ("full", (snap, ec))):
+        memory = evict_sentinel_solve.lower(*args).compile().memory_analysis()
+        temp[name] = memory.temp_size_in_bytes
+    assert 0 < temp["bucket"] < GIB < 4 * GIB < temp["full"], temp
+    assert temp["full"] > 5 * temp["bucket"]
