@@ -597,12 +597,13 @@ class AllocateAction(Action):
             # ran, nothing below runs (no replay, no binds, no fit errors)
             gp.fail_closed("allocate", str(e))
             return False
+        warm_plan = (topk_info or {}).get("warm") or {}
         tracer.note_solve_dispatch(
             sp_solve, "allocate", self.last_solve_mode, ginfo["engaged"],
             program=solve_program(
-                ginfo["engaged"],
-                rebuilt=bool(((topk_info or {}).get("warm") or {}).get("cold")),
-            ),
+                ginfo["engaged"], rebuilt=bool(warm_plan.get("cold"))),
+            bucket=(topk_info or {}).get("bucket"),
+            rungs=warm_plan.get("rungs"),
         )
         # shadow-oracle audit (guard tier 2): every KB_AUDIT_EVERY-th
         # dispatch re-runs the committed solve through its oracle path,

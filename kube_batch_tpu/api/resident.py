@@ -1024,6 +1024,10 @@ class WarmTableState:
             # re-rank attribution (bench/sim evidence): fresh bucket rows,
             # rows whose own features moved, θ/φ-eroded rows
             "new": n_new, "dirty": n_dirty, "eroded": n_eroded,
+            # the shapes this plan's program is compiled for: the merge,
+            # re-rank and changed-node rungs (a compile in the serving loop
+            # is a rung no warm-up visited: the compile log names it)
+            "rungs": [int(m_rung), int(rrung), int(changed.shape[0])],
         }
         return {
             "row_map": row_map, "changed": changed,

@@ -27,6 +27,9 @@ The reference serves Prometheus `/metrics` (+ pprof) on --listen-address
                                  tree, flight-recorder ring stats, and the
                                  ring's solve dispatches tallied by
                                  mode + engaged fast paths
+- GET  /v1/trace/cycles/<n>    — the whole span tree of cycle n, while the
+                                 ring or the kept list holds it (the rows
+                                 of `cycles` / `kept` on /v1/trace)
 - GET  /v1/trace/dumps         — flight-recorder dump index; append
                                  /<name>/<trace.json|meta.json> to stream
                                  one dump's files (warm standbys and
@@ -286,6 +289,20 @@ def make_handler(cache: SchedulerCache, query_plane=None):
                 from kube_batch_tpu.obs.trace import tracer_of
 
                 self._send(200, json.dumps(tracer_of(cache).state()))
+            elif self.path.startswith("/v1/trace/cycles/"):
+                # one record's whole tree, by the number its row in
+                # /v1/trace's `cycles` or `kept` gives
+                from kube_batch_tpu.obs.trace import tracer_of
+
+                number = self.path[len("/v1/trace/cycles/"):]
+                tree = (tracer_of(cache).cycle_tree(int(number))
+                        if number.isdigit() else None)
+                if tree is None:
+                    self._send(404, json.dumps(
+                        {"error": f"no cycle {number!r} in the ring or "
+                                  "the kept list"}))
+                else:
+                    self._send(200, json.dumps(tree))
             elif self.path == "/v1/trace/dumps" or self.path.startswith(
                 "/v1/trace/dumps/"
             ):

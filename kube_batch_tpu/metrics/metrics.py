@@ -136,6 +136,33 @@ class Counter:
         return "\n".join(lines)
 
 
+class PolledCounter(Counter):
+    """A counter whose values are kept elsewhere and read when the page is
+    rendered.  The garbage collector's callback can run inside any
+    allocation, one made while a Counter's lock is held included, so it
+    adds into plain lists and never takes a lock
+    (``obs/interruptions.py::GCLedger``); ``poll`` returns those totals as
+    ``{label values: value}``."""
+
+    poll = None
+
+    def _refresh(self) -> None:
+        if self.poll is not None:
+            polled = self.poll()
+            with self._lock:
+                self._values.update(polled)
+
+    def values(self) -> Dict[Tuple[str, ...], float]:
+        """The series as they stand now (what :meth:`render` prints)."""
+        self._refresh()
+        with self._lock:
+            return dict(self._values)
+
+    def render(self) -> str:
+        self._refresh()
+        return super().render()
+
+
 class Gauge(Counter):
     """A settable series rendered with TYPE gauge (Counter already carries
     set(); only the exposition type differs — Prometheus clients treat a
@@ -538,6 +565,41 @@ EVICT_REPEAT_CLAIMS = Counter(
     "another pod)",
     ("earlier",),
 )
+# the interruption ledger (obs/interruptions.py): what stopped the loop
+# thread for a reason no span names.  The collector's pauses by generation
+# (CPython's collection is stop-the-world under the GIL; the reference
+# exports the same as go_gc_duration_seconds), the stalls the loop's
+# watchdog declared, and the deciding cycles the flight recorder pinned
+# because their worst decision stood out
+GC_COLLECTIONS = PolledCounter(
+    f"{_SUBSYSTEM}_gc_collections_total",
+    "Garbage collections of the process, by generation",
+    ("generation",),
+)
+GC_PAUSE_SECONDS = PolledCounter(
+    f"{_SUBSYSTEM}_gc_pause_seconds_total",
+    "Seconds every thread stood still for a garbage collection, by "
+    "generation",
+    ("generation",),
+)
+LOOP_STALLS = Counter(
+    f"{_SUBSYSTEM}_loop_stalls_total",
+    "Stalls of the scheduling loop the watchdog declared (parked: an "
+    "ingest signal left unconsumed | cycle: a root span held open), each "
+    "past four times what the loop expects and 250 ms",
+    ("phase",),
+)
+LOOP_STALL_SECONDS = Counter(
+    f"{_SUBSYSTEM}_loop_stall_seconds_total",
+    "Seconds the declared stalls lasted, from their start, by phase",
+    ("phase",),
+)
+SLOW_DECISIONS = Counter(
+    f"{_SUBSYSTEM}_slow_decisions_total",
+    "Cycles kept because the worst arrival-to-decision latency they "
+    "closed was 100 ms and twice above the median of the last 32 "
+    "deciding cycles",
+)
 DEVICE_PEAK_BYTES = Gauge(
     f"{_SUBSYSTEM}_device_peak_bytes",
     "peak_bytes_in_use of each local device, refreshed at most once a cycle",
@@ -569,6 +631,13 @@ for _action in ("reclaim", "preempt"):
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
+for _generation in ("0", "1", "2"):
+    GC_COLLECTIONS.add(0.0, _generation)
+    GC_PAUSE_SECONDS.add(0.0, _generation)
+for _phase in ("parked", "cycle"):
+    LOOP_STALLS.add(0.0, _phase)
+    LOOP_STALL_SECONDS.add(0.0, _phase)
+SLOW_DECISIONS.add(0.0)
 
 METRICS = [
     E2E_LATENCY,
@@ -640,6 +709,11 @@ METRICS = [
     EVICT_SOLVE_COMPACTED,
     EVICTION_RELEASE_LATENCY,
     EVICT_REPEAT_CLAIMS,
+    GC_COLLECTIONS,
+    GC_PAUSE_SECONDS,
+    LOOP_STALLS,
+    LOOP_STALL_SECONDS,
+    SLOW_DECISIONS,
 ]
 
 
@@ -848,6 +922,18 @@ def register_jit_compile(phase: str, seconds: float) -> None:
     JIT_COMPILE_SECONDS.add(seconds, phase)
     if phase == "backend":
         JIT_COMPILES.inc()
+
+
+def register_loop_stall(phase: str) -> None:
+    LOOP_STALLS.inc(phase)
+
+
+def observe_loop_stall_seconds(phase: str, seconds: float) -> None:
+    LOOP_STALL_SECONDS.add(seconds, phase)
+
+
+def register_slow_decision() -> None:
+    SLOW_DECISIONS.inc()
 
 
 def register_solve_dispatch(action: str, mode: str, program: str) -> None:

@@ -27,7 +27,18 @@ microsecond).  The loop's parked time is two root spans
 record of the cycle they precede; the read plane's flush tree
 (``whatif:*``, :meth:`Tracer.detached_span`) is timed, annotated and
 totalled but kept out of the cycle records.  ``span_counts``/``span_ms``
-total every span by name, children and roots alike.
+total every span by name, children and roots alike, and under ``between``
+the time from one root of the cycle thread to the next.
+
+What stops the loop for a reason no span names is on the same plane
+(:mod:`kube_batch_tpu.obs.interruptions`): every root span samples the
+garbage collector's pause totals at entry and exit (``gc_ms``/``gc_full``;
+a pause stops every thread, so it is charged to whatever root was open on
+any thread), the gap before a root carries the pause that fell in it
+(``gap_gc_ms``), every compile is logged with its program, its span path
+and the attributes of the dispatch span that paid (``compiles`` on
+``/v1/trace``), and the stalls the loop's watchdog declares ride the
+record of the cycle they belong to.
 
 Complete per-cycle trace trees land in the flight recorder's ring
 (:mod:`kube_batch_tpu.obs.recorder`) and export as Chrome trace-event
@@ -61,6 +72,7 @@ import numpy as np
 
 from kube_batch_tpu import metrics
 from kube_batch_tpu.envutil import env_flag
+from kube_batch_tpu.obs.interruptions import GC
 from kube_batch_tpu.utils import telemetry
 
 import time as _time  # identity sentinel only: `clock is _time` ⇒ no vt
@@ -80,17 +92,34 @@ _KEEP_CYCLE, _KEEP_NEXT, _KEEP_NONE = 0, 1, 2
 #: "two or more cycles drained ingest since the pod arrived" needs
 _CYCLE_STARTS = 4
 
+#: the newest backend compiles kept with their names (``compiles`` on
+#: /v1/trace), and the largest decision latencies kept of one cycle
+COMPILE_LOG = 64
+TOP_LATENCIES = 16
+
+#: the spans whose attributes say which program a compile under them was
+#: for (``program``, ``engaged``, ``mode``, ``bucket``, ``rungs``, ...)
+_DISPATCH_SPANS = ("solve_dispatch", "audit_dispatch", "whatif:probe")
+
 # the open spans of each thread, innermost last.  ONE stack per thread for
 # every tracer of the process: a cache has one tracer and a thread works for
 # one cache at a time, and JAX's process-wide compile listener has to find
 # the span the compiling thread is inside without knowing whose it is.
 _OPEN = threading.local()
+# the same stacks by thread id, for the one reader that is not the owner:
+# the loop's watchdog looks at the loop thread's open spans from its own
+_STACKS: Dict[int, list] = {}
 
 
 def _open_spans() -> list:
     stack = getattr(_OPEN, "stack", None)
     if stack is None:
         stack = _OPEN.stack = []
+        if len(_STACKS) >= 64:
+            alive = {t.ident for t in threading.enumerate()}
+            for tid in [t for t in _STACKS if t not in alive]:
+                del _STACKS[tid]
+        _STACKS[threading.get_ident()] = stack
     return stack
 
 
@@ -116,24 +145,40 @@ _COMPILE_PHASES = {
 }
 _listener_lock = threading.Lock()
 _listening = False
+# thread id -> (phase, function name) of the compile phase that thread is
+# in: what a stall record says was in flight
+_COMPILING: Dict[int, tuple] = {}
 
 
-def _on_compile_event(event: str, duration: float, **_kw) -> None:
+def _on_compile_start(event: str, _value, fun_name: str = "", **_kw) -> None:
+    """JAX's scalar listener: a compile phase is entered (it reports the
+    phase's start time under the event's name)."""
+    phase = _COMPILE_PHASES.get(event)
+    if phase is not None:
+        _COMPILING[threading.get_ident()] = (phase, fun_name)
+
+
+def _on_compile_event(event: str, duration: float, fun_name: str = "",
+                      **_kw) -> None:
     """JAX's duration listener (runs on the compiling thread): every trace,
     lowering and backend compile of the process with its seconds — also of
     programs ``utils/jitstats`` never heard of and of compiles outside any
-    device span — stamped onto the innermost span that thread has open."""
+    device span — stamped onto the innermost span that thread has open and
+    logged, with the function's name, by that span's tracer."""
     phase = _COMPILE_PHASES.get(event)
     if phase is None:
         return
+    _COMPILING.pop(threading.get_ident(), None)
     metrics.register_jit_compile(phase, duration)
     stack = getattr(_OPEN, "stack", None)
     if stack:
         stack[-1]._note_compile(phase, duration)
+        stack[-1]._tracer._log_compile(stack, phase, duration, fun_name)
 
 
 def _listen_for_compiles() -> None:
-    """Register the process-wide listener, once."""
+    """Register the process-wide listeners, once: JAX's compile events and
+    the garbage collector's callback."""
     global _listening
     with _listener_lock:
         if not _listening:
@@ -141,6 +186,8 @@ def _listen_for_compiles() -> None:
 
             monitoring.register_event_duration_secs_listener(
                 _on_compile_event)
+            monitoring.register_scalar_listener(_on_compile_start)
+            GC.install(annotate=_annotation)
             _listening = True
 
 
@@ -151,7 +198,7 @@ class Span:
 
     __slots__ = ("name", "t0", "t1", "vt0", "vt1", "tid", "attrs",
                  "children", "_tracer", "_record", "_cols", "_c0", "_sc0",
-                 "_keep", "_annotation")
+                 "_keep", "_annotation", "_gc0", "_gap")
 
     def __init__(self, tracer: "Tracer", name: str,
                  record: Optional["CycleRecord"] = None,
@@ -169,6 +216,11 @@ class Span:
         self._c0 = self._sc0 = None
         self._keep = keep
         self._annotation = None
+        # a ROOT's samples of the collector's totals at entry, and the gap
+        # since the cycle thread's last root: (seconds, pause seconds and
+        # full collections that fell in it)
+        self._gc0 = None
+        self._gap = None
 
     # -- timing -----------------------------------------------------------
     @property
@@ -206,6 +258,7 @@ class Span:
         tracer = self._tracer
         self.tid = threading.get_ident()
         stack = _open_spans()
+        root = not stack
         stack.append(self)
         # device-attribution sampling happens OUTSIDE the stamped window so
         # the counter reads never inflate the span's own duration — and
@@ -227,7 +280,19 @@ class Span:
         # session the span shows on the host plane beside the XLA events
         # (with none running this is a fraction of a microsecond)
         self._annotation = _annotation(self.name)
-        self.t0 = telemetry.perf_counter()
+        if not root:
+            self.t0 = telemetry.perf_counter()
+            return self
+        # interruptions are charged to roots: the collector's totals now,
+        # and, for a root of the cycle thread (a stage or parked time), how
+        # long ago that thread's last root ended and what was collected
+        # meanwhile — the loop's time outside every root span
+        self._gc0 = gc0 = (GC.pause_s, GC.full)
+        self.t0 = t0 = telemetry.perf_counter()
+        if self._record is None and self._keep != _KEEP_NONE:
+            last = getattr(_OPEN, "root_end", None)
+            if last is not None and last[3] is tracer:
+                self._gap = (t0 - last[0], gc0[0] - last[1], gc0[1] - last[2])
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -255,6 +320,15 @@ class Span:
                     self.set(resident=delta)
             if exc_type is not None:
                 self.set(error=exc_type.__name__)
+            gc0 = self._gc0
+            if gc0 is not None:
+                paused, full = GC.pause_s, GC.full
+                if paused != gc0[0]:
+                    self.set(gc_ms=round((paused - gc0[0]) * 1e3, 3))
+                    if full != gc0[1]:
+                        self.set(gc_full=full - gc0[1])
+                if self._record is None and self._keep != _KEEP_NONE:
+                    _OPEN.root_end = (self.t1, paused, full, tracer)
         except Exception:  # noqa: BLE001 — attribution only; the stack
             pass           # unwind below must ALWAYS run
         finally:
@@ -275,6 +349,8 @@ class Span:
             d["vt0"] = round(self.vt0, 6)
             if self.vt1 is not None:
                 d["vt_dur"] = round(self.vt1 - self.vt0, 6)
+        if self._gap is not None:
+            d["gap_ms"] = round(self._gap[0] * 1e3, 4)
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if self.children:
@@ -303,7 +379,8 @@ class CycleRecord:
     guarded by the tracer's lock."""
 
     __slots__ = ("cycle", "reason", "t0", "t1", "vt0", "vt1", "spans",
-                 "attrs", "closed")
+                 "attrs", "closed", "decisions", "stalls", "compile_ms",
+                 "_lat", "_seen")
 
     def __init__(self, cycle: int, reason: str, t0: float,
                  vt0: Optional[float]):
@@ -316,11 +393,50 @@ class CycleRecord:
         self.spans: List[Span] = []
         self.attrs: Dict = {}
         self.closed = False
+        # the arrival→decision latencies the cycle closed, summarised when
+        # it is finalized (:meth:`summarize`): a record does not carry
+        # 50,000 floats through the ring for a cold drain
+        self.decisions: Optional[Dict] = None
+        self._lat: List = []   # the samples, as observed, until then
+        # [earliest arrival, worst latency (ms), when it was bound, cycles
+        # started since that arrival] over the binds so far
+        self._seen: Optional[List] = None
+        # the interruptions that are no span attribute: the stalls the
+        # loop's watchdog declared (obs/interruptions.py), and the seconds
+        # of the compiles JAX reported under this cycle's spans (ms)
+        self.stalls: List[Dict] = []
+        self.compile_ms = 0.0
+
+    def summarize(self) -> None:
+        """Reduce the cycle's latency samples to what the table of cycles
+        and the kept rule read: how many, the worst, the median, the
+        :data:`TOP_LATENCIES` largest, and for the earliest arrival its
+        wait until this cycle started and the cycles started since."""
+        chunks, self._lat = self._lat, []
+        if not chunks or self._seen is None:
+            return
+        lat = np.sort(
+            np.concatenate([np.asarray(c, np.float64) for c in chunks]))
+        n = int(lat.size)
+        earliest, worst, worst_at, spanned = self._seen
+        self.decisions = {
+            "decided": n,
+            "worst_ms": round(float(lat[-1]), 3),
+            "median_ms": round(float(lat[(n - 1) // 2] + lat[n // 2]) / 2, 3),
+            "top_ms": [round(float(v), 3)
+                       for v in lat[::-1][:TOP_LATENCIES]],
+            "wait_ms": round(max(self.t0 - earliest, 0.0) * 1e3, 3),
+            "spanned": spanned,
+            # the interval of the worst one, on the telemetry clock
+            "worst_from": round(worst_at - worst / 1e3, 6),
+            "worst_at": round(worst_at, 6),
+        }
 
     def to_dict(self) -> Dict:
         d = {
             "cycle": self.cycle,
             "reason": self.reason,
+            "t0": round(self.t0, 6),
             "dur_ms": (round((self.t1 - self.t0) * 1e3, 4)
                        if self.t1 is not None else None),
             "spans": [s.to_dict() for s in self.spans],
@@ -329,6 +445,12 @@ class CycleRecord:
             d["vt0"] = round(self.vt0, 6)
         if self.attrs:
             d["attrs"] = dict(self.attrs)
+        if self.decisions is not None:
+            d["decisions"] = dict(self.decisions)
+        if self.stalls:
+            d["stalls"] = [dict(st) for st in self.stalls]
+        if self.compile_ms:
+            d["compile_ms"] = round(self.compile_ms, 3)
         return d
 
 
@@ -367,6 +489,12 @@ class Tracer:
         # when the last few cycles started: a cycle drains ingest first, so
         # a pod that arrived before two of these was passed over by a cycle
         self._cycle_starts: deque = deque(maxlen=_CYCLE_STARTS)
+        # the newest backend compiles with their names, and the compile
+        # each thread is in the middle of (trace, lower, then backend)
+        self._compiles: deque = deque(maxlen=COMPILE_LOG)
+        self._compile_open: Dict[int, Dict] = {}
+        # stalls that wait for the record of the cycle that follows them
+        self._stalls_waiting: List[Dict] = []
         # seed-stable longitudinal stats (the sim report's section)
         self.cycles_total = 0
         self.spans_total = 0
@@ -387,8 +515,10 @@ class Tracer:
         t0 = telemetry.perf_counter()
         with self._mu:
             rec = CycleRecord(self._take_cycle_number(), reason, t0, vt0)
-            # the parked time before this cycle leads its record
+            # the parked time before this cycle leads its record, and the
+            # stalls of that time ride it
             rec.spans, self._preceding = self._preceding, []
+            rec.stalls, self._stalls_waiting = self._stalls_waiting, []
             self._cycle_starts.append(t0)
             prev, self.current = self.current, rec
         if prev is not None:
@@ -418,6 +548,7 @@ class Tracer:
         if self.clock is not None:
             rec.vt1 = self.clock.monotonic()
         rec.closed = True
+        rec.summarize()
         with self._mu:
             self.cycles_total += 1
         recorder = self.recorder
@@ -435,6 +566,18 @@ class Tracer:
             )
             if span.attrs and span.attrs.get("retrace"):
                 self.retraces_attributed += int(span.attrs["retrace"])
+            gap = span._gap
+            if gap is not None:
+                # the cycle thread's time between two of its roots: no
+                # stage on /metrics, a name of its own here
+                self.span_counts["between"] = (
+                    self.span_counts.get("between", 0) + 1)
+                self.span_ms["between"] = (
+                    self.span_ms.get("between", 0.0) + gap[0] * 1e3)
+                if gap[1] > 0:
+                    span.set(gap_gc_ms=round(gap[1] * 1e3, 3))
+                    if gap[2]:
+                        span.set(gap_gc_full=gap[2])
 
     def _close_root(self, span: Span) -> None:
         """A span finished with no parent on its thread: attach it to its
@@ -515,16 +658,26 @@ class Tracer:
 
     # -- cycle annotations -------------------------------------------------
     def note_solve_dispatch(self, span: Span, action: str, mode: str,
-                            engaged, program: Optional[str] = None) -> None:
+                            engaged, program: Optional[str] = None,
+                            bucket: Optional[int] = None,
+                            rungs=None) -> None:
         """Say which program a ``solve_dispatch`` span ran (``mode``,
         ``engaged``, ``program``) and count it on ``/metrics``
         (``volcano_solve_dispatches_total``), from the same values: the
         span's attributes and the counter cannot drift apart.  ``program``
         defaults to :func:`solve_program` of ``engaged``.  The counter
-        moves with ``KB_TRACE=0`` too, like every metric a span feeds."""
+        moves with ``KB_TRACE=0`` too, like every metric a span feeds.
+        ``bucket`` (the pending bucket of a compacted solve) and ``rungs``
+        (a warm plan's ``[merge, rerank, changed]`` rungs) are the shapes
+        the program was compiled for: a compile logged under this span
+        (:meth:`_log_compile`) is named by them."""
         if program is None:
             program = solve_program(engaged)
         span.set(mode=mode, engaged=list(engaged), program=program)
+        if bucket is not None:
+            span.set(bucket=bucket)
+        if rungs is not None:
+            span.set(rungs=list(rungs))
         metrics.register_solve_dispatch(action, mode, program)
 
     def note_evict_dispatch(self, span: Span, action: str, mode: str,
@@ -584,17 +737,16 @@ class Tracer:
                 rec.attrs[key] = value
 
     def note_decision_latencies(self, ms_values) -> None:
-        """Stamp this cycle's arrival→decision samples onto the trace tree
-        (the exact values the histogram/sink observe — test_trace pins the
-        equality) and arm a flight dump on an SLO breach."""
+        """Hand this cycle's arrival→decision samples to its trace record
+        (the exact values the histogram/sink observe; the record keeps
+        their summary, :meth:`CycleRecord.summarize` — test_trace pins it
+        against the sink) and arm a flight dump on an SLO breach."""
         if not ms_values or not self.enabled:
             return
         with self._mu:
             rec = self.current
             if rec is not None:
-                rec.attrs.setdefault("decision_lat_ms", []).extend(
-                    round(v, 3) for v in ms_values
-                )
+                rec._lat.append(ms_values)
         if self.slo_ms > 0 and self.recorder is not None:
             worst = max(ms_values)
             if worst > self.slo_ms:
@@ -620,16 +772,106 @@ class Tracer:
         t_cycle = rec.t0 if rec is not None else now
         if len(arrivals) == 1:
             # bind(): one pod a call, a hundred calls a backfill cycle
-            wait_ms = max(t_cycle - arrivals[0], 0.0) * 1e3
-            left = len(starts) - bisect.bisect_right(starts, arrivals[0]) >= 2
+            earliest = arrivals[0]
+            wait_ms = max(t_cycle - earliest, 0.0) * 1e3
+            left = len(starts) - bisect.bisect_right(starts, earliest) >= 2
         else:
             # bulk_bind(): the 50,000-pod cold drain pays milliseconds
             arr = np.asarray(arrivals, dtype=np.float64)
+            earliest = float(arr.min())
             wait_ms = float(np.clip(t_cycle - arr, 0.0, None).sum()) * 1e3
             left = int((len(starts) - np.searchsorted(
                 starts, arr, side="right") >= 2).sum())
         metrics.observe_decision_queue_wait(wait_ms, len(arrivals))
         metrics.register_decisions_leftover(int(left))
+        if rec is not None and self.enabled:
+            # the cycle's worst decision: whose it was and what it spanned
+            # (written by the one thread that binds for this record)
+            worst = (now - earliest) * 1e3
+            spanned = len(starts) - bisect.bisect_right(starts, earliest)
+            seen = rec._seen
+            if seen is None:
+                rec._seen = [earliest, worst, now, spanned]
+            else:
+                if worst > seen[1]:
+                    seen[1], seen[2] = worst, now
+                if earliest < seen[0]:
+                    seen[0], seen[3] = earliest, spanned
+
+    # -- interruptions ----------------------------------------------------
+    def _log_compile(self, stack, phase: str, secs: float,
+                     fun_name: str) -> None:
+        """One compile phase JAX reported on the thread whose open spans
+        are ``stack`` (innermost last, this tracer's).  A compile is its
+        trace, its lowering, then its backend compile; the entry is logged
+        when the last is reported, with the function's name, the span path
+        and the dispatch span (:data:`_DISPATCH_SPANS`) it ran under, whose
+        attributes (``program``, ``rungs``, ...) are set after the span has
+        closed and so are read when the log is."""
+        if not self.enabled:
+            return
+        root = stack[0]
+        with self._mu:
+            entry = self._compile_open.get(root.tid)
+            if entry is None or phase == "trace":
+                # an outer function's trace is reported after those of the
+                # functions it calls, and spans them: the last one stands
+                rec = root._record
+                if rec is None and root._keep == _KEEP_CYCLE:
+                    rec = self.current
+                entry = self._compile_open[root.tid] = {
+                    "rec": rec,
+                    "path": " > ".join(sp.name for sp in stack),
+                    "dispatch": next(
+                        (sp for sp in reversed(stack)
+                         if sp.name in _DISPATCH_SPANS), None),
+                    "ms": {},
+                }
+            entry["fun_name"] = fun_name
+            entry["ms"][phase] = round(
+                entry["ms"].get(phase, 0.0) + secs * 1e3, 3)
+            if entry["rec"] is not None:
+                entry["rec"].compile_ms += secs * 1e3
+            if phase == "backend":
+                self._compiles.append(self._compile_open.pop(root.tid))
+
+    @staticmethod
+    def _compile_entry(entry: Dict) -> Dict:
+        rec, dispatch = entry["rec"], entry["dispatch"]
+        out = {"cycle": rec.cycle if rec is not None else None,
+               "fun_name": entry["fun_name"], "path": entry["path"],
+               "ms": dict(entry["ms"])}
+        if dispatch is not None:
+            out["dispatch"] = dict(dispatch.attrs or {}, span=dispatch.name)
+        return out
+
+    def compile_in_flight(self) -> List[Dict]:
+        """The compile phases threads are in right now."""
+        return [{"thread": tid, "phase": phase, "fun_name": fun_name}
+                for tid, (phase, fun_name) in list(_COMPILING.items())]
+
+    def open_spans_of(self, tid: Optional[int]) -> list:
+        """The open spans of thread ``tid``, outermost first (the thread's
+        own list: look, do not touch)."""
+        return _STACKS.get(tid) or []
+
+    def open_path(self, tid: Optional[int]) -> str:
+        return " > ".join(sp.name for sp in list(self.open_spans_of(tid)))
+
+    def note_stall(self, stall: Dict, waits_for_cycle: bool) -> None:
+        """Keep a stall the loop's watchdog declared: on the record of the
+        cycle it interrupts, or, for a stall before a cycle
+        (``waits_for_cycle``: nothing started while work waited) or with
+        no cycle open, on the record of the cycle that follows.  Until that
+        cycle begins the stall is a kept record of its own."""
+        if not self.enabled:
+            return
+        with self._mu:
+            rec = None if waits_for_cycle else self.current
+            if rec is not None:
+                rec.stalls.append(stall)
+            else:
+                self._stalls_waiting = self._stalls_waiting[-7:] + [stall]
 
     def anomaly(self, reason: str, detail: str = "") -> None:
         """Route a non-guard anomaly (budget shed, duplicate bind) to the
@@ -651,6 +893,9 @@ class Tracer:
             out = {
                 "enabled": self.enabled,
                 "cycles_traced": self.cycles_total,
+                # the number the next record gets: what a reader of
+                # `cycles` holds a window's rows against
+                "next_cycle": self._next_cycle,
                 "spans_total": self.spans_total,
                 "span_counts": dict(self.span_counts),
                 "span_ms": {k: round(v, 3) for k, v in self.span_ms.items()},
@@ -658,11 +903,24 @@ class Tracer:
                 "last_detached": {k: sp.to_dict() for k, sp
                                   in self._last_detached.items()},
             }
+            compiles = list(self._compiles)
+            waiting = list(self._stalls_waiting)
+        out["compiles"] = [self._compile_entry(e) for e in compiles]
         if self.recorder is not None:
             out["ring"] = self.recorder.stats()
             out["solve_dispatches"] = self._solve_dispatches()
+            # built here, at GET time: nothing of the table is on the
+            # cycle's path
+            out["cycles"], out["kept"] = self.recorder.table(waiting)
         out["last_cycle"] = self.last_cycle()
         return out
+
+    def cycle_tree(self, cycle: int) -> Optional[Dict]:
+        """The whole tree of record ``cycle``, while the ring or the kept
+        list holds it."""
+        recorder = self.recorder
+        rec = recorder.find(cycle) if recorder is not None else None
+        return rec.to_dict() if rec is not None else None
 
     def _solve_dispatches(self) -> Dict[str, int]:
         """Tally of the allocate solve dispatches still in the ring, keyed

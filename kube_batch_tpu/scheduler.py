@@ -75,6 +75,7 @@ from kube_batch_tpu.framework.session import (
 )
 from kube_batch_tpu import metrics
 from kube_batch_tpu.obs.alerts import alerts_of
+from kube_batch_tpu.obs.interruptions import LoopWatchdog
 from kube_batch_tpu.obs.trace import tracer_of
 from kube_batch_tpu.utils import telemetry
 
@@ -147,6 +148,10 @@ class CycleTrigger:
         self._widest_gap = 0.0
         # stop() asked for this wake: it is never held
         self._stopping = False
+        # (when the signal the last wake consumed first came, when it was
+        # consumed): how the loop's watchdog learns that a signal it saw
+        # waiting was taken, and when
+        self._consumed: Optional[tuple] = None
 
     def notify(self, leftover: bool = False, stop: bool = False) -> None:
         """Wake the loop (never blocks; safe from any thread, including
@@ -179,6 +184,14 @@ class CycleTrigger:
         from its own open."""
         with self._cond:
             return self._pending == "ingest"
+
+    def unconsumed(self):
+        """(the pending wake reason or None, when its first signal came,
+        the last consumed signal's (came, consumed) or None), all on the
+        injected clock: what the loop's watchdog
+        (:class:`obs.interruptions.LoopWatchdog`) asks every tick."""
+        with self._cond:
+            return self._pending, self._signalled_at, self._consumed
 
     def poll(self) -> bool:
         """Consume a pending signal without waiting (the sim's virtual-time
@@ -229,8 +242,9 @@ class CycleTrigger:
                 if settle is not None:
                     self._settle(*settle)
                 with self._cond:
-                    signalled_ms = (
-                        clock.monotonic() - self._signalled_at) * 1e3
+                    now = clock.monotonic()
+                    signalled_ms = (now - self._signalled_at) * 1e3
+                    self._consumed = (self._signalled_at, now)
                     reason = self._take()
         if floor_sp is not None:
             floor_sp.set(woke_by=reason)
@@ -382,6 +396,9 @@ class Scheduler:
         # the last cycle raised: what it left (and the loop's re-list
         # recovery) is for a whole cycle to look at, whatever woke the loop
         self._cycle_failed = False
+        # run_forever's stall watchdog (obs/interruptions.py); the direct
+        # drives (run_once*, the sim) pace themselves and have none
+        self._watchdog: Optional[LoopWatchdog] = None
 
     def _stat_conf(self) -> Optional[float]:
         if not self._conf_path:
@@ -800,6 +817,11 @@ class Scheduler:
         # re-arm after a prior stop(): the warm-standby loop re-enters
         # run_forever in the same process after a leadership loss
         self._stop = False
+        # nobody is there to ask /debug/stacks when the loop stalls: a
+        # watchdog looks at this thread's loop every 100 ms of wall time
+        watchdog = LoopWatchdog(self)
+        watchdog.start()
+        self._watchdog = watchdog
         try:
             if self.pipelined:
                 self._run_forever_pipelined()
@@ -814,9 +836,17 @@ class Scheduler:
                 elapsed = self.clock.monotonic() - tick
                 self.clock.sleep(max(self.schedule_period - elapsed, 0.0))
         finally:
+            self._stop_watchdog()
             cache_stop = getattr(self.cache, "stop", None)
             if cache_stop is not None:
                 cache_stop()
+
+    def _stop_watchdog(self) -> None:
+        """End and join the watchdog's thread (whoever comes first, the
+        ending loop or :meth:`stop`; the other finds none)."""
+        watchdog, self._watchdog = self._watchdog, None
+        if watchdog is not None:
+            watchdog.stop()
 
     def _run_forever_pipelined(self) -> None:
         """The event-driven pipelined loop (the caller holds the cache-run /
@@ -905,6 +935,7 @@ class Scheduler:
         self._stop = True
         # a stopping pipelined loop may be idling at the slow floor — wake it
         self.trigger.notify(stop=True)
+        self._stop_watchdog()
 
     def close(self) -> None:
         """Retire the pipelined writeback pool with a bounded drain.

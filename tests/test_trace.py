@@ -10,6 +10,7 @@ tree, and the span names found on a jax.profiler trace's host plane."""
 from __future__ import annotations
 
 import json
+import statistics
 import threading
 import time
 import urllib.request
@@ -473,16 +474,26 @@ class TestPipelinedOverlap:
 # ---------------------------------------------------------------------------
 
 
-def _span_stamped_latencies(cache):
-    out = []
-    for rec in cache.flight_recorder.records():
-        out.extend(rec.attrs.get("decision_lat_ms", ()))
+def _assert_one_cycle_kept_the_summary_of(cache, samples, deciding=1):
+    """A record keeps no list of every pod's latency: the one cycle that
+    decided ``samples`` (the last of ``deciding`` that decided anything)
+    carries what the table of cycles reads (how many, the worst, the
+    median, the 16 largest), equal to the histogram sink's samples."""
     tr = cache.tracer
     with tr._mu:
-        cur = tr.current
-    if cur is not None:
-        out.extend(cur.attrs.get("decision_lat_ms", ()))
-    return out
+        assert tr.current is None  # finalized: the summary is made then
+    decided = [rec.decisions for rec in cache.flight_recorder.records()
+               if rec.decisions is not None]
+    assert len(decided) == deciding
+    decisions = decided[-1]
+    largest = sorted((round(v, 3) for v in samples), reverse=True)
+    assert decisions["decided"] == len(samples)
+    assert decisions["worst_ms"] == largest[0]
+    assert decisions["median_ms"] == pytest.approx(
+        statistics.median(samples), abs=1e-3)
+    assert decisions["top_ms"] == largest[:16]
+    for rec in cache.flight_recorder.records():
+        assert "decision_lat_ms" not in rec.attrs and rec._lat == []
 
 
 class TestDecisionLatencySink:
@@ -500,8 +511,7 @@ class TestDecisionLatencySink:
         finally:
             prom_metrics.set_decision_latency_sink(None)
         assert len(sink) == 4, "both 2-gangs decided"
-        stamped = _span_stamped_latencies(cache)
-        assert sorted(round(v, 3) for v in sink) == sorted(stamped)
+        _assert_one_cycle_kept_the_summary_of(cache, sink)
         cache.stop()
 
     def test_staged_path_sink_and_spans_agree(self):
@@ -526,8 +536,7 @@ class TestDecisionLatencySink:
         assert min(sink) * 1.0 >= 10.0, (
             "stage-time clock must cover the stage→drain wait"
         )
-        stamped = _span_stamped_latencies(cache)
-        assert sorted(round(v, 3) for v in sink) == sorted(stamped)
+        _assert_one_cycle_kept_the_summary_of(cache, sink)
         cache.stop()
 
     def test_slo_breach_arms_a_flight_dump(self, tmp_path, monkeypatch):
@@ -648,7 +657,8 @@ class TestParkSpans:
         # root spans of the loop thread: on the stage histogram, counted ...
         assert prom.STAGE_LATENCY._count[("park:floor",)] == floors0 + 1
         assert prom.STAGE_LATENCY._count[("park:event",)] == events0 + 2
-        assert tr.span_counts == {"park:floor": 1, "park:event": 2}
+        assert tr.span_counts == {"park:floor": 1, "park:event": 2,
+                                  "between": 2}
         # ... and no record of their own: no implicit one, nothing ringed
         assert tr.current is None and rec.records() == []
         assert tr.cycles_total == 0
@@ -728,8 +738,10 @@ class TestSpanTotals:
             pass
         tr.end_cycle()
         state = tr.state()
-        assert state["span_counts"] == {"child": 1, "root": 2}
-        assert state["span_ms"] == {"child": 1000.0, "root": 4000.0}
+        # the tick between the two roots is the loop's own time, by name
+        assert state["span_counts"] == {"child": 1, "root": 2, "between": 1}
+        assert state["span_ms"] == {"child": 1000.0, "root": 4000.0,
+                                    "between": 1000.0}
 
     def test_disabled_tracer_totals_nothing(self, tmp_path):
         tr = Tracer(enabled=False)
@@ -932,8 +944,13 @@ class TestDecisionParts:
         assert len(sink) == 2
         assert prom.DECISION_LATENCY._count[()] == decided0 + 2
         deciding = cache.flight_recorder.records()[-1]
-        assert sorted(deciding.attrs["decision_lat_ms"]) == sorted(
-            round(v, 3) for v in sink)
+        _assert_one_cycle_kept_the_summary_of(cache, sink, deciding=2)
+        # the first member waited through cycle A; two cycles had started
+        # since it arrived when B bound it (the warm-up's, stamped on the
+        # real clock, may count as a third)
+        assert deciding.decisions["wait_ms"] == pytest.approx(
+            (deciding.t0 - arrival_0) * 1e3, abs=1e-3)
+        assert deciding.decisions["spanned"] in (2, 3)
         # wait = arrival -> the deciding cycle's start, for each pod
         waited = prom.DECISION_QUEUE_WAIT._sum - wait0[0]
         assert prom.DECISION_QUEUE_WAIT._count - wait0[1] == 2
