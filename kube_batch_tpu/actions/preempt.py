@@ -23,7 +23,12 @@ import logging
 from collections import defaultdict
 from typing import Callable, Dict, List, Tuple
 
-from kube_batch_tpu.actions.reclaim import ReplayTally, find_task, solve_claims
+from kube_batch_tpu.actions.reclaim import (
+    ReplayTally,
+    covering_prefix,
+    find_task,
+    solve_claims,
+)
 from kube_batch_tpu.api.task_info import TaskInfo
 from kube_batch_tpu.api.types import PodGroupPhase, TaskStatus
 from kube_batch_tpu.framework.interface import Action
@@ -64,7 +69,7 @@ class PreemptAction(Action):
                 tally.host_rejected += len(job_claims)
                 continue
             stmt = ssn.statement()
-            staged = []  # (task, victims evicted) of this Statement
+            staged = []  # victims evicted for each claim of this Statement
             for task, node_name, victim_refs in job_claims:
                 # host predicate re-check (preempt.go:191), only for
                 # host-only constraints (see allocate replay)
@@ -85,31 +90,21 @@ class PreemptAction(Action):
                 if not victims:
                     tally.host_rejected += 1
                     continue
-                total = ssn.spec.empty()
-                for v in victims:
-                    total.add_(v.resreq)
-                if not task.init_resreq.less_equal(total):
+                # evict lowest-task-order first (preempt.go:219-237), as
+                # many as cover the claimant in every dimension
+                ordered = _lowest_first(ssn, victims)
+                evicted = covering_prefix(task, ordered)
+                if not evicted:
                     tally.uncovered += 1
-                    continue  # victims must cover every dimension
-                # evict lowest-task-order first (preempt.go:219-237)
-                vq = PriorityQueue(less=lambda l, r: not ssn.task_order_fn(l, r))
-                for v in victims:
-                    vq.push(v)
-                preempted = ssn.spec.empty()
-                evicted = 0
-                while vq:
-                    victim = vq.pop()
-                    stmt.evict(victim, "preempt", claimant=task)
-                    evicted += 1
-                    preempted.add_(victim.resreq)
-                    if task.init_resreq.less_equal(preempted):
-                        break
+                    continue
+                stmt.evict_batch(ordered[:evicted], "preempt", claimant=task)
                 stmt.pipeline(task, node_name)
-                staged.append((task, evicted))
+                staged.append(evicted)
             if ssn.job_pipelined(job):
-                stmt.commit()
-                for task, evicted in staged:
-                    tally.commit(task, evicted)
+                stmt.commit()  # its evictions reach the cache in one call
+                tally.commits += bool(staged)
+                for evicted in staged:
+                    tally.commit(evicted)
             else:
                 stmt.discard()
                 tally.host_rejected += len(staged)
@@ -197,22 +192,23 @@ class PreemptAction(Action):
             victims = ssn.preemptable(preemptor, preemptees)
             if not victims:
                 continue
-            total = ssn.spec.empty()
-            for v in victims:
-                total.add_(v.resreq)
-            if not preemptor.init_resreq.less_equal(total):
+            ordered = _lowest_first(ssn, victims)
+            evicted = covering_prefix(preemptor, ordered)
+            if not evicted:
                 continue  # victims must cover every dimension
-            vq = PriorityQueue(less=lambda l, r: not ssn.task_order_fn(l, r))
-            for v in victims:
-                vq.push(v)
-            preempted = ssn.spec.empty()
-            while vq:
-                victim = vq.pop()
-                stmt.evict(victim, "preempt")
-                preempted.add_(victim.resreq)
-                if preemptor.init_resreq.less_equal(preempted):
-                    break
-            if preemptor.init_resreq.less_equal(preempted):
-                stmt.pipeline(preemptor, node.name)
-                return True
+            stmt.evict_batch(ordered[:evicted], "preempt")
+            stmt.pipeline(preemptor, node.name)
+            return True
         return False
+
+
+def _lowest_first(ssn, victims: List[TaskInfo]) -> List[TaskInfo]:
+    """``victims`` in the order preempt evicts them: lowest task order
+    first (preempt.go:219-237)."""
+    vq = PriorityQueue(less=lambda l, r: not ssn.task_order_fn(l, r))
+    for v in victims:
+        vq.push(v)
+    ordered = []
+    while vq:
+        ordered.append(vq.pop())
+    return ordered

@@ -292,12 +292,15 @@ def solve_claims(ssn, mode: str):
 class ReplayTally:
     """What one action's replay made of the solve's claims: the
     ``evict_replay`` span's attributes and ``volcano_evict_claims_total``,
-    set from the same counts when :meth:`replaying` ends."""
+    set from the same counts when :meth:`replaying` ends, and the cache's
+    half of the evictions the session made meanwhile (``pending``), which
+    the cache hears of in one ``bulk_evict`` before the span closes."""
 
     def __init__(self, ssn, mode: str, claims: int):
         self.ssn, self.mode, self.claims = ssn, mode, claims
         self.committed = self.host_rejected = self.uncovered = 0
-        self.victims = 0
+        self.victims = self.commits = 0
+        self.pending: list = []  # [(task, reason, claimant)], claim order
 
     @classmethod
     @contextlib.contextmanager
@@ -307,20 +310,44 @@ class ReplayTally:
 
         tally = cls(ssn, mode, len(claims))
         with tracer_of(ssn.cache).span("evict_replay") as span:
-            yield tally
+            try:
+                yield tally
+            finally:
+                # what the session moved the cache must hear of
+                tally.flush()
             tally.close(span)
 
-    def commit(self, task, n_victims: int) -> None:
+    def commit(self, n_victims: int) -> None:
         self.committed += 1
         self.victims += n_victims
-        self.ssn.cache.note_evict_claim(task.key(), n_victims)
+
+    def flush(self) -> None:
+        """Hand the cache the evictions kept in ``pending``."""
+        if self.pending:
+            items, self.pending = self.pending, []
+            self.commits += 1
+            self.ssn.cache.bulk_evict(items)
 
     def close(self, span) -> None:
         span.set(claims=self.claims, victims=self.victims,
-                 rejected=self.host_rejected + self.uncovered)
+                 rejected=self.host_rejected + self.uncovered,
+                 commits=self.commits)
         for outcome in ("committed", "host_rejected", "uncovered"):
             metrics.register_evict_claims(
                 self.mode, outcome, getattr(self, outcome))
+
+
+def covering_prefix(task, victims: list) -> int:
+    """How many of ``victims``, in the order given, it takes to cover
+    ``task`` in EVERY dimension (reclaim.go:150-163, preempt.go:219-237);
+    0 when all of them together do not."""
+    covered = task.init_resreq.less_equal
+    freed = task.init_resreq.spec.empty()
+    for n, victim in enumerate(victims, 1):
+        freed.add_(victim.resreq)
+        if covered(freed):
+            return n
+    return 0
 
 
 def find_task(ssn, ref: tuple):
@@ -370,25 +397,20 @@ class ReclaimAction(Action):
         if not victims:
             tally.host_rejected += 1
             return
-        total = ssn.spec.empty()
-        for v in victims:
-            total.add_(v.resreq)
         # sufficiency: victims must cover the claimant in EVERY dimension
         # (reclaim.go:150-163) — checked before any eviction happens
-        if not task.init_resreq.less_equal(total):
+        n = covering_prefix(task, victims)
+        if not n:
             logger.info(
                 "reclaim claim %s→%s lost victims to host validation, skipped",
                 claimant_ref, node_name,
             )
             tally.uncovered += 1
             return
-        reclaimed = ssn.spec.empty()
-        evicted = 0
-        for victim in victims:  # immediate evict, no Statement
-            ssn.evict(victim, "reclaim", claimant=task)
-            evicted += 1
-            reclaimed.add_(victim.resreq)
-            if task.init_resreq.less_equal(reclaimed):
-                break
+        # immediate evict, no Statement: the session's ledgers move now (the
+        # next claim's validation reads them), the cache hears at the end
+        # of the action
+        ssn.evict_batch(victims[:n], "reclaim", claimant=task,
+                        later=tally.pending)
         ssn.pipeline(task, node_name)
-        tally.commit(task, evicted)
+        tally.commit(n)

@@ -265,6 +265,30 @@ class NodeInfo:
             self.used.add_(pipe_sum)
             self.releasing.sub_(pipe_sum)
 
+    def bulk_release(self, tasks, resreq_sum) -> None:
+        """Batched update_task for a claim's victims: `tasks` have just gone
+        RELEASING and were accounted here under an AllocatedStatus;
+        `resreq_sum` is the presummed Resource over them.  For such a task
+        remove_task + add_task net to `releasing += r` (idle and used come
+        back where they were), so the group is ONE vector op and one ledger
+        mark; per-task work is the `_acct` stamp and the dict store that
+        makes the moved object the one this node holds (a cloned session's
+        node keeps its own copies).  Any other accounting in the group
+        sends the whole group through update_task, task by task."""
+        acct = self._acct
+        if not all(is_allocated(acct.get(t._key)) for t in tasks):
+            for task in tasks:
+                self.update_task(task)
+            return
+        mine = self.tasks
+        for task in tasks:
+            key = task._key
+            mine[key] = task
+            acct[key] = TaskStatus.RELEASING
+        if self.node is not None:
+            self._note_ledger()
+            self.releasing.add_(resreq_sum)
+
     def bulk_register_tasks(self, alloc_tasks, pipe_tasks) -> None:
         """Task-dict/acct registration ONLY, for the columnar allocate
         replay: the (Idle, Used, Releasing) algebra was already applied to

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import logging
 import threading
+from collections import Counter
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -718,18 +719,12 @@ class SchedulerCache:
             # a real DELETE (not a status replay's or a repair's rebuild):
             # if the pod was a victim, its order has been released
             self._evict_claimants.discard(pod.key())
-            ordered = self._evict_ordered_at.pop(pod.key(), None)
-            if ordered is not None:
-                left = self._evict_in_flight.get(ordered[1], 0) - 1
-                if left > 0:
-                    self._evict_in_flight[ordered[1]] = left
-                else:
-                    self._evict_in_flight.pop(ordered[1], None)
-        if ordered is not None:
+            ordered_at = self._evict_withdrawn_locked(pod.key())
+        if ordered_at is not None:
             from kube_batch_tpu import metrics
 
             metrics.observe_eviction_release_latency(
-                (telemetry.perf_counter() - ordered[0]) * 1e3)
+                (telemetry.perf_counter() - ordered_at) * 1e3)
 
     def _delete_pod_locked(self, pod: Pod, retire_placeholder: bool = True,
                            forget_resync: bool = True) -> None:
@@ -1386,72 +1381,115 @@ class SchedulerCache:
                 f for f in self._dispatch_futures if not f.done()
             ]
 
-    def note_evict_claim(self, claimant_key: str, n_victims: int) -> None:
-        """A claim of ``claimant_key`` was committed this cycle (its
-        ``n_victims`` victims are evicted, it is pipelined).  A claimant
-        that was given victims in an earlier cycle already is counted on
-        ``volcano_evict_repeat_claims_total{earlier}``: ``in_flight`` while
-        a victim of the earlier claim is still to be deleted (an eviction
-        ordered again while the first was in flight), ``released`` once
-        they all went (the room they left went to another pod)."""
-        from kube_batch_tpu import metrics
-
-        with self._lock:
-            seen = claimant_key in self._evict_claimants
-            self._evict_claimants.add(claimant_key)
-            earlier = self._evict_in_flight.get(claimant_key, 0) - n_victims
-        if seen:
-            metrics.register_evict_repeat_claim(
-                "in_flight" if earlier > 0 else "released")
-
     def evict(self, task: TaskInfo, reason: str,
               claimant: Optional[TaskInfo] = None) -> None:
         """(cache.go:404-444)  ``reason`` is the action that ordered it,
-        ``claimant`` the task it makes room for."""
-        with self._lock:
-            if not self._session_active:
-                own = self._own_task(task)
-                if own is not None:
-                    job = self.jobs[task.job]
-                    job.update_task_status(own, TaskStatus.RELEASING)
-                    node = self.nodes.get(own.node_name) if own.node_name else None
-                    if node is not None:
-                        node.update_task(own)
-            # exclusive session: the Statement already moved this very task
-            # to Releasing and re-accounted its node; re-applying here would
-            # double-charge (the session may since have pipelined a
-            # preemptor onto the freed Releasing budget)
-            pod = self.pods.get(task.key())
-        try:
-            if pod is not None:
-                self.evictor.evict(pod)
-                self.events.append(("Evict", task.key(), reason))
-                self._note_eviction(task, reason, claimant)
-        except CircuitOpenError:
-            logger.warning("evict of %s parked: egress breaker open",
-                           task.key())
-            self.resync_task(task, reason="breaker-open")
-        except Exception as e:  # noqa: BLE001
-            logger.error("evict of %s failed: %s", task.key(), e)
-            self.resync_task(task)
+        ``claimant`` the task it makes room for.  The batch of one."""
+        self._evict_many([(task, reason, claimant)], "single")
 
-    def _note_eviction(self, task: TaskInfo, action: str,
-                       claimant: Optional[TaskInfo]) -> None:
-        """An eviction went out: count it, start its release clock, and
-        append it to the standalone feed if there is one."""
+    def bulk_evict(self, items) -> None:
+        """evict() for a batch, ``[(task, reason, claimant)]`` in the order
+        the evictions were decided (reclaim's replay hands over an action's,
+        a Statement its own on commit): ONE acquisition of the lock, one
+        append to the events, the counters and the feed; per-task semantics
+        are evict()'s, a failed evictor call parks that task alone."""
+        self._evict_many(items, "bulk")
+
+    def _evict_many(self, items, path: str) -> None:
         from kube_batch_tpu import metrics
 
-        metrics.register_eviction(action)
-        whose = claimant.key() if claimant is not None else ""
+        if not items:
+            return
+        now = telemetry.perf_counter()
+        repeats = []
         with self._lock:
-            if task.key() not in self._evict_ordered_at:
-                self._evict_ordered_at[task.key()] = (
-                    telemetry.perf_counter(), whose)
-                self._evict_in_flight[whose] = (
-                    self._evict_in_flight.get(whose, 0) + 1)
+            if not self._session_active:
+                for task, _, _ in items:
+                    own = self._own_task(task)
+                    if own is not None:
+                        job = self.jobs[task.job]
+                        job.update_task_status(own, TaskStatus.RELEASING)
+                        node = (self.nodes.get(own.node_name)
+                                if own.node_name else None)
+                        if node is not None:
+                            node.update_task(own)
+            # exclusive session: the session already moved these very tasks
+            # to Releasing and re-accounted their nodes; re-applying here
+            # would double-charge (the session may since have pipelined a
+            # claimant onto the freed Releasing budget)
+            pods_get = self.pods.get
+            ordered_at = self._evict_ordered_at
+            in_flight = self._evict_in_flight
+            claimants = self._evict_claimants
+            # a claimant given victims in an earlier cycle already is a
+            # repeat: read what is in flight for it BEFORE this batch adds
+            for whose in {c._key for _, _, c in items if c is not None}:
+                if whose in claimants:
+                    repeats.append(
+                        "in_flight" if in_flight.get(whose, 0) > 0
+                        else "released")
+                claimants.add(whose)
+            staged = []
+            for task, reason, claimant in items:
+                key = task._key
+                pod = pods_get(key)
+                if pod is None:
+                    continue
+                whose = claimant._key if claimant is not None else ""
+                # the release clock and the in-flight count start with the
+                # order; a failed evictor call takes its own back below
+                fresh = key not in ordered_at
+                if fresh:
+                    ordered_at[key] = (now, whose)
+                    in_flight[whose] = in_flight.get(whose, 0) + 1
+                staged.append((task, reason, whose, pod, fresh))
+        for earlier in repeats:
+            metrics.register_evict_repeat_claim(earlier)
+        metrics.register_evict_commit(items[0][1], path)
+        evict = self.evictor.evict
+        done = []
+        for entry in staged:
+            task, _, _, pod, fresh = entry
+            try:
+                evict(pod)
+            except CircuitOpenError:
+                logger.warning("evict of %s parked: egress breaker open",
+                               task.key())
+                parked_for = "breaker-open"
+            except Exception as e:  # noqa: BLE001
+                logger.error("evict of %s failed: %s", task.key(), e)
+                parked_for = "error"
+            else:
+                done.append(entry)
+                continue
+            if fresh:
+                with self._lock:
+                    self._evict_withdrawn_locked(task._key)
+            self.resync_task(task, reason=parked_for)
+        if not done:
+            return
+        self.events.extend(
+            [("Evict", task._key, reason) for task, reason, _, _, _ in done])
+        for action, n in Counter(entry[1] for entry in done).items():
+            metrics.register_eviction(action, n)
         log = self.eviction_log
         if log is not None:
-            log.record(task.key(), task.node_name or "", action, whose)
+            log.record_many(
+                [(task._key, task.node_name or "", reason, whose)
+                 for task, reason, whose, _, _ in done])
+
+    def _evict_withdrawn_locked(self, key: str) -> Optional[float]:
+        """Take ``key``'s eviction out of flight (its DELETE drained, or
+        the order never went out); when it was ordered, if it was."""
+        ordered = self._evict_ordered_at.pop(key, None)
+        if ordered is None:
+            return None
+        left = self._evict_in_flight.get(ordered[1], 0) - 1
+        if left > 0:
+            self._evict_in_flight[ordered[1]] = left
+        else:
+            self._evict_in_flight.pop(ordered[1], None)
+        return ordered[0]
 
     # volume seams (cache.go:189-209; real ledger in cache/volume.py,
     # no-op fake by default)

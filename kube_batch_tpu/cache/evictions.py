@@ -10,8 +10,8 @@ kubelet's) act and arrives as the ordinary ``DELETE /v1/pods``; this log
 is how the client learns which pods to take away, in the shape of
 ``/v1/replicate?since=N``: a sequence number a client resumes from.
 
-One writer at a time (``record`` takes the log's own lock; the cache calls
-it from ``evict``), any number of readers, none of which takes a lock: a
+One writer at a time (``record_many`` takes the log's own lock; the cache
+calls it once a batch of evictions), any number of readers, none of which takes a lock: a
 reader copies the slots between its cursor and the sequence number it read
 first, and keeps those whose own number says they were not overwritten
 meanwhile.
@@ -47,12 +47,22 @@ class EvictionLog:
 
     def record(self, pod: str, node: str, action: str, claimant: str) -> int:
         """Append one ordered eviction; returns its sequence number."""
+        return self.record_many([(pod, node, action, claimant)])
+
+    def record_many(self, entries) -> int:
+        """Append ordered evictions, ``[(pod, node, action, claimant)]``, in
+        the order given, under one acquisition of the log's lock; returns
+        the first's sequence number.  ``_next`` moves an entry at a time,
+        after the slot is written: a reader sees what a ``record`` a piece
+        would have shown it, whole entries and no gap."""
         with self._mu:
-            seq = self._next
-            self._ring[seq % self.capacity] = (
-                seq, pod, node, action, claimant)
-            self._next = seq + 1
-        return seq
+            first = seq = self._next
+            ring, cap = self._ring, self.capacity
+            for pod, node, action, claimant in entries:
+                ring[seq % cap] = (seq, pod, node, action, claimant)
+                seq += 1
+                self._next = seq
+        return first
 
     def since(self, since: int, page: int = PAGE) -> dict:
         """The entries numbered ``since`` and later, oldest first, ``page``

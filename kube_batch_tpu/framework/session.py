@@ -24,6 +24,7 @@ from kube_batch_tpu.api.pod import PodGroupCondition
 from kube_batch_tpu.api.queue_info import QueueInfo
 from kube_batch_tpu.api.task_info import TaskInfo
 from kube_batch_tpu.api.types import (
+    ALLOCATED_STATUSES,
     PodGroupPhase,
     TaskStatus,
     queue_phase_counts,
@@ -75,14 +76,21 @@ class EventHandler:
     one call per replay with the [capJ, R] per-job-row resreq sums (zeros for
     untouched jobs).  The columnar allocate replay requires every handler
     with allocate-side effects to provide it (actions/allocate.py gates on
-    that), so no handler can silently miss events."""
+    that), so no handler can silently miss events.
+
+    `batch_deallocate_func(job, tasks, total_resreq)` is the mirror of
+    `batch_allocate_func` for the evict verbs: one call per job of a claim's
+    victims with their resreq presummed.  Handlers without it are fired per
+    task (`Session.fire_batch_deallocations`)."""
 
     def __init__(self, allocate_func=None, deallocate_func=None,
-                 batch_allocate_func=None, columnar_allocate_func=None):
+                 batch_allocate_func=None, columnar_allocate_func=None,
+                 batch_deallocate_func=None):
         self.allocate_func = allocate_func
         self.deallocate_func = deallocate_func
         self.batch_allocate_func = batch_allocate_func
         self.columnar_allocate_func = columnar_allocate_func
+        self.batch_deallocate_func = batch_deallocate_func
 
 
 class FitFailure(Exception):
@@ -445,6 +453,15 @@ class Session:
                 for t in tasks:
                     eh.allocate_func(Event(t))
 
+    def fire_batch_deallocations(self, job: JobInfo, tasks, total_resreq) -> None:
+        """The deallocate twin of :meth:`fire_batch_allocations`."""
+        for eh in self.event_handlers:
+            if eh.batch_deallocate_func is not None:
+                eh.batch_deallocate_func(job, tasks, total_resreq)
+            elif eh.deallocate_func is not None:
+                for t in tasks:
+                    eh.deallocate_func(Event(t))
+
     def fire_columnar_allocations(self, cols, job_sums) -> None:
         """One vectorized allocate-event pass for the whole replay
         (job_sums: [capJ, R] per-job-row resreq sums)."""
@@ -496,16 +513,89 @@ class Session:
         if job is not None:
             job.update_task_status(task, TaskStatus.BINDING)
 
+    def _release(self, victims) -> List[TaskInfo]:
+        """The session's half of an eviction, for ``victims`` as a group:
+        the victims of one job go RELEASING in one ``bulk_transition``,
+        those on one node in one ``bulk_release``, and every handler hears
+        of a job's victims once, each with the group's resreq presummed.
+        The end state is that of the per-victim verbs (``update_task_status``,
+        ``update_task`` and a deallocate event a victim).
+
+        What moves is the session's RESIDENT task of each victim's key: a
+        victim handed in as a copy (the evict actions validate clones) only
+        names it.  Returns the residents, in the order given."""
+        jobs_get = self.jobs.get
+        by_job: Dict[str, tuple] = {}  # uid -> (job, its victims)
+        by_node: Dict[Optional[str], list] = {}
+        moved = []
+        for v in victims:
+            job = jobs_get(v.job)
+            if job is None:
+                # not this session's to move (the per-victim verbs left its
+                # ledgers where they were too); the handlers still hear
+                self._fire(False, v)
+                moved.append(v)
+                continue
+            own = job.tasks.get(v._key)
+            if own is None:
+                job.add_task(v)  # not resident yet: from here on it is
+                own = v
+            moved.append(own)
+            group = by_job.get(v.job)
+            if group is None:
+                by_job[v.job] = (job, [own])
+            else:
+                group[1].append(own)
+            group = by_node.get(own.node_name)
+            if group is None:
+                by_node[own.node_name] = [own]
+            else:
+                group.append(own)
+        for job, tasks in by_job.values():
+            total = self._resreq_sum(tasks)
+            flip = [t for t in tasks if t.status in ALLOCATED_STATUSES]
+            if len(flip) == len(tasks):
+                job.bulk_transition(tasks, TaskStatus.RELEASING, total)
+            else:  # bulk_transition wants one allocated-ness flip a call
+                rest = [t for t in tasks if t.status not in ALLOCATED_STATUSES]
+                job.bulk_transition(flip, TaskStatus.RELEASING,
+                                    self._resreq_sum(flip))
+                job.bulk_transition(rest, TaskStatus.RELEASING, None)
+            self.fire_batch_deallocations(job, tasks, total)
+        for name, tasks in by_node.items():
+            node = self.nodes.get(name)
+            if node is not None:
+                node.bulk_release(tasks, self._resreq_sum(tasks))
+        return moved
+
+    def _resreq_sum(self, tasks):
+        """The summed resreq of ``tasks``, to read and not to write: a
+        single task's own Resource stands for its sum."""
+        if len(tasks) == 1:
+            return tasks[0].resreq
+        total = self.spec.empty()
+        for t in tasks:
+            total.add_(t.resreq)
+        return total
+
+    def evict_batch(self, victims, reason: str,
+                    claimant: Optional[TaskInfo] = None,
+                    later: Optional[list] = None) -> None:
+        """Evict ``victims`` (one claim's) for ``claimant``: the session's
+        ledgers move once (:meth:`_release`), and the cache is told in one
+        ``bulk_evict``: at once, or by whoever owns ``later`` when it is
+        given (the cache's half is appended there; reclaim's replay hands a
+        whole action's over when it ends)."""
+        items = [(t, reason, claimant) for t in self._release(victims)]
+        if later is not None:
+            later.extend(items)
+        else:
+            self.cache.bulk_evict(items)
+
     def evict(self, task: TaskInfo, reason: str,
               claimant: Optional[TaskInfo] = None) -> None:
-        self.cache.evict(task, reason, claimant)
-        job = self.jobs.get(task.job)
-        if job is not None:
-            job.update_task_status(task, TaskStatus.RELEASING)
-        node = self.nodes.get(task.node_name)
-        if node is not None:
-            node.update_task(task)
-        self._fire(False, task)
+        """The batch of one."""
+        self.cache.evict(self._release([task])[0], reason, claimant)
 
     def statement(self) -> "Statement":
         return Statement(self)
@@ -543,16 +633,16 @@ class Statement:
         self.operations: List[Tuple[str, tuple]] = []
 
     # -- session-visible verbs -------------------------------------------
+    def evict_batch(self, victims, reason: str,
+                    claimant: Optional[TaskInfo] = None) -> None:
+        """``Session.evict_batch`` with the cache's half kept for
+        :meth:`commit`; the operations hold the resident tasks that moved."""
+        for own in self.ssn._release(victims):
+            self.operations.append(("evict", (own, reason, claimant)))
+
     def evict(self, reclaimee: TaskInfo, reason: str,
               claimant: Optional[TaskInfo] = None) -> None:
-        job = self.ssn.jobs.get(reclaimee.job)
-        if job is not None:
-            job.update_task_status(reclaimee, TaskStatus.RELEASING)
-        node = self.ssn.nodes.get(reclaimee.node_name)
-        if node is not None:
-            node.update_task(reclaimee)
-        self.ssn._fire(False, reclaimee)
-        self.operations.append(("evict", (reclaimee, reason, claimant)))
+        self.evict_batch([reclaimee], reason, claimant)
 
     def pipeline(self, task: TaskInfo, hostname: str) -> None:
         job = self.ssn.jobs.get(task.job)
@@ -597,18 +687,25 @@ class Statement:
                         job.update_task_status(task, TaskStatus.BINDING)
             self.operations = []
             return
+        cache = self.ssn.cache
+        evictions: list = []  # consecutive evicts reach the cache in one call
         for name, args in self.operations:
             if name == "evict":
-                self.ssn.cache.evict(*args)
+                evictions.append(args)
             elif name == "pipeline":
                 pass  # session-only state (statement.go pipeline no-ops on commit)
             elif name == "allocate":
+                if evictions:
+                    cache.bulk_evict(evictions)
+                    evictions = []
                 task, _ = args
                 self.ssn.cache.bind_volumes(task)
                 self.ssn.cache.bind(task, task.node_name)
                 job = self.ssn.jobs.get(task.job)
                 if job is not None:
                     job.update_task_status(task, TaskStatus.BINDING)
+        if evictions:
+            cache.bulk_evict(evictions)
         self.operations = []
 
     def discard(self) -> None:

@@ -545,6 +545,14 @@ EVICT_CLAIMS = Counter(
     "plus what that node's releasing victims have promised)",
     ("action", "outcome"),
 )
+EVICT_COMMITS = Counter(
+    f"{_SUBSYSTEM}_evict_commits_total",
+    "Batches of evictions handed to the cache, by the action that ordered "
+    "them and the verb (bulk: bulk_evict, once an action of reclaim's "
+    "replay and once a committed Statement of preempt's | single: evict, "
+    "one task from outside a replay)",
+    ("action", "path"),
+)
 EVICT_SOLVE_COMPACTED = Counter(
     f"{_SUBSYSTEM}_evict_solve_compacted_total",
     "Evict solve dispatches, by action and whether the bids ran on the "
@@ -628,6 +636,8 @@ for _action in ("reclaim", "preempt"):
         EVICT_CLAIMS.add(0.0, _action, _outcome)
     for _compacted in ("true", "false"):
         EVICT_SOLVE_COMPACTED.add(0.0, _action, _compacted)
+    for _path in ("bulk", "single"):
+        EVICT_COMMITS.add(0.0, _action, _path)
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
@@ -706,6 +716,7 @@ METRICS = [
     DEVICE_PEAK_BYTES,
     EVICTIONS,
     EVICT_CLAIMS,
+    EVICT_COMMITS,
     EVICT_SOLVE_COMPACTED,
     EVICTION_RELEASE_LATENCY,
     EVICT_REPEAT_CLAIMS,
@@ -956,8 +967,12 @@ def register_topk_fallbacks(action: str, exhausted: int,
     TOPK_REENTRIES.add(reentries, action)
 
 
-def register_eviction(action: str) -> None:
-    EVICTIONS.inc(action)
+def register_eviction(action: str, n: int = 1) -> None:
+    EVICTIONS.add(n, action)
+
+
+def register_evict_commit(action: str, path: str) -> None:
+    EVICT_COMMITS.inc(action, path)
 
 
 def register_evict_claims(action: str, outcome: str, n: int) -> None:

@@ -392,42 +392,10 @@ def gates_on(config: EvictConfig) -> bool:
         or config.releasing_gate
 
 
-def evict_rounds(
-    snap: DeviceSnapshot,
-    config: EvictConfig,
-    bids_fn,
-    gate_room=None,
-    n_nodes=None,
-    claimant_mask=None,
-    pend_rows=None,
-) -> EvictResult:
-    """The eviction machinery shared by every solve path: victim/claimant
-    eligibility, ranks, winner-per-node selection, victim picking, global
-    caps, coverage, and the commit gate — everything that reads only the
-    task/job/queue-axis vectors (replicated under shard_map).  The [C, N]-
-    scale bids come from ``bids_fn``; ``gate_room`` ([C, 2]) is the
-    claimant gates' probe (:func:`gate_room_local` summed over the node
-    shards; required iff :func:`gates_on`).  The claimant axis C is the
-    task axis, or the pending bucket where ``pend_rows`` ([P] i32 global
-    task rows in ascending order, -1 padding, covering EVERY pending row)
-    is given: eligibility is computed on [T] and gathered to the bucket,
-    the claimants' virtual rank, the bid and the winner selection run on
-    the bucket, a winner's bucket slot is mapped back to its task row
-    before it indexes anything, and the probe and the claims are scattered
-    to [T] — so the result, and all the victim machinery, is on the task
-    axis whichever axis the bids ran on, and is the same result (a row
-    that is not pending can never bid).
-    ``n_nodes`` overrides the GLOBAL node count when ``snap``'s node arrays
-    are shard-local blocks (the shard_map body).  ``claimant_mask`` ([T]
-    bool) restricts claimants beyond the standard eligibility — callers
-    probing a SUBSET of the pending work (a single job's what-if, a drained
-    queue) share this machinery instead of forking it."""
-    T, R = snap.task_req.shape
-    N = n_nodes if n_nodes is not None else snap.node_alloc.shape[0]
-    J = snap.job_min_avail.shape[0]
-    Q = snap.queue_weight.shape[0]
-    preempt = config.mode == "preempt"
-
+def _claimant_axis(pend_rows, T: int):
+    """(to_claimants, to_tasks): between the task axis and the claimant
+    axis, which is the task axis itself (``pend_rows`` None) or the pending
+    bucket."""
     if pend_rows is None:
         def to_claimants(x):
             return x
@@ -452,19 +420,61 @@ def evict_rounds(
             buf = jnp.full((T + 1,) + x.shape[1:], fill, x.dtype)
             return buf.at[scat].set(x)[:T]
 
-    task_queue = snap.job_queue[snap.task_job]                      # [T]
-    running = victim_running(snap)
-    subrank = ordering.task_subranks(snap.task_prio, snap.task_creation)
-    # victims pop in reverse task order (!TaskOrderFn, preempt.go:219-224)
-    victim_rank = ordering.multisort_ranks([snap.task_prio, -snap.task_creation])
+    return to_claimants, to_tasks
 
-    deserved = fairness.proportion_deserved(
-        snap.total, snap.queue_weight, snap.queue_request, snap.queue_valid
-    )
-    slack0 = gang_slack0(snap, config)
-    # proportion budget: resource a queue can lose while staying ≥ deserved
-    qbudget0 = jnp.maximum(snap.queue_alloc - deserved, 0.0)        # [Q, R]
 
+def any_claimant(claimant_base) -> jnp.ndarray:
+    """[] bool — somebody is left to bid once the gates have counted: the
+    predicate of :func:`evict_rounds`' one branch."""
+    return jnp.any(claimant_base)
+
+
+def evict_rounds(
+    snap: DeviceSnapshot,
+    config: EvictConfig,
+    make_bids,
+    gate_room=None,
+    n_nodes=None,
+    claimant_mask=None,
+    pend_rows=None,
+) -> EvictResult:
+    """The eviction machinery shared by every solve path: victim/claimant
+    eligibility, ranks, winner-per-node selection, victim picking, global
+    caps, coverage, and the commit gate — everything that reads only the
+    task/job/queue-axis vectors (replicated under shard_map).  The [C, N]-
+    scale bids come from the ``bids(victim_ok, claimant_ok)`` that
+    ``make_bids()`` builds; ``gate_room`` ([C, 2]) is the
+    claimant gates' probe (:func:`gate_room_local` summed over the node
+    shards; required iff :func:`gates_on`).
+
+    A solve whose gates leave no claimant ENDS AT THE GATES: everything
+    below them (the bids head, the victims' and the claimants' ranks, the
+    fairness setup, the rounds, the commit gate) is one branch of a
+    ``lax.cond`` on :func:`any_claimant`, and the other returns the empty
+    result the rounds would have returned, with ``rounds_run`` 0: no round
+    ran.  The predicate is the program's own gate, computed on the device
+    from its input, so the result is the same by construction; the builder
+    is called inside the branch so that its planes are not built above it.
+
+    The claimant axis C is the
+    task axis, or the pending bucket where ``pend_rows`` ([P] i32 global
+    task rows in ascending order, -1 padding, covering EVERY pending row)
+    is given: eligibility is computed on [T] and gathered to the bucket,
+    the claimants' virtual rank, the bid and the winner selection run on
+    the bucket, a winner's bucket slot is mapped back to its task row
+    before it indexes anything, and the probe and the claims are scattered
+    to [T] — so the result, and all the victim machinery, is on the task
+    axis whichever axis the bids ran on, and is the same result (a row
+    that is not pending can never bid).
+    ``n_nodes`` overrides the GLOBAL node count when ``snap``'s node arrays
+    are shard-local blocks (the shard_map body).  ``claimant_mask`` ([T]
+    bool) restricts claimants beyond the standard eligibility — callers
+    probing a SUBSET of the pending work (a single job's what-if, a drained
+    queue) share this machinery instead of forking it."""
+    T = snap.task_req.shape[0]
+    N = n_nodes if n_nodes is not None else snap.node_alloc.shape[0]
+    _, to_tasks = _claimant_axis(pend_rows, T)
+    subrank = None  # the gates' ranking reads it before anything bids
     claimant_base = (
         snap.task_pending
         & snap.task_valid
@@ -504,6 +514,7 @@ def evict_rounds(
         gate_room = to_tasks(gate_room, 0)
         room = gate_room[:, 1]
         cand = claimant_base & (room > 0) & ~snap.task_needs_host
+        subrank = ordering.task_subranks(snap.task_prio, snap.task_creation)
         # most constrained first: a node with room for a large claimant
         # has room for a smaller one, so the candidates with the least room
         # are counted against it before those that could go elsewhere
@@ -515,6 +526,50 @@ def evict_rounds(
             gated & (ahead >= gate_room[:, 0])).astype(jnp.int32)
     else:
         gated_releasing = jnp.int32(0)
+
+    # the gates are the program's own: where they leave no claimant (and
+    # where nothing was pending to begin with) the result is known here,
+    # and it is the rounds' initial state, less the round that finds that
+    # out: no round ran
+    claim_node, evicted, victim_claimant, rounds_run = jax.lax.cond(
+        any_claimant(claimant_base),
+        lambda: _bid_rounds(snap, config, make_bids, claimant_base, subrank,
+                            N, pend_rows),
+        lambda: (jnp.full(T, -1, jnp.int32), jnp.zeros(T, bool),
+                 jnp.full(T, -1, jnp.int32), jnp.int32(0)),
+    )
+    return EvictResult(
+        claim_node=claim_node, evicted=evicted,
+        victim_claimant=victim_claimant, rounds_run=rounds_run,
+        gated_releasing=gated_releasing,
+    )
+
+
+def _bid_rounds(snap: DeviceSnapshot, config: EvictConfig, make_bids,
+                claimant_base, subrank, N: int, pend_rows):
+    """The taken branch of :func:`evict_rounds`: the bidding rounds and the
+    commit gate, with everything only they read (the bids head, the
+    victims' and the claimants' ranks, the fairness setup) built HERE, so
+    that none of it is computed for a solve that ends at its gates.
+    ``subrank`` is the task axis' subrank where the gates ranked with it,
+    else None.  Returns (claim_node, evicted, victim_claimant, rounds_run)."""
+    T, R = snap.task_req.shape
+    J = snap.job_min_avail.shape[0]
+    Q = snap.queue_weight.shape[0]
+    preempt = config.mode == "preempt"
+    to_claimants, to_tasks = _claimant_axis(pend_rows, T)
+    bids_fn = make_bids()
+    task_queue = snap.job_queue[snap.task_job]                      # [T]
+    running = victim_running(snap)
+    # victims pop in reverse task order (!TaskOrderFn, preempt.go:219-224)
+    victim_rank = ordering.multisort_ranks([snap.task_prio, -snap.task_creation])
+
+    deserved = fairness.proportion_deserved(
+        snap.total, snap.queue_weight, snap.queue_request, snap.queue_valid
+    )
+    slack0 = gang_slack0(snap, config)
+    # proportion budget: resource a queue can lose while staying ≥ deserved
+    qbudget0 = jnp.maximum(snap.queue_alloc - deserved, 0.0)        # [Q, R]
 
     # the claimants' side of the rank, on the claimant axis: on the bucket
     # the virtual order is computed among the bucket's rows alone (a third
@@ -530,7 +585,10 @@ def evict_rounds(
     c_resreq = to_claimants(snap.task_resreq)
     c_job = to_claimants(snap.task_job)
     c_queue = to_claimants(task_queue)
-    c_subrank = subrank if pend_rows is None else ordering.task_subranks(
+    # (the task axis' own subrank is the gates' where they ranked)
+    c_subrank = subrank if (
+        pend_rows is None and subrank is not None
+    ) else ordering.task_subranks(
         to_claimants(snap.task_prio), to_claimants(snap.task_creation))
 
     def round_body(state):
@@ -705,11 +763,7 @@ def evict_rounds(
         evicted &= ~victim_revert
         victim_claimant = jnp.where(victim_revert, -1, victim_claimant)
 
-    return EvictResult(
-        claim_node=claim_node, evicted=evicted,
-        victim_claimant=victim_claimant, rounds_run=rounds_run,
-        gated_releasing=gated_releasing,
-    )
+    return claim_node, evicted, victim_claimant, rounds_run
 
 
 @partial(jax.jit, static_argnames=("config",))
@@ -725,5 +779,5 @@ def evict_solve(snap: DeviceSnapshot, config: EvictConfig,
         room = gate_room_local(
             view.task_req, static_predicates(view), snap, config)
     return evict_rounds(
-        snap, config, local_evict_bids(snap, config, pend_rows, view), room,
-        pend_rows=pend_rows)
+        snap, config, partial(local_evict_bids, snap, config, pend_rows, view),
+        room, pend_rows=pend_rows)

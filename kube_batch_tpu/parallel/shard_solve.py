@@ -554,67 +554,75 @@ def _evict_body(snap, *, config, node_shards, task_shards):
         else 0
     )
     view = _block_view(snap, t0, T_blk, task_shards)
-    static_ok = static_predicates(view)
-    score = score_matrix(view, config.weights)
-    tie_blk = asg._tie_break_hash(T_blk, N_loc, t0=t0, n0=n0)
-    task_queue = snap.job_queue[snap.task_job]          # [T] replicated
-    tq_blk = view.job_queue[view.task_job]              # [T_blk]
 
-    def tslice(x):
-        if task_shards == 1:
-            return x
-        return jax.lax.dynamic_slice_in_dim(x, t0, T_blk, axis=0)
+    def make_bids():
+        # the block head, built where evict_rounds calls for it: inside the
+        # branch a solve that ends at its gates does not take (the
+        # predicate is replicated, so every shard takes the same one)
+        static_ok = static_predicates(view)
+        score = score_matrix(view, config.weights)
+        tie_blk = asg._tie_break_hash(T_blk, N_loc, t0=t0, n0=n0)
+        task_queue = snap.job_queue[snap.task_job]          # [T] replicated
+        tq_blk = view.job_queue[view.task_job]              # [T_blk]
 
-    def bids(victim_ok, claimant_ok):
-        # ---- per-(queue, local-node) evictable capacity --------------
-        # built from the REPLICATED task vectors, restricted to victims
-        # resident on this shard's nodes: same values in the same task
-        # order per (queue, node) cell as the global scatter
-        vreq = jnp.where(victim_ok[:, None], snap.task_resreq, 0.0)
-        vnode_l = snap.task_node - n0
-        in_shard = (vnode_l >= 0) & (vnode_l < N_loc)
-        vreq_l = jnp.where(in_shard[:, None], vreq, 0.0)
-        tot_v = jax.ops.segment_sum(
-            vreq_l,
-            jnp.where(victim_ok & in_shard, vnode_l, N_loc),
-            num_segments=N_loc + 1,
-        )[:N_loc]                                        # [N_loc, R]
-        per_qn = jnp.zeros((Q, N_loc, R), jnp.float32).at[
-            task_queue, jnp.clip(vnode_l, 0, N_loc - 1)
-        ].add(vreq_l)
-        if preempt:
-            cap = per_qn                  # same-queue victims
-        else:
-            cap = tot_v[None] - per_qn    # cross-queue victims
+        def tslice(x):
+            if task_shards == 1:
+                return x
+            return jax.lax.dynamic_slice_in_dim(x, t0, T_blk, axis=0)
 
-        # ---- block bids (one-hot queue gather, exact f32 matmul) -----
-        co_b = tslice(claimant_ok)
-        onehot_q = (tq_blk[:, None] == jnp.arange(Q)[None, :]).astype(
-            jnp.float32
-        )
-        feas = static_ok & co_b[:, None]
-        feas &= ((tq_blk >= 0) & (tq_blk < Q))[:, None]
-        for r in range(R):
-            # kbt: allow[KBT005] trace-time unroll over the small static
-            # resource dim R inside jit (same rationale as the single path)
-            cap_tr = jnp.matmul(
-                onehot_q, cap[:, :, r], precision=jax.lax.Precision.HIGHEST
-            )                                            # [T_blk, N_loc]
-            feas &= view.task_req[:, r, None] <= cap_tr + snap.quanta[r]
-        masked = jnp.where(feas, score, NEG)
-        lval, lkey, _pick, lidx = _local_best(masked, tie_blk, n0)
-        vmax, best_b = _combine_best(lval, lkey, lidx)
-        best = _gather_tasks(best_b, task_shards)
-        has = _gather_tasks(vmax > NEG, task_shards)
-        return best, has
+        def bids(victim_ok, claimant_ok):
+            # ---- per-(queue, local-node) evictable capacity --------------
+            # built from the REPLICATED task vectors, restricted to victims
+            # resident on this shard's nodes: same values in the same task
+            # order per (queue, node) cell as the global scatter
+            vreq = jnp.where(victim_ok[:, None], snap.task_resreq, 0.0)
+            vnode_l = snap.task_node - n0
+            in_shard = (vnode_l >= 0) & (vnode_l < N_loc)
+            vreq_l = jnp.where(in_shard[:, None], vreq, 0.0)
+            tot_v = jax.ops.segment_sum(
+                vreq_l,
+                jnp.where(victim_ok & in_shard, vnode_l, N_loc),
+                num_segments=N_loc + 1,
+            )[:N_loc]                                        # [N_loc, R]
+            per_qn = jnp.zeros((Q, N_loc, R), jnp.float32).at[
+                task_queue, jnp.clip(vnode_l, 0, N_loc - 1)
+            ].add(vreq_l)
+            if preempt:
+                cap = per_qn                  # same-queue victims
+            else:
+                cap = tot_v[None] - per_qn    # cross-queue victims
+
+            # ---- block bids (one-hot queue gather, exact f32 matmul) -----
+            co_b = tslice(claimant_ok)
+            onehot_q = (tq_blk[:, None] == jnp.arange(Q)[None, :]).astype(
+                jnp.float32
+            )
+            feas = static_ok & co_b[:, None]
+            feas &= ((tq_blk >= 0) & (tq_blk < Q))[:, None]
+            for r in range(R):
+                # kbt: allow[KBT005] trace-time unroll over the small static
+                # resource dim R inside jit (same rationale as the single path)
+                cap_tr = jnp.matmul(
+                    onehot_q, cap[:, :, r], precision=jax.lax.Precision.HIGHEST
+                )                                            # [T_blk, N_loc]
+                feas &= view.task_req[:, r, None] <= cap_tr + snap.quanta[r]
+            masked = jnp.where(feas, score, NEG)
+            lval, lkey, _pick, lidx = _local_best(masked, tie_blk, n0)
+            vmax, best_b = _combine_best(lval, lkey, lidx)
+            best = _gather_tasks(best_b, task_shards)
+            has = _gather_tasks(vmax > NEG, task_shards)
+            return best, has
+
+        return bids
 
     room = None
     if evi.gates_on(config):
-        room_l = evi.gate_room_local(view.task_req, static_ok, snap, config)
+        room_l = evi.gate_room_local(
+            view.task_req, static_predicates(view), snap, config)
         with jax.named_scope("xchip_any_bid"):
             room_g = jax.lax.psum(room_l, NODE_AXIS)
         room = _gather_tasks(room_g, task_shards)
-    return evi.evict_rounds(snap, config, bids, room, n_nodes=N)
+    return evi.evict_rounds(snap, config, make_bids, room, n_nodes=N)
 
 
 # --------------------------------------------------------------------------

@@ -139,6 +139,13 @@ class DrfPlugin(Plugin):
                 attr.allocated.add_(total_resreq)
                 attr._dirty = True
 
+        def on_batch_deallocate(job: JobInfo, tasks, total_resreq) -> None:
+            # the evict verbs' mirror: one presummed sub per job of a claim
+            attr = attr_for(job.uid)
+            if attr is not None:
+                attr.allocated.sub_(total_resreq)
+                attr._dirty = True
+
         def on_columnar_allocate(cols, job_sums) -> None:
             # one matrix add for the whole replay ≡ 12.5k batch events
             self._arr += job_sums
@@ -153,6 +160,7 @@ class DrfPlugin(Plugin):
                 columnar_allocate_func=(
                     on_columnar_allocate if self._arr is not None else None
                 ),
+                batch_deallocate_func=on_batch_deallocate,
             )
         )
 

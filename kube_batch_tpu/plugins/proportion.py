@@ -181,6 +181,13 @@ class ProportionPlugin(Plugin):
                 attr.allocated.add_(total_resreq)
                 attr._dirty = True
 
+        def on_batch_deallocate(job: JobInfo, tasks, total_resreq) -> None:
+            # the evict verbs' mirror: one presummed sub per job of a claim
+            if job.queue in self.queue_attrs:
+                attr = self.queue_attrs[job.queue]
+                attr.allocated.sub_(total_resreq)
+                attr._dirty = True
+
         def on_columnar_allocate(cols, job_sums) -> None:
             # one segment-sum for the whole replay ≡ 12.5k batch events
             np.add.at(self._qalloc, self._jq_vals, job_sums[self._jq_rows])
@@ -197,6 +204,7 @@ class ProportionPlugin(Plugin):
                 columnar_allocate_func=(
                     on_columnar_allocate if self._qalloc is not None else None
                 ),
+                batch_deallocate_func=on_batch_deallocate,
             )
         )
 

@@ -40,7 +40,7 @@ def bucket_rows(snap, bucket: int) -> np.ndarray:
     """The pending rows of ``snap`` in a bucket of ``bucket`` slots, as the
     dispatch plans them, whatever rung the task axis would give."""
     rows = np.flatnonzero(np.asarray(snap.task_pending))
-    assert 0 < rows.size <= bucket
+    assert rows.size <= bucket
     out = np.full(bucket, -1, np.int32)
     out[: rows.size] = rows
     return out

@@ -96,6 +96,7 @@ def drive():
     seen = {"claims_before": dict(m.EVICT_CLAIMS._values),
             "evictions_before": dict(m.EVICTIONS._values),
             "repeat_before": dict(m.EVICT_REPEAT_CLAIMS._values),
+            "commits_before": dict(m.EVICT_COMMITS._values),
             "released_before": m.EVICTION_RELEASE_LATENCY._count[()]}
     try:
         assert served.cycles() == ZERO                  # the cold drain
@@ -191,6 +192,24 @@ def test_the_counters_and_the_spans_say_what_happened(drive):
     assert waits and all({"rounds", "claims", "victims"} <= set(sp.attrs)
                          for sp in waits)
     assert sum(sp.attrs["claims"] for sp in waits) >= committed
+
+
+def test_an_action_with_claims_tells_the_cache_once_and_never_singly(drive):
+    """``volcano_evict_commits_total``: every replay that committed a claim
+    handed its evictions over in ONE ``bulk_evict`` (its span says so too),
+    and nothing on the served path evicts a task at a time."""
+    replays = [sp for sp in drive["spans"] if sp.name == "evict_replay"]
+    with_victims = [sp for sp in replays if sp.attrs["victims"]]
+    assert with_victims
+    assert all(sp.attrs["commits"] == 1 for sp in with_victims)
+    assert all(sp.attrs["commits"] == 0 for sp in replays
+               if not sp.attrs["victims"])
+    bulk = sum(grew(m.EVICT_COMMITS._values, drive["commits_before"],
+                    (a, "bulk")) for a in ("reclaim", "preempt"))
+    assert bulk == len(with_victims)
+    for action in ("reclaim", "preempt"):
+        assert grew(m.EVICT_COMMITS._values, drive["commits_before"],
+                    (action, "single")) == 0
 
 
 # -- the reference counts each planted fault --------------------------------
@@ -384,6 +403,49 @@ def test_the_feed_is_bounded_and_says_what_a_slow_client_missed():
     assert paged["next"] == 15
 
 
+def test_a_reader_polling_under_a_batch_sees_whole_gap_free_pages():
+    """``record_many`` appends 4,096 entries in one call while a reader
+    polls ``since()`` without a lock: every page it gets is whole entries
+    with consecutive numbers from its cursor on, and the pages together
+    are the batch, in order."""
+    log = EvictionLog()
+    for i in range(3):                      # the feed is not new
+        log.record(f"ns/old{i}", "n0", "preempt", "")
+    batch = [(f"ns/p{i}", f"n{i % 7}", "reclaim", f"ns/c{i // 7}")
+             for i in range(4096)]
+    end = 3 + len(batch)
+    pages, started = [], threading.Event()
+
+    def read():
+        cursor = 3
+        while cursor < end:
+            page = log.since(cursor, page=97)
+            started.set()
+            pages.append((cursor, page))
+            cursor = page["next"]
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)             # the reader gets in between
+    try:
+        reader = threading.Thread(target=read, daemon=True)
+        reader.start()
+        assert started.wait(5)
+        assert log.record_many(batch) == 3
+        reader.join(30)
+        assert not reader.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    seen = []
+    for cursor, page in pages:
+        got = page["evictions"]
+        assert [e["seq"] for e in got] == list(range(cursor, page["next"]))
+        assert page["first"] == 0 and page["next"] <= end
+        seen.extend(got)
+    assert [(e["pod"], e["node"], e["action"], e["claimant"])
+            for e in seen] == batch
+    assert [e["seq"] for e in seen] == list(range(3, end))
+
+
 def test_the_endpoint_answers_while_the_cache_lock_is_held():
     """``GET /v1/evictions`` takes no cache lock: it answers while another
     thread holds it, which ``/v1/bindings`` would wait out."""
@@ -558,6 +620,7 @@ def test_with_one_queue_preempt_commits_and_the_gangs_bind():
         served.report_running()
         served.cycle()
         claims = dict(m.EVICT_CLAIMS._values)
+        commits = dict(m.EVICT_COMMITS._values)
         pods = served.production(3)
         served.cycle()
         feed = served.cache.eviction_log.since(0)["evictions"]
@@ -573,6 +636,12 @@ def test_with_one_queue_preempt_commits_and_the_gangs_bind():
                   for root in rec.spans for sp in _walk(root)
                   if sp.name == "evict_replay" and sp.attrs["victims"]]
         assert sum(sp.attrs["victims"] for sp in replay) == len(feed)
+        # a committed Statement's evictions reach the cache in one call
+        bulk = grew(m.EVICT_COMMITS._values, commits, ("preempt", "bulk"))
+        assert bulk == sum(sp.attrs["commits"] for sp in replay) > 0
+        assert bulk <= committed
+        assert grew(m.EVICT_COMMITS._values, commits,
+                    ("preempt", "single")) == 0
         served.cycle()                  # nobody released: nothing more
         assert served.cache.eviction_log.since(0)["next"] == len(feed)
         for _ in range(6):

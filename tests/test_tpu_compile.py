@@ -14,6 +14,7 @@ One file, one fixture, the compile inside the test: only the worker that is
 given this file loads the TPU's library."""
 
 import os
+import re
 
 import jax
 import numpy as np
@@ -68,13 +69,14 @@ def test_the_pjit_oracle_at_the_envelope_fits_as_the_audit_models_it(topo):
     assert GIB < compiled <= modelled <= 16 * GIB, (compiled, modelled)
 
 
-def test_on_the_pending_bucket_the_evict_solve_holds_a_sixth_of_the_planes(
-        topo):
+T_ROWS, N_NODES, BUCKET = 50_176, 5_120, 8_192
+
+
+@pytest.fixture(scope="module")
+def evict_compiled(topo):
     """``overcommit-50k-5k``'s evict program (reclaim, both claimant gates,
-    the guard's sentinel fused) at 50,176 x 5,120 on one chip: bidding on
-    the pending bucket of 8,192 rows the compiler allocates under a GiB of
-    temporaries, where the full-axis fallback takes over four (the cell's
-    whole ``peak_bytes_reserved`` before PR 36)."""
+    the guard's sentinel fused) at 50,176 x 5,120 on one chip, compiled on
+    the pending bucket of 8,192 rows and on the full axis."""
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
@@ -84,16 +86,84 @@ def test_on_the_pending_bucket_the_evict_solve_holds_a_sixth_of_the_planes(
     from kube_batch_tpu.ops.invariants import evict_sentinel_solve
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    T, N = 50_176, 5_120
-    assert topk_bucket_for(T) == 8_192
+    assert topk_bucket_for(T_ROWS) == BUCKET
     snap = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        abstract_snapshot(T=T, N=N, J=13_312, Q=8, R=4, W=4, K=4))
-    rows = jax.ShapeDtypeStruct((8_192,), jnp.int32, sharding=one_chip)
+        abstract_snapshot(T=T_ROWS, N=N_NODES, J=13_312, Q=8, R=4, W=4, K=4))
+    rows = jax.ShapeDtypeStruct((BUCKET,), jnp.int32, sharding=one_chip)
     ec = EvictConfig(mode="reclaim", idle_gate=True, releasing_gate=True)
-    temp = {}
-    for name, args in (("bucket", (snap, ec, rows)), ("full", (snap, ec))):
-        memory = evict_sentinel_solve.lower(*args).compile().memory_analysis()
-        temp[name] = memory.temp_size_in_bytes
-    assert 0 < temp["bucket"] < GIB < 4 * GIB < temp["full"], temp
+    return {name: evict_sentinel_solve.lower(*args).compile()
+            for name, args in (("bucket", (snap, ec, rows)),
+                               ("full", (snap, ec)))}
+
+
+def test_on_the_pending_bucket_the_evict_solve_holds_a_sixth_of_the_planes(
+        evict_compiled):
+    """Bidding on the pending bucket the compiler allocates under a GiB of
+    temporaries, where the full-axis fallback takes nearly four (3.84 GiB;
+    4.26 before the rounds became a conditional's branch, the cell's whole
+    ``peak_bytes_reserved`` before PR 36)."""
+    temp = {name: compiled.memory_analysis().temp_size_in_bytes
+            for name, compiled in evict_compiled.items()}
+    assert 0 < temp["bucket"] < GIB < 3 * GIB < temp["full"], temp
     assert temp["full"] > 5 * temp["bucket"]
+
+
+def _computations(hlo: str) -> dict:
+    """name -> the lines of each computation of a compiled module's text."""
+    comps, lines = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if head:
+            lines = comps.setdefault(
+                "ENTRY" if line.startswith("ENTRY") else head.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _reach(comps: dict, root_lines: list) -> list:
+    """Every line of the computations ``root_lines`` call, however deep."""
+    seen, todo, out = set(), list(root_lines), []
+    while todo:
+        line = todo.pop()
+        out.append(line)
+        called = re.findall(
+            r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        for name in called:
+            if name not in seen and name in comps:
+                seen.add(name)
+                todo.extend(comps[name])
+    return out
+
+
+def _task_axis_argsorts(lines) -> int:
+    return sum(bool(re.search(rf"= \(s32\[{T_ROWS}\]\S*, \S+\) sort\(", ln))
+               and "argsort" in ln for ln in lines)
+
+
+@pytest.mark.parametrize("shape", ("bucket", "full"))
+def test_a_solve_that_ends_at_its_gates_skips_the_task_axis_sorts(
+        evict_compiled, shape):
+    """The TPU compiler keeps what only the rounds read INSIDE the branch a
+    solve with no claimant left does not take: the round loop and the
+    victims' [T] rankings are in the conditional's one branch, the other is
+    a handful of constants, and above the conditional only the gates' own
+    two rankings (two keys each) sort the task axis."""
+    comps = _computations(evict_compiled[shape].as_text())
+    entry = comps["ENTRY"]
+    (cond,) = [ln for ln in entry if " conditional(" in ln]
+    (names,) = re.findall(r"branch_computations=\{([^}]*)\}", cond)
+    nobody, rounds = (_reach(comps, comps[n.strip().lstrip("%")])
+                      for n in names.split(","))
+    assert sum(" while(" in ln for ln in rounds) >= 1
+    assert _task_axis_argsorts(rounds) >= 2           # the victims' rank
+    assert not any(" while(" in ln or " sort(" in ln for ln in nobody)
+    assert len(nobody) < 16
+    above = _reach(comps, [ln for ln in entry if ln is not cond])
+    assert not any(" while(" in ln for ln in above)
+    assert _task_axis_argsorts(above) == 4            # the gates' rankings
