@@ -576,6 +576,13 @@ class AllocateAction(Action):
             return False
         with tracer.span("snapshot_build"):
             snap, meta = build_session_snapshot(ssn)
+            if cols is not None and cols.affinity.live_signatures:
+                # the mask and score rows derived from the match-count
+                # planes, timed where it ran (api/affinity_planes.py)
+                with tracer.tallied_span(
+                        "affinity_mask",
+                        cols.last_affinity.get("derive_s", 0.0)) as sp_aff:
+                    tracer.note_affinity_rows(sp_aff, cols.last_affinity)
         # multi-chip parts shard the node axis over the ICI mesh — the
         # production analog of the reference's always-on 16-worker fan-out
         # (scheduler_helper.go:34-64); single-chip or small-N stays local
@@ -627,12 +634,13 @@ class AllocateAction(Action):
         # counters idiom), so the guard adds zero extra transfers
         with tracer.device_span("device_wait") as sp_wait:
             (assigned, pipelined, rounds_run, topk_exh, topk_reent,
-             verdict, vhist, echeck) = jax.device_get(  # kbt: allow[KBT010] ^
+             verdict, vhist, echeck, turned_away) = jax.device_get(  # kbt: allow[KBT010] ^
                 (result.assigned, result.pipelined, result.rounds_run,
                  result.topk_exhausted, result.topk_reentries,
                  sentinel[0] if sentinel is not None else np.int32(0),
                  sentinel[1] if sentinel is not None else None,
-                 sentinel[2] if sentinel is not None else np.int32(0))
+                 sentinel[2] if sentinel is not None else np.int32(0),
+                 result.term_exclusions)
             )
         # convergence diagnostic: how far into rounds x outer the solve went
         self.last_solve_rounds = int(rounds_run)
@@ -640,6 +648,12 @@ class AllocateAction(Action):
             sp_wait, "allocate", self.last_solve_rounds, config.rounds)
         tracer.note_topk_fallbacks(
             sp_wait, "allocate", int(topk_exh), int(topk_reent))
+        if turned_away is not None:
+            tracer.note_term_exclusions(sp_wait, "allocate", int(turned_away))
+        # the solve carried the in-solve rule of the required inter-pod
+        # terms (the snapshot it consumed had them: one device): its termed
+        # placements need no host predicate at replay
+        meta.terms_exact = ginfo["dev"].aff_terms is not None
         if topk_info is not None:
             topk_info = dict(
                 topk_info, exhausted=int(topk_exh), reentries=int(topk_reent)
@@ -854,6 +868,10 @@ class AllocateAction(Action):
         # JobReady gate (gang.go:122-129 delegates to job.ready(), which is
         # exactly snapshot ready count + new allocations vs min_available)
         gang_only_ready = ssn.enabled_plugin_names(JOB_READY) <= {"gang"}
+        # inter-pod terms were exact in the solve (the in-solve rule ran:
+        # one device, a columnar snapshot): tasks whose only host-side
+        # constraint they are keep the bulk path
+        terms_exact = meta.terms_exact and meta.task_terms_only is not None
         nJ, nN = len(meta.job_objs), len(meta.node_names)
         resreq64 = meta.task_resreq64
         spec = ssn.spec
@@ -872,7 +890,10 @@ class AllocateAction(Action):
         if not gang_only_ready or ssn.host_only_predicates:
             job_slow[:] = True
         else:
-            np.logical_or.at(job_slow, pjobs, meta.task_needs_host[placed])
+            needs_host = meta.task_needs_host[placed]
+            if terms_exact:
+                needs_host = needs_host & ~meta.task_terms_only[placed]
+            np.logical_or.at(job_slow, pjobs, needs_host)
 
         # ---- bulk path FIRST ------------------------------------------
         # Bulk placements need no host state (the solve guarantee covers
@@ -936,7 +957,8 @@ class AllocateAction(Action):
                 claims: Optional[set] = set()
                 for i in range(lo, hi):
                     t = task_objs[placed_l[i]]
-                    if not t.needs_host_predicate:
+                    if not t.needs_host_predicate or (
+                            terms_exact and t.inter_pod_terms_only):
                         continue
                     if t.pod.affinity is not None:
                         claims = None  # rich constraints → sequential path
@@ -1042,7 +1064,7 @@ class AllocateAction(Action):
             cols.t_status[alloc_rows] = BINDING_I
             cols.t_status[pipe_rows] = PIPELINED_I
             apply_rows = placed[apply_mask]
-            cols.t_node[apply_rows] = node_of[apply_mask]
+            cols.set_task_nodes(apply_rows, node_of[apply_mask])
             cols.j_alloc += job_alloc_sum
             # alloc-twin choke: the f32 j_alloc32 refresh visits exactly
             # the rows this vectorized update moved
@@ -1355,18 +1377,20 @@ class AllocateAction(Action):
 
     def _host_place_columns(self, ssn, stmt, task) -> Optional[bool]:
         """Vectorized residual placement over the column matrices for tasks
-        whose only host-side constraint is hostPorts: fit + static predicates
-        + port exclusion as array ops, device-weight scoring, then the same
-        Idle-vs-Releasing decision.  Returns None when the task needs the
-        full object scan (affinity, host-only predicate plugins, no
-        columns)."""
+        whose host-side constraints are hostPorts and inter-pod terms: fit +
+        static predicates + port exclusion + the match-count planes' mask
+        and preferred rows (live: this session's placements count) as array
+        ops, device-weight scoring, then the same Idle-vs-Releasing
+        decision.  Returns None when the task needs the full object scan
+        (node-affinity terms the label bits cannot encode, host-only
+        predicate plugins, no columns)."""
         cols = ssn.columns
         from kube_batch_tpu.framework.session import NODE_ORDER
 
         if (
             cols is None
             or ssn.host_only_predicates
-            or task.pod.affinity is not None
+            or task.rich_node_affinity
             or getattr(task, "_row", -1) < 0
             # a custom scoring policy (an extension score row or a NODE_ORDER
             # scorer beyond the built-in nodeorder plugin) isn't encoded in
@@ -1396,6 +1420,9 @@ class AllocateAction(Action):
             held = self._port_held_nodes(cols, p, exclude_row=task._row)
             if held:
                 cand[list(held)] = False
+        planes = cols.affinity
+        if planes.t_req[row]:
+            cand &= planes.mask_row(row)
         if not cand.any():
             return False
         # device-weight scoring rows (ops/scoring.py's host twin)
@@ -1410,6 +1437,10 @@ class AllocateAction(Action):
             + w.balanced_resource * (10.0 - np.abs(free_cpu - free_mem) * 10.0)
             + w.binpack * (frac[:, 0] + frac[:, 1]) * 5.0
         )
+        if planes.t_pref[row]:
+            score = score + w.pod_affinity * planes.scaled_score_row(row)
+            if task.pod.affinity.preferred_node_terms:
+                score = score + w.node_affinity * planes.node_score_row(row)
         score = np.where(cand, score, -np.inf)
         volume_ok = getattr(ssn.cache.volume_binder, "noop", False)
         for _ in range(8):  # volume-infeasible nodes retire and we re-pick

@@ -181,4 +181,5 @@ class BackfillAction(Action):
         # replay through a throwaway action instance so the allocate
         # action's recorded phases/fallback stay those of the main pass
         helper = AllocateAction()
+        meta.terms_exact = ginfo["dev"].aff_terms is not None
         helper._replay(ssn, snap, meta, assigned, pipelined, task_job)

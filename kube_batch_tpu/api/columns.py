@@ -85,6 +85,11 @@ def resident_snap(cols, snap, mesh=None):
     mesh-sharded solve path."""
     if cols is None:
         return snap
+    if mesh is not None and snap.aff_terms is not None:
+        # the in-solve rule of the required inter-pod terms runs on one
+        # device; a sharded solve's termed placements are re-validated by
+        # the host predicate at replay, as all of them were before
+        snap = snap._replace(aff_terms=None)
     snap = cols.resident_features(snap, mesh=mesh)
     return cols.per_cycle_resident(snap, mesh=mesh)
 
@@ -162,16 +167,18 @@ class ColumnStore:
         self.t_best_effort = np.zeros(capT, bool)
         self.t_critical = np.zeros(capT, bool)
         self.t_needs_host = np.zeros(capT, bool)
+        # rows whose ONLY host-side constraint is inter-pod terms: exact on
+        # the device wherever the solve carries the in-solve rule
+        # (DeviceSnapshot.aff_terms), so allocate's replay trusts them there
+        self.t_terms_only = np.zeros(capT, bool)
         self.t_sel_bits = np.zeros((capT, 1), np.uint32)
         self.t_sel_impossible = np.zeros(capT, bool)
         self.t_tol_bits = np.zeros((capT, 1), np.uint32)
         self.task_by_row: List = [None] * capT
         # sparse feature registries: rows whose pods carry selectors /
-        # tolerations / required pod-(anti)affinity / preferred terms
+        # tolerations (inter-pod and preferred terms: self.affinity below)
         self._sel_rows: Set[int] = set()
         self._tol_rows: Set[int] = set()
-        self._aff_rows: Set[int] = set()
-        self._pref_rows: Set[int] = set()
         self._ported_rows: Set[int] = set()  # tasks carrying hostPorts
 
         # ---- job axis ---------------------------------------------------
@@ -248,6 +255,14 @@ class ColumnStore:
         self.queue_rows: Dict[str, int] = {}
         self.queue_names: List[str] = [""] * capQ
 
+        # ---- inter-pod (anti-)affinity: match-count planes ---------------
+        from kube_batch_tpu.api.affinity_planes import AffinityPlanes
+
+        self.affinity = AffinityPlanes(self)
+        # what the last device snapshot derived from them (the tracer's
+        # counters: obs/trace.py note_affinity)
+        self.last_affinity: Dict = {}
+
         # ---- label / taint interning (monotone tables) ------------------
         self.label_pair_bit: Dict[tuple, int] = {}
         self.taint_bit: Dict[tuple, int] = {}
@@ -322,6 +337,7 @@ class ColumnStore:
             or task.namespace == CRITICAL_NAMESPACE
         )
         self.t_needs_host[row] = task.needs_host_predicate
+        self.t_terms_only[row] = task.inter_pod_terms_only
         # sparse features
         if pod.node_selector or pod.affinity is not None:
             self._sel_rows.add(row)
@@ -329,11 +345,7 @@ class ColumnStore:
         if pod.tolerations:
             self._tol_rows.add(row)
             self._fill_tol_bits(row, task)
-        if pod.affinity is not None:
-            if pod.affinity.pod_affinity or pod.affinity.pod_anti_affinity:
-                self._aff_rows.add(row)
-            if pod.affinity.has_preferences():
-                self._pref_rows.add(row)
+        self.affinity.bind_row(row, pod, int(self.t_node[row]))
         if pod.host_ports:
             self._ported_rows.add(row)
         self.task_by_row[row] = task
@@ -350,10 +362,12 @@ class ColumnStore:
             return
         task._store = None
         task._row = -1
+        self.affinity.free_row(row, int(self.t_node[row]))
         self.t_valid[row] = False
         self.t_status[row] = 0
         self.t_node[row] = -1
         self.t_best_effort[row] = False
+        self.t_terms_only[row] = False
         if row in self._sel_rows:
             self._sel_rows.discard(row)
             self.t_sel_bits[row] = 0
@@ -361,8 +375,6 @@ class ColumnStore:
         if row in self._tol_rows:
             self._tol_rows.discard(row)
             self.t_tol_bits[row] = 0
-        self._aff_rows.discard(row)
-        self._pref_rows.discard(row)
         self._ported_rows.discard(row)
         self.task_by_row[row] = None
         self.tasks.free(row)
@@ -372,13 +384,14 @@ class ColumnStore:
         cap = self.tasks.grown_cap()
         for name in ("t_init32", "t_res32", "t_resreq64", "t_job", "t_prio",
                      "t_creation", "t_status", "t_valid", "t_best_effort",
-                     "t_critical", "t_needs_host", "t_sel_bits",
-                     "t_sel_impossible", "t_tol_bits"):
+                     "t_critical", "t_needs_host", "t_terms_only",
+                     "t_sel_bits", "t_sel_impossible", "t_tol_bits"):
             setattr(self, name, _grow(getattr(self, name), cap))
         tn = np.full(cap, -1, np.int32)
         tn[: self.t_node.shape[0]] = self.t_node
         self.t_node = tn
         self.task_by_row.extend([None] * (cap - self.tasks.cap))
+        self.affinity.grow_tasks(cap)
         self.tasks.on_grown(cap)
         # a task-axis re-grow moves the bucket rung the warm allocate
         # compacts into — drop the carried candidate tables wholesale
@@ -437,9 +450,15 @@ class ColumnStore:
         self.t_status[row] = status
 
     def task_node_changed(self, row: int, node_name) -> None:
-        self.t_node[row] = (
-            self.node_rows.get(node_name, -1) if node_name is not None else -1
-        )
+        new = self.node_rows.get(node_name, -1) if node_name is not None else -1
+        self.affinity.move_row(row, int(self.t_node[row]), new)
+        self.t_node[row] = new
+
+    def set_task_nodes(self, rows: np.ndarray, nodes: np.ndarray) -> None:
+        """``task_node_changed`` for whole arrays of rows and node rows (the
+        columnar replay's bulk bind)."""
+        self.affinity.move_rows(rows, self.t_node[rows], nodes)
+        self.t_node[rows] = nodes
 
     # ==================================================================
     # job axis
@@ -549,8 +568,10 @@ class ColumnStore:
         self.sync_node_meta(node)
         # resident tasks bound before their node rows resolve to -1;
         # repoint them now that the name has a row
+        self.affinity.nodes_changed()
         for t in node.tasks.values():
             if getattr(t, "_row", -1) >= 0 and t._store is self:
+                self.affinity.move_row(t._row, int(self.t_node[t._row]), row)
                 self.t_node[t._row] = row
 
     def free_node(self, node) -> None:
@@ -576,7 +597,12 @@ class ColumnStore:
         self.node_names[row] = ""
         # tasks still referencing the freed row (bound pods of a deleted
         # node) must not alias whatever node reuses it
-        self.t_node[self.t_node == row] = -1
+        residents = np.flatnonzero(self.t_node == row)
+        self.affinity.move_rows(
+            residents, self.t_node[residents],
+            np.full(residents.size, -1, np.int32))
+        self.t_node[residents] = -1
+        self.affinity.nodes_changed()
         self.nodes.free(row)
         self.node_feature_version += 1
 
@@ -592,6 +618,7 @@ class ColumnStore:
         self.node_by_row.extend([None] * (cap - self.nodes.cap))
         self.node_names.extend([""] * (cap - self.nodes.cap))
         self.nodes.on_grown(cap)
+        self.affinity.grow_nodes(cap)
         # node-axis growth changes the node-index space the carried
         # candidate tables rank over — wholesale drop, never index-shift
         self.drop_warm_tables()
@@ -655,6 +682,7 @@ class ColumnStore:
             and np.array_equal(self.n_taint_bits[row], taint_row)
         ):
             self.node_feature_version += 1
+            self.affinity.nodes_changed()
         self.n_label_bits[row] = label_row
         self.n_taint_bits[row] = taint_row
 
@@ -1211,45 +1239,18 @@ class ColumnStore:
         task_pending = self.schedulable_pending_mask()
 
         # ---- sparse affinity / preference rows --------------------------
-        aff_live = [r for r in self._aff_rows if self.t_valid[r]]
-        K = max(1, len(aff_live))
-        task_aff_idx = np.full(K, -1, np.int32)
-        task_aff_mask = np.ones((K, capN), bool)
-        node_objs_cache = None
-        if aff_live:
-            from kube_batch_tpu.plugins.predicates import pod_affinity_ok
-
-            node_objs_cache = [n for n in self.node_by_row if n is not None]
-            for k, row in enumerate(aff_live):
-                task_aff_idx[k] = row
-                t = self.task_by_row[row]
-                for n in node_objs_cache:
-                    task_aff_mask[k, n._row] = pod_affinity_ok(
-                        t, n, node_objs_cache
-                    )
-        pref_live = [r for r in self._pref_rows if self.t_valid[r]]
-        Kp = max(1, len(pref_live))
-        task_pref_idx = np.full(Kp, -1, np.int32)
-        task_pref_node = np.zeros((Kp, capN), np.float32)
-        task_pref_pod = np.zeros((Kp, capN), np.float32)
-        if pref_live:
-            from kube_batch_tpu.plugins.nodeorder import (
-                minmax_scale_rows,
-                preferred_node_affinity_score,
-                preferred_pod_affinity_score,
-            )
-
-            if node_objs_cache is None:
-                node_objs_cache = [n for n in self.node_by_row if n is not None]
-            for k, row in enumerate(pref_live):
-                task_pref_idx[k] = row
-                t = self.task_by_row[row]
-                for n in node_objs_cache:
-                    task_pref_node[k, n._row] = preferred_node_affinity_score(t, n)
-                    task_pref_pod[k, n._row] = preferred_pod_affinity_score(
-                        t, n, node_objs_cache
-                    )
-            task_pref_pod = minmax_scale_rows(task_pref_pod)
+        # derived from the match-count planes for the pending rows alone
+        # (api/affinity_planes.py); a bound row costs nothing here
+        (task_aff_idx, task_aff_mask, task_pref_idx, task_pref_node,
+         task_pref_pod, aff_terms, aff_stats) = self.affinity.snapshot_rows(
+            task_pending)
+        self.last_affinity = aff_stats
+        if aff_stats["changed_nodes"] is not None:
+            # a carried candidate row reads these planes: what moved joins
+            # the next warm plan's invalidation (api/resident.py)
+            for st in self._warm_tables.values():
+                st.note_term_rows(aff_stats["changed_nodes"],
+                                  aff_stats["rerank_rows"])
 
         node_valid = self.n_valid
         # node ledgers: persistent f32 twins refreshed at the dirty rows
@@ -1289,6 +1290,7 @@ class ColumnStore:
             task_pref_idx=task_pref_idx,
             task_pref_node=task_pref_node,
             task_pref_pod=task_pref_pod,
+            aff_terms=aff_terms,
             node_idle=idle32,
             node_releasing=rel32,
             node_used=used32,
@@ -1330,6 +1332,7 @@ class ColumnStore:
             node_objs=self.node_by_row,
             task_resreq64=self.t_resreq64,
             task_needs_host=self.t_needs_host,
+            task_terms_only=self.t_terms_only,
         )
         meta.live_nodes = int(node_valid.sum())
         return snap, meta
@@ -1409,6 +1412,12 @@ class ColumnStore:
         for name, q in cache.queues.items():
             if self.queue_rows.get(name) is None:
                 errs.append(f"queue {name} unbound")
+        # the match-count planes against a scan of every row: a missed
+        # t_node choke point shows up here
+        if self.affinity.live_signatures and not np.array_equal(
+            self.affinity.cnt, self.affinity.rebuilt_counts()
+        ):
+            errs.append("affinity match-count plane differs from a rebuild")
         # the f32 ledger twins must track the f64 ledgers exactly once the
         # dirty rows are flushed — a missed note_node_ledger choke point
         # (a new ledger write path) shows up here

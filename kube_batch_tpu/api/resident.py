@@ -676,9 +676,13 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
 #     label / taint bits) — version-keyed uploads carry no row deltas, so
 #     the state keeps its own mirrors and diffs them at plan time;
 #   a row's own bucket churn — membership/position handled by row_map;
-#   sparse affinity/preference rows — conservatively re-ranked every
-#     cycle (their score/predicate corrections are rebuilt per cycle from
-#     object state, invisible to both sources above);
+#   sparse affinity/preference rows — derived per cycle from the
+#     match-count planes (api/affinity_planes.py), which report where a
+#     derived row moved since the last snapshot (``note_term_rows``): at
+#     few nodes, those nodes join the changed set (a bind or a delete
+#     moves a hostname-keyed term at the node whose ledger it moved
+#     anyway); at many (a zone's count crossing zero, a min-max range
+#     moving), the rows that read it re-rank;
 #   erosion — the solve's per-row ``eroded`` output (θ-cut rows whose
 #     valid prefix fell below the nominal K) re-ranks next cycle.
 #
@@ -777,6 +781,7 @@ class WarmTableState:
         self._consumed_version = -1
         self._t_mirror: Optional[Dict[str, np.ndarray]] = None
         self._n_mirror: Optional[Dict[str, np.ndarray]] = None
+        self._term_rerank: set = set()  # task rows a moved term re-ranks
         self._t_feat_ver = -1   # mirror-diff short circuits (see plan)
         self._n_feat_ver = -1
         # sticky rung ratchets (the TOPK bucket-ratchet discipline): a
@@ -818,6 +823,16 @@ class WarmTableState:
                 else:
                     self._node_full = True  # shape drift — cold
         self._absorbed_version = version
+
+    def note_term_rows(self, changed_nodes: np.ndarray, rerank_rows) -> None:
+        """Fold one snapshot's moved inter-pod/preferred rows into the
+        pending invalidation (ColumnStore.device_snapshot, every build)."""
+        if self._changed is not None:
+            if changed_nodes.shape == self._changed.shape:
+                self._changed |= changed_nodes
+            else:
+                self._node_full = True
+        self._term_rerank.update(rerank_rows)
 
     # ------------------------------------------------------------------
     def _ensure(self, key, cols) -> None:
@@ -953,10 +968,9 @@ class WarmTableState:
             n_new = int(np.sum(~carried))
             rerank_mask[:n_live] |= task_dirty[new_live]    # own features
             n_dirty = int(np.sum(task_dirty[new_live]))
-            sparse = cols._aff_rows | cols._pref_rows       # conservative
-            if sparse:
+            if self._term_rerank:                           # a moved term
                 rerank_mask[:n_live] |= np.isin(
-                    new_live, np.fromiter(sparse, np.int64)
+                    new_live, np.fromiter(self._term_rerank, np.int64)
                 )
             if self.eroded_dev is not None:
                 # kbt: allow[KBT010] tiny [P]-bool readback of LAST cycle's
@@ -1013,6 +1027,7 @@ class WarmTableState:
         # swap version so same-version re-notifies can't re-mark them);
         # the next swaps rebuild
         self._changed = np.zeros(capN, bool)
+        self._term_rerank = set()
         self._node_full = False
         self._consumed_version = self._absorbed_version
         self.rows = pend_rows.copy()

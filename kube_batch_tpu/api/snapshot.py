@@ -123,6 +123,20 @@ class DeviceSnapshot(NamedTuple):
     # cluster
     total: "np.ndarray"             # [R] f32 — Σ allocatable over valid nodes
     quanta: "np.ndarray"            # [R] f32 — comparison quanta
+    # the in-solve half of the required inter-pod terms
+    # (api/affinity_planes.AffinityTerms): with it the allocate rounds count
+    # pods placed earlier in the same solve, and the rows of task_aff_idx
+    # are exact on the device.  None (no pytree leaf: the programs trace as
+    # they did before the field existed) where no live row carries a
+    # required term, for snapshots built from objects, and on the sharded
+    # paths, where the host predicate re-validates at replay as before
+    aff_terms: object = None
+
+
+#: the fields that are arrays in every snapshot (what a guard bundle stores
+#: and the replication stream ships); ``aff_terms`` is a group of arrays or
+#: None and stays with the process that built it
+ARRAY_FIELDS = tuple(f for f in DeviceSnapshot._fields if f != "aff_terms")
 
 
 @dataclasses.dataclass
@@ -151,6 +165,11 @@ class SnapshotMeta:
     task_resreq64: "np.ndarray" = None
     # [nT] bool — task carries host-only constraints (ports, rich affinity)
     task_needs_host: "np.ndarray" = None
+    # rows whose only host-side constraint is inter-pod terms (columnar
+    # snapshots only): trusted at replay when the solve carried aff_terms,
+    # which the action that dispatched it says here
+    task_terms_only: "np.ndarray" = None
+    terms_exact: bool = False
 
     @property
     def shape(self) -> Tuple[int, int, int, int]:
@@ -420,7 +439,10 @@ def build_snapshot(
                 queue_request[qi] += t.resreq.vec
 
     # sparse inter-pod-affinity rows, evaluated host-side at snapshot time
-    # (the string/label matching stays host-precompiled, SURVEY.md §7.3)
+    # (the string/label matching stays host-precompiled, SURVEY.md §7.3).
+    # This is the ONE builder that scans objects for them: isolated and
+    # hand-built sessions are small, and it is the oracle the columnar
+    # store's match-count planes (api/affinity_planes.py) are tested against
     K = max(1, len(aff_tasks))
     task_aff_idx = np.full(K, -1, np.int32)
     task_aff_mask = np.ones((K, N), bool)

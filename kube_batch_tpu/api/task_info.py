@@ -136,12 +136,16 @@ class TaskInfo:
 
     @property
     def needs_host_predicate(self) -> bool:
-        """True when the task carries constraints the device mask only
-        approximates (snapshot.py's encoding notes): host ports, inter-pod
-        (anti-)affinity, or node-affinity terms richer than one single-value
-        In term. The allocate replay re-validates only these — everything
-        else (ready/unschedulable nodes, selectors, taints, resource fit,
-        max-pods) is exact on device."""
+        """True when the task carries constraints some device program only
+        approximates: host ports and node-affinity terms richer than one
+        single-value In term (no program encodes them), and required
+        inter-pod (anti-)affinity (the evict programs and the sharded
+        allocate solves read the snapshot-time mask alone; the one-device
+        allocate solve also counts same-solve placements and is exact: see
+        ``inter_pod_terms_only``). A replay re-validates these on the host
+        wherever its solve was approximate — everything else (ready /
+        unschedulable nodes, selectors, taints, resource fit, max-pods) is
+        exact on device."""
         pod = self.pod
         if pod.host_ports:
             return True
@@ -150,7 +154,14 @@ class TaskInfo:
             return False
         if aff.pod_affinity or aff.pod_anti_affinity:
             return True
-        terms = aff.node_terms
+        return self.rich_node_affinity
+
+    @property
+    def rich_node_affinity(self) -> bool:
+        """Required node-affinity terms the label bits cannot encode: more
+        than one term, or a requirement other than one single-value In."""
+        aff = self.pod.affinity
+        terms = aff.node_terms if aff is not None else None
         if not terms:
             return False
         if len(terms) > 1:
@@ -158,6 +169,22 @@ class TaskInfo:
         return any(
             op != "In" or len(values) != 1 for (_, op, values) in terms[0]
         )
+
+    @property
+    def inter_pod_terms_only(self) -> bool:
+        """True when required inter-pod (anti-)affinity terms are the ONLY
+        reason for ``needs_host_predicate``: no host port, no node-affinity
+        term the label bits cannot encode.  The columnar store answers those
+        terms from its match-count planes and the allocate solve counts
+        same-solve placements (DeviceSnapshot.aff_terms), so such a task is
+        exact on the device there and its job keeps the bulk replay."""
+        pod = self.pod
+        aff = pod.affinity
+        if pod.host_ports or aff is None:
+            return False
+        if not (aff.pod_affinity or aff.pod_anti_affinity):
+            return False
+        return not self.rich_node_affinity
 
     def clone(self) -> "TaskInfo":
         """Copy with value semantics for the mutable fields (status,

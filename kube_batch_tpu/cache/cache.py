@@ -319,6 +319,11 @@ class SchedulerCache:
         # bind decisions made so far (bind / bulk_bind, under the big lock);
         # the event-driven loop reads its growth over a cycle as progress
         self.binds_total = 0
+        # the order in which the live pods' binds were decided (pod key ->
+        # its number among all binds so far): ``GET /v1/bindings`` reports
+        # it, so a client can walk the binds as they were made (an
+        # order-sensitive check such as required inter-pod affinity's)
+        self.bind_seq: Dict[str, int] = {}
 
     # ------------------------------------------------------------------
     # exclusive-session gate (no-clone session mode)
@@ -732,6 +737,7 @@ class SchedulerCache:
         self.pod_conditions.pop(pod.key(), None)  # fresh pod ⇒ fresh dedup
         self._arrival_ts.pop(pod.key(), None)
         self._inflight_bind_hosts.pop(pod.key(), None)
+        self.bind_seq.pop(pod.key(), None)
         if forget_resync:
             # external change/delete: all repair bookkeeping (incl. the
             # quarantine) starts over. The resync pass's OWN delete+add
@@ -1016,6 +1022,7 @@ class SchedulerCache:
             t0, gangs = None, []
             if pod is not None:
                 self.binds_total += 1
+                self.bind_seq[task.key()] = self.binds_total
                 t0 = self._arrival_ts.pop(task.key(), None)
                 if t0 is not None:
                     gangs = self._gangs_decided_locked({task.job})
@@ -1090,6 +1097,7 @@ class SchedulerCache:
                 continue
             binds += 1
             inflight[task._key] = hostname
+            self.bind_seq[task._key] = self.binds_total + binds
             t0 = pop_ts(task._key, None)
             if t0 is not None:
                 arrivals.append(t0)

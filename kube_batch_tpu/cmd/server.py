@@ -21,6 +21,8 @@ The reference serves Prometheus `/metrics` (+ pprof) on --listen-address
                                  Queue CRD status the CLI renders, list.go:51)
 - GET  /v1/jobs                — podgroup phases/conditions
 - GET  /v1/bindings            — pod→node decisions made so far
+                                 (``?seq=1``: with each bind's number, the
+                                 order the binds were made in)
 - GET  /v1/guard               — result-integrity guard plane state (per-
                                  fast-path breaker, trips, audits, bundles)
 - GET  /v1/trace               — cycle tracing plane: last cycle's span
@@ -140,14 +142,20 @@ def _job_status(cache: SchedulerCache) -> list:
         return rows
 
 
-def _bindings(cache: SchedulerCache) -> list:
+def _bindings(cache: SchedulerCache, seq: bool = False) -> list:
+    """``seq``: each row also says the how-manieth bind of this process
+    the pod's was (0: it arrived bound), so that a client can walk the
+    binds in the order they were made."""
     with cache._lock:
         out = []
         for job in cache.jobs.values():
             for task in job.tasks.values():
                 if task.node_name is not None:
-                    out.append({"pod": task.key(), "node": task.node_name,
-                                "status": task.status.name})
+                    row = {"pod": task.key(), "node": task.node_name,
+                           "status": task.status.name}
+                    if seq:
+                        row["seq"] = cache.bind_seq.get(task.key(), 0)
+                    out.append(row)
         return sorted(out, key=lambda r: r["pod"])
 
 
@@ -274,8 +282,12 @@ def make_handler(cache: SchedulerCache, query_plane=None):
                 self._send(200, json.dumps(_queue_status(cache)))
             elif self.path == "/v1/jobs":
                 self._send(200, json.dumps(_job_status(cache)))
-            elif self.path == "/v1/bindings":
-                self._send(200, json.dumps(_bindings(cache)))
+            elif self.path.partition("?")[0] == "/v1/bindings":
+                from urllib.parse import parse_qs, urlparse
+
+                q = parse_qs(urlparse(self.path).query)
+                self._send(200, json.dumps(_bindings(
+                    cache, seq=q.get("seq", ["0"])[0] == "1")))
             elif self.path == "/v1/guard":
                 # result-integrity guard plane state: per-fast-path breaker
                 # (healthy|demoted|probing), trips, audits, bundle paths —

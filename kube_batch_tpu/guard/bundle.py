@@ -68,6 +68,7 @@ def dump_bundle(action: str, snap, config, report: Dict,
     read back here, once, on the rare trip path)."""
     import jax
 
+    from kube_batch_tpu.api.snapshot import ARRAY_FIELDS
     from kube_batch_tpu.ops.invariants import INVARIANT_NAMES
 
     root = directory or bundle_dir()
@@ -75,7 +76,10 @@ def dump_bundle(action: str, snap, config, report: Dict,
     # kbt: allow[KBT010] trip-path readback — the bundle must capture the
     # exact (possibly corrupted) device bytes the solve consumed
     host = jax.device_get(snap)
-    arrays = {f: np.asarray(getattr(host, f)) for f in snap._fields}
+    arrays = {f: np.asarray(getattr(host, f)) for f in ARRAY_FIELDS}
+    if host.aff_terms is not None:
+        arrays.update({f"aff_terms.{k}": np.asarray(v)
+                       for k, v in host.aff_terms._asdict().items()})
     if pend_rows is not None:
         arrays["pend_rows"] = np.asarray(pend_rows)
     meta = {
@@ -118,14 +122,20 @@ def dump_bundle(action: str, snap, config, report: Dict,
 
 def load_bundle(path: str):
     """(DeviceSnapshot of host arrays, meta dict, pend_rows|None)."""
-    from kube_batch_tpu.api.snapshot import DeviceSnapshot
+    from kube_batch_tpu.api.snapshot import ARRAY_FIELDS, DeviceSnapshot
 
     with open(os.path.join(path, "meta.json")) as f:
         meta = json.load(f)
     with np.load(os.path.join(path, "arrays.npz")) as z:
         arrays = {k: z[k] for k in z.files}
     pend_rows = arrays.pop("pend_rows", None)
-    snap = DeviceSnapshot(**{f: arrays[f] for f in DeviceSnapshot._fields})
+    snap = DeviceSnapshot(**{f: arrays[f] for f in ARRAY_FIELDS})
+    terms = {k.split(".", 1)[1]: v for k, v in arrays.items()
+             if k.startswith("aff_terms.")}
+    if terms:
+        from kube_batch_tpu.api.affinity_planes import AffinityTerms
+
+        snap = snap._replace(aff_terms=AffinityTerms(**terms))
     return snap, meta, pend_rows
 
 

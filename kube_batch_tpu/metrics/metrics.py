@@ -511,6 +511,34 @@ TOPK_EXHAUSTED = Counter(
     "candidate list held no node that still fit, by action",
     ("action",),
 )
+# inter-pod (anti-)affinity answered from the match-count planes
+# (api/affinity_planes.py): what a cycle derived from them, what keeping
+# them cost, and what the in-solve rule turned away
+AFFINITY_ROWS = Counter(
+    f"{_SUBSYSTEM}_affinity_rows_total",
+    "Pending rows whose required mask (kind=required) or preferred score "
+    "row (kind=preferred) a device snapshot derived from the planes",
+    ("kind",),
+)
+AFFINITY_PLANE_UPDATES = Counter(
+    f"{_SUBSYSTEM}_affinity_plane_updates_total",
+    "Cells of the match-count planes that a bind, a delete or a status "
+    "change moved",
+)
+AFFINITY_SIGNATURES = Gauge(
+    f"{_SUBSYSTEM}_affinity_signatures",
+    "Distinct pod selectors of live inter-pod terms (rows of the planes)",
+)
+AFFINITY_DOMAINS = Gauge(
+    f"{_SUBSYSTEM}_affinity_domains",
+    "Distinct topology domains over the topology keys live terms use",
+)
+INTER_POD_EXCLUSIONS = Counter(
+    f"{_SUBSYSTEM}_inter_pod_exclusions_total",
+    "Bidders of the allocate solves that a placement of the same solve "
+    "turned away from the node they would have chosen, by action",
+    ("action",),
+)
 TOPK_REENTRIES = Counter(
     f"{_SUBSYSTEM}_topk_reentries_total",
     "Bidding rounds of the compacted solves that re-entered the full "
@@ -627,6 +655,14 @@ SOLVE_OVER_BUDGET.add(0.0, "allocate")
 ALLOCATE_RUNS_ON.add(0.0)
 TOPK_EXHAUSTED.add(0.0, "allocate")
 TOPK_REENTRIES.add(0.0, "allocate")
+for _kind in ("required", "preferred"):
+    AFFINITY_ROWS.add(0.0, _kind)
+AFFINITY_PLANE_UPDATES.add(0.0)
+AFFINITY_SIGNATURES.set(0.0)
+AFFINITY_DOMAINS.set(0.0)
+INTER_POD_EXCLUSIONS.add(0.0, "allocate")
+SLOW_REPLAY_JOBS.add(0.0)
+HOST_FALLBACK_TASKS.add(0.0)
 for _earlier in ("in_flight", "released"):
     EVICT_REPEAT_CLAIMS.add(0.0, _earlier)
 for _action in ("reclaim", "preempt"):
@@ -712,6 +748,11 @@ METRICS = [
     ALLOCATE_RUNS_ON,
     TOPK_EXHAUSTED,
     TOPK_REENTRIES,
+    AFFINITY_ROWS,
+    AFFINITY_PLANE_UPDATES,
+    AFFINITY_SIGNATURES,
+    AFFINITY_DOMAINS,
+    INTER_POD_EXCLUSIONS,
     GANG_DECISION_LATENCY,
     DEVICE_PEAK_BYTES,
     EVICTIONS,
@@ -965,6 +1006,22 @@ def register_topk_fallbacks(action: str, exhausted: int,
                             reentries: int) -> None:
     TOPK_EXHAUSTED.add(exhausted, action)
     TOPK_REENTRIES.add(reentries, action)
+
+
+def register_affinity_rows(required: int, preferred: int) -> None:
+    AFFINITY_ROWS.add(required, "required")
+    AFFINITY_ROWS.add(preferred, "preferred")
+
+
+def register_affinity_planes(updates: int, signatures: int,
+                             domains: int) -> None:
+    AFFINITY_PLANE_UPDATES.add(updates)
+    AFFINITY_SIGNATURES.set(signatures)
+    AFFINITY_DOMAINS.set(domains)
+
+
+def register_inter_pod_exclusions(action: str, n: int) -> None:
+    INTER_POD_EXCLUSIONS.add(n, action)
 
 
 def register_eviction(action: str, n: int = 1) -> None:

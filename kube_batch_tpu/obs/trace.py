@@ -63,6 +63,7 @@ pairs — the span IS the measurement; metrics feed from ``Span.dur_us``.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import os
 import threading
 from collections import deque
@@ -656,6 +657,16 @@ class Tracer:
         otherwise leave within seconds."""
         return Span(self, name, attrs=attrs or None, keep=_KEEP_NONE)
 
+    @contextlib.contextmanager
+    def tallied_span(self, name: str, seconds: float):
+        """A span for work that was timed where it ran, in pieces (a tally
+        kept inside a choke point many callers go through): opened after
+        the work, as a child of the stage that held it, with its start set
+        back by ``seconds``, so that its duration is the tally."""
+        with self.span(name) as sp:
+            sp.t0 -= seconds
+            yield sp
+
     # -- cycle annotations -------------------------------------------------
     def note_solve_dispatch(self, span: Span, action: str, mode: str,
                             engaged, program: Optional[str] = None,
@@ -727,6 +738,35 @@ class Tracer:
         from the same values."""
         span.set(exhausted=exhausted, reentries=reentries)
         metrics.register_topk_fallbacks(action, exhausted, reentries)
+
+    def note_affinity_rows(self, span: Span, stats: Dict) -> None:
+        """Say on an ``affinity_mask`` span what the device snapshot derived
+        from the match-count planes (``required`` and ``preferred`` pending
+        rows, ``rerank`` carried rows a moved term re-ranks), and count the
+        rows on ``/metrics`` (``volcano_affinity_rows_total{kind}``) from
+        the same values."""
+        required = int(stats.get("required", 0))
+        preferred = int(stats.get("preferred", 0))
+        span.set(required=required, preferred=preferred,
+                 rerank=len(stats.get("rerank_rows", ())))
+        metrics.register_affinity_rows(required, preferred)
+
+    def note_affinity_planes(self, span: Span, updates: int, signatures: int,
+                             domains: int) -> None:
+        """Say on an ``affinity_plane_update`` span how many cells of the
+        planes the ingest it closes moved and how many selectors and
+        topology domains are live, and put the three on ``/metrics``
+        (``volcano_affinity_plane_updates_total``,
+        ``volcano_affinity_signatures``, ``volcano_affinity_domains``)."""
+        span.set(updates=updates, signatures=signatures, domains=domains)
+        metrics.register_affinity_planes(updates, signatures, domains)
+
+    def note_term_exclusions(self, span: Span, action: str, n: int) -> None:
+        """Say on a ``device_wait`` span how many bidders of its solve a
+        placement of the same solve turned away (``term_exclusions``), and
+        count them (``volcano_inter_pod_exclusions_total{action}``)."""
+        span.set(term_exclusions=n)
+        metrics.register_inter_pod_exclusions(action, n)
 
     def note_cycle_attr(self, key: str, value) -> None:
         if not self.enabled:

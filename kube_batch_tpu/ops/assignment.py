@@ -44,7 +44,7 @@ from kube_batch_tpu.utils import jitstats
 from kube_batch_tpu.ops import fairness, ordering
 from kube_batch_tpu.ops.ordering import segmented_prefix as _segmented_prefix
 from kube_batch_tpu.ops.feasibility import fits, static_predicates
-from kube_batch_tpu.ops.scoring import ScoreWeights, score_matrix
+from kube_batch_tpu.ops.scoring import MAX_PRIORITY, ScoreWeights, score_matrix
 
 NEG = jnp.float32(-3.0e38)
 
@@ -139,6 +139,9 @@ class AllocateResult(NamedTuple):
     #                              was exhausted (0 on the full-matrix path)
     topk_reentries: jnp.ndarray  # [] i32 — rounds that re-entered the
     #                              full-matrix head for exhausted rows
+    term_exclusions: object = None  # [] i32 — bidders a same-solve placement
+    #                                 turned away from their first choice
+    #                                 (None without DeviceSnapshot.aff_terms)
 
 
 @jax.jit
@@ -301,6 +304,131 @@ def local_round_head(snap: DeviceSnapshot, config: AllocateConfig):
     return round_head_parts(snap, config)[0]
 
 
+def make_term_round(snap: DeviceSnapshot, config: AllocateConfig):
+    """The in-solve half of inter-pod (anti-)affinity, required and
+    preferred: pods placed earlier in the same solve count.  ``snap`` is the
+    solve's task view with ``aff_terms`` (api/affinity_planes.AffinityTerms)
+    beside the sparse rows of ``task_aff_idx``; returns ``term_round(idle,
+    releasing, assigned, rank, best, has, chose_idle) -> (best, has,
+    chose_idle, turned_away)``, which every bidding round calls after its
+    head and its queue gate.
+
+    The head's choice for a sparse row rests on ``task_aff_mask`` and
+    ``task_pref_pod``, the planes as they stood when the snapshot was taken.
+    Here the round's bidding sparse rows are walked once more IN RANK ORDER,
+    each choosing its best node (the round head's own two-key argmax over
+    its full [N] score row) among those the placements so far leave open:
+    those the solve has accepted in earlier rounds, and the choices of the
+    rows walked before it in this round (their room is taken too).  So two
+    pods that a required anti-affinity term makes exclusive never win one
+    domain in one round (the first bidder wins, the capacity conflict's own
+    shape), a later round sees the earlier rounds' placements, and a
+    group's first pod pins the domain its followers join (``need``: the
+    first-pod fast path of predicates.pod_affinity_ok).  Inside one solve the anti-affinity rule is
+    symmetric (a selected pod does not join the domain of a pod whose term
+    excludes it either): order-free, which is what lets the sentinel check
+    it.  A row's preferred score is nodeorder.preferred_pod_affinity_score
+    over the same placements (``here`` joined with them), min-max reduced
+    as the host reduces it: twenty replicas that prefer to sit apart do not
+    all read the one score row the cycle's start gave them.  Whether a
+    choice is then admitted is still the capacity conflict's to say; a
+    bidder that loses there bids again next round."""
+    x = snap.aff_terms
+    T = snap.task_req.shape[0]
+    N = snap.node_alloc.shape[0]
+    K, Pp = x.anti.shape
+    idx = snap.task_aff_idx
+    live = idx >= 0
+    rows = jnp.clip(idx, 0, T - 1)
+    slot = jnp.arange(K, dtype=jnp.int32)
+    view_k = pend_view(snap._replace(aff_terms=None), idx)._replace(
+        task_aff_idx=jnp.where(live, slot, -1))
+    w_pod = config.weights.pod_affinity
+    with jax.named_scope("term_rows"):
+        # everything of the score but the preferred pod terms, which move
+        # with the walk
+        score_static = jnp.where(
+            static_predicates(view_k),
+            score_matrix(view_k, config.weights._replace(pod_affinity=0.0)),
+            NEG)                                               # [K, N]
+        tie = tie_break_hash_rows(
+            jnp.maximum(x.row, 0), jnp.arange(N, dtype=jnp.int32))
+    req_k = view_k.task_req
+    pair = jnp.arange(Pp, dtype=jnp.int32)
+    i32max = jnp.iinfo(jnp.int32).max
+
+    def term_round(idle, releasing, assigned, rank, best, has, chose_idle):
+        a_k = assigned[rows]
+        placed_k = live & (a_k >= 0)
+        dom_a = x.dom[:, jnp.clip(a_k, 0, N - 1)]              # [Pp, K]
+
+        def plane(flag):
+            """[Pp, N] bool: a placed row with ``flag`` on pair p sits in
+            the node's domain under p."""
+            upd = (flag & placed_k[:, None]).T.astype(jnp.int32)
+            by_domain = jnp.zeros((Pp, N), jnp.int32).at[
+                pair[:, None], dom_a].add(upd) > 0
+            return jnp.take_along_axis(by_domain, x.dom, axis=1)
+
+        sel0, anti0 = plane(x.selp), plane(x.anti)
+        any0 = jnp.any(x.selp & placed_k[:, None], axis=0)     # [Pp]
+        bid_k = live & has[rows]
+        first = best[rows]          # the head's choice, static mask only
+        order = jnp.argsort(jnp.where(bid_k, rank[rows], i32max))
+        rel_any = jnp.any(releasing > 0.0)
+
+        def step(i, st):
+            (sel_at, anti_at, anyp, idle, releasing, best_k, has_k, chose_k,
+             turned) = st
+            k = order[i]
+            shut = jnp.any(
+                (x.anti[k][:, None] & sel_at) | (x.selp[k][:, None] & anti_at)
+                | ((x.need[k] & anyp)[:, None] & ~sel_at), axis=0)
+            fit_i = jnp.all(req_k[k] <= idle + snap.quanta, axis=-1)
+            fit_r = rel_any & jnp.all(
+                req_k[k] <= releasing + snap.quanta, axis=-1)
+            score = score_static[k]
+            if w_pod:
+                raw = x.pw[k] @ (x.here | sel_at).astype(jnp.float32)  # [N]
+                lo = jnp.min(jnp.where(x.live, raw, jnp.inf))
+                span = jnp.max(jnp.where(x.live, raw, -jnp.inf)) - lo
+                score = score + w_pod * jnp.where(
+                    x.live & (span > 0.0),
+                    MAX_PRIORITY * (raw - lo)
+                    / jnp.where(span > 0.0, span, 1.0), 0.0)
+            masked = jnp.where(~shut & (fit_i | fit_r), score, NEG)
+            top = jnp.max(masked)
+            b = jnp.argmax(
+                jnp.where(masked >= top, tie[k], -1)).astype(jnp.int32)
+            h = top > NEG
+            same = x.dom == x.dom[:, b][:, None]    # [Pp, N]: b's domains
+            sel_at = sel_at | (same & (h & x.selp[k])[:, None])
+            anti_at = anti_at | (same & (h & x.anti[k])[:, None])
+            # the room the rows walked before it took is gone for this one
+            # (replicas that prefer company would else all choose the one
+            # node and the capacity conflict would admit a node's worth a
+            # round); what it is finally given is still the conflict's to say
+            took = jnp.where(h, req_k[k], 0.0)
+            idle = idle.at[b].add(jnp.where(fit_i[b], -took, 0.0))
+            releasing = releasing.at[b].add(jnp.where(fit_i[b], 0.0, -took))
+            return (sel_at, anti_at, anyp | (h & x.selp[k]), idle, releasing,
+                    best_k.at[k].set(b), has_k.at[k].set(h),
+                    chose_k.at[k].set(fit_i[b]),
+                    turned + shut[first[k]].astype(jnp.int32))
+
+        with jax.named_scope("term_round"):
+            *_, best_k, has_k, chose_k, turned = jax.lax.fori_loop(
+                0, jnp.sum(bid_k, dtype=jnp.int32), step,
+                (sel0, anti0, any0, idle, releasing, first,
+                 jnp.zeros(K, bool), jnp.zeros(K, bool), jnp.int32(0)))
+        scat = jnp.where(bid_k, idx, T)   # everything else drops
+        return (best.at[scat].set(best_k, mode="drop"),
+                has.at[scat].set(has_k, mode="drop"),
+                chose_idle.at[scat].set(chose_k, mode="drop"), turned)
+
+    return term_round
+
+
 def allocate_rounds(
     snap: DeviceSnapshot,
     config: AllocateConfig,
@@ -329,6 +457,10 @@ def allocate_rounds(
     Q = snap.queue_weight.shape[0]
 
     subrank = ordering.task_subranks(snap.task_prio, snap.task_creation)
+    # required inter-pod terms: same-solve placements count (no leaf and no
+    # equation where the snapshot carries none)
+    term_round = (make_term_round(snap, config)
+                  if snap.aff_terms is not None else None)
 
     # proportion deserved is computed once per cycle from the session-open
     # state (proportion.go:101-154 runs in OnSessionOpen)
@@ -345,7 +477,7 @@ def allocate_rounds(
 
     def outer_body(state):
         (idle, releasing, used, assigned, pipelined, job_failed, o,
-         rounds_total, exh_total, reent_total, _more) = state
+         rounds_total, exh_total, reent_total, _more, turned_total) = state
 
         # ---- fairness state + virtual-time rank, once per outer pass -----
         # (the rank is a static plan for the whole round set: virtual time
@@ -388,12 +520,12 @@ def allocate_rounds(
         qgate_order = ordering.sort_by_segment_then_rank(task_queue, rank, Q)
 
         def round_cond(state):
-            *_, i, progress = state
+            *_, i, progress, _turned = state
             return (i < config.rounds) & progress
 
         def round_body(state):
             (idle, releasing, used, assigned, pipelined, exh_n, reent_n,
-             i, _) = state
+             i, _, turned_n) = state
             placed = assigned >= 0
             placed_req = jnp.where(placed[:, None], snap.task_resreq, 0.0)
             job_new = jax.ops.segment_sum(placed_req, snap.task_job, num_segments=J)
@@ -431,6 +563,10 @@ def allocate_rounds(
                     job_need,
                     J,
                 )
+            if term_round is not None:
+                best, has, chose_idle, turned = term_round(
+                    idle, releasing, assigned, rank, best, has, chose_idle)
+                turned_n = turned_n + turned
             alloc_cand = has & chose_idle
             pipe_cand = has & ~chose_idle
 
@@ -457,15 +593,15 @@ def allocate_rounds(
             assigned = jnp.where(newly, best, assigned)
             pipelined = pipelined | acc_p
             return (idle, releasing, used, assigned, pipelined, exh_n,
-                    reent_n, i + 1, jnp.any(newly))
+                    reent_n, i + 1, jnp.any(newly), turned_n)
 
         (idle, releasing, used, assigned, pipelined, exh_total, reent_total,
-         rounds_i, rounds_progress) = (
+         rounds_i, rounds_progress, turned_total) = (
             jax.lax.while_loop(
                 round_cond,
                 round_body,
                 (idle, releasing, used, assigned, pipelined, exh_total,
-                 reent_total, jnp.int32(0), jnp.bool_(True)),
+                 reent_total, jnp.int32(0), jnp.bool_(True), turned_total),
             )
         )
         # inner loop capped while still placing? another outer pass continues
@@ -523,10 +659,11 @@ def allocate_rounds(
             eligible & (assigned < 0) & ~job_failed[snap.task_job]
         )
         return (idle, releasing, used, assigned, pipelined, job_failed, o + 1,
-                rounds_total + rounds_i, exh_total, reent_total, more)
+                rounds_total + rounds_i, exh_total, reent_total, more,
+                turned_total)
 
     def outer_cond(state):
-        *_, o, _rounds, _exh, _reent, more = state
+        *_, o, _rounds, _exh, _reent, more, _turned = state
         return (o < config.outer) & more
 
     init = (
@@ -541,11 +678,14 @@ def allocate_rounds(
         jnp.int32(0),
         jnp.int32(0),
         jnp.bool_(True),
+        # the bidders turned away by a same-solve placement: a carry of no
+        # leaf where the snapshot has no term
+        jnp.int32(0) if term_round is not None else (),
     )
     # while_loop with early exit — a scan would pay every outer iteration
     # (~12% of solve time each) even after everything is placed
     (idle, releasing, used, assigned, pipelined, _, _, rounds_run,
-     exhausted, reentries, _) = (
+     exhausted, reentries, _, turned_away) = (
         jax.lax.while_loop(outer_cond, outer_body, init)
     )
 
@@ -566,6 +706,7 @@ def allocate_rounds(
         rounds_run=rounds_run,
         topk_exhausted=exhausted,
         topk_reentries=reentries,
+        term_exclusions=turned_away if term_round is not None else None,
     )
 
 

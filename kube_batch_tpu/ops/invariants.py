@@ -209,6 +209,29 @@ def _static_feasible_at(snap: DeviceSnapshot, node_idx: jnp.ndarray,
     return ok | ~active
 
 
+def _same_solve_exclusions(snap: DeviceSnapshot, assigned: jnp.ndarray,
+                           N: int) -> jnp.ndarray:
+    """[] i32 — placements of this solve that a required anti-affinity
+    term forbids beside ANOTHER placement of this solve: for each placed
+    sparse row and each pair (signature, topology key) its terms exclude,
+    the placed rows the signature selects in its domain, itself apart.  The
+    rule ``assignment.make_term_round`` keeps, order-free; what the solve's
+    placements share with pods already bound is ``task_aff_mask``'s, which
+    ``_static_feasible_at`` re-checks."""
+    x = snap.aff_terms
+    T = assigned.shape[0]
+    Pp = x.anti.shape[1]
+    idx = snap.task_aff_idx
+    a_k = assigned[jnp.clip(idx, 0, T - 1)]
+    placed_k = (idx >= 0) & (a_k >= 0)
+    dom_a = x.dom[:, jnp.clip(a_k, 0, N - 1)]                  # [Pp, K]
+    pair = jnp.arange(Pp, dtype=jnp.int32)[:, None]
+    sel = (x.selp & placed_k[:, None]).T.astype(jnp.int32)     # [Pp, K]
+    count = jnp.zeros((Pp, N), jnp.int32).at[pair, dom_a].add(sel)
+    others = jnp.take_along_axis(count, dom_a, axis=1) - sel
+    return _i32sum((x.anti & placed_k[:, None]).T & (others > 0))
+
+
 def allocate_invariants(snap: DeviceSnapshot, res: AllocateResult,
                         config: AllocateConfig):
     """(verdict i32, hist [N_INVARIANTS] i32) for one allocate-shaped
@@ -228,6 +251,8 @@ def allocate_invariants(snap: DeviceSnapshot, res: AllocateResult,
     in_range = (assigned >= -1) & (assigned < N)
     feas = _static_feasible_at(snap, assigned, placed)
     n_infeas = _i32sum(~in_range) + _i32sum(placed & ~feas)
+    if snap.aff_terms is not None:
+        n_infeas = n_infeas + _same_solve_exclusions(snap, assigned, N)
 
     # (3) per-node budget + capacity: the committed deltas must fit the
     # cycle-start budgets (what the solve promised), AND post-solve used

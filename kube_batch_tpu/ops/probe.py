@@ -187,6 +187,7 @@ def _gang_view(snap: DeviceSnapshot, req, valid, min_avail, queue, prio,
         task_pref_idx=jnp.full(1, -1, i32),
         task_pref_node=jnp.zeros((1, N), jnp.float32),
         task_pref_pod=jnp.zeros((1, N), jnp.float32),
+        aff_terms=None,  # a what-if gang carries no inter-pod term
         job_min_avail=app(snap.job_min_avail, min_avail),
         job_ready=app(snap.job_ready, 0),
         job_queue=app(snap.job_queue, qsafe),

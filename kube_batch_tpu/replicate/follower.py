@@ -186,9 +186,9 @@ class FollowerApplier:
         resident cache keeps their device buffers) — the follower-side
         revalidate_resident."""
         from kube_batch_tpu.api.resident import changed_rows
-        from kube_batch_tpu.api.snapshot import DeviceSnapshot
+        from kube_batch_tpu.api.snapshot import ARRAY_FIELDS
 
-        missing = [f for f in DeviceSnapshot._fields if f not in rec.full]
+        missing = [f for f in ARRAY_FIELDS if f not in rec.full]
         if missing:
             raise ValueError(f"full record missing fields {missing[:3]}")
         for field, arr in rec.full.items():
@@ -220,7 +220,7 @@ class FollowerApplier:
         import jax
 
         from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
-        from kube_batch_tpu.api.snapshot import DeviceSnapshot
+        from kube_batch_tpu.api.snapshot import ARRAY_FIELDS, DeviceSnapshot
         from kube_batch_tpu.serve.lease import SnapshotLease
 
         spec = self._spec_for(rec.lease)
@@ -228,7 +228,7 @@ class FollowerApplier:
         config = stream.config_from_wire(rec.lease["config"])
         evict_config = stream.config_from_wire(rec.lease["evict_config"])
         host_snap = DeviceSnapshot(
-            **{f: self.fields[f] for f in DeviceSnapshot._fields})
+            **{f: self.fields[f] for f in ARRAY_FIELDS})
         span = (self.tracer.span("replicate_apply", seq=rec.seq,
                                  kind=rec.kind)
                 if self.tracer is not None else None)
@@ -238,7 +238,7 @@ class FollowerApplier:
             try:
                 dev_snap = self.resident.swap(host_snap)
                 updates = {}
-                for field in DeviceSnapshot._fields:
+                for field in ARRAY_FIELDS:
                     if field in PER_CYCLE_FIELDS:
                         continue
                     stamp = self._stamp.get(field, 0)

@@ -113,8 +113,9 @@ class ReplicationPublisher:
                 and cache_version == self._last_cache_version + 1
             )
             self._last_cache_version = cache_version
-        fields = {f: np.asarray(getattr(snap, f))
-                  for f in type(snap)._fields}
+        from kube_batch_tpu.api.snapshot import ARRAY_FIELDS
+
+        fields = {f: np.asarray(getattr(snap, f)) for f in ARRAY_FIELDS}
         tables = stream.meta_tables(meta)
         lease_wire = _lease_wire(lease)
         version = int(lease.version)

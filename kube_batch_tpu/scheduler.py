@@ -606,9 +606,25 @@ class Scheduler:
             # pod store
             drain = getattr(self.cache, "drain_staged_ingest", None)
             if drain is not None:
+                planes = getattr(getattr(self.cache, "columns", None),
+                                 "affinity", None)
+                if planes is not None:
+                    since = planes.take_tally()  # the last cycle's binds
                 with tracer.span("ingest_drain") as sp:
                     n_staged = drain()
                     sp.set(events=n_staged)
+                    if planes is not None and planes.live_signatures:
+                        # what keeping the match-count planes cost this
+                        # drain: added up inside the row choke points it ran
+                        # through
+                        busy_s, updates = planes.take_tally()
+                        with tracer.tallied_span("affinity_plane_update",
+                                                 busy_s) as sp_pl:
+                            tracer.note_affinity_planes(
+                                sp_pl, updates + since[1],
+                                planes.live_signatures,
+                                planes.live_domains())
+                            sp_pl.set(replay_ms=round(since[0] * 1e3, 3))
                 metrics.register_staged_ingest(n_staged)
         # drain the resync queue at the cycle boundary: the background repair
         # tick (cache.go:563-581) skips while an exclusive session owns the

@@ -279,6 +279,20 @@ class QueryPlane:
         queue_rows = {
             name: i for i, name in enumerate(meta.queue_names) if name
         }
+        if dev.aff_terms is not None or dev.task_aff_idx.shape[0] != 1:
+            # a what-if gang carries no inter-pod term and the probe replaces
+            # the sparse rows in its view: lease their padding, so that the
+            # probe programs keep one shape whatever rung the cycle's termed
+            # rows stood on
+            capN = dev.node_alloc.shape[0]
+            dev = dev._replace(
+                aff_terms=None,
+                task_aff_idx=np.full(1, -1, np.int32),
+                task_aff_mask=np.ones((1, capN), bool),
+                task_pref_idx=np.full(1, -1, np.int32),
+                task_pref_node=np.zeros((1, capN), np.float32),
+                task_pref_pod=np.zeros((1, capN), np.float32),
+            )
         lease = SnapshotLease(
             snap=dev,
             meta=meta,

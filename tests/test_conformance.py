@@ -645,6 +645,95 @@ class TestInterPodAffinity:
         assert cache.binder.binds["c1/near"] in ("n0", "n1")  # zone a only
 
 
+def _affinity_scenarios():
+    """The reference e2e scenarios that ``affinity-10k-5k`` is the at-size
+    twin of (SURVEY section 4.2; hostname anti-affinity is
+    ``test_pod_anti_affinity_spreads`` above): nodeorder.go's three, and a
+    zone-keyed required affinity over more than one zone.  Each is (nodes,
+    pods, check(binds))."""
+    from kube_batch_tpu.api.pod import Affinity, PodAffinityTerm
+
+    def pending(name, **kw):
+        return build_pod("c1", name, None, PodPhase.PENDING,
+                         {"cpu": 1000, "memory": GiB}, **kw)
+
+    def running(name, node, **kw):
+        return build_pod("c1", name, node, PodPhase.RUNNING,
+                         {"cpu": 1000, "memory": GiB}, **kw)
+
+    four = [build_node(f"n{i}", cpu=8000, mem=16 * GiB) for i in range(4)]
+    zoned = [build_node(f"n{i}", cpu=8000, mem=16 * GiB,
+                        labels={"zone": "abc"[i // 2]}) for i in range(6)]
+    db = PodAffinityTerm(match_labels={"app": "db"})
+    return {
+        # nodeorder.go:29 "Node Affinity": a preferred term steers
+        "node_affinity": (
+            [build_node("plain", cpu=8000, mem=16 * GiB),
+             build_node("ssd", cpu=8000, mem=16 * GiB, labels={"disk": "ssd"})],
+            [pending("p", affinity=Affinity(preferred_node_terms=[
+                (50.0, [("disk", "In", ("ssd",))])]))],
+            lambda b: b["c1/p"] == "ssd"),
+        # nodeorder.go:74 "Pod Affinity": soft co-location
+        "pod_affinity": (
+            four,
+            [running("anchor", "n2", labels={"app": "db"}),
+             pending("near", affinity=Affinity(
+                 preferred_pod_affinity=[(50.0, db)]))],
+            lambda b: b["c1/near"] == "n2"),
+        # nodeorder.go:138 "Least Requested": the idler node
+        "least_requested": (
+            [build_node("busy", cpu=8000, mem=16 * GiB),
+             build_node("idle", cpu=8000, mem=16 * GiB)],
+            [build_pod("c1", "resident", "busy", PodPhase.RUNNING,
+                       {"cpu": 6000, "memory": 8 * GiB}),
+             pending("new")],
+            lambda b: b["c1/new"] == "idle"),
+        # a required affinity under a zone key, three zones: the follower
+        # lands in the anchor's zone, on either of its nodes
+        "zone_affinity": (
+            zoned,
+            [running("anchor", "n3", labels={"app": "db"}),
+             pending("near", affinity=Affinity(pod_affinity=[
+                 PodAffinityTerm(match_labels={"app": "db"},
+                                 topology_key="zone")]))],
+            lambda b: b["c1/near"] in ("n2", "n3")),
+        # and its anti twin: one pod a zone, the third zone is the free one
+        "zone_anti_affinity": (
+            zoned,
+            [running("a", "n0", labels={"app": "db"}),
+             running("b", "n5", labels={"app": "db"}),
+             pending("c", labels={"app": "db"}, affinity=Affinity(
+                 pod_anti_affinity=[PodAffinityTerm(
+                     match_labels={"app": "db"}, topology_key="zone")]))],
+            lambda b: b["c1/c"] in ("n2", "n3")),
+    }
+
+
+@pytest.mark.parametrize("path", ["planes", "object_scan"])
+@pytest.mark.parametrize("scenario", sorted(_affinity_scenarios()))
+def test_affinity_scenarios_on_the_planes_and_on_the_object_scan(
+        scenario, path):
+    """Each scenario through the columnar store's match-count planes (the
+    exclusive session every deployment runs) and through the object-scan
+    oracle (an isolated session: ``build_snapshot`` calls
+    ``pod_affinity_ok`` / ``preferred_pod_affinity_score`` per node, and the
+    host predicate re-validates at replay)."""
+    from kube_batch_tpu.framework.conf import parse_scheduler_conf
+    from kube_batch_tpu.framework.interface import get_action
+    from kube_batch_tpu.framework.session import close_session, open_session
+    from tests.test_actions import TWO_TIER_CONF
+
+    nodes, pods, check = _affinity_scenarios()[scenario]
+    cache = build_cache(queues=["default"], nodes=nodes, pods=pods)
+    ssn = open_session(cache, parse_scheduler_conf(TWO_TIER_CONF).tiers,
+                       isolated=(path == "object_scan"))
+    assert (ssn.columns is None) == (path == "object_scan")
+    get_action("allocate").execute(ssn)
+    close_session(ssn)
+    cache.flush_binds()
+    assert check(cache.binder.binds), cache.binder.binds
+
+
 class TestPreferredAffinity:
     def test_preferred_node_affinity_steers(self):
         """e2e nodeorder.go "Node Affinity" (:29): a preferred term steers

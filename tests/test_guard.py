@@ -522,7 +522,8 @@ class TestInvariantMath:
         from kube_batch_tpu.api.snapshot import DeviceSnapshot
 
         ab = abstract_snapshot()
-        z = DeviceSnapshot(*[jnp.zeros(s.shape, s.dtype) for s in ab])
+        z = DeviceSnapshot(*[jnp.zeros(s.shape, s.dtype) for s in ab
+                             if s is not None])  # aff_terms: no leaf
         T, R, N, J = 16, 3, 8, 4
         return z._replace(
             task_req=jnp.ones((T, R), jnp.float32),

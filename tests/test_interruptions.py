@@ -680,15 +680,21 @@ class TestPlantedStall:
             with urllib.request.urlopen(req, timeout=30) as r:
                 assert json.loads(r.read())["ok"] is True
 
-        def send(name):
-            """A one-pod gang in q0, as a client posts it."""
+        def send_group(name):
             post("podgroups", [{"name": name, "namespace": "st",
                                 "uid": f"pg-{name}", "min_member": 1,
                                 "queue": "q0"}])
+
+        def send_pod(name):
             post("pods", [{"name": name, "namespace": "st",
                            "uid": f"u-{name}", "requests": {"cpu": 500.0},
                            "phase": "Pending",
                            "annotations": {GROUP_NAME_ANNOTATION: name}}])
+
+        def send(name):
+            """A one-pod gang in q0, as a client posts it."""
+            send_group(name)
+            send_pod(name)
 
         def bound(name, timeout):
             deadline = time.monotonic() + timeout
@@ -711,6 +717,13 @@ class TestPlantedStall:
             deadline = time.monotonic() + 10
             while sched._watchdog._open and time.monotonic() < deadline:
                 time.sleep(0.02)            # the compile was a stall itself
+            # the late pod's PodGroup goes ahead of the hold, so that ONE
+            # request wakes the loop under it: with both under it, a loaded
+            # machine puts them more than the settle's few ms apart, the
+            # first wakes a cycle that drains the PodGroup alone and the pod
+            # is decided by the next (two kept rows; seen under -n 6)
+            send_group("late")
+            time.sleep(0.3)
             declared0, seconds0 = _stalls("cycle")
             holding = threading.Event()
             holder = threading.Thread(
@@ -719,7 +732,7 @@ class TestPlantedStall:
             holder.start()
             assert holding.wait(10)
             held_from = time.monotonic()
-            send("late")                    # staged; the drain needs the lock
+            send_pod("late")                # staged; the drain needs the lock
             assert bound("late", 30.0)
             holder.join(timeout=10)
             waited = time.monotonic() - held_from
