@@ -618,7 +618,7 @@ _DEVICE_SOURCES = {
 }
 #: local-name fallbacks for intra-module dispatch helpers: direct calls
 #: (`..._solve(...)`) and the jitted-fn factory form the resident scatters
-#: use (`_scatter_fn()(dev, ...)`, `_mesh_shard_scatter_fn(mesh)(dev, ...)`)
+#: use (`_swap_scatter_fn()(devs, ...)`, `_mesh_shard_scatter_fn(mesh)(devs, ...)`)
 _DEVICE_SOURCE_SUFFIXES = ("_solve", "solve_dispatch")
 _DEVICE_FACTORY_SUFFIXES = ("_scatter_fn", "_gate_fn")
 

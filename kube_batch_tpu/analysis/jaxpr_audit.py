@@ -10,7 +10,7 @@ module is the JaxPruner-style answer (PAPERS.md): audit what actually gets
 compiled, not what the source looks like.
 
 Mechanism: a REGISTRY of the package's jitted entry points (ops/ solves,
-the resident scatter).  Each entry is traced with
+the resident swap).  Each entry is traced with
 ABSTRACT inputs (jax.ShapeDtypeStruct — no device work, no compile) under
 ``jax.enable_x64`` so dtype promotion is visible instead of
 silently canonicalized away, then the closed jaxpr is walked recursively
@@ -26,7 +26,7 @@ silently canonicalized away, then the closed jaxpr is walked recursively
   inside a hot-path program: a host round-trip per invocation.
 - **KBT104 donation mismatch** — the wrapper's traced donate_argnums
   differ from what the registry entry declares for the current backend
-  (e.g. someone drops donate_argnums from the resident scatter: CPU tests
+  (e.g. someone drops donate_argnums from the resident swap: CPU tests
   stay green, every TPU cycle silently double-allocates).
 
 Suppression: registry entries carry ``allow={"KBT10x": "reason"}`` — the
@@ -64,7 +64,7 @@ class EntryPoint:
     values.  ``build`` accepts an optional ShapePoint: ``build()`` traces at
     the tier-B audit extents, ``build(sp)`` at a tier-C shape-ladder point.
     ``donate`` maps backend name → expected donate_argnums, with ``"*"`` as
-    the fallback (the resident scatter donates everywhere except CPU).
+    the fallback (the resident swap donates everywhere except CPU).
     ``allow`` suppresses one audit rule for this entry, reason mandatory.
     ``steady`` declares the program steady-path/sparse: dispatched every
     cycle at scale, so tier C's KBT202 asserts it materializes no
@@ -124,14 +124,13 @@ class ShapePoint:
     warm_pi: int         # warm rerank rung (re-ranked rows per refresh)
     probe_b: int = 2     # what-if probe batch
     probe_g: int = 4     # what-if gang width
-    scatter_rows: int = 64  # resident scatter's device-ledger rows
 
 
 #: tier B's extents as a ShapePoint — `build()` with no argument traces here
 _AUDIT_POINT = ShapePoint(
     name="audit", tasks=_T, nodes=_N, T=_T, N=_N, J=_J, Q=_Q, R=_R, W=_W,
     K_aff=_K, P=8, topk=2, warm_w=4, warm_c=4, warm_pi=4,
-    probe_b=2, probe_g=4, scatter_rows=64,
+    probe_b=2, probe_g=4,
 )
 
 
@@ -163,7 +162,6 @@ def shape_point(name: str, tasks: int, nodes: int, R: int = 8,
         name=name, tasks=tasks, nodes=nodes, T=T, N=N, J=J, Q=8, R=R, W=W,
         K_aff=4, P=P, topk=k, warm_w=k + WARM_WIDTH_MARGIN, warm_c=warm_c,
         warm_pi=warm_rerank_rungs(P)[-1], probe_b=2, probe_g=4,
-        scatter_rows=N,
     )
 
 
@@ -333,18 +331,37 @@ def _build_evict(mode, compact, sp: Optional[ShapePoint] = None):
     return evict_solve, (_snap(ax), EvictConfig(mode=mode)) + rows
 
 
-def _build_resident_scatter(sp: Optional[ShapePoint] = None):
+def _abstract_swap_args(ax: ShapePoint, fields, slots: int, lead=()):
+    """(buffers, row-index block, value blocks, layout) of a resident swap
+    program over `fields` at its widest slot bucket — every column of the
+    snapshot at `ax` rides, whatever its size (the production layout
+    leaves out only columns smaller than their own payload)."""
     import jax.numpy as jnp
     from jax import ShapeDtypeStruct as S
 
-    from kube_batch_tpu.api.resident import SCATTER_SLOTS, _scatter_fn
+    from kube_batch_tpu.api.resident import swap_layout
 
-    ax = sp or _AUDIT_POINT
-    return _scatter_fn(), (
-        S((ax.scatter_rows, ax.R), jnp.float32),
-        S((SCATTER_SLOTS,), jnp.int32),
-        S((SCATTER_SLOTS, ax.R), jnp.float32),
+    snap = _snap(ax)
+    devs = {f: getattr(snap, f) for f in fields}
+    layout = swap_layout(devs)
+    return (
+        devs,
+        S(lead + (len(layout.fields), slots), jnp.int32),
+        tuple(S(lead + (slots, width), jnp.dtype(dtype))
+              for dtype, width in layout.groups),
+        layout,
     )
+
+
+def _build_resident_swap(sp: Optional[ShapePoint] = None):
+    from kube_batch_tpu.api.resident import (
+        SCATTER_SLOTS,
+        SWAP_FIELDS,
+        _swap_scatter_fn,
+    )
+
+    return _swap_scatter_fn(), _abstract_swap_args(
+        sp or _AUDIT_POINT, SWAP_FIELDS, SCATTER_SLOTS)
 
 
 def _build_enqueue_gate(sp: Optional[ShapePoint] = None):
@@ -395,9 +412,10 @@ def _build_probe(sp: Optional[ShapePoint] = None):
     )
 
 
-def _scatter_donation() -> Dict[str, Tuple[int, ...]]:
-    # the resident scatter donates the stale device buffer everywhere
-    # donation is supported; CPU skips it (api/resident.py's own gate)
+def _swap_donation() -> Dict[str, Tuple[int, ...]]:
+    # a resident swap program donates the dict of stale device buffers it
+    # refreshes (argument 0, every leaf) everywhere donation is supported;
+    # CPU skips it (api/resident.py's own gate)
     return {"cpu": (), "*": (0,)}
 
 
@@ -477,8 +495,8 @@ REGISTRY: Tuple[EntryPoint, ...] = (
     EntryPoint("ops.eviction.evict_solve[preempt,compact]",
                lambda sp=None: _build_evict("preempt", True, sp),
                steady=True),
-    EntryPoint("api.resident.scatter", _build_resident_scatter,
-               donate=_scatter_donation(), steady=True),
+    EntryPoint("api.resident.swap", _build_resident_swap,
+               donate=_swap_donation(), steady=True),
     EntryPoint("ops.admission.enqueue_gate", _build_enqueue_gate,
                steady=True),
     EntryPoint("ops.probe.probe_solve", _build_probe, steady=True),
@@ -641,37 +659,28 @@ def _build_sharded_gate(mesh, sp: Optional[ShapePoint] = None):
     )
 
 
-def _build_shard_scatter(mesh, sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
+def _build_shard_swap(mesh, sp: Optional[ShapePoint] = None):
     from kube_batch_tpu.api.resident import (
+        NODE_SWAP_FIELDS,
         SHARD_SCATTER_SLOTS,
         _mesh_shard_scatter_fn,
     )
     from kube_batch_tpu.parallel.mesh import NODE_AXIS
 
-    ax = sp or _AUDIT_POINT
     d = int(dict(mesh.shape)[NODE_AXIS])  # node-axis extent, not device count
-    return _mesh_shard_scatter_fn(mesh), (
-        S((ax.N, ax.R), jnp.float32),
-        S((d, SHARD_SCATTER_SLOTS), jnp.int32),
-        S((d, SHARD_SCATTER_SLOTS, ax.R), jnp.float32),
+    return _mesh_shard_scatter_fn(mesh), _abstract_swap_args(
+        sp or _AUDIT_POINT, NODE_SWAP_FIELDS, SHARD_SCATTER_SLOTS, lead=(d,))
+
+
+def _build_repl_swap(mesh, sp: Optional[ShapePoint] = None):
+    from kube_batch_tpu.api.resident import (
+        REPL_SWAP_FIELDS,
+        SCATTER_SLOTS,
+        _mesh_repl_scatter_fn,
     )
 
-
-def _build_repl_scatter(mesh, sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.api.resident import SCATTER_SLOTS, _mesh_repl_scatter_fn
-
-    ax = sp or _AUDIT_POINT
-    return _mesh_repl_scatter_fn(mesh), (
-        S((ax.T,), jnp.int32),
-        S((SCATTER_SLOTS,), jnp.int32),
-        S((SCATTER_SLOTS,), jnp.int32),
-    )
+    return _mesh_repl_scatter_fn(mesh), _abstract_swap_args(
+        sp or _AUDIT_POINT, REPL_SWAP_FIELDS, SCATTER_SLOTS)
 
 
 def sharded_registry(n_devices: Optional[int] = None
@@ -747,12 +756,12 @@ def sharded_registry(n_devices: Optional[int] = None
     entries += [
         EntryPoint("parallel.mesh.sharded_enqueue_gate",
                    p(_build_sharded_gate, mesh), steady=True),
-        EntryPoint("api.resident.scatter_sharded",
-                   p(_build_shard_scatter, mesh),
-                   donate=_scatter_donation(), steady=True),
-        EntryPoint("api.resident.scatter_repl",
-                   p(_build_repl_scatter, mesh),
-                   donate=_scatter_donation(), steady=True),
+        EntryPoint("api.resident.swap_sharded",
+                   p(_build_shard_swap, mesh),
+                   donate=_swap_donation(), steady=True),
+        EntryPoint("api.resident.swap_repl",
+                   p(_build_repl_swap, mesh),
+                   donate=_swap_donation(), steady=True),
     ]
     if n_dev >= 4 and n_dev % 2 == 0 and _T % 2 == 0:
         mesh2 = make_mesh(n_dev, task_shards=2)
@@ -862,7 +871,12 @@ def audit_entry(entry: EntryPoint) -> List[Finding]:
 
     expected = entry.donate.get(
         jax.default_backend(), entry.donate.get("*", ()))
-    actual = tuple(sorted(traced.donate_argnums or ()))
+    # positional argnums, as the registry declares them: the trace reports
+    # donation per flat LEAF, and a swap program's argument 0 is a dict
+    actual = tuple(
+        i for i, arg in enumerate(traced.args_info[0])
+        if any(leaf.donated for leaf in jax.tree_util.tree_leaves(arg))
+    )
     if tuple(sorted(expected)) != actual:
         raw.append((
             "KBT104",

@@ -78,20 +78,38 @@ N_PHASES = len(CODE_PHASE)
 def resident_snap(cols, snap, mesh=None):
     """The call-site shape for the device-resident snapshot cache: swap in
     cached device arrays when a ColumnStore backs the session, pass the
-    snapshot through untouched otherwise.  Static ingest features ride the
-    version-keyed cache (resident_features); the per-cycle columns ride the
-    scatter-delta cache (api/resident.py) — single-device scatters when
-    `mesh` is None, per-shard NamedSharding-placed scatters on the
-    mesh-sharded solve path."""
+    snapshot through untouched otherwise.  The per-cycle columns and the
+    task feature columns ride the packed-delta cache (api/resident.py) —
+    one single-device program a swap when `mesh` is None, NamedSharding-
+    placed programs on the mesh-sharded solve path; the node feature
+    columns ride the version-keyed cache (resident_features).
+
+    Memoized on the exact `snap` object (and mesh): a repeat call — the
+    same cycle's oracle or histogram dispatch, the lease publish after the
+    solve — returns the IDENTICAL device snapshot with no diff, no version
+    bump and no dispatch, for as long as no other swap has run since."""
     if cols is None:
         return snap
+    def swapped():
+        """The resident cache this path holds and the swaps it has made:
+        the memo stands only while neither has moved."""
+        cache = cols._per_cycle_dev.get(mesh)
+        return cache, 0 if cache is None else cache.version
+
+    memo = cols._resident_memo
+    if (memo is not None and memo[0] is snap and memo[1] is mesh
+            and memo[2] == swapped()):
+        return memo[3]
+    out = snap
     if mesh is not None and snap.aff_terms is not None:
         # the in-solve rule of the required inter-pod terms runs on one
         # device; a sharded solve's termed placements are re-validated by
         # the host predicate at replay, as all of them were before
-        snap = snap._replace(aff_terms=None)
-    snap = cols.resident_features(snap, mesh=mesh)
-    return cols.per_cycle_resident(snap, mesh=mesh)
+        out = out._replace(aff_terms=None)
+    out = cols.resident_features(cols.per_cycle_resident(out, mesh=mesh),
+                                 mesh=mesh)
+    cols._resident_memo = (snap, mesh, swapped(), out)
+    return out
 
 
 def _grow(arr: np.ndarray, cap: int) -> np.ndarray:
@@ -274,14 +292,19 @@ class ColumnStore:
         # ---- device-resident feature cache ------------------------------
         # The ingest-static snapshot columns (task requests/bits/priorities,
         # node allocatable/bits) change only at the ingest choke points that
-        # bump the per-axis feature versions; resident_features() re-uploads them to the
-        # device ONLY when it moved — per-cycle host→device traffic drops to
-        # the genuinely per-cycle columns (statuses, node ledgers, job rows),
-        # the SURVEY §7.3 one-transfer-in budget.  Disabled with
+        # bump the per-axis feature versions.  The node columns (nodes do
+        # not churn) are re-uploaded whole by resident_features() ONLY when
+        # their version moved; the task columns, which every pod that comes
+        # or goes writes a row of, ride the resident swap's packed delta
+        # (api/resident.py), which skips their diff while the task version
+        # stands still — per-cycle host→device traffic is the rows that
+        # changed, the SURVEY §7.3 one-transfer-in budget.  Disabled with
         # KB_DEVICE_CACHE=0.
         self.task_feature_version = 0
         self.node_feature_version = 0
         self._dev_cache: Dict = {}
+        # resident_snap's memo: (host snap, mesh, (cache, its version), out)
+        self._resident_memo = None
         # per-cycle device-resident caches (api/resident.py), keyed by mesh
         # (None = the single-device scatter cache): the truly per-cycle
         # snapshot columns stay alive on device between cycles — sharded
@@ -937,26 +960,16 @@ class ColumnStore:
         for row in self._tol_rows:
             self._fill_tol_bits(row, self.task_by_row[row])
 
-    # snapshot field → (backing column, version axis): per-axis versions
-    # keep pod churn (every successful bind produces a pod update) from
-    # flushing the node columns and vice versa
+    # snapshot field → backing column of the version-keyed NODE feature
+    # cache (the task feature columns are api/resident.py's
+    # TASK_FEATURE_FIELDS: pod churn rewrites their rows every burst, so
+    # they ride the resident swap's delta instead)
     FEATURE_FIELDS = {
-        "task_req": ("t_init32", "task"),
-        "task_resreq": ("t_res32", "task"),
-        "task_job": ("t_job", "task"),
-        "task_prio": ("t_prio", "task"),
-        "task_creation": ("t_creation", "task"),
-        "task_best_effort": ("t_best_effort", "task"),
-        "task_critical": ("t_critical", "task"),
-        "task_needs_host": ("t_needs_host", "task"),
-        "task_sel_bits": ("t_sel_bits", "task"),
-        "task_sel_impossible": ("t_sel_impossible", "task"),
-        "task_tol_bits": ("t_tol_bits", "task"),
         # n_alloc32: the dirty-row-refreshed f32 twin (node_ledgers32) — the
         # device snapshot build always refreshes it before any dispatch
-        "node_alloc": ("n_alloc32", "node"),
-        "node_label_bits": ("n_label_bits", "node"),
-        "node_taint_bits": ("n_taint_bits", "node"),
+        "node_alloc": "n_alloc32",
+        "node_label_bits": "n_label_bits",
+        "node_taint_bits": "n_taint_bits",
     }
 
     def bump_node_features(self) -> None:
@@ -1011,10 +1024,11 @@ class ColumnStore:
         return self.n_idle32, self.n_rel32, self.n_used32, self.n_alloc32
 
     def per_cycle_resident(self, snap, mesh=None):
-        """Swap the per-cycle snapshot columns for their device-resident
-        copies, refreshed by scatter deltas (api/resident.py) — sharded
-        placements when `mesh` is given.  Shares the KB_DEVICE_CACHE kill
-        switch with the static feature cache."""
+        """Swap the per-cycle snapshot columns and the task feature columns
+        for their device-resident copies, refreshed by one packed delta
+        (api/resident.py) — sharded placements when `mesh` is given.
+        Shares the KB_DEVICE_CACHE kill switch with the node feature
+        cache."""
         import os
 
         if os.environ.get("KB_DEVICE_CACHE", "").strip().lower() in (
@@ -1050,16 +1064,16 @@ class ColumnStore:
             self._per_cycle_dev[mesh] = cache
         guard = self.resident_swap_guard
         if guard is not None:
-            # the swap's scatters DONATE the resident buffers a published
+            # the swap's program DONATES every resident buffer a published
             # lease may still reference — the guard (serve/lease.py)
             # excludes probe dispatches for the swap's duration and retires
             # the stale lease on donating backends
             with guard():
-                out = cache.swap(snap)
+                out = cache.swap(snap, self.task_feature_version)
         else:
-            out = cache.swap(snap)
+            out = cache.swap(snap, self.task_feature_version)
         # feed this swap's row-exact delta record to the warm-table carry
-        # (idempotent per cache version — the memoized repeat swap above
+        # (idempotent per cache version — a memoized repeat swap
         # re-notifies the same record harmlessly)
         for st in self._warm_tables.values():
             if st.mesh is mesh:
@@ -1099,6 +1113,7 @@ class ColumnStore:
         guard heal must not leave a possibly-corrupt ranking behind."""
         self._per_cycle_dev.clear()
         self._dev_cache.clear()
+        self._resident_memo = None
         self.drop_warm_tables()
 
     # ---- warm-started allocate: carried candidate tables (KB_WARM) ----
@@ -1151,15 +1166,17 @@ class ColumnStore:
         }
 
     def resident_features(self, snap, mesh=None):
-        """`snap` with the ingest-static feature arrays swapped for cached
-        DEVICE-RESIDENT copies, re-uploaded only when the column's axis
-        version moved since the last call — steady-state cycles then ship only the truly
-        per-cycle columns (statuses, node ledgers, job/queue rows) to the
-        device (SURVEY §7.3's one-transfer-in budget).  `shardings`/`key` select a placement (the
-        mesh solve needs mesh-sharded uploads; committed single-device
-        arrays would be rejected by its in_shardings).  Callers keep using
-        the ORIGINAL host-backed snap for numpy reads — only the returned
-        copy goes to the solve.  KB_DEVICE_CACHE=0 disables."""
+        """`snap` with the node feature arrays (allocatable, label / taint
+        bits) swapped for cached DEVICE-RESIDENT copies, re-uploaded whole
+        only when the node feature version moved since the last call —
+        nodes do not churn, so steady-state cycles ship none of them.  (The
+        task feature columns ride the resident swap's packed delta,
+        per_cycle_resident: a pod that comes or goes moves its rows, not
+        the columns.)  `mesh` selects the placement (the mesh solve needs
+        mesh-sharded uploads; committed single-device arrays would be
+        rejected by its in_shardings).  Callers keep using the ORIGINAL
+        host-backed snap for numpy reads — only the returned copy goes to
+        the solve.  KB_DEVICE_CACHE=0 disables."""
         import os
 
         if os.environ.get("KB_DEVICE_CACHE", "").strip().lower() in (
@@ -1174,20 +1191,15 @@ class ColumnStore:
 
             shardings = snapshot_shardings(mesh)
         cache = self._dev_cache.setdefault(mesh, {})
-        versions = {"task": self.task_feature_version,
-                    "node": self.node_feature_version}
+        version = self.node_feature_version
         updates = {}
-        for field, (col, axis) in self.FEATURE_FIELDS.items():
-            version = versions[axis]
+        for field, col in self.FEATURE_FIELDS.items():
             ver, arr = cache.get(field, (-1, None))
             host = getattr(self, col)
             if ver != version or arr.shape != host.shape:
-                sharding = (
-                    getattr(shardings, field) if shardings is not None else None
-                )
                 arr = (
-                    jax.device_put(host, sharding)
-                    if sharding is not None else jax.device_put(host)
+                    jax.device_put(host, getattr(shardings, field))
+                    if shardings is not None else jax.device_put(host)
                 )
                 cache[field] = (version, arr)
             updates[field] = arr

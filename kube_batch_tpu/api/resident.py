@@ -1,27 +1,40 @@
-"""Per-cycle device-resident snapshot columns with scatter-delta refresh.
+"""Device-resident snapshot columns, refreshed by ONE packed delta a swap.
 
-``ColumnStore.resident_features`` already keeps the ingest-static columns
-(task requests/bitsets, node allocatable) alive on device across cycles.
-This module extends residency to the *per-cycle* columns — statuses, node
-ledgers, job/queue rows — which until now were re-uploaded wholesale by
-every solve dispatch even when a steady-state cycle changed a few hundred
-rows out of 50k.
+Every column a solve reads from the device lives here between cycles: the
+*per-cycle* columns (statuses, node ledgers, job/queue rows) and the task
+*feature* columns (requests, priorities, selector/toleration bitsets) that
+pod churn rewrites a few rows of on every burst.  Only the three node
+feature columns stay on ``ColumnStore.resident_features``' version gate
+(nodes do not churn).
 
 Mechanism: for each cached field the host keeps a mirror of what the device
-holds.  Each cycle the freshly built host column is diffed against the
-mirror (one vectorized compare — cheaper than the upload it replaces):
+holds.  Each swap the freshly built host column is diffed against the
+mirror (one vectorized compare — cheaper than the upload it replaces; the
+task feature columns skip even that while the store's task feature version
+has not moved):
 
 - no rows changed  → the cached device array is handed to the solve as-is;
-- a small delta    → the (rows, values) pair is padded to a FIXED slot
-  count and applied on device as one scatter (``.at[rows].set(mode="drop")``
-  with out-of-range padding indices), with the stale device buffer DONATED
-  to the update so XLA writes in place instead of allocating;
-- a large delta or a shape change (axis growth) → full re-upload.
+- a small delta    → the field's (rows, values) join the swap's ONE packed
+  payload: an ``int32 [F, slots]`` row-index block plus one ``[slots,
+  width]`` value block per dtype, padded to a FIXED slot count with
+  out-of-range indices (each index row sorted and unique, declared so).  One jitted program takes the dict of resident
+  buffers and the payload and returns the dict refreshed
+  (``.at[rows].set(vals, mode="drop")`` per field; a field with nothing to
+  write this swap carries all-padding rows), with every stale buffer
+  DONATED so XLA writes in place;
+- a large delta, a shape change (axis growth), or a column smaller than
+  its own scatter payload → whole re-upload (``device_put``) of that field.
 
-The fixed slot width keeps the scatter's jit cache to one specialization
-per (field shape, dtype): steady-state cycles compile nothing (the
-bench's retrace counters prove it).  Values are bit-identical to a full
-upload by construction — the scatter writes exactly the host rows — and
+So a swap is one program dispatch plus one ``device_put`` per whole upload,
+whatever number of fields moved (``counters()["dispatches"]``).  The slot
+width is one bucket for the whole swap — the smallest of
+``SCATTER_SLOT_BUCKETS`` that holds the widest field's delta — so the
+program has three specializations per set of column shapes.  All three are
+compiled at the cold upload (and again whenever a column's shape changes),
+in two passes so that both upload-placed and program-output buffers have
+been seen: steady-state cycles compile nothing (the bench's retrace
+counters prove it).  Values are bit-identical to a whole upload by
+construction — the scatter writes exactly the host rows — and
 tests/test_snapshot_delta.py checks the round-trip.
 
 Donation is skipped on the CPU backend (unsupported there; jax would warn
@@ -30,42 +43,45 @@ every cycle).
 Mesh-sharded residency (:class:`ShardedPerCycleDeviceCache`): the sharded
 solve keeps the same columns alive as ``NamedSharding``-placed buffers —
 node-axis columns sharded over the mesh, everything else replicated — and
-refreshes them with PER-SHARD fixed-width donated scatter deltas.  The
-changed rows are partitioned by owning shard on the host and shipped as
-``[n_shards, slots]`` LOCAL indices + values whose leading axis carries the
-mesh sharding, so the jitted update (a vmapped per-shard scatter with
-explicit ``in_shardings``/``out_shardings``) routes each delta slice
-straight to its owning chip — no gather, no reshard, no cross-chip traffic.
-Fallbacks to a full (sharded) re-upload: cold cache, axis growth, a delta
-wider than the per-shard slot budget (high churn), or a mesh change (the
-ColumnStore drops the old mesh's cache wholesale — see
+refreshes them with at most TWO programs a swap: the replicated columns
+ride the same packed program under explicit replicated shardings, and the
+node-axis columns ride a per-shard twin of it.  The changed node rows are
+partitioned by owning shard on the host and shipped as ``[n_shards, F,
+slots]`` LOCAL indices + ``[n_shards, slots, width]`` values whose leading
+axis carries the mesh sharding, so the jitted update (a vmapped per-shard
+scatter with explicit ``in_shardings``/``out_shardings``) routes each
+delta slice straight to its owning chip — no gather, no reshard, no
+cross-chip traffic.  Fallbacks to a whole (sharded) re-upload: cold cache,
+axis growth, a delta wider than the per-shard slot budget (high churn), or
+a mesh change (the ColumnStore drops the old mesh's cache wholesale — see
 ``per_cycle_resident``; the shape buckets are divisible by any
 power-of-two mesh axis, and jax itself rejects an indivisible placement
 before any solve could run).
 
-Donation audit (PR 4): every donating call site in this module rebinds the
-donated name to the call's result (``dev = _scatter_fn()(dev, ...)``) —
-the shape KBT006 (analysis/flowrules.py) verifies package-wide, so a
+Donation audit (PR 4, PR 42): the one donating call site in this module,
+``PerCycleDeviceCache._dispatch``, rebinds the donated name to the call's
+result (``devs = _swap_scatter_fn()(devs, rows, vals, layout)``, and its
+mesh twins in the sharded cache's override) before anything reads it
+— the shape KBT006 (analysis/flowrules.py) verifies package-wide, so a
 post-donation read introduced later fails the tier-1 self-enforcement
-test.  The scatters (single-device AND per-mesh) are registered in the
-jaxpr audit (analysis/jaxpr_audit.py), which asserts their donation wiring
-per backend (KBT104) and that no f64/transfer/callback sneaks into the
-traced update.
+test.  The swap programs (single-device AND per-mesh) are registered in
+the jaxpr audit (analysis/jaxpr_audit.py), which asserts their donation
+wiring per backend (KBT104) and that no f64/transfer/callback sneaks into
+the traced update.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from kube_batch_tpu.utils import jitstats
 
-# snapshot fields refreshed per cycle (everything the static feature cache
-# does not own, minus the variable-K sparse affinity rows)
+# snapshot fields rebuilt every cycle (minus the variable-K sparse affinity
+# rows, which ship with each solve)
 PER_CYCLE_FIELDS: Tuple[str, ...] = (
     "task_status", "task_node", "task_valid", "task_pending",
-    "task_best_effort",
     "node_idle", "node_releasing", "node_used", "node_valid", "node_sched",
     "job_min_avail", "job_ready", "job_queue", "job_prio", "job_creation",
     "job_valid", "job_schedulable", "job_allocated",
@@ -74,32 +90,59 @@ PER_CYCLE_FIELDS: Tuple[str, ...] = (
     "total",
 )
 
+#: the task feature columns: written at ingest (alloc_task / free_task /
+#: refresh_task_bits, each of which bumps
+#: ``ColumnStore.task_feature_version``), a few rows a burst — they ride the
+#: swap's delta like the per-cycle fields, gated on that version
+TASK_FEATURE_FIELDS: Tuple[str, ...] = (
+    "task_req", "task_resreq", "task_job", "task_prio", "task_creation",
+    "task_best_effort", "task_critical", "task_needs_host",
+    "task_sel_bits", "task_sel_impossible", "task_tol_bits",
+)
+_TASK_FEATURES = frozenset(TASK_FEATURE_FIELDS)
+
+#: everything one swap refreshes, in the order it stages them
+SWAP_FIELDS: Tuple[str, ...] = PER_CYCLE_FIELDS + TASK_FEATURE_FIELDS
+
 #: the subset whose leading axis is the node axis — sharded over the mesh
 #: on the sharded solve path (parallel/mesh.snapshot_shardings); everything
 #: else replicates
 NODE_AXIS_FIELDS = frozenset((
     "node_idle", "node_releasing", "node_used", "node_valid", "node_sched",
 ))
+#: the sharded cache's two programs' fields: the per-shard one's, and the
+#: replicated one's
+NODE_SWAP_FIELDS = tuple(f for f in SWAP_FIELDS if f in NODE_AXIS_FIELDS)
+REPL_SWAP_FIELDS = tuple(f for f in SWAP_FIELDS if f not in NODE_AXIS_FIELDS)
 
-#: fixed scatter width buckets — a delta ships at the smallest bucket that
-#: holds it, so tiny steady-state deltas don't pay the worst-case payload;
-#: every bucket is pre-warmed at full-upload time, so the bounded set of
-#: specializations per (field shape, dtype) never retraces mid-steady-state.
-#: Deltas wider than the largest bucket take the full-upload path (at which
-#: point the transfer is no longer the bottleneck anyway).
+#: fixed scatter width buckets — a swap ships at the smallest bucket that
+#: holds its widest field's delta, so tiny steady-state deltas don't pay
+#: the worst-case payload; every bucket is pre-warmed at the cold upload,
+#: so the bounded set of specializations per set of column shapes never
+#: retraces mid-steady-state.  A field whose delta is wider than the
+#: largest bucket takes the whole-upload path (at which point the transfer
+#: is no longer the bottleneck anyway).
 SCATTER_SLOT_BUCKETS: Tuple[int, ...] = (64, 512, 4096)
 SCATTER_SLOTS = SCATTER_SLOT_BUCKETS[-1]
 
-#: per-shard slot-width buckets of the mesh scatter: the [n_shards, slots]
-#: delta is sharded on its leading axis, so each chip receives exactly its
-#: own slice.  This static ladder is the DEFAULT (zero observed churn);
-#: the sharded cache retargets its live ladder from the churn EWMA
+#: per-shard slot-width buckets of the mesh scatter: the [n_shards, F,
+#: slots] delta is sharded on its leading axis, so each chip receives
+#: exactly its own slice.  This static ladder is the DEFAULT (zero observed
+#: churn); the sharded cache retargets its live ladder from the churn EWMA
 #: (:func:`adaptive_ladder`), capped by SHARD_SCATTER_SLOTS.
 SHARD_SCATTER_SLOT_BUCKETS: Tuple[int, ...] = (16, 128, 1024)
 SHARD_SCATTER_SLOTS = SHARD_SCATTER_SLOT_BUCKETS[-1]
 
 #: churn EWMA smoothing for the adaptive per-shard ladder
 CHURN_EWMA_DECAY = 0.8
+
+#: where a payload's padding row indices start: slot k of a field's index
+#: row holds either a changed row or PAD_ROW + k — out of range for every
+#: column, so mode="drop" discards the write and the program's shape
+#: depends only on the (pre-warmed) slot bucket, never on a delta's size;
+#: and, the changed rows ascending below it, every index row is SORTED and
+#: UNIQUE, which the scatter declares so XLA need not sort or combine
+PAD_ROW = 1 << 30
 
 
 def _slot_bucket(n: int, buckets: Tuple[int, ...]) -> int:
@@ -146,41 +189,104 @@ def adaptive_ladder(ewma: float, max_slots: int) -> Tuple[int, ...]:
     return tuple(ladder)
 
 
-_SCATTER = None
+# --------------------------------------------------------------------------
+# the packed payload and the programs that apply it
+# --------------------------------------------------------------------------
 
 
-def _scatter_fn():
-    """The shared jitted scatter — ONE module-level function so every cache
-    instance (simulator multi-scheduler runs, bench pairs, the test suite)
-    reuses the same compiled specializations and jitstats tracks a single
-    entry instead of retaining one wrapper per dead instance."""
-    global _SCATTER
-    if _SCATTER is None:
+class SwapLayout(NamedTuple):
+    """Where each field of one swap program sits in the packed payload —
+    hashable, so it is the program's STATIC argument: one specialization
+    per (layout, slot bucket).  ``fields[i]`` is ``(name, group, offset,
+    width)``: row ``i`` of the index block holds the field's row indices,
+    and columns ``offset : offset + width`` of value block ``group`` hold
+    its rows, flattened.  ``groups[g]`` is ``(dtype name, total width)``."""
+
+    fields: Tuple[Tuple[str, int, int, int], ...]
+    groups: Tuple[Tuple[str, int], ...]
+
+
+def _row_width(host) -> int:
+    """Elements in one row of a column (1 for a 1-D column)."""
+    width = 1
+    for extent in host.shape[1:]:
+        width *= int(extent)
+    return width
+
+
+def swap_layout(columns: Dict[str, np.ndarray]) -> SwapLayout:
+    """The payload layout for `columns` (field → host column, in staging
+    order): value blocks grouped by the dtype the DEVICE holds (the
+    canonical one — x64 is off, so a 64-bit host column lives 32-bit)."""
+    import jax
+
+    widths: Dict[str, int] = {}
+    placed = []
+    for name, host in columns.items():
+        dtype = np.dtype(jax.dtypes.canonicalize_dtype(host.dtype)).name
+        width = _row_width(host)
+        placed.append((name, dtype, widths.get(dtype, 0), width))
+        widths[dtype] = widths.get(dtype, 0) + width
+    order = sorted(widths)
+    return SwapLayout(
+        fields=tuple(
+            (name, order.index(dtype), off, width)
+            for name, dtype, off, width in placed
+        ),
+        groups=tuple((dtype, widths[dtype]) for dtype in order),
+    )
+
+
+def _apply_payload(devs, rows, vals, layout: SwapLayout):
+    """The traced body of every swap program: scatter each field's slice
+    of the payload into its buffer.  `rows` is [F, slots], `vals[g]` is
+    [slots, width_g]; padding rows (PAD_ROW + slot) drop."""
+    slots = rows.shape[1]
+    out = {}
+    for i, (name, group, off, width) in enumerate(layout.fields):
+        dev = devs[name]
+        v = vals[group][:, off:off + width].reshape((slots,) + dev.shape[1:])
+        out[name] = dev.at[rows[i]].set(
+            v, mode="drop", indices_are_sorted=True, unique_indices=True)
+    return out
+
+
+_SWAP_SCATTER = None
+
+
+def _swap_scatter_fn():
+    """The shared jitted swap program — ONE module-level function so every
+    cache instance (simulator multi-scheduler runs, bench pairs, the test
+    suite) reuses the same compiled specializations and jitstats tracks a
+    single entry instead of retaining one wrapper per dead instance."""
+    global _SWAP_SCATTER
+    if _SWAP_SCATTER is None:
         import jax
 
-        def scatter(dev, rows, vals):
-            return dev.at[rows].set(vals, mode="drop")
+        def swap_scatter(devs, rows, vals, layout):
+            return _apply_payload(devs, rows, vals, layout)
 
-        # donate the stale device buffer on real accelerators so the
+        # donate the stale device buffers on real accelerators so the
         # update writes in place; CPU ignores donation (and warns), so
         # skip it there
         donate = () if jax.default_backend() == "cpu" else (0,)
-        _SCATTER = jitstats.register(
-            "resident_scatter", jax.jit(scatter, donate_argnums=donate)
+        _SWAP_SCATTER = jitstats.register(
+            "resident_swap",
+            jax.jit(swap_scatter, static_argnums=(3,), donate_argnums=donate),
         )
-    return _SCATTER
+    return _SWAP_SCATTER
 
 
-# per-(mesh, sharded?) jitted scatters — memoized so steady-state sharded
-# cycles reuse one compiled specialization per (field shape, dtype), same
-# contract as the single-device _scatter_fn
+# per-(mesh, sharded?) jitted swap programs — memoized so steady-state
+# sharded cycles reuse one compiled specialization per (layout, bucket),
+# same contract as the single-device _swap_scatter_fn
 _MESH_SCATTER: dict = {}
 
 
 def _mesh_repl_scatter_fn(mesh):
-    """The replicated-placement scatter for `mesh`: same update as the
+    """The replicated-placement swap program for `mesh`: same update as the
     single-device one, with explicit replicated in/out shardings so the
-    result stays a committed mesh array the sharded solve accepts as-is."""
+    results stay committed mesh arrays the sharded solve accepts as-is."""
     fn = _MESH_SCATTER.get((mesh, "repl"))
     if fn is None:
         import jax
@@ -188,13 +294,14 @@ def _mesh_repl_scatter_fn(mesh):
 
         repl = NamedSharding(mesh, P())
 
-        def scatter(dev, rows, vals):
-            return dev.at[rows].set(vals, mode="drop")
+        def swap_scatter_repl(devs, rows, vals, layout):
+            return _apply_payload(devs, rows, vals, layout)
 
         donate = () if jax.default_backend() == "cpu" else (0,)
         fn = jitstats.register(
-            "resident_scatter_repl",
-            jax.jit(scatter, donate_argnums=donate,
+            "resident_swap_repl",
+            jax.jit(swap_scatter_repl, static_argnums=(3,),
+                    donate_argnums=donate,
                     in_shardings=(repl, repl, repl), out_shardings=repl),
         )
         _MESH_SCATTER[(mesh, "repl")] = fn
@@ -202,12 +309,13 @@ def _mesh_repl_scatter_fn(mesh):
 
 
 def _mesh_shard_scatter_fn(mesh):
-    """The per-shard scatter for node-axis columns: `dev` is [N, ...]
-    sharded over the node axis, `rows`/`vals` are [n_shards, slots(, ...)]
-    sharded on their LEADING axis with shard-LOCAL row indices — the vmap
-    over the shard axis makes each chip scatter only its own delta slice
-    (out-of-range padding rows drop), and the explicit shardings keep GSPMD
-    from inserting any gather/reshard around the update."""
+    """The per-shard swap program for the node-axis columns: each buffer is
+    [N, ...] sharded over the node axis, `rows` is [n_shards, F, slots]
+    and `vals[g]` [n_shards, slots, width_g], sharded on their LEADING
+    axis with shard-LOCAL row indices — the vmap over the shard axis makes
+    each chip scatter only its own delta slice (padding rows drop), and the
+    explicit shardings keep GSPMD from inserting any gather/reshard around
+    the update."""
     fn = _MESH_SCATTER.get((mesh, "shard"))
     if fn is None:
         import jax
@@ -217,20 +325,24 @@ def _mesh_shard_scatter_fn(mesh):
 
         shard = NamedSharding(mesh, P(NODE_AXIS))
 
-        def scatter_sharded(dev, rows, vals):
+        def swap_scatter_sharded(devs, rows, vals, layout):
             n_shards = rows.shape[0]
-            dev3 = dev.reshape(
-                (n_shards, dev.shape[0] // n_shards) + dev.shape[1:]
-            )
+            devs3 = {
+                name: dev.reshape(
+                    (n_shards, dev.shape[0] // n_shards) + dev.shape[1:])
+                for name, dev in devs.items()
+            }
             out = jax.vmap(
-                lambda d, r, v: d.at[r].set(v, mode="drop")
-            )(dev3, rows, vals)
-            return out.reshape(dev.shape)
+                lambda d, r, v: _apply_payload(d, r, v, layout)
+            )(devs3, rows, vals)
+            return {name: out[name].reshape(devs[name].shape)
+                    for name in devs}
 
         donate = () if jax.default_backend() == "cpu" else (0,)
         fn = jitstats.register(
-            "resident_scatter_sharded",
-            jax.jit(scatter_sharded, donate_argnums=donate,
+            "resident_swap_sharded",
+            jax.jit(swap_scatter_sharded, static_argnums=(3,),
+                    donate_argnums=donate,
                     in_shardings=(shard, shard, shard), out_shardings=shard),
         )
         _MESH_SCATTER[(mesh, "shard")] = fn
@@ -244,7 +356,17 @@ def changed_rows(mirror: np.ndarray, host: np.ndarray) -> np.ndarray:
     follower's scatter payload is row-for-row the leader's."""
     if host.ndim == 1:
         return np.flatnonzero(mirror != host)
-    return np.flatnonzero(np.any(mirror != host, axis=1))
+    # the differing ELEMENTS, folded to their rows: a sparse delta touches
+    # few of them, and a per-row any() over a short inner axis costs ten
+    # times the compare itself on a [50k, R] column
+    hits = np.flatnonzero(mirror != host)
+    if hits.size == 0:
+        return hits
+    rows = hits // _row_width(host)
+    keep = np.empty(rows.size, bool)
+    keep[0] = True
+    np.not_equal(rows[1:], rows[:-1], out=keep[1:])
+    return rows[keep]
 
 
 def scatter_summary(per_path_counters: Dict[str, Dict[str, int]]
@@ -265,10 +387,24 @@ def scatter_summary(per_path_counters: Dict[str, Dict[str, int]]
     return out
 
 
+class _Staged(NamedTuple):
+    """One field's share of a swap's payload, decided on the host."""
+
+    host: np.ndarray
+    changed: np.ndarray   # ascending row indices
+    slots: int            # the smallest bucket holding this field's delta
+
+
 class PerCycleDeviceCache:
     def __init__(self) -> None:
         self._mirror: Dict[str, np.ndarray] = {}
         self._dev: Dict[str, object] = {}
+        # the packed-payload layout of the fields that can ever take the
+        # scatter path at their current shapes (a column smaller than its
+        # smallest payload always re-uploads whole and stays out); rebuilt,
+        # and its program specializations pre-warmed, whenever a column is
+        # uploaded cold or at a new shape
+        self._layout = SwapLayout((), ())
         # per-swap delta record: field → changed row indices (np.ndarray)
         # for a scatter refresh, None for a full upload; clean fields are
         # absent.  The warm-started allocate's table invalidation
@@ -276,21 +412,30 @@ class PerCycleDeviceCache:
         # knows exactly where state moved, so the candidate-table carry
         # rides the same knowledge instead of re-deriving it.
         self.delta_record: Dict[str, object] = {}
-        # last (input snap, swapped result): the failure-histogram dispatch
-        # re-swaps the SAME snap the solve dispatch just synced — a
-        # guaranteed all-clean diff over every field, skipped by identity
+        # last (input snap, swapped result): a repeat swap of the SAME snap
+        # object (api.columns.resident_snap memoizes one level up; the
+        # follower and direct callers land here) is a guaranteed all-clean
+        # diff over every field, skipped by identity
         self._last_in = None
         self._last_out = None
+        # the store's task feature version at the last swap: while it has
+        # not moved no task feature row was written, and their diff is
+        # skipped (callers with no such token pass None and always diff)
+        self._feature_token = None
         # monotonic swap version — the warm-standby revalidation's token:
         # a cache that has synced at least one snapshot (version > 0) and
         # passes the store's consistency check after a failover rebuild is
         # kept (buffers + compiled specializations survive; the next swap's
         # mirror diff absorbs any residual divergence as ordinary deltas)
         self.version = 0
-        # diagnostics for the bench / tests
+        # diagnostics for the bench / tests: per-FIELD outcomes ...
         self.full_uploads = 0
         self.scatter_updates = 0
         self.clean_hits = 0
+        # ... task feature columns among the whole uploads ...
+        self.feature_uploads = 0
+        # ... and device calls made: swap programs + whole device_puts
+        self.dispatches = 0
         # bytes actually shipped host→device vs what full per-cycle uploads
         # would have shipped — the bench's delta-vs-full reduction evidence
         self.bytes_full = 0
@@ -303,6 +448,8 @@ class PerCycleDeviceCache:
             "full_uploads": self.full_uploads,
             "scatter_updates": self.scatter_updates,
             "clean_hits": self.clean_hits,
+            "feature_uploads": self.feature_uploads,
+            "dispatches": self.dispatches,
             "bytes_full": self.bytes_full,
             "bytes_scatter": self.bytes_scatter,
             "bytes_if_full": self.bytes_if_full,
@@ -312,103 +459,181 @@ class PerCycleDeviceCache:
     def _payload_bytes(slots: int, host: np.ndarray) -> int:
         """Scatter payload size for a `slots`-wide delta of `host`'s row
         shape (int32 index + one value row per slot)."""
-        row = host.dtype.itemsize * int(
-            np.prod(host.shape[1:], dtype=np.int64)
-        )
-        return slots * (4 + row)
+        return slots * (4 + host.dtype.itemsize * _row_width(host))
 
-    def _refresh(self, field: str, host: np.ndarray):
+    # ---- placement hooks (the sharded cache overrides these) -------------
+    def _put(self, field: str, host: np.ndarray):
         import jax
 
-        self.bytes_if_full += host.nbytes
-        mirror = self._mirror.get(field)
-        if (
-            mirror is None
-            or mirror.shape != host.shape
-            or mirror.dtype != host.dtype
-        ):
-            self.full_uploads += 1
-            self.bytes_full += host.nbytes
-            self.delta_record[field] = None
-            dev = jax.device_put(host)
-            # pre-warm EVERY slot-bucket specialization for this (shape,
-            # dtype) NOW — an all-out-of-range index vector writes nothing,
-            # so the values are untouched, but any real delta width in a
-            # later steady-state cycle becomes a cache hit, never a
-            # retrace.  TWO passes: the first bucket's first call sees the
-            # device_put-placed buffer, while real deltas always see a
-            # scatter OUTPUT buffer — whose layout can key a fresh
-            # specialization; the second pass compiles every bucket against
-            # the output-typed buffer too
-            for _ in range(2):
-                for slots in SCATTER_SLOT_BUCKETS:
-                    rows = np.full(slots, host.shape[0], np.int32)
-                    vals = np.zeros((slots,) + host.shape[1:], host.dtype)
-                    dev = _scatter_fn()(dev, rows, vals)
-            self._mirror[field] = host.copy()
-            self._dev[field] = dev
-            return dev
-        changed = changed_rows(mirror, host)
-        if changed.size == 0:
-            self.clean_hits += 1
-            return self._dev[field]
-        # the delta is known row-exactly from here down — either path
-        # moves exactly `changed`, which is what the warm-table carry's
-        # invalidation consumes
-        self.delta_record[field] = changed
-        slots = _slot_bucket(changed.size, SCATTER_SLOT_BUCKETS)
-        if (
-            changed.size > SCATTER_SLOTS
-            # a tiny column: shipping the whole thing is cheaper than the
-            # smallest fixed-width scatter payload
-            or self._payload_bytes(slots, host) >= host.nbytes
-        ):
-            # specializations are already warm — no prewarm on this path
-            self.full_uploads += 1
-            self.bytes_full += host.nbytes
-            dev = jax.device_put(host)
-            self._mirror[field] = host.copy()
-            self._dev[field] = dev
-            return dev
-        n = host.shape[0]
-        # pad with an out-of-range row index — mode="drop" discards the
-        # padding writes, so the scatter shape depends only on the (pre-
-        # warmed) slot bucket, never on the exact delta size
-        rows = np.full(slots, n, np.int32)
-        rows[: changed.size] = changed
-        vals = np.zeros((slots,) + host.shape[1:], host.dtype)
-        vals[: changed.size] = host[changed]
-        dev = _scatter_fn()(self._dev[field], rows, vals)
-        mirror[changed] = host[changed]
-        self._dev[field] = dev
-        self.scatter_updates += 1
-        self.bytes_scatter += rows.nbytes + vals.nbytes
-        return dev
+        return jax.device_put(host)
 
-    def swap(self, snap):
-        """`snap` with every per-cycle field replaced by its device-resident
-        copy (refreshed by delta).  The caller keeps using the ORIGINAL
-        host-backed snap for numpy reads — only the returned copy feeds the
-        solve, mirroring the resident_features contract.  A repeat call
-        with the identical snap object (the same cycle's second dispatch)
-        returns the memoized result without re-diffing."""
+    def _share(self, field: str) -> float:
+        """The share of `field`'s bytes THIS process ships (1 unless the
+        field is sharded over a multi-host mesh)."""
+        return 1.0
+
+    def _scatter_slots(self, field: str, host: np.ndarray,
+                       changed: np.ndarray) -> Optional[int]:
+        """The slot bucket `field`'s delta needs, or None when it must
+        re-upload whole: a delta past the widest bucket, or a column no
+        larger than the payload that would patch it."""
+        if changed.size > SCATTER_SLOTS:
+            return None
+        slots = _slot_bucket(changed.size, SCATTER_SLOT_BUCKETS)
+        if self._payload_bytes(slots, host) >= host.nbytes:
+            return None
+        return slots
+
+    def _scatterable(self, field: str, host: np.ndarray) -> bool:
+        """Whether `field` can take the scatter path at ANY delta width —
+        a shape-only fact, so it decides program membership."""
+        return self._payload_bytes(SCATTER_SLOT_BUCKETS[0], host) < host.nbytes
+
+    # ---- host side: decide each field's path -----------------------------
+    def _upload(self, field: str, host: np.ndarray) -> None:
+        """Whole upload of one field (cold, reshaped, or a wide delta)."""
+        self.full_uploads += 1
+        self.dispatches += 1
+        if field in _TASK_FEATURES:
+            self.feature_uploads += 1
+        self.bytes_full += int(host.nbytes * self._share(field))
+        self._dev[field] = self._put(field, host)
+        self._mirror[field] = host.copy()
+
+    def swap(self, snap, feature_token=None):
+        """`snap` with every field of SWAP_FIELDS replaced by its
+        device-resident copy (refreshed by delta).  The caller keeps using
+        the ORIGINAL host-backed snap for numpy reads — only the returned
+        copy feeds the solve.  A repeat call with the identical snap object
+        (the same cycle's second dispatch) returns the memoized result
+        without re-diffing.  ``feature_token`` is the store's task feature
+        version: equal to the last swap's, the task feature columns are
+        clean without a diff."""
         if snap is self._last_in:
             return self._last_out
         self.version += 1
         self.delta_record = {}
-        updates = {
-            field: self._refresh(field, np.asarray(getattr(snap, field)))
-            for field in PER_CYCLE_FIELDS
-        }
-        out = snap._replace(**updates)
+        features_clean = (
+            feature_token is not None
+            and feature_token == self._feature_token
+        )
+        staged: Dict[str, _Staged] = {}
+        reshaped = False
+        for field in SWAP_FIELDS:
+            host = np.asarray(getattr(snap, field))
+            self.bytes_if_full += int(host.nbytes * self._share(field))
+            mirror = self._mirror.get(field)
+            if (
+                mirror is None
+                or mirror.shape != host.shape
+                or mirror.dtype != host.dtype
+            ):
+                self.delta_record[field] = None
+                self._upload(field, host)
+                reshaped = True
+                continue
+            if features_clean and field in _TASK_FEATURES:
+                self.clean_hits += 1
+                continue
+            changed = changed_rows(mirror, host)
+            if changed.size == 0:
+                self.clean_hits += 1
+                continue
+            # the delta is known row-exactly from here down — either path
+            # moves exactly `changed`, which is what the warm-table carry's
+            # invalidation consumes
+            self.delta_record[field] = changed
+            slots = self._scatter_slots(field, host, changed)
+            if slots is None:
+                # specializations are already warm — no prewarm on this path
+                self._upload(field, host)
+            else:
+                staged[field] = _Staged(host, changed, slots)
+        if reshaped:
+            self._prewarm()
+        if staged:
+            self._scatter(staged)
+        self._feature_token = feature_token
+        out = snap._replace(**self._dev)
         self._last_in, self._last_out = snap, out
         return out
 
+    # ---- device side: one program for everything staged ------------------
+    #: the fields the packed program covers (the sharded cache keeps the
+    #: node-axis fields out of it, for their per-shard program)
+    _packed_fields: Tuple[str, ...] = SWAP_FIELDS
+
+    def _layout_of(self, fields) -> SwapLayout:
+        """The payload layout over those of `fields` that can ever take
+        the scatter path at their current shapes."""
+        return swap_layout({
+            field: self._mirror[field] for field in fields
+            if self._scatterable(field, self._mirror[field])
+        })
+
+    def _dispatch(self, layout: SwapLayout, rows, vals) -> None:
+        """THE donating call: the program takes the resident buffers of
+        `layout`'s fields and hands back their refreshed successors,
+        rebound before anything can read the donated ones."""
+        devs = {name: self._dev[name] for name, _, _, _ in layout.fields}
+        devs = _swap_scatter_fn()(devs, rows, vals, layout)
+        self._dev.update(devs)
+        self.dispatches += 1
+
+    @staticmethod
+    def _empty_payload(layout: SwapLayout, lead: Tuple[int, ...], slots: int):
+        """An all-padding payload for `layout` ([*lead, F, slots] indices,
+        [*lead, slots, width] values per dtype group)."""
+        rows = np.empty(lead + (len(layout.fields), slots), np.int32)
+        rows[...] = PAD_ROW + np.arange(slots, dtype=np.int32)
+        vals = tuple(
+            np.zeros(lead + (slots, width), dtype)
+            for dtype, width in layout.groups
+        )
+        return rows, vals
+
+    def _prewarm(self) -> None:
+        """Rebuild the layout for the columns' current shapes and compile
+        EVERY slot-bucket specialization of its program NOW — an all-
+        padding payload writes nothing, so the values are untouched, but
+        any real delta width in a later steady-state cycle becomes a cache
+        hit, never a retrace.  TWO passes: the first bucket's first call
+        sees upload-placed buffers, while real deltas mostly see program
+        OUTPUT buffers — whose layout can key a fresh specialization; the
+        second pass compiles every bucket against those too."""
+        self._layout = layout = self._layout_of(self._packed_fields)
+        if not layout.fields:
+            return
+        for _ in range(2):
+            for slots in SCATTER_SLOT_BUCKETS:
+                self._dispatch(layout,
+                               *self._empty_payload(layout, (), slots))
+
+    def _scatter(self, staged: Dict[str, _Staged]) -> None:
+        """Pack every staged field's delta into one payload at the widest
+        field's bucket and apply it in one dispatch."""
+        layout = self._layout
+        slots = max(s.slots for s in staged.values())
+        rows, vals = self._empty_payload(layout, (), slots)
+        for i, (name, group, off, width) in enumerate(layout.fields):
+            s = staged.get(name)
+            if s is None:
+                continue
+            n = s.changed.size
+            fresh = s.host[s.changed]
+            rows[i, :n] = s.changed
+            vals[group][:n, off:off + width] = fresh.reshape(n, width)
+            self._mirror[name][s.changed] = fresh
+        self._dispatch(layout, rows, vals)
+        self.scatter_updates += len(staged)
+        self.bytes_scatter += rows.nbytes + sum(v.nbytes for v in vals)
+
 
 class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
-    """Per-cycle residency for the mesh-sharded solve path (module
-    docstring): node-axis columns live sharded over `mesh`, everything else
-    replicated across it, refreshed by per-shard donated scatter deltas.
+    """Residency for the mesh-sharded solve path (module docstring):
+    node-axis columns live sharded over `mesh`, everything else replicated
+    across it; a swap refreshes the replicated columns with one packed
+    program and the node-axis columns with its per-shard twin.
 
     Multi-host meshes: each process materializes and ships only its own
     ADDRESSABLE shards — uploads and per-shard payloads go through
@@ -421,10 +646,10 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
     churn EWMA over the per-cycle max per-shard delta width retargets the
     bucket set, replacing the static 16/128/1024 sizing.  The cold-upload
     prewarm compiles the FULL reachable bucket set up front
-    (:func:`all_shard_buckets`, no-op scatters with all-out-of-range
-    padding indices), so a retarget is pure payload-sizing bookkeeping
-    and a real delta of any admissible width is a jit cache hit — steady
-    state never retraces regardless of where the ladder moves."""
+    (:func:`all_shard_buckets`, all-padding payloads), so a retarget is
+    pure payload-sizing bookkeeping and a real delta of any admissible
+    width is a jit cache hit — steady state never retraces regardless of
+    where the ladder moves."""
 
     def __init__(self, mesh) -> None:
         super().__init__()
@@ -433,13 +658,14 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
 
         # the SCATTER shard count is the node-axis extent — on a 2-D
         # (tasks, nodes) mesh the node columns replicate across the task
-        # axis, so the [n_shards, slots] payload splits by node shard only
+        # axis, so the [n_shards, F, slots] payload splits by node shard
+        # only
         self.n_shards = int(dict(mesh.shape)[NODE_AXIS])
         self.churn_ewma = 0.0
         self._ladder: Tuple[int, ...] = adaptive_ladder(
             0.0, SHARD_SCATTER_SLOTS
         )
-        self._warm: Dict[str, set] = {}   # field → warmed bucket widths
+        self._shard_layout = SwapLayout((), ())
         self._cycle_max = 0
         self.ladder_retargets = 0
 
@@ -450,26 +676,33 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
         out["ladder_retargets"] = self.ladder_retargets
         return out
 
-    def _sharding(self, field: str):
-        from kube_batch_tpu.parallel.mesh import snapshot_shardings
-
-        return getattr(snapshot_shardings(self.mesh), field)
-
     def _host_fraction(self) -> float:
         """This process's addressable share of the mesh — the per-host
         byte-counter scale for sharded payloads."""
         import jax
 
-        pc = jax.process_count()
-        return 1.0 / pc if pc > 1 else 1.0
+        return 1.0 / jax.process_count()
 
-    def _put(self, host: np.ndarray, sharding):
+    def _share(self, field: str) -> float:
+        """Per-host accounting: a sharded field ships this process's share
+        (numerator AND the bytes_if_full denominator, or upload_reduction
+        would read inflated on multi-host meshes)."""
+        return self._host_fraction() if field in NODE_AXIS_FIELDS else 1.0
+
+    def _put(self, field: str, host: np.ndarray):
         """Placed upload: single-process goes through device_put; on a
         multi-host mesh each process materializes only its addressable
         shards via make_array_from_callback (the per-host scatter/upload
-        contract above)."""
+        contract above).  A node axis the mesh cannot divide would make
+        per-shard indexing undefined — but jax itself rejects such a
+        placement (NamedSharding divisibility), so the sharded solve path
+        never reaches here with one; the shape buckets (snapshot.bucket)
+        are divisible by any power-of-two mesh."""
         import jax
 
+        from kube_batch_tpu.parallel.mesh import snapshot_shardings
+
+        sharding = getattr(snapshot_shardings(self.mesh), field)
         if jax.process_count() > 1:
             return jax.make_array_from_callback(
                 host.shape, sharding, lambda idx: host[idx]
@@ -477,11 +710,10 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
         return jax.device_put(host, sharding)
 
     def _put_payload(self, arr: np.ndarray):
-        """Per-shard scatter payload ([n_shards, slots, ...], leading axis
-        sharded over the node axis): pre-placed per host on multi-process
-        meshes so only the local shards' slices upload; single-process
-        passes the numpy array straight to the jitted scatter (whose
-        in_shardings place it)."""
+        """Per-shard payload block (leading axis sharded over the node
+        axis): pre-placed per host on multi-process meshes so only the
+        local shards' slices upload; single-process passes the numpy array
+        straight to the jitted program (whose in_shardings place it)."""
         import jax
 
         if jax.process_count() == 1:
@@ -495,39 +727,104 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
             lambda idx: arr[idx],
         )
 
-    def _prewarm_shard_field(self, field: str, dev, n_rows: int):
-        """Compile every not-yet-warm per-shard bucket for `field` — the
-        FULL reachable set (:func:`all_shard_buckets`), not just the live
-        ladder — with no-op scatters (all padding indices → zero writes,
-        two passes so the scatter-OUTPUT buffer layout is covered too).
-        Returns the (donated and rebound) device buffer."""
-        host = self._mirror.get(field)
-        dtype = host.dtype if host is not None else np.float32
-        tail = host.shape[1:] if host is not None else ()
-        s = n_rows // self.n_shards
-        warm = self._warm.setdefault(field, set())
-        todo = [
-            b for b in all_shard_buckets(SHARD_SCATTER_SLOTS)
-            if b not in warm
-        ]
+    def _shard_counts(self, host: np.ndarray, changed: np.ndarray):
+        """(owning shard of each changed row, rows per shard)."""
+        shard_ids = changed // (host.shape[0] // self.n_shards)
+        return shard_ids, np.bincount(shard_ids, minlength=self.n_shards)
+
+    def _scatter_slots(self, field, host, changed):
+        if field not in NODE_AXIS_FIELDS:
+            return super()._scatter_slots(field, host, changed)
+        widest = int(self._shard_counts(host, changed)[1].max())
+        self._cycle_max = max(self._cycle_max, widest)
+        if widest > min(self._ladder[-1], SHARD_SCATTER_SLOTS):
+            # over the LIVE ladder's cap — whole re-upload; the churn note
+            # above grows the EWMA so a sustained regime retargets a wider
+            # ladder instead of thrashing
+            return None
+        slots = _slot_bucket(widest, self._ladder)
+        if self._payload_bytes(slots, host) * self.n_shards >= host.nbytes:
+            # tiny sharded column: the whole upload is cheaper than the
+            # smallest per-shard scatter payload
+            return None
+        return slots
+
+    def _scatterable(self, field, host):
+        if field not in NODE_AXIS_FIELDS:
+            return super()._scatterable(field, host)
+        return (self._payload_bytes(all_shard_buckets(SHARD_SCATTER_SLOTS)[0],
+                                    host) * self.n_shards < host.nbytes)
+
+    _packed_fields = REPL_SWAP_FIELDS
+
+    def _dispatch(self, layout: SwapLayout, rows, vals) -> None:
+        """The mesh twins of the base class's donating call, same shape:
+        the per-shard program for the node-axis layout, the replicated one
+        for the rest."""
+        devs = {name: self._dev[name] for name, _, _, _ in layout.fields}
+        if layout is self._shard_layout:
+            devs = _mesh_shard_scatter_fn(self.mesh)(devs, rows, vals, layout)
+        else:
+            devs = _mesh_repl_scatter_fn(self.mesh)(devs, rows, vals, layout)
+        self._dev.update(devs)
+        self.dispatches += 1
+
+    def _scatter_sharded(self, slots: int, staged: Dict[str, _Staged]) -> int:
+        """One per-shard program over the node-axis layout: `staged`'s
+        deltas partitioned by owning shard, shard-LOCAL indices.  Returns
+        the payload bytes."""
+        layout = self._shard_layout
+        rows, vals = self._empty_payload(layout, (self.n_shards,), slots)
+        for i, (name, group, off, width) in enumerate(layout.fields):
+            s = staged.get(name)
+            if s is None:
+                continue
+            per = s.host.shape[0] // self.n_shards
+            shard_ids, counts = self._shard_counts(s.host, s.changed)
+            # position of each changed row inside its shard's slots
+            # (`changed` ascends, so each shard's rows are contiguous)
+            offs = np.concatenate(([0], np.cumsum(counts)[:-1]))
+            pos = np.arange(s.changed.size) - np.repeat(offs, counts)
+            fresh = s.host[s.changed]
+            rows[shard_ids, i, pos] = s.changed % per
+            vals[group][shard_ids, pos, off:off + width] = fresh.reshape(
+                s.changed.size, width)
+            self._mirror[name][s.changed] = fresh
+        self._dispatch(layout, self._put_payload(rows),
+                       tuple(self._put_payload(v) for v in vals))
+        return rows.nbytes + sum(v.nbytes for v in vals)
+
+    def _prewarm(self) -> None:
+        """The replicated program's buckets (base class), then the per-
+        shard program's FULL reachable set (:func:`all_shard_buckets`),
+        not just the live ladder."""
+        super()._prewarm()
+        self._shard_layout = self._layout_of(NODE_SWAP_FIELDS)
+        if not self._shard_layout.fields:
+            return
         for _ in range(2):
-            for slots in todo:
-                rows = np.full((self.n_shards, slots), s, np.int32)
-                vals = np.zeros((self.n_shards, slots) + tail, dtype)
-                dev = _mesh_shard_scatter_fn(self.mesh)(
-                    dev, self._put_payload(rows), self._put_payload(vals)
-                )
-        warm.update(todo)
-        return dev
+            for slots in all_shard_buckets(SHARD_SCATTER_SLOTS):
+                self._scatter_sharded(slots, {})
 
-    def _note_churn(self, per_shard_max: int) -> None:
-        self._cycle_max = max(self._cycle_max, per_shard_max)
+    def _scatter(self, staged: Dict[str, _Staged]) -> None:
+        sharded = {f: s for f, s in staged.items() if f in NODE_AXIS_FIELDS}
+        repl = {f: s for f, s in staged.items() if f not in NODE_AXIS_FIELDS}
+        if repl:
+            super()._scatter(repl)
+        if sharded:
+            shipped = self._scatter_sharded(
+                max(s.slots for s in sharded.values()), sharded)
+            self.scatter_updates += len(sharded)
+            self.bytes_scatter += int(shipped * self._host_fraction())
 
-    def _retarget_ladder(self) -> None:
-        """EWMA update + ladder retarget at swap end.  Retargeting only
-        changes which payload widths later deltas ship — every reachable
-        bucket was compiled at cold-upload prewarm, so this costs nothing
-        and can never retrace a steady-state cycle."""
+    def swap(self, snap, feature_token=None):
+        if snap is self._last_in:
+            return self._last_out
+        out = super().swap(snap, feature_token)
+        # EWMA update + ladder retarget at swap end.  Retargeting only
+        # changes which payload widths later deltas ship — every reachable
+        # bucket was compiled at the cold-upload prewarm, so this costs
+        # nothing and can never retrace a steady-state cycle.
         self.churn_ewma = (
             CHURN_EWMA_DECAY * self.churn_ewma
             + (1.0 - CHURN_EWMA_DECAY) * self._cycle_max
@@ -537,125 +834,7 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
         if new != self._ladder:
             self._ladder = new
             self.ladder_retargets += 1
-
-    def swap(self, snap):
-        if snap is self._last_in:
-            return self._last_out
-        out = super().swap(snap)
-        self._retarget_ladder()
         return out
-
-    def _full_upload(self, field: str, host: np.ndarray,
-                     prewarm: bool = True):
-        """Sharded full upload; on cold/shape-change uploads (`prewarm`)
-        every scatter slot bucket is pre-compiled so later deltas never
-        retrace.  A node axis the mesh cannot divide would make per-shard
-        indexing undefined — but jax itself rejects such a placement
-        (NamedSharding divisibility), so the sharded solve path never
-        reaches here with one; the shape buckets (snapshot.bucket) are
-        divisible by any power-of-two mesh."""
-        sharded_axis = field in NODE_AXIS_FIELDS
-        self.full_uploads += 1
-        # a full upload with no recorded row delta invalidates wholesale
-        # (the warm-table carry treats an unrecorded field as all-moved)
-        self.delta_record.setdefault(field, None)
-        self.bytes_full += int(
-            host.nbytes * (self._host_fraction() if sharded_axis else 1.0)
-        )
-        dev = self._put(host, self._sharding(field))
-        if not prewarm:
-            self._mirror[field] = host.copy()
-            self._dev[field] = dev
-            return dev
-        # two prewarm passes — see PerCycleDeviceCache._refresh: real deltas
-        # see scatter-OUTPUT buffers, whose (sharded) layout can key a fresh
-        # specialization vs the device_put-placed first input
-        self._mirror[field] = host.copy()
-        if sharded_axis:
-            self._warm.pop(field, None)  # shape may have changed — rewarm
-            dev = self._prewarm_shard_field(field, dev, host.shape[0])
-        else:
-            for _ in range(2):
-                for slots in SCATTER_SLOT_BUCKETS:
-                    rows = np.full(slots, host.shape[0], np.int32)
-                    vals = np.zeros((slots,) + host.shape[1:], host.dtype)
-                    dev = _mesh_repl_scatter_fn(self.mesh)(dev, rows, vals)
-        self._dev[field] = dev
-        return dev
-
-    def _refresh(self, field: str, host: np.ndarray):
-        sharded_axis = field in NODE_AXIS_FIELDS
-        # per-host accounting on sharded fields must scale the DENOMINATOR
-        # too, or upload_reduction would read inflated on multi-host meshes
-        self.bytes_if_full += int(
-            host.nbytes * (self._host_fraction() if sharded_axis else 1.0)
-        )
-        mirror = self._mirror.get(field)
-        if (
-            mirror is None
-            or mirror.shape != host.shape
-            or mirror.dtype != host.dtype
-        ):
-            return self._full_upload(field, host)
-        changed = changed_rows(mirror, host)
-        if changed.size == 0:
-            self.clean_hits += 1
-            return self._dev[field]
-        # row-exact delta known from here down (warm-table invalidation)
-        self.delta_record[field] = changed
-        if sharded_axis:
-            s = host.shape[0] // self.n_shards
-            shard_ids = changed // s  # ascending: flatnonzero sorts rows
-            counts = np.bincount(shard_ids, minlength=self.n_shards)
-            widest = int(counts.max())
-            self._note_churn(widest)
-            if widest > min(self._ladder[-1], SHARD_SCATTER_SLOTS):
-                # over the LIVE ladder's cap — full re-upload; the churn
-                # note above grows the EWMA so a sustained regime retargets
-                # (and pre-warms) a wider ladder instead of thrashing
-                return self._full_upload(field, host, prewarm=False)
-            slots = _slot_bucket(widest, self._ladder)
-            if self._payload_bytes(slots, host) * self.n_shards >= host.nbytes:
-                # tiny sharded column: the whole upload is cheaper than the
-                # smallest per-shard scatter payload
-                return self._full_upload(field, host, prewarm=False)
-            rows = np.full((self.n_shards, slots), s, np.int32)
-            offs = np.concatenate(([0], np.cumsum(counts)[:-1]))
-            pos = np.arange(changed.size) - np.repeat(offs, counts)
-            rows[shard_ids, pos] = (changed % s).astype(np.int32)
-            vals = np.zeros(
-                (self.n_shards, slots) + host.shape[1:], host.dtype
-            )
-            vals[shard_ids, pos] = host[changed]
-            dev = _mesh_shard_scatter_fn(self.mesh)(
-                self._dev[field], self._put_payload(rows),
-                self._put_payload(vals),
-            )
-            mirror[changed] = host[changed]
-            self._dev[field] = dev
-            self.scatter_updates += 1
-            self.bytes_scatter += int(
-                (rows.nbytes + vals.nbytes) * self._host_fraction()
-            )
-            return dev
-        else:
-            if changed.size > SCATTER_SLOTS:
-                return self._full_upload(field, host, prewarm=False)
-            slots = _slot_bucket(changed.size, SCATTER_SLOT_BUCKETS)
-            if self._payload_bytes(slots, host) >= host.nbytes:
-                return self._full_upload(field, host, prewarm=False)
-            rows = np.full(slots, host.shape[0], np.int32)
-            rows[: changed.size] = changed
-            vals = np.zeros((slots,) + host.shape[1:], host.dtype)
-            vals[: changed.size] = host[changed]
-            dev = _mesh_repl_scatter_fn(self.mesh)(
-                self._dev[field], rows, vals
-            )
-        mirror[changed] = host[changed]
-        self._dev[field] = dev
-        self.scatter_updates += 1
-        self.bytes_scatter += rows.nbytes + vals.nbytes
-        return dev
 
 
 # ==========================================================================
@@ -672,9 +851,11 @@ class ShardedPerCycleDeviceCache(PerCycleDeviceCache):
 #     delta records above (``delta_record``): the diff that sizes the
 #     scatter IS the row-exact "these nodes moved" set, absorbed into the
 #     state between solves (multiple swaps per cycle accumulate);
-#   ingest-static features (task requests/bitsets, node allocatable /
-#     label / taint bits) — version-keyed uploads carry no row deltas, so
-#     the state keeps its own mirrors and diffs them at plan time;
+#   task features (requests, selector/toleration bitsets) — they ride
+#     the same swap, so the same records name the task rows that moved;
+#   node features (allocatable / label / taint bits) — version-keyed
+#     uploads carry no row deltas, so the state keeps its own mirrors and
+#     diffs them at plan time;
 #   a row's own bucket churn — membership/position handled by row_map;
 #   sparse affinity/preference rows — derived per cycle from the
 #     match-count planes (api/affinity_planes.py), which report where a
@@ -759,6 +940,11 @@ class WarmTableState:
         "node_idle", "node_releasing", "node_used", "node_valid",
         "node_sched",
     )
+    #: task feature fields a carried row's ranking read: a row whose own
+    #: request or bitsets moved re-ranks
+    TASK_DELTA_FIELDS = (
+        "task_req", "task_sel_bits", "task_sel_impossible", "task_tol_bits",
+    )
 
     def __init__(self, mesh=None, impl=None):
         self.mesh = mesh
@@ -777,13 +963,13 @@ class WarmTableState:
         self.eroded_dev = None
         self._changed: Optional[np.ndarray] = None  # np bool [capN]
         self._node_full = True
+        self._task_dirty: Optional[np.ndarray] = None  # np bool [capT]
+        self._task_full = True
         self._absorbed_version = -1
         self._consumed_version = -1
-        self._t_mirror: Optional[Dict[str, np.ndarray]] = None
         self._n_mirror: Optional[Dict[str, np.ndarray]] = None
         self._term_rerank: set = set()  # task rows a moved term re-ranks
-        self._t_feat_ver = -1   # mirror-diff short circuits (see plan)
-        self._n_feat_ver = -1
+        self._n_feat_ver = -1   # mirror-diff short circuit (see plan)
         # sticky rung ratchets (the TOPK bucket-ratchet discipline): a
         # rung, once visited, stays — churn oscillating across a rung
         # boundary must not retrace every other steady cycle.  The
@@ -811,18 +997,29 @@ class WarmTableState:
         the accumulators would double every delta into the next merge."""
         if version <= self._consumed_version:
             return
-        for field in self.NODE_DELTA_FIELDS:
+        if not self._mark(record, self.NODE_DELTA_FIELDS, self._changed):
+            self._node_full = True
+        if not self._mark(record, self.TASK_DELTA_FIELDS, self._task_dirty):
+            self._task_full = True
+        self._absorbed_version = version
+
+    @staticmethod
+    def _mark(record: Dict, fields, mask: Optional[np.ndarray]) -> bool:
+        """Set `mask` at the rows `record` names under `fields`; False when
+        some of it moved wholesale (a full upload) or past the mask's axis
+        (shape drift).  With no mask yet (before the first plan, which
+        starts from all-moved anyway) there is nothing to mark."""
+        for field in fields:
             if field not in record:
                 continue
             rows = record[field]
             if rows is None:
-                self._node_full = True
-            elif self._changed is not None:
-                if rows.size and rows[-1] < self._changed.shape[0]:
-                    self._changed[rows] = True
-                else:
-                    self._node_full = True  # shape drift — cold
-        self._absorbed_version = version
+                return False
+            if mask is not None:
+                if rows.size and rows[-1] >= mask.shape[0]:
+                    return False
+                mask[rows] = True
+        return True
 
     def note_term_rows(self, changed_nodes: np.ndarray, rerank_rows) -> None:
         """Fold one snapshot's moved inter-pod/preferred rows into the
@@ -840,37 +1037,29 @@ class WarmTableState:
             self._reset()
             self.shape_key = key
 
-    def _diff_mirror(self, mirror_slot: str, ver_slot: str, version: int,
-                     sources) -> np.ndarray:
-        """Union of changed-row masks across the named ColumnStore arrays
-        (ingest-static features carry no scatter deltas — the state keeps
-        its own mirrors).  Returns a bool mask over the axis; a shape
-        change (bitset width growth, axis growth) reads as all-changed.
-        Short-circuits on the ColumnStore's per-axis feature VERSION (the
-        resident_features upload-cache key): an unmoved version means no
-        ingest-static column changed, so the megabytes of copy+compare
-        are skipped on every steady cycle."""
-        mirror = getattr(self, mirror_slot)
-        n = sources[0][1].shape[0]
-        if mirror is not None and getattr(self, ver_slot) == version:
-            return np.zeros(n, bool)
-        out = np.zeros(n, bool)
-        fresh = {}
-        for name, arr in sources:
-            fresh[name] = arr.copy()
-            if mirror is None:
-                out[:] = True
-                continue
-            old = mirror.get(name)
+    def _node_feature_dirty(self, cols) -> np.ndarray:
+        """Bool mask of the node rows whose feature columns (allocatable,
+        label / taint bits) moved since the last plan.  The node features
+        carry no scatter deltas, so the state keeps its own mirrors; a
+        shape change (bitset width growth, axis growth) reads as
+        all-changed.  Short-circuits on the ColumnStore's node feature
+        VERSION (the resident_features upload-cache key): an unmoved
+        version means no node feature column changed, so the copy+compare
+        is skipped on every steady cycle."""
+        sources = {name: getattr(cols, name)
+                   for name in ("n_alloc32", "n_label_bits", "n_taint_bits")}
+        out = np.zeros(cols.n_alloc32.shape[0], bool)
+        mirror = self._n_mirror
+        if mirror is not None and self._n_feat_ver == cols.node_feature_version:
+            return out
+        for name, arr in sources.items():
+            old = None if mirror is None else mirror.get(name)
             if old is None or old.shape != arr.shape:
                 out[:] = True
-                continue
-            if arr.ndim == 1:
-                out |= old != arr
             else:
                 out |= np.any(old != arr, axis=1)
-        setattr(self, mirror_slot, fresh)
-        setattr(self, ver_slot, version)
+        self._n_mirror = {name: arr.copy() for name, arr in sources.items()}
+        self._n_feat_ver = cols.node_feature_version
         return out
 
     # ------------------------------------------------------------------
@@ -895,22 +1084,16 @@ class WarmTableState:
         self.plans += 1
         if self._changed is None:
             self._changed = np.zeros(capN, bool)
+        if self._task_dirty is None:
+            self._task_dirty = np.zeros(capT, bool)
 
         new_live = pend_rows[pend_rows >= 0]
-        # ---- ingest-static feature diffs (no scatter deltas to ride) --
-        task_dirty = self._diff_mirror(
-            "_t_mirror", "_t_feat_ver", cols.task_feature_version, (
-                ("t_init32", cols.t_init32),
-                ("t_sel_bits", cols.t_sel_bits),
-                ("t_sel_impossible", cols.t_sel_impossible),
-                ("t_tol_bits", cols.t_tol_bits),
-            ))
-        node_feat_dirty = self._diff_mirror(
-            "_n_mirror", "_n_feat_ver", cols.node_feature_version, (
-                ("n_alloc32", cols.n_alloc32),
-                ("n_label_bits", cols.n_label_bits),
-                ("n_taint_bits", cols.n_taint_bits),
-            ))
+        # ---- task features: the rows the absorbed swaps recorded -------
+        task_dirty = (
+            np.ones(capT, bool) if self._task_full else self._task_dirty
+        )
+        # ---- node feature diffs (no scatter deltas to ride) ------------
+        node_feat_dirty = self._node_feature_dirty(cols)
 
         # C rungs past the node capacity would make the fresh block wider
         # than the cold build it replaces — they escalate to cold instead
@@ -1027,8 +1210,10 @@ class WarmTableState:
         # swap version so same-version re-notifies can't re-mark them);
         # the next swaps rebuild
         self._changed = np.zeros(capN, bool)
+        self._task_dirty = np.zeros(capT, bool)
         self._term_rerank = set()
         self._node_full = False
+        self._task_full = False
         self._consumed_version = self._absorbed_version
         self.rows = pend_rows.copy()
         self.reranked_total += n_rerank

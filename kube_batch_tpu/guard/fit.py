@@ -60,7 +60,6 @@ def _point_of(snap):
         J=snap.job_valid.shape[0], Q=snap.queue_valid.shape[0], R=R,
         W=snap.task_sel_bits.shape[1], K_aff=snap.task_aff_idx.shape[0],
         P=T, topk=0, warm_w=0, warm_c=0, warm_pi=0, probe_b=0, probe_g=0,
-        scatter_rows=N,
     )
 
 

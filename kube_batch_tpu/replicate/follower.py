@@ -219,7 +219,7 @@ class FollowerApplier:
     def _publish(self, rec) -> None:
         import jax
 
-        from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+        from kube_batch_tpu.api.resident import SWAP_FIELDS
         from kube_batch_tpu.api.snapshot import ARRAY_FIELDS, DeviceSnapshot
         from kube_batch_tpu.serve.lease import SnapshotLease
 
@@ -239,7 +239,7 @@ class FollowerApplier:
                 dev_snap = self.resident.swap(host_snap)
                 updates = {}
                 for field in ARRAY_FIELDS:
-                    if field in PER_CYCLE_FIELDS:
+                    if field in SWAP_FIELDS:
                         continue
                     stamp = self._stamp.get(field, 0)
                     cached = self._static_dev.get(field)

@@ -16,8 +16,9 @@ The publisher keeps its own host mirrors of ALL snapshot fields (not
 just the device cache's per-cycle set) and diffs them with the SAME
 :func:`~kube_batch_tpu.api.resident.changed_rows` the scatter refresh
 uses — so the wire deltas are row-exact and independent of
-KB_DEVICE_CACHE / mesh choice.  For the per-cycle fields it trusts the
-resident swap's own delta record as a fast path whenever the dirty
+KB_DEVICE_CACHE / mesh choice.  For the fields the resident swap
+refreshes (per-cycle AND task feature columns) it trusts the swap's own
+delta record as a fast path whenever the dirty
 tracker advanced by exactly one (``ColumnStore.export_delta_record``);
 any other cadence falls back to the self-diff.  The mirrors double as
 the source for synthesized full-snapshot resync frames when a
@@ -90,7 +91,7 @@ class ReplicationPublisher:
         self.records = {stream.FULL: 0, stream.DELTA: 0}
         self.heartbeats = 0
         self.bytes_published = 0
-        self.hint_fields = 0            # per-cycle fields served by the
+        self.hint_fields = 0            # swap fields served by the
         self.diff_fields = 0            # resident delta record vs self-diff
         self.encode_errors = 0
 
@@ -178,6 +179,7 @@ class ReplicationPublisher:
 
         with self._lock:
             cold = not self._mirror
+            swap_fields = resident_swap_fields()
             full: Dict[str, np.ndarray] = {}
             delta: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
             for field, host in fields.items():
@@ -187,7 +189,7 @@ class ReplicationPublisher:
                     full[field] = host
                     self._mirror[field] = host.copy()
                     continue
-                if hint is not None and field in stream_per_cycle():
+                if hint is not None and field in swap_fields:
                     if field not in hint:
                         self.hint_fields += 1
                         continue  # the swap proved this field clean
@@ -300,9 +302,9 @@ class ReplicationPublisher:
             }
 
 
-def stream_per_cycle():
-    """The device cache's per-cycle field set (lazy import — resident.py
-    pulls jitstats)."""
-    from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+def resident_swap_fields():
+    """The field set a resident swap refreshes and records (lazy import —
+    resident.py pulls jitstats)."""
+    from kube_batch_tpu.api.resident import SWAP_FIELDS
 
-    return PER_CYCLE_FIELDS
+    return SWAP_FIELDS

@@ -254,10 +254,8 @@ class FaultInjector:
             field = "task_pending"
         elif kind == "score":
             # NaN a live node's releasing word — a fit/score input; the
-            # sentinel's all-finite sweep condemns the next solve.  (Task-
-            # axis feature columns re-upload on every arrival's version
-            # bump, which would silently heal the corruption before a
-            # solve ever saw it — node ledgers only scatter at moved rows)
+            # sentinel's all-finite sweep condemns the next solve (a node
+            # ledger only scatters at moved rows, so the flip survives)
             rc = cols._per_cycle_dev.get(None)
             dev = rc._dev.get("node_releasing") if rc is not None else None
             if dev is None or live.size == 0:

@@ -446,12 +446,14 @@ class TestFullPipelineChurnSoak:
 
 class TestResidentFeatureCache:
     def test_reuse_and_invalidation(self):
-        """resident_features returns the SAME device arrays while the
-        feature_version is unchanged, refreshes after ingest (bind/free
-        task, node meta change), and the refreshed upload carries the new
-        values — the staleness hazard the version counter exists for."""
+        """The resident snapshot hands out the SAME device arrays while
+        nothing moved, refreshes after ingest (a new task: its feature rows
+        ride the resident swap's delta; a node meta change: the node
+        feature version re-uploads), and the refreshed arrays carry the new
+        values — the staleness hazard the version counters exist for."""
         import numpy as np
 
+        from kube_batch_tpu.api.columns import resident_snap
         from kube_batch_tpu.framework.conf import load_scheduler_conf
         from kube_batch_tpu.framework.session import close_session, open_session
 
@@ -468,10 +470,11 @@ class TestResidentFeatureCache:
         ssn = open_session(cache, conf.tiers)
         try:
             snap, _meta = cols.device_snapshot(ssn)
-            r1 = cols.resident_features(snap)
-            r2 = cols.resident_features(snap)
+            r1 = resident_snap(cols, snap)
+            r2 = resident_snap(cols, snap)
             assert r1.task_req is r2.task_req  # cached, no re-upload
             assert r1.node_alloc is r2.node_alloc
+            assert cols.resident_features(snap).node_alloc is r1.node_alloc
             np.testing.assert_array_equal(
                 np.asarray(r1.task_req), cols.t_init32)
         finally:
@@ -487,10 +490,11 @@ class TestResidentFeatureCache:
         ssn = open_session(cache, conf.tiers)
         try:
             snap2, meta2 = cols.device_snapshot(ssn)
-            r3 = cols.resident_features(snap2)
+            r3 = resident_snap(cols, snap2)
             assert r3.task_req is not r1.task_req
             np.testing.assert_array_equal(
                 np.asarray(r3.task_req), cols.t_init32)
+            assert r3.node_alloc is r1.node_alloc  # no node moved
             # node meta change (labels) invalidates node bits
             prev_bits = r3.node_label_bits
             node = cache.nodes["n1"]
@@ -498,8 +502,10 @@ class TestResidentFeatureCache:
                              labels={"zone": "z1"})
             node.set_node(obj)
             snap3, _ = cols.device_snapshot(ssn)
-            r4 = cols.resident_features(snap3)
+            r4 = resident_snap(cols, snap3)
             assert r4.node_label_bits is not prev_bits
+            np.testing.assert_array_equal(
+                np.asarray(r4.node_label_bits), cols.n_label_bits)
         finally:
             close_session(ssn)
 
@@ -519,5 +525,6 @@ class TestResidentFeatureCache:
         try:
             snap, _ = cols.device_snapshot(ssn)
             assert cols.resident_features(snap) is snap
+            assert cols.per_cycle_resident(snap) is snap
         finally:
             close_session(ssn)

@@ -81,7 +81,7 @@ def _add_gang_cpu(cache, serial, size=2, cpu=500.0):
 def _cycle(cache, conf, check_resident=False):
     """One real scheduling cycle; optionally assert the device-resident
     per-cycle columns are bit-exact with the freshly built host columns."""
-    from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+    from kube_batch_tpu.api.resident import SWAP_FIELDS
 
     ssn = open_session(cache, conf.tiers)
     try:
@@ -89,7 +89,7 @@ def _cycle(cache, conf, check_resident=False):
             cols = cache.columns
             snap, _meta = cols.device_snapshot(ssn)
             swapped = cols.per_cycle_resident(snap)
-            for field in PER_CYCLE_FIELDS:
+            for field in SWAP_FIELDS:
                 host = np.asarray(getattr(snap, field))
                 dev = np.asarray(getattr(swapped, field))
                 assert np.array_equal(host, dev), (
@@ -148,19 +148,19 @@ class TestWarmStandbyRevalidation:
         # the first post-failover cycle costs no more than an ordinary
         # steady-state cycle — and far less than a cold start (which pays
         # one full upload per per-cycle field)
-        from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+        from kube_batch_tpu.api.resident import SWAP_FIELDS
 
         assert post_failover_delta <= steady_delta, (
             f"warm failover re-uploaded: {post_failover_delta} vs "
             f"steady {steady_delta}"
         )
-        assert post_failover_delta < len(PER_CYCLE_FIELDS)
+        assert post_failover_delta < len(SWAP_FIELDS)
         assert cache.columns.check_consistency(cache) == []
 
     def test_cold_start_for_comparison_re_uploads_everything(self):
         """The cold path the warm standby avoids: dropping residency makes
         the next cycle full-upload every per-cycle field."""
-        from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+        from kube_batch_tpu.api.resident import SWAP_FIELDS
 
         conf = load_scheduler_conf(None)
         cache = _mk_cache()
@@ -170,7 +170,7 @@ class TestWarmStandbyRevalidation:
         _cycle(cache, conf, check_resident=True)
         rc = cache.columns._per_cycle_dev.get(None)
         assert rc is not None
-        assert rc.counters()["full_uploads"] >= len(PER_CYCLE_FIELDS)
+        assert rc.counters()["full_uploads"] >= len(SWAP_FIELDS)
 
     def test_failed_revalidation_cold_starts(self, monkeypatch):
         conf = load_scheduler_conf(None)

@@ -38,7 +38,7 @@ from kube_batch_tpu.analysis.hbm_audit import (
 _SP = ShapePoint(
     name="fixture", tasks=4000, nodes=500, T=4096, N=512, J=8, Q=2, R=3,
     W=1, K_aff=1, P=1024, topk=2, warm_w=4, warm_c=4, warm_pi=4,
-    probe_b=2, probe_g=4, scatter_rows=8,
+    probe_b=2, probe_g=4,
 )
 
 

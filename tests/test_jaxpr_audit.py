@@ -50,8 +50,8 @@ class TestRegistryClean:
 
     def test_sharded_variants_traced_on_the_virtual_mesh(self):
         """The conftest's forced 8-device CPU mesh stands in for multi-chip
-        hardware: the sharded solve variants and both mesh scatters must be
-        registered and trace clean (KBT101-104 over the sharded path)."""
+        hardware: the sharded solve variants and both mesh swap programs
+        must be registered and trace clean (KBT101-104 over the sharded path)."""
         from kube_batch_tpu.analysis.jaxpr_audit import sharded_registry
 
         assert len(jax.devices()) >= 2
@@ -61,8 +61,8 @@ class TestRegistryClean:
         assert any("sharded_allocate_topk_solve" in n for n in names)
         assert any("sharded_failure_histogram" in n for n in names)
         assert any("sharded_evict_solve" in n for n in names)
-        assert any("scatter_sharded" in n for n in names)
-        assert any("scatter_repl" in n for n in names)
+        assert any("swap_sharded" in n for n in names)
+        assert any("swap_repl" in n for n in names)
         findings = run_audit(registry=sharded)
         assert findings == [], "\n" + "\n".join(f.render() for f in findings)
 

@@ -107,7 +107,7 @@ def test_allocate_dispatches_sharded_with_resident_cache():
     few churn cycles the sharded cache exists, scatter-delta updates
     engaged, and every cached field round-trips bit-exact."""
     from kube_batch_tpu.api.columns import resident_snap
-    from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+    from kube_batch_tpu.api.resident import SWAP_FIELDS
     from kube_batch_tpu.parallel.mesh import default_mesh
 
     import itertools
@@ -125,7 +125,7 @@ def test_allocate_dispatches_sharded_with_resident_cache():
         try:
             snap, _meta = cols.device_snapshot(ssn)
             swapped = resident_snap(cols, snap, mesh)
-            for field in PER_CYCLE_FIELDS:
+            for field in SWAP_FIELDS:
                 host = np.asarray(getattr(snap, field))
                 dev = np.asarray(getattr(swapped, field))
                 assert np.array_equal(host, dev), (
@@ -141,6 +141,81 @@ def test_allocate_dispatches_sharded_with_resident_cache():
     assert sharded is not None
     assert sharded.scatter_updates > 0, "per-shard delta path never engaged"
     assert sharded.clean_hits > 0
+    assert cols.check_consistency(cache) == []
+
+
+def test_sharded_swap_is_two_programs_and_no_feature_upload():
+    """The mesh path's fused swap: the replicated columns ride one packed
+    program and the node-axis columns its per-shard twin, so a steady churn
+    swap makes at most two program calls (plus the tiny columns' whole
+    puts), uploads no task feature column whole, adds no jit
+    specialization after the cold upload, and every column it refreshes
+    fetches back byte-for-byte the host column.  A repeat resident_snap on
+    the identical host snapshot is free."""
+    from kube_batch_tpu.api.columns import resident_snap
+    from kube_batch_tpu.api.resident import (
+        NODE_AXIS_FIELDS,
+        SWAP_FIELDS,
+        TASK_FEATURE_FIELDS,
+    )
+    from kube_batch_tpu.parallel.mesh import default_mesh
+    from kube_batch_tpu.utils import jitstats
+
+    import itertools
+
+    cache = _mk_cache(seed=5)
+    # axes wide enough that the job/task columns take the scatter path
+    cache.columns.reserve(n_tasks=2048, n_jobs=512)
+    conf = load_scheduler_conf(None)
+    rng = np.random.default_rng(11)
+    serial = itertools.count(1)
+    cols = cache.columns
+    mesh = default_mesh()
+    tiny = None
+    for cycle in range(6):
+        _churn(cache, rng, serial)
+        ssn = open_session(cache, conf.tiers)
+        try:
+            snap, _meta = cols.device_snapshot(ssn)
+            before = cols.resident_counters().get("sharded", {})
+            compiles = jitstats.total_compiles()
+            swapped = resident_snap(cols, snap, mesh)
+            after = cols.resident_counters()["sharded"]
+            compiles = jitstats.total_compiles() - compiles
+            for field in SWAP_FIELDS:
+                host = np.asarray(getattr(snap, field))
+                dev = np.asarray(getattr(swapped, field))
+                assert dev.tobytes() == host.tobytes(), (
+                    f"cycle {cycle}: sharded-resident {field} diverged")
+            assert resident_snap(cols, snap, mesh) is swapped
+            assert cols.resident_counters()["sharded"] == after
+            moved = {k: after[k] - before.get(k, 0)
+                     for k in ("version", "dispatches", "full_uploads",
+                               "feature_uploads", "scatter_updates")}
+            sharded = cols._per_cycle_dev[mesh]
+            if cycle == 0:
+                assert moved["feature_uploads"] == len(TASK_FEATURE_FIELDS)
+                assert sharded._shard_layout.fields, (
+                    "no node-axis column took the per-shard layout")
+                packed = {f for f, *_ in sharded._layout.fields}
+                assert packed and not packed & NODE_AXIS_FIELDS
+                tiny = len(SWAP_FIELDS) - len(packed) - len(
+                    sharded._shard_layout.fields)
+            else:
+                assert moved["version"] == 1
+                assert moved["feature_uploads"] == 0, moved
+                assert compiles == 0, f"cycle {cycle} compiled {compiles}"
+                # two programs at most; every other call is a whole put of
+                # a column too small for its own payload
+                assert moved["dispatches"] - moved["full_uploads"] <= 2, moved
+                assert moved["full_uploads"] <= tiny, moved
+                assert moved["scatter_updates"] > 0, moved
+            for name in conf.actions:
+                get_action(name).execute(ssn)
+            assert get_action("allocate").last_solve_mode == "sharded"
+        finally:
+            close_session(ssn)
+        cache.flush_binds()
     assert cols.check_consistency(cache) == []
 
 

@@ -216,7 +216,7 @@ def test_delta_open_state_matches_full_view(seed):
 def test_per_cycle_device_cache_round_trips_bit_exact():
     """The scatter-refreshed device-resident per-cycle columns fetch back
     bit-identical to the host columns after every churn cycle."""
-    from kube_batch_tpu.api.resident import PER_CYCLE_FIELDS
+    from kube_batch_tpu.api.resident import SWAP_FIELDS
 
     rng = np.random.default_rng(5)
     cache = _mk_cache()
@@ -237,7 +237,7 @@ def test_per_cycle_device_cache_round_trips_bit_exact():
         try:
             snap, _meta = cols.device_snapshot(ssn)
             swapped = cols.per_cycle_resident(snap)
-            for field in PER_CYCLE_FIELDS:
+            for field in SWAP_FIELDS:
                 host = np.asarray(getattr(snap, field))
                 dev = np.asarray(getattr(swapped, field))
                 assert np.array_equal(host, dev), (
@@ -252,6 +252,216 @@ def test_per_cycle_device_cache_round_trips_bit_exact():
     assert pcd is not None and pcd.scatter_updates > 0, (
         "scatter-delta path never engaged"
     )
+
+
+def _bit_equal_to_whole_upload(snap, swapped, context):
+    """Every field a swap refreshes fetches back byte-for-byte what a
+    whole upload of the host column would hold."""
+    from kube_batch_tpu.api.resident import SWAP_FIELDS
+
+    for field in SWAP_FIELDS:
+        host = np.asarray(getattr(snap, field))
+        dev = np.asarray(getattr(swapped, field))
+        assert dev.dtype == host.dtype and dev.shape == host.shape, (
+            f"{context}: {field} {dev.dtype}{dev.shape} vs "
+            f"{host.dtype}{host.shape}")
+        assert dev.tobytes() == host.tobytes(), (
+            f"{context}: device-resident {field} is not the host column")
+
+
+def _selector_gang(cache, name, label):
+    """A one-pod gang whose node selector names `label` — a sparse task
+    bitset row that refresh_task_bits recomputes when the label universe
+    moves."""
+    cache.add_pod_group(PodGroup(
+        name=name, namespace="churn", uid=f"pg-{name}", min_member=1,
+        queue="q0", creation_index=9000,
+    ))
+    cache.add_pod(Pod(
+        name=f"{name}-0", namespace="churn", uid=f"pod-{name}-0",
+        requests={"cpu": 250.0, "memory": 1 * GiB},
+        annotations={GROUP_NAME_ANNOTATION: name},
+        node_selector=dict([label]),
+        phase=PodPhase.PENDING, creation_index=900000,
+    ))
+
+
+def _swap_cycle(cache, conf, context, run_actions=True):
+    """One session: the device snapshot through resident_snap, checked
+    bit-equal to a whole upload; returns the resident counters' movement
+    over the swap and the jit specializations it added."""
+    from kube_batch_tpu.api.columns import resident_snap
+    from kube_batch_tpu.utils import jitstats
+
+    cols = cache.columns
+    ssn = open_session(cache, conf.tiers)
+    try:
+        snap, _meta = cols.device_snapshot(ssn)
+        before = cols.resident_counters().get("single", {})
+        compiles = jitstats.total_compiles()
+        swapped = resident_snap(cols, snap)
+        after = cols.resident_counters()["single"]
+        compiles = jitstats.total_compiles() - compiles
+        _bit_equal_to_whole_upload(snap, swapped, context)
+        if run_actions:
+            for name in conf.actions:
+                get_action(name).execute(ssn)
+    finally:
+        close_session(ssn)
+    cache.flush_binds()
+    moved = {k: after[k] - before.get(k, 0) for k in after}
+    return moved, compiles
+
+
+@pytest.mark.parametrize("case", [
+    "churn", "freed_and_reallocated", "refresh_task_bits", "axis_growth",
+    "delta_past_scatter_slots",
+])
+def test_fused_swap_bit_equal_to_whole_upload(case, monkeypatch):
+    """The one-program swap leaves every resident column — per-cycle AND
+    task feature — byte-for-byte what a whole upload would hold, through
+    the events that move task feature rows: randomized churn, rows freed
+    and re-allocated inside one cycle, a refresh_task_bits cycle, an axis
+    growth, and a delta wider than the widest slot bucket."""
+    from kube_batch_tpu.api import resident as res
+
+    rng = np.random.default_rng(17)
+    cache = _mk_cache()
+    conf = load_scheduler_conf(None)
+    churn = _Churner(cache, rng)
+    cols = cache.columns
+    if case == "delta_past_scatter_slots":
+        # narrow buckets: a 24-pod arrival overflows the widest one and
+        # must re-upload its columns whole (values exact either way)
+        monkeypatch.setattr(res, "SCATTER_SLOT_BUCKETS", (4, 8, 16))
+        monkeypatch.setattr(res, "SCATTER_SLOTS", 16)
+    if case != "axis_growth":
+        cols.reserve(n_tasks=2048, n_nodes=128, n_jobs=512)
+    for _ in range(3):
+        churn.add_gang()
+    _swap_cycle(cache, conf, f"{case}: cold")
+    cache_obj = cols._per_cycle_dev[None]
+    for cycle in range(6):
+        context = f"{case}: cycle {cycle}"
+        if case == "churn":
+            for _ in range(3):
+                churn.step()
+        elif case == "freed_and_reallocated":
+            # the allocator is LIFO: the rows a completed gang frees are
+            # the rows the next arrivals take, all inside one cycle
+            churn.complete_gang()
+            churn.add_gang(size=3)
+            churn.add_gang(size=2)
+        elif case == "refresh_task_bits":
+            if cycle == 0:
+                _selector_gang(cache, "sel", ("zone", "z1"))
+            elif cycle == 2:
+                # a new label pair joins the universe: the selector row's
+                # bitset is recomputed at the next snapshot build
+                cache.add_node(Node(
+                    name="nz", labels={"zone": "z1"},
+                    allocatable={"cpu": 16000.0, "memory": 64 * GiB,
+                                 "pods": 110.0},
+                ))
+            else:
+                churn.add_gang()
+        elif case == "axis_growth":
+            for _ in range(4):
+                churn.add_gang(size=3)   # walks the task axis past a bucket
+        else:
+            churn.add_gang(size=24 if cycle == 2 else 2)
+        moved, _ = _swap_cycle(cache, conf, context)
+        if case == "delta_past_scatter_slots" and cycle == 2:
+            assert moved["feature_uploads"] > 0, (
+                "a delta past the widest bucket must re-upload whole")
+    if case == "axis_growth":
+        assert cols.tasks.cap > 8, "the task axis never grew"
+    if case == "refresh_task_bits":
+        assert int(cols.t_sel_bits.any(axis=1).sum()) > 0
+    assert cols._per_cycle_dev[None] is cache_obj
+    assert cols.check_consistency(cache) == []
+
+
+def test_steady_swap_is_one_program_and_no_feature_upload():
+    """A steady churn swap (pods come and go, no node moves) makes at most
+    three device calls — the packed program plus the two tiny queue
+    columns' whole puts — uploads no task feature column whole, and adds
+    no jit specialization once the cold upload has prewarmed."""
+    rng = np.random.default_rng(29)
+    cache = _mk_cache()
+    cache.columns.reserve(n_tasks=2048, n_nodes=128, n_jobs=512)
+    conf = load_scheduler_conf(None)
+    churn = _Churner(cache, rng)
+    for _ in range(3):
+        churn.add_gang()
+    cold, _ = _swap_cycle(cache, conf, "cold")
+    from kube_batch_tpu.api.resident import SWAP_FIELDS, TASK_FEATURE_FIELDS
+
+    assert cold["full_uploads"] == len(SWAP_FIELDS)
+    assert cold["feature_uploads"] == len(TASK_FEATURE_FIELDS)
+    # the cold swap: one put a field, then 3 buckets x 2 prewarm passes
+    assert cold["dispatches"] == len(SWAP_FIELDS) + 6
+    scattered = 0
+    for cycle in range(8):
+        churn.complete_gang()
+        churn.add_gang()
+        churn.flip_statuses()
+        moved, compiles = _swap_cycle(cache, conf, f"cycle {cycle}")
+        assert moved["version"] == 1
+        assert 1 <= moved["dispatches"] <= 3, moved
+        assert moved["feature_uploads"] == 0, moved
+        assert compiles == 0, f"cycle {cycle} compiled {compiles}"
+        scattered += moved["scatter_updates"]
+    assert scattered > 0, "the packed scatter path never engaged"
+
+
+def test_repeat_resident_snap_is_free_and_reads_nothing_back(monkeypatch):
+    """A second resident_snap on the identical host snapshot returns the
+    identical device snapshot — no diff, no version bump, no dispatch (the
+    same cycle's oracle, histogram and lease publish lean on it) — and no
+    swap is ever handed a device array to convert back to numpy."""
+    from kube_batch_tpu.api import resident as res
+    from kube_batch_tpu.api.columns import resident_snap
+
+    seen = []
+    real_swap = res.PerCycleDeviceCache.swap
+
+    def spy(self, snap, feature_token=None):
+        seen.extend(
+            (f, type(getattr(snap, f))) for f in res.SWAP_FIELDS
+            if not isinstance(getattr(snap, f), np.ndarray)
+        )
+        return real_swap(self, snap, feature_token)
+
+    monkeypatch.setattr(res.PerCycleDeviceCache, "swap", spy)
+    rng = np.random.default_rng(31)
+    cache = _mk_cache()
+    cache.columns.reserve(n_tasks=2048, n_nodes=128, n_jobs=512)
+    conf = load_scheduler_conf(None)
+    churn = _Churner(cache, rng)
+    for _ in range(3):
+        churn.add_gang()
+    cols = cache.columns
+    for cycle in range(4):
+        churn.step()
+        ssn = open_session(cache, conf.tiers)
+        try:
+            snap, _meta = cols.device_snapshot(ssn)
+            first = resident_snap(cols, snap)
+            held = cols.resident_counters()["single"]
+            again = resident_snap(cols, snap)
+            assert again is first, f"cycle {cycle}: repeat built a new snap"
+            assert cols.resident_counters()["single"] == held, (
+                f"cycle {cycle}: a repeat moved the counters")
+            # the actions' own dispatch of this very snapshot is a repeat
+            for name in conf.actions:
+                get_action(name).execute(ssn)
+        finally:
+            close_session(ssn)
+        cache.flush_binds()
+    assert seen == [], f"a swap was handed non-host columns: {seen[:4]}"
+    assert "task_best_effort" not in res.PER_CYCLE_FIELDS
+    assert "task_best_effort" in res.TASK_FEATURE_FIELDS
 
 
 def test_full_fallback_on_row_space_changes():

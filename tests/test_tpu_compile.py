@@ -167,3 +167,64 @@ def test_a_solve_that_ends_at_its_gates_skips_the_task_axis_sorts(
     above = _reach(comps, [ln for ln in entry if ln is not cond])
     assert not any(" while(" in ln for ln in above)
     assert _task_axis_argsorts(above) == 4            # the gates' rankings
+
+
+def _donating(fn):
+    """`fn`'s traced body under a jit that donates argument 0 — what the
+    resident swap programs are on an accelerator (their own wrappers gate
+    donation off on this sandbox's CPU backend)."""
+    return jax.jit(fn.__wrapped__, static_argnums=(3,), donate_argnums=(0,))
+
+
+@pytest.mark.parametrize("where", ["one_chip_50k", "mesh_150k"])
+def test_the_resident_swap_programs_compile_for_the_chip_and_alias(
+        topo, where):
+    """The packed swap programs (api/resident.py) at the benchmark's axes:
+    the TPU compiler takes them at the widest slot bucket, and every
+    donated resident buffer is aliased to its refreshed successor — the
+    update is in place, so a swap allocates nothing the size of a column."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+
+    from kube_batch_tpu.analysis.jaxpr_audit import (
+        _build_repl_swap,
+        _build_resident_swap,
+        _build_shard_swap,
+    )
+    from kube_batch_tpu.api import resident as res
+    from kube_batch_tpu.parallel import mesh as pm
+
+    def placed(args, sharding):
+        devs, rows, vals, layout = args
+        put = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=sharding)
+        return jax.tree.map(put, (devs, rows, vals)) + (layout,)
+
+    if where == "one_chip_50k":
+        point = next(sp for sp in shape_points() if sp.name == "headline-50k")
+        _fn, args = _build_resident_swap(point)
+        programs = [(res._swap_scatter_fn(),
+                     placed(args, SingleDeviceSharding(topo.devices[0])))]
+    else:
+        point = next(sp for sp in shape_points()
+                     if sp.name == "envelope-150k")
+        mesh = Mesh(np.asarray(topo.devices), (pm.NODE_AXIS,))
+        programs = [
+            (res._mesh_repl_scatter_fn(mesh),
+             placed(_build_repl_swap(mesh, point)[1],
+                    NamedSharding(mesh, P()))),
+            (res._mesh_shard_scatter_fn(mesh),
+             placed(_build_shard_swap(mesh, point)[1],
+                    NamedSharding(mesh, P(pm.NODE_AXIS)))),
+        ]
+    for fn, args in programs:
+        memory = _donating(fn).lower(*args).compile().memory_analysis()
+        held = sum(
+            int(np.prod(a.shape)) * a.dtype.itemsize
+            for a in jax.tree.leaves(args[0]))
+        per_device = held // (4 if "sharded" in fn.__name__ else 1)
+        assert memory.alias_size_in_bytes >= per_device, (
+            where, fn.__name__, memory.alias_size_in_bytes, per_device)
+        # nothing column-sized beyond the aliased buffers and the payload
+        assert memory.temp_size_in_bytes < per_device, (
+            where, fn.__name__, memory.temp_size_in_bytes)
