@@ -276,7 +276,9 @@ LEADER_FAILOVER = Counter(
 )
 # query-plane counters (serve/): the amortization story is readable straight
 # off /metrics — requests_total vs device_dispatches_total is the
-# requests-per-dispatch ratio the serving bench asserts
+# requests-per-dispatch ratio the serving bench asserts; dispatches over
+# batch_size_count is the dispatches a flush, dispatch_points over
+# dispatches x the batch bucket the share of the lanes paid for that were live
 WHATIF_REQUESTS = Counter(
     f"{_SUBSYSTEM}_whatif_requests_total",
     "What-if probe requests, by verdict (feasible|infeasible|error)",
@@ -284,11 +286,17 @@ WHATIF_REQUESTS = Counter(
 )
 WHATIF_DISPATCHES = Counter(
     f"{_SUBSYSTEM}_whatif_device_dispatches_total",
-    "Batched probe device dispatches (one per flush window)",
+    "Batched probe device dispatches (a flush window's points in as few "
+    "as hold them)",
+)
+WHATIF_DISPATCH_POINTS = Counter(
+    f"{_SUBSYSTEM}_whatif_dispatch_points_total",
+    "Live lanes put into probe dispatches (a plain request is one point, "
+    "a sweep one per count it probes)",
 )
 WHATIF_BATCH_SIZE = Histogram(
     f"{_SUBSYSTEM}_whatif_batch_size",
-    "Requests amortized into one probe dispatch",
+    "Requests one flush window answered together",
 )
 WHATIF_QUEUE_DEPTH = Histogram(
     f"{_SUBSYSTEM}_whatif_queue_depth",
@@ -709,6 +717,7 @@ METRICS = [
     LEADER_FAILOVER,
     WHATIF_REQUESTS,
     WHATIF_DISPATCHES,
+    WHATIF_DISPATCH_POINTS,
     WHATIF_BATCH_SIZE,
     WHATIF_QUEUE_DEPTH,
     WHATIF_LATENCY,
@@ -895,8 +904,9 @@ def register_whatif_request(verdict: str) -> None:
     WHATIF_REQUESTS.inc(verdict)
 
 
-def register_whatif_dispatch() -> None:
+def register_whatif_dispatch(points: int) -> None:
     WHATIF_DISPATCHES.inc()
+    WHATIF_DISPATCH_POINTS.add(float(points))
 
 
 def observe_whatif_batch(size: int, queue_depth: int) -> None:

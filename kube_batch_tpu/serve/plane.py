@@ -5,9 +5,11 @@ scheduling cycle publishes a :class:`serve.lease.SnapshotLease` after its
 resident swap (actions/allocate.py calls :meth:`publish_session` on both
 the solve path and the idle-cycle path, so an idle cluster still serves);
 HTTP handler threads :meth:`submit` requests; the micro-batcher flushes
-them as ONE :func:`ops.probe.probe_solve` dispatch against the lease's
-device-resident columns — the shard_map variant when the lease's solve ran
-sharded.
+them together: a flush plans its probe points (a plain request is one, a
+sweep its count grid) and answers them in as few
+:func:`ops.probe.probe_solve` dispatches as hold them — usually one —
+against the lease's device-resident columns; the shard_map variant when
+the lease's solve ran sharded.
 
 Probe answers are oracle-exact on a frozen snapshot (ops/probe.py module
 docstring); the lease's ``snapshot_version`` tells clients which cache
@@ -44,8 +46,8 @@ MAX_GANG = 64
 #: 400 their own request at parse time, never overflow inside the flush
 _I32_MAX = 2**31 - 1
 
-#: /v1/whatif/sweep: the geometric count grid the first dispatch pass
-#: probes to bracket the feasibility boundary before binary search
+#: /v1/whatif/sweep: the geometric count grid a sweep's first points
+#: probe to bracket the feasibility boundary before binary search
 _SWEEP_GRID = (1, 2, 4, 8, 16, 32, 64)
 
 
@@ -150,6 +152,66 @@ def _parse_request(body: dict, spec) -> dict:
     }
 
 
+class _SweepPlan:
+    """One /v1/whatif/sweep as a planner of probe points: the largest
+    replica count whose gang fits, against ONE lease.  A geometric grid
+    brackets the feasibility boundary, then classic binary search refines
+    it — the server does the log(N) probes the client would otherwise issue
+    as round-trips (feasibility is monotone in count on a frozen snapshot:
+    a (c+1)-gang placement contains a c-gang placement).  The plan only
+    NAMES the counts it needs next; the flush answers them, every sweep's
+    together with the window's other points, and writes the verdicts into
+    ``feasible``."""
+
+    def __init__(self, req: dict):
+        self.req = req
+        self.feasible: Dict[int, bool] = {}
+        self.max_fit: Optional[int] = None  # known once the walk has ended
+        self._walk = self._plan()
+
+    def _plan(self):
+        max_count = self.req["max_count"]
+        feasible = self.feasible
+        grid = sorted({c for c in _SWEEP_GRID if c < max_count}
+                      | {max_count})
+        yield grid
+        if not feasible[grid[0]]:
+            return 0
+        if feasible[max_count]:
+            return max_count
+        lo = max(c for c in grid if feasible[c])
+        hi = min(c for c in grid if not feasible[c])
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            yield [mid]
+            if feasible[mid]:
+                lo = mid
+            else:
+                hi = mid
+        return lo
+
+    def next_points(self) -> List[int]:
+        """The counts to probe next (each answered in ``feasible`` before
+        the next call); empty once ``max_fit`` is known."""
+        if self.max_fit is not None:
+            return []
+        try:
+            return next(self._walk)
+        except StopIteration as ended:
+            self.max_fit = ended.value
+            return []
+
+    def response(self, lease: SnapshotLease, staleness: dict) -> dict:
+        return {
+            "snapshot_version": lease.version,
+            "max_fit": self.max_fit,
+            "feasible": self.max_fit >= 1,
+            "max_count": self.req["max_count"],
+            "probes": len(self.feasible),
+            "staleness": staleness,
+        }
+
+
 class QueryPlane:
     def __init__(self, cache, max_batch: Optional[int] = None,
                  window_s: Optional[float] = None,
@@ -195,6 +257,7 @@ class QueryPlane:
             max_queue=max_queue, start_thread=start_thread,
         )
         self.dispatches = 0
+        self.points = 0  # live lanes put into those dispatches
         self.requests_served = 0
         self.flushes = 0  # the sequence number a flush's span carries
         # the cache's one span plane: a flush is a tree of its own there
@@ -333,8 +396,8 @@ class QueryPlane:
         real serving scale outlasts the request timeout, so without this
         the first window after startup (and after every shape-bucket
         growth) would 503 through a healthy system.  The eviction variant
-        stays lazily compiled: it runs in its own dispatch (see _flush),
-        so only its first requester waits on it.
+        stays lazily compiled: it runs in its own dispatch (see
+        _flush_traced), so only its first requester waits on it.
 
         The warm dispatch probes a ZEROS TWIN of the lease snapshot, not
         the lease itself: the jit cache keys on shapes/dtypes/shardings,
@@ -425,7 +488,7 @@ class QueryPlane:
         return self.batcher.submit(req)
 
     # ------------------------------------------------------------------
-    # batch flush — ONE device dispatch for every queued request
+    # batch flush — plan the window's probe points, dispatch them together
     # ------------------------------------------------------------------
     def _flush(self, batch) -> None:
         # a client that timed out already 503'd and CANCELLED its future
@@ -448,22 +511,30 @@ class QueryPlane:
 
     def _flush_traced(self, batch, sp_flush) -> None:
         tracer = self.tracer
-        # a mixed window splits by the evictions flag: with_evictions is a
-        # static jit arg selecting a superset program, so one --evictions
-        # request must not make every co-batched plain probe pay the
-        # eviction pass's device time (each sub-batch is still a jit-stable
-        # (B, G) bucket — at most two dispatches per window, answered
-        # against the SAME lease).  Sweeps run their own multi-dispatch
-        # search, still inside the single held dispatch region, so every
-        # probed point answers against one snapshot.
-        sweeps = [(r, f) for r, f in batch if r.get("_sweep")]
-        plain = [(r, f) for r, f in batch if not r.get("_sweep")]
-        subs = [
-            [(r, f) for r, f in plain if not r["evictions"]],
-            [(r, f) for r, f in plain if r["evictions"]],
-        ]
-        done = []
-        done_sweeps = []
+        # a point is (index into the batch, count): one lane of a dispatch.
+        # A plain request is one point (count None: as it was asked); a
+        # sweep names the counts its plan needs next.  Every lane of the
+        # probe program is an independent hypothetical against the frozen
+        # snapshot, so a sweep's grid rides the plain requests' dispatch
+        # and all sweeps take a refinement step in ONE dispatch: a window
+        # costs one dispatch, plus one a step while a boundary lies inside
+        # a grid gap, plus one where the points pass the batch bucket.
+        # Requests with evictions keep a dispatch of their own:
+        # with_evictions is a static jit arg selecting a superset program,
+        # so one of them must not make every co-batched point pay the
+        # eviction pass's device time (a sweep never carries evictions).
+        sweeps = {i: _SweepPlan(req) for i, (req, _) in enumerate(batch)
+                  if req.get("_sweep")}
+        answers: list = [None] * len(batch)  # a response or a WhatifError
+        first: List[tuple] = []
+        for i, (req, _) in enumerate(batch):
+            if i in sweeps:
+                first += [(i, c) for c in sweeps[i].next_points()]
+            elif not req["evictions"]:
+                first.append((i, None))
+        evicting = [(i, None) for i, (req, _) in enumerate(batch)
+                    if req["evictions"]]
+        dispatches0, points0 = self.dispatches, self.points
         with contextlib.ExitStack() as held:
             # from entering the broker until the lease is held or refused:
             # a resident swap in flight, or no lease since the last one
@@ -479,100 +550,74 @@ class QueryPlane:
                         metrics.register_whatif_request("error")
                 return
             sp_flush.set(lease_version=lease.version)
-            for sub in subs:
-                if not sub:
-                    continue
-                try:
-                    done.append(
-                        (sub, self._probe(lease, [req for req, _ in sub]))
-                    )
-                except Exception as e:  # noqa: BLE001 — fail THIS sub-batch, keep serving
-                    logger.exception("whatif probe dispatch failed")
-                    for _req, fut in sub:
-                        if self._deliver(
-                            fut, error=WhatifError(500, f"probe failed: {e}")
-                        ):
-                            metrics.register_whatif_request("error")
-            for req, fut in sweeps:
-                try:
-                    done_sweeps.append((req, fut, self._sweep(lease, req)))
-                except Exception as e:  # noqa: BLE001 — fail THIS sweep, keep serving
-                    logger.exception("whatif sweep failed")
-                    if self._deliver(
-                        fut, error=WhatifError(500, f"sweep failed: {e}")
-                    ):
-                        metrics.register_whatif_request("error")
+            # every dispatch inside the single held region: each point of
+            # the window answers against one snapshot
+            self._dispatch_points(lease, batch, sweeps, answers, first)
+            self._dispatch_points(lease, batch, sweeps, answers, evicting)
+            while True:
+                step = [(i, c) for i, plan in sweeps.items()
+                        if answers[i] is None for c in plan.next_points()]
+                if not step:
+                    break
+                self._dispatch_points(lease, batch, sweeps, answers, step)
+            staleness = self._staleness(lease)
+            for i, plan in sweeps.items():
+                if answers[i] is None:
+                    answers[i] = plan.response(lease, staleness)
+            sp_flush.set(dispatches=self.dispatches - dispatches0,
+                         points=self.points - points0)
         # delivered once the lease is released, so a waiting swap goes first
         with tracer.span("whatif:deliver"):
-            self._deliver_all(done, done_sweeps)
+            self._deliver_all(batch, answers)
 
-    def _deliver_all(self, done, done_sweeps) -> None:
-        for req, fut, resp in done_sweeps:
-            if not self._deliver(fut, result=resp):
+    def _dispatch_points(self, lease: SnapshotLease, batch, sweeps, answers,
+                         points) -> None:
+        """Answer ``points`` in chunks of at most the batch bucket's lanes,
+        in the order given, one dispatch a chunk, and route each lane back:
+        a plain request's response into ``answers``, a count's verdict
+        into the plan of the sweep that asked it.  A chunk that fails fails
+        the requests with a point in it (500) and no others."""
+        lanes = self.batcher.max_batch
+        for k in range(0, len(points), lanes):
+            chunk = points[k:k + lanes]
+            reqs = [
+                batch[i][0] if c is None
+                else dict(batch[i][0], count=c, min_avail=c)
+                for i, c in chunk
+            ]
+            try:
+                host = self._probe(lease, reqs)
+                with self.tracer.span("whatif:decode"):
+                    for b, (i, c) in enumerate(chunk):
+                        if answers[i] is not None:
+                            continue  # failed with an earlier chunk
+                        if c is None:
+                            answers[i] = self._decode(lease, reqs[b], host, b)
+                        else:
+                            sweeps[i].feasible[c] = bool(host.feasible[b])
+            except Exception as e:  # noqa: BLE001 — fail THIS chunk, keep serving
+                logger.exception("whatif probe dispatch failed")
+                for i, c in chunk:
+                    what = "probe" if c is None else "sweep"
+                    answers[i] = WhatifError(500, f"{what} failed: {e}")
+
+    def _deliver_all(self, batch, answers) -> None:
+        for (req, fut), answer in zip(batch, answers):
+            if isinstance(answer, WhatifError):
+                if self._deliver(fut, error=answer):
+                    metrics.register_whatif_request("error")
                 continue
-            metrics.register_whatif_sweep()
+            if not self._deliver(fut, result=answer):
+                continue  # client gave up mid-dispatch
+            if req.get("_sweep"):
+                metrics.register_whatif_sweep()
+            else:
+                metrics.register_whatif_request(
+                    "feasible" if answer["feasible"] else "infeasible")
             metrics.observe_whatif_latency(
                 (telemetry.perf_counter() - req["_t0"]) * 1e3
             )
             self.requests_served += 1
-        for sub, results in done:
-            for (req, fut), resp in zip(sub, results):
-                if not self._deliver(fut, result=resp):
-                    continue  # client gave up mid-dispatch
-                verdict = "feasible" if resp["feasible"] else "infeasible"
-                metrics.register_whatif_request(verdict)
-                metrics.observe_whatif_latency(
-                    (telemetry.perf_counter() - req["_t0"]) * 1e3
-                )
-                self.requests_served += 1
-
-    def _sweep(self, lease: SnapshotLease, req: dict) -> dict:
-        """Binary-search the largest replica count whose gang fits,
-        against ONE lease: a geometric grid pass brackets the feasibility
-        boundary (one or two chunked probe dispatches), then classic
-        binary search refines it — the server does the log(N) probes the
-        client would otherwise issue as round-trips, and every point
-        answers against the same snapshot (feasibility is monotone in
-        count on a frozen snapshot: a (c+1)-gang placement contains a
-        c-gang placement)."""
-        max_count = req["max_count"]
-        feasible: Dict[int, bool] = {}
-        probes = 0
-
-        def probe(counts: List[int]) -> None:
-            nonlocal probes
-            for i in range(0, len(counts), self.batcher.max_batch):
-                chunk = counts[i:i + self.batcher.max_batch]
-                reqs = [dict(req, count=c, min_avail=c) for c in chunk]
-                for c, r in zip(chunk, self._probe(lease, reqs)):
-                    feasible[c] = bool(r["feasible"])
-                probes += len(chunk)
-
-        grid = sorted({c for c in _SWEEP_GRID if c < max_count}
-                      | {max_count})
-        probe(grid)
-        if not feasible[grid[0]]:
-            lo = 0
-        elif feasible[max_count]:
-            lo = max_count
-        else:
-            lo = max(c for c in grid if feasible[c])
-            hi = min(c for c in grid if not feasible[c])
-            while hi - lo > 1:
-                mid = (lo + hi) // 2
-                probe([mid])
-                if feasible[mid]:
-                    lo = mid
-                else:
-                    hi = mid
-        return {
-            "snapshot_version": lease.version,
-            "max_fit": lo,
-            "feasible": lo >= 1,
-            "max_count": max_count,
-            "probes": probes,
-            "staleness": self._staleness(lease),
-        }
 
     @staticmethod
     def _deliver(fut: Future, result=None, error=None) -> bool:
@@ -657,19 +702,21 @@ class QueryPlane:
 
     # ---- dispatch + decode -------------------------------------------
     def _probe(self, lease: SnapshotLease, reqs: List[dict],
-               record: bool = True) -> List[dict]:
+               record: bool = True):
+        """One ``(B, G)`` program over ``reqs``, a lane each: the device's
+        answers on the host, for :meth:`_decode` to read by lane."""
         import jax
 
         from kube_batch_tpu.ops.probe import probe_solve
 
         # children of the caller's root (a flush, a pre-warm): one of each
-        # per sub-batch and per sweep dispatch
+        # per dispatch
         tracer = self.tracer
         with tracer.span("whatif:encode"):
             pbatch, rows = self._encode(lease, reqs)
         with_evictions = any(r["evictions"] for r in reqs)
         # program dispatch + device_get: the device's share of a flush
-        with tracer.span("whatif:probe", batch=len(reqs)):
+        with tracer.span("whatif:probe", batch=len(reqs), gang=len(rows)):
             if lease.mesh is not None:
                 from kube_batch_tpu.parallel.mesh import sharded_probe_solve
 
@@ -684,26 +731,23 @@ class QueryPlane:
                 )
             if record:  # pre-warm dispatches stay out of the serving counters
                 self.dispatches += 1
-                metrics.register_whatif_dispatch()
+                self.points += len(reqs)
+                metrics.register_whatif_dispatch(len(reqs))
             if not with_evictions:
                 # the eviction fields are all-zeros placeholders on this
                 # program, and victims is [B, T]-sized — at big snapshots
                 # that dead transfer would rival the batch window itself.
                 # None is an empty pytree: device_get skips it, and _decode
                 # only reads these fields for evictions requests (the flush
-                # partitions windows by that flag, so the sub-batch is
+                # keeps those in dispatches of their own, so a dispatch is
                 # uniform)
                 res = res._replace(
                     claim_node=None, victims=None, evict_covered=None
                 )
             # kbt: allow[KBT010] THE sanctioned serving choke point: one
-            # blocking transfer per batch window — the whole point of the
-            # micro-batcher is that every queued request shares it
-            host = jax.device_get(res)
-        with tracer.span("whatif:decode"):
-            return [
-                self._decode(lease, r, host, b) for b, r in enumerate(reqs)
-            ]
+            # blocking transfer per dispatch — the whole point of the
+            # micro-batcher is that every queued point shares it
+            return jax.device_get(res)
 
     def _staleness(self, lease: SnapshotLease) -> dict:
         """The version-token-bounded staleness block every verdict

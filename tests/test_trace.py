@@ -868,13 +868,15 @@ class TestReadPlaneSpans:
             assert state["span_counts"][name] >= 1, name
             assert state["span_ms"][name] >= 0.0
         assert state["span_counts"]["whatif:flush"] == 1
-        # one probe for the plain sub-batch, the rest the sweep's dispatches
-        assert state["span_counts"]["whatif:probe"] == qp.dispatches >= 2
+        # one dispatch: the sweep's three counts ride the plain probe's
+        assert state["span_counts"]["whatif:probe"] == qp.dispatches == 1
         assert (state["span_ms"]["whatif:flush"]
                 >= state["span_ms"]["whatif:probe"])
         flush = state["last_detached"]["whatif:flush"]
         assert flush["attrs"]["seq"] == 1 and flush["attrs"]["batch"] == 2
         assert flush["attrs"]["lease_version"] == qp.broker.current().version
+        assert flush["attrs"]["dispatches"] == 1
+        assert flush["attrs"]["points"] == 1 + 3
         assert [c["name"] for c in flush["children"]][0] == "whatif:lease"
         # no record added to the ring, no span to a cycle's record, no
         # stage label on /metrics
@@ -888,6 +890,51 @@ class TestReadPlaneSpans:
         assert waited == pytest.approx(
             2 * (t_flush - t_enqueue) * 1e3, abs=2 * 50.0)
         assert waited >= 2 * 250.0
+        qp.close()
+        cache.stop()
+
+
+    def test_a_flush_counts_its_dispatches_and_its_live_lanes(self):
+        """`volcano_whatif_dispatch_points_total` grows by the lanes that
+        carried a point, beside the dispatches and the flushes, so the
+        dispatches a flush and the fill share fall out of /metrics; the
+        flush's span carries both, each probe span its gang bucket."""
+        from kube_batch_tpu.serve.plane import QueryPlane
+
+        cache = _mk_cache()
+        sched = _mk_scheduler(cache)
+        qp = QueryPlane(cache, start_thread=False)
+        _add_gang(cache, 1)
+        sched.run_once()
+        before = (_counter(prom.WHATIF_DISPATCHES),
+                  _counter(prom.WHATIF_DISPATCH_POINTS),
+                  prom.WHATIF_BATCH_SIZE._count[()])
+        # a member takes most of a node, so 4 fit: the grid (1, 2, 4, 8)
+        # leaves 6 and then 5 to two refinement steps
+        futs = [qp.submit({"queue": "q0", "count": 9,
+                           "requests": {"cpu": 500, "memory": GiB}}),
+                qp.submit_sweep({"queue": "q1", "max_count": 8,
+                                 "requests": {"cpu": 10000, "memory": GiB}})]
+        assert qp.batcher.tick(now=qp.batcher.clock.monotonic() + 1.0) == 2
+        assert futs[0].result(timeout=120)["feasible"]
+        assert futs[1].result(timeout=120)["max_fit"] == 4
+        assert futs[1].result()["probes"] == 4 + 2
+        assert _counter(prom.WHATIF_DISPATCHES) - before[0] == 3
+        assert _counter(prom.WHATIF_DISPATCH_POINTS) - before[1] == 1 + 4 + 2
+        assert prom.WHATIF_BATCH_SIZE._count[()] - before[2] == 1
+        assert (qp.dispatches, qp.points) == (3, 7)
+        flush = cache.tracer.state()["last_detached"]["whatif:flush"]
+        assert flush["attrs"]["batch"] == 2
+        assert flush["attrs"]["dispatches"] == 3
+        assert flush["attrs"]["points"] == 7
+        probes = [c["attrs"] for c in flush["children"]
+                  if c["name"] == "whatif:probe"]
+        # the window's first dispatch at the bucket of its widest point
+        # (the plain request's 9 members), each step's single count at 8
+        assert [(a["batch"], a["gang"]) for a in probes] == [
+            (5, 16), (1, 8), (1, 8)]
+        rendered = prom.render_prometheus()
+        assert "volcano_whatif_dispatch_points_total" in rendered
         qp.close()
         cache.stop()
 

@@ -1090,6 +1090,271 @@ class TestFlushPartitionAndPrewarm:
 
 
 # ==========================================================================
+# the flush plans its points: sweeps' counts ride the plain probes' dispatch
+# ==========================================================================
+
+
+def _flush_window(qp: QueryPlane) -> int:
+    """Flush what is queued; the dispatches that took."""
+    d0 = qp.dispatches
+    qp.batcher.tick(now=qp.batcher.clock.monotonic() + 1e6)
+    return qp.dispatches - d0
+
+
+def _gang_buckets(qp: QueryPlane) -> list:
+    """The gang bucket G of each dispatch of the last flush."""
+    flush = qp.tracer.state()["last_detached"]["whatif:flush"]
+    return [c["attrs"]["gang"] for c in flush["children"]
+            if c["name"] == "whatif:probe"]
+
+
+def _reference_sweep(qp: QueryPlane, body: dict):
+    """The plain reference: the walk every sweep made for itself before a
+    flush planned its points together — grid, bracket, binary search —
+    each count asked as a request of its own, alone in its window, at its
+    own gang bucket.  Returns (max_fit, probes)."""
+    max_count = body["max_count"]
+    feasible = {}
+
+    def probe(counts):
+        for c in counts:
+            feasible[c] = _probe(
+                qp, dict(body, count=c, min_available=c))["feasible"]
+
+    grid = sorted({c for c in (1, 2, 4, 8, 16, 32, 64) if c < max_count}
+                  | {max_count})
+    probe(grid)
+    if not feasible[grid[0]]:
+        lo = 0
+    elif feasible[max_count]:
+        lo = max_count
+    else:
+        lo = max(c for c in grid if feasible[c])
+        hi = min(c for c in grid if not feasible[c])
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            probe([mid])
+            if feasible[mid]:
+                lo = mid
+            else:
+                hi = mid
+    return lo, len(feasible)
+
+
+class TestFlushPlan:
+    #: on five 8,000 m nodes a member of 8,000 m fits 5 times (the grid
+    #: brackets 4..8: two binary steps), one of 2,000 m 20 times (16..32:
+    #: four steps), one of 1,000 m 40 times (32..64: five steps, asked to
+    #: 64); 100 m fits to any count asked and 64,000 m never (no step)
+    FIT_5 = {"cpu": 8000, "memory": GiB}
+    FIT_20 = {"cpu": 2000, "memory": GiB}
+    FIT_ALL = {"cpu": 100, "memory": 64 * 2**20}
+    FIT_NONE = {"cpu": 64000, "memory": GiB}
+
+    def _plane(self, plane_factory, **kw):
+        cache = build_cache(
+            queues=[Queue(name="default", weight=1)],
+            nodes=[build_node(f"f{i}", cpu=8000, mem=64 * GiB)
+                   for i in range(5)],
+            pods=[build_pod("c1", "low", "f0", PodPhase.RUNNING,
+                            {"cpu": 10, "memory": 2**20}, group_name="run")],
+            pod_groups=[PodGroup(name="run", namespace="c1", min_member=1,
+                                 queue="default")],
+        )
+        qp = plane_factory(cache, **kw)
+        _run(cache)
+        return qp
+
+    @staticmethod
+    def _plain(requests, count=1, **kw):
+        return dict({"queue": "default", "count": count,
+                     "requests": dict(requests)}, **kw)
+
+    @staticmethod
+    def _sweep(requests, max_count=64):
+        return {"queue": "default", "max_count": max_count,
+                "requests": dict(requests)}
+
+    def test_an_answer_does_not_depend_on_lane_chunk_or_gang_bucket(
+            self, plane_factory):
+        """The same seeded requests answered alone, each at its own gang
+        bucket, and as lanes of mixed dispatches at G = 64 beside sweeps'
+        counts, in two lane orders: byte-identical responses; and every
+        sweep's max_fit and probes are what the per-sweep walk gives."""
+        cache = TestWhatifOracle()._heterogeneous_cache()
+        qp = plane_factory(cache)
+        _run(cache)
+        rng = np.random.default_rng(45)
+        bodies = [
+            self._plain(
+                {"cpu": int(rng.choice([250, 500, 1000, 2000, 3000, 9000])),
+                 "memory": int(rng.choice([1, 2, 4])) * GiB},
+                count=int(rng.integers(1, 9)),
+                priority=int(rng.integers(0, 3)))
+            for _ in range(8)
+        ]
+        sweeps = [self._sweep({"cpu": 1000, "memory": GiB}),
+                  self._sweep({"cpu": 3000, "memory": 2 * GiB}, 48)]
+        alone = []
+        for b in bodies:
+            alone.append(json.dumps(_probe(qp, b), sort_keys=True))
+            assert _gang_buckets(qp) == [8]
+        walked = [_reference_sweep(qp, b) for b in sweeps]
+        assert {fit for fit, _ in walked} - {0, 48, 64}, (
+            "the fixture must put a boundary inside a grid gap")
+        for order in (bodies, bodies[::-1]):
+            futs = [qp.submit(b) for b in order[:4]]
+            swept = [qp.submit_sweep(b) for b in sweeps]
+            futs += [qp.submit(b) for b in order[4:]]
+            # 8 + 7 + 7 points: two chunks, a sweep's 64 or 48 in each, then
+            # the steps of the sweeps whose boundary lies in a grid gap
+            assert _flush_window(qp) >= 2
+            assert _gang_buckets(qp)[:2] == [64, 64]
+            mixed = [json.dumps(f.result(timeout=120), sort_keys=True)
+                     for f in futs]
+            expect = alone if order is bodies else alone[::-1]
+            assert mixed == expect
+            for fut, (fit, probes) in zip(swept, walked):
+                resp = fut.result(timeout=120)
+                assert (resp["max_fit"], resp["probes"]) == (fit, probes)
+                assert resp["feasible"] == (fit >= 1)
+
+    def test_grid_points_ride_the_plain_dispatch(self, plane_factory):
+        qp = self._plane(plane_factory)
+        p0 = qp.points
+        futs = [qp.submit(self._plain(self.FIT_20, count=c))
+                for c in (1, 3, 8)]
+        sweep = qp.submit_sweep(self._sweep(self.FIT_ALL))
+        assert _flush_window(qp) == 1
+        assert qp.points - p0 == 3 + 7
+        assert all(f.result(timeout=120)["feasible"] for f in futs)
+        assert sweep.result(timeout=120)["max_fit"] == 64
+        assert sweep.result()["probes"] == 7
+
+    def test_sweeps_refine_together_one_dispatch_a_step(self, plane_factory):
+        qp = self._plane(plane_factory)
+        p0 = qp.points
+        short = qp.submit_sweep(self._sweep(self.FIT_5))
+        long_ = qp.submit_sweep(self._sweep(self.FIT_20))
+        # the grid, then four steps: the short sweep rides the first two
+        assert _flush_window(qp) == 1 + 4
+        assert qp.points - p0 == 2 * 7 + 2 + 4
+        assert short.result(timeout=120)["max_fit"] == 5
+        assert short.result()["probes"] == 7 + 2
+        assert long_.result(timeout=120)["max_fit"] == 20
+        assert long_.result()["probes"] == 7 + 4
+        for fut, body in ((short, self.FIT_5), (long_, self.FIT_20)):
+            assert _reference_sweep(qp, self._sweep(body)) == (
+                fut.result()["max_fit"], fut.result()["probes"])
+
+    def test_evictions_keep_a_dispatch_of_their_own(self, plane_factory):
+        qp = self._plane(plane_factory)
+        plain = qp.submit(self._plain(self.FIT_20, count=2))
+        sweep = qp.submit_sweep(self._sweep(self.FIT_NONE))
+        evict = qp.submit(self._plain(self.FIT_20, evictions=True))
+        assert _flush_window(qp) == 2
+        assert "evictions" not in plain.result(timeout=120)
+        assert "evictions" in evict.result(timeout=120)
+        assert sweep.result(timeout=120)["max_fit"] == 0
+
+    def test_points_past_the_bucket_spill_into_a_second_chunk(
+            self, plane_factory):
+        qp = self._plane(plane_factory)
+        p0 = qp.points
+        futs = [qp.submit_sweep(self._sweep(self.FIT_ALL)),
+                qp.submit(self._plain(self.FIT_5)),
+                qp.submit_sweep(self._sweep(self.FIT_NONE)),
+                qp.submit_sweep(self._sweep(self.FIT_ALL, 16))]
+        assert 7 + 1 + 7 + 5 > qp.batcher.max_batch
+        assert _flush_window(qp) == 2
+        assert qp.points - p0 == 20
+        fits = [f.result(timeout=120) for f in futs]
+        assert [r.get("max_fit") for r in fits] == [64, None, 0, 16]
+        assert fits[1]["feasible"]
+
+    def test_a_mixed_flush_answers_against_one_lease(self, plane_factory):
+        """Plain, sweep (with refinement steps) and evictions of one window:
+        one entry into the broker, one snapshot_version on every response,
+        whatever the cache does between the dispatches."""
+        qp = self._plane(plane_factory)
+        entered = []
+        dispatch = qp.broker.dispatch
+
+        def counted(**kw):
+            entered.append(kw)
+            return dispatch(**kw)
+
+        qp.broker.dispatch = counted
+        probe = qp._probe
+
+        def probe_then_ingest(lease, reqs, **kw):
+            # the cluster moves on under the flush: a lease published
+            # mid-window must not answer the window's later dispatches
+            qp.cache.add_node(build_node(f"late{qp.dispatches}", cpu=8000))
+            return probe(lease, reqs, **kw)
+
+        qp._probe = probe_then_ingest
+        version = qp.broker.current().version
+        futs = [qp.submit(self._plain(self.FIT_20, count=4)),
+                qp.submit_sweep(self._sweep(self.FIT_5)),
+                qp.submit(self._plain(self.FIT_20, evictions=True)),
+                qp.submit(self._plain(self.FIT_NONE))]
+        assert _flush_window(qp) == 2 + 2
+        assert len(entered) == 1
+        resps = [f.result(timeout=120) for f in futs]
+        assert {r["snapshot_version"] for r in resps} == {version}
+        assert {r["staleness"]["version"] for r in resps} == {version}
+        assert resps[1]["max_fit"] == 5
+
+    @pytest.mark.parametrize("failing, failed", [
+        # lanes 0-15: plain 0, sweeps 1 and 2, the first count of sweep 3;
+        # the second chunk: the rest of sweep 3's grid and plain 4
+        (1, {0, 1, 2, 3}),
+        (2, {3, 4}),
+        # the evictions request's own dispatch
+        (3, {5}),
+        # the refinement step: sweep 2 alone has a boundary to find
+        (4, {2}),
+    ])
+    def test_a_failing_chunk_fails_only_its_own_requests(
+            self, plane_factory, failing, failed):
+        qp = self._plane(plane_factory)
+        probe = qp._probe
+        calls = []
+
+        def flaky(lease, reqs, **kw):
+            calls.append(len(reqs))
+            if len(calls) == failing:
+                raise RuntimeError("device fell over")
+            return probe(lease, reqs, **kw)
+
+        qp._probe = flaky
+        futs = [qp.submit(self._plain(self.FIT_20)),
+                qp.submit_sweep(self._sweep(self.FIT_ALL)),
+                qp.submit_sweep(self._sweep(self.FIT_5)),
+                qp.submit_sweep(self._sweep(self.FIT_NONE)),
+                qp.submit(self._plain(self.FIT_20, count=2)),
+                qp.submit(self._plain(self.FIT_20, evictions=True))]
+        served0 = qp.requests_served
+        _flush_window(qp)
+        # sweep 2's steps stop with the chunk that failed it
+        assert calls[:3] == [16, 7, 1]
+        assert len(calls) == {1: 3, 4: 4}.get(failing, 5)
+        for i, fut in enumerate(futs):
+            if i in failed:
+                with pytest.raises(WhatifError) as err:
+                    fut.result(timeout=120)
+                assert err.value.status == 500
+                assert "device fell over" in str(err.value)
+            else:
+                resp = fut.result(timeout=120)
+                assert resp["snapshot_version"] >= 0
+                if i == 2:
+                    assert (resp["max_fit"], resp["probes"]) == (5, 9)
+        assert qp.requests_served - served0 == len(futs) - len(failed)
+
+
+# ==========================================================================
 # HTTP surface — POST /v1/whatif + metrics counters
 # ==========================================================================
 
