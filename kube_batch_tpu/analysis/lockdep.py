@@ -75,6 +75,10 @@ DEFAULT_MODULE_PREFIXES = (
     # graph
     "kube_batch_tpu.obs",
     "kube_batch_tpu.guard",
+    # the read plane: two batcher workers flush at once, each through the
+    # batcher's condition, the broker's and the plane's counter lock, while
+    # the cycle publishes and swaps through the broker under its own locks
+    "kube_batch_tpu.serve",
 )
 
 _REAL_LOCK = threading.Lock

@@ -298,6 +298,11 @@ WHATIF_BATCH_SIZE = Histogram(
     f"{_SUBSYSTEM}_whatif_batch_size",
     "Requests one flush window answered together",
 )
+WHATIF_FLUSHES_OVERLAPPED = Counter(
+    f"{_SUBSYSTEM}_whatif_flushes_overlapped_total",
+    "Flush windows that began while another flush was in flight (over "
+    "whatif_batch_size_count: the share the second worker engaged for)",
+)
 WHATIF_QUEUE_DEPTH = Histogram(
     f"{_SUBSYSTEM}_whatif_queue_depth",
     "Whatif requests still queued at flush time",
@@ -658,6 +663,7 @@ QUIESCENT_TICKS.add(0.0)
 for _outcome in ("published", "ingest_pending", "not_owed"):
     LEASE_REARMS.add(0.0, _outcome)
 DECISIONS_LEFTOVER.add(0.0)
+WHATIF_FLUSHES_OVERLAPPED.add(0.0)
 SOLVE_ROUNDS.add(0.0, "allocate")
 SOLVE_OVER_BUDGET.add(0.0, "allocate")
 ALLOCATE_RUNS_ON.add(0.0)
@@ -719,6 +725,7 @@ METRICS = [
     WHATIF_DISPATCHES,
     WHATIF_DISPATCH_POINTS,
     WHATIF_BATCH_SIZE,
+    WHATIF_FLUSHES_OVERLAPPED,
     WHATIF_QUEUE_DEPTH,
     WHATIF_LATENCY,
     WHATIF_SNAPSHOT_VERSION,
@@ -909,9 +916,14 @@ def register_whatif_dispatch(points: int) -> None:
     WHATIF_DISPATCH_POINTS.add(float(points))
 
 
-def observe_whatif_batch(size: int, queue_depth: int) -> None:
+def observe_whatif_batch(size: int, queue_depth: int,
+                         in_flight: int) -> None:
+    """One flush window: its requests, what it left queued, and how many
+    flushes were in flight once it began (itself included)."""
     WHATIF_BATCH_SIZE.observe(float(size))
     WHATIF_QUEUE_DEPTH.observe(float(queue_depth))
+    if in_flight > 1:
+        WHATIF_FLUSHES_OVERLAPPED.inc()
 
 
 def observe_whatif_latency(ms: float) -> None:

@@ -1,11 +1,15 @@
 """MicroBatcher — the /v1/whatif front end's amortization engine.
 
 Concurrent HTTP handler threads call :meth:`submit` and block on a future;
-one worker thread collects requests into a batch and hands it to the flush
-callback (the QueryPlane's probe dispatch).  Flush fires when EITHER the
-batch bucket fills OR the oldest queued request's deadline window elapses
-— so a lone request pays at most ``window`` extra latency while a burst of
-hundreds rides one device dispatch.
+:data:`WORKERS` worker threads on one loop each collect requests into a
+batch and hand it to the flush callback (the QueryPlane's probe dispatch),
+so up to that many flushes are in flight: while one waits for the device,
+the next takes its batch, encodes and dispatches.  Flush fires when EITHER
+the batch bucket fills OR the oldest queued request's deadline window
+elapses — so a lone request pays at most ``window`` extra latency while a
+burst of hundreds rides one device dispatch.  A batch belongs to the one
+worker that took it (``_take`` runs under the condition), and the callback
+has to be safe to run on two threads at once.
 
 Knobs (all overridable per instance; env defaults):
 
@@ -29,6 +33,14 @@ from typing import Callable, List, Optional, Tuple
 
 from kube_batch_tpu import metrics
 from kube_batch_tpu.envutil import env_int
+
+
+#: flushes in flight at once (the worker threads on the one loop).  A
+#: constant, not a knob: the device's share of a flush is about half (it
+#: works 8.5-10.8 ms inside a host span of 16.7-18.8 ms; PERF.md section 6,
+#: chip runs of PR 45), so a second flush finds the device free while the
+#: first is on the host and a third would find it taken.
+WORKERS = 2
 
 
 def _env_float(name: str, default: float) -> float:
@@ -60,16 +72,19 @@ class MicroBatcher:
         self.max_queue = max_queue if max_queue is not None else env_int(
             "KB_WHATIF_QUEUE", 1024)
         self.clock = clock
-        self._cond = threading.Condition()
+        # the lock is made here, not inside threading, so that the runtime
+        # lockdep checker tracks it (as CycleTrigger's)
+        self._cond = threading.Condition(lock=threading.Lock())
         self._pending: deque = deque()  # (request, future, enqueue_t)
         self._stopped = False
         self.rejected = 0
-        self._thread: Optional[threading.Thread] = None
-        if start_thread:
-            self._thread = threading.Thread(
-                target=self._loop, daemon=True, name="whatif-batcher"
-            )
-            self._thread.start()
+        self._threads = [
+            threading.Thread(target=self._loop, daemon=True,
+                             name=f"whatif-batcher-{i}")
+            for i in range(WORKERS if start_thread else 0)
+        ]
+        for t in self._threads:
+            t.start()
 
     # ---- producer side ---------------------------------------------------
     def submit(self, request) -> Future:
@@ -118,8 +133,9 @@ class MicroBatcher:
 
     def tick(self, now: Optional[float] = None) -> int:
         """Flush if due; returns the number of requests flushed.  The unit
-        tests drive this directly with a stubbed clock; the worker thread
-        is just tick() in a wait loop."""
+        tests drive this directly with a stubbed clock, one flush at a
+        time on the caller's thread; a worker thread is just tick() in a
+        wait loop."""
         now = self.clock.monotonic() if now is None else now
         with self._cond:
             if not self._due(now):
@@ -136,29 +152,32 @@ class MicroBatcher:
                 if not fut.done():
                     fut.set_exception(e)
 
+    def _await_batch(self) -> Optional[List[Tuple[object, Future]]]:
+        """The next due batch (caller holds the condition), None once
+        stopped.  Waits until tick's OWN flush condition holds — _due is
+        the single flush policy (bucket full, or the FIRST queued
+        request's window elapsed; submit notifies on fill, the timed wait
+        tracks the window deadline) — and looks again after every wait:
+        the other worker may have taken what this one was waiting for."""
+        while not self._stopped:
+            if not self._pending:
+                self._cond.wait()
+                continue
+            now = self.clock.monotonic()
+            if self._due(now):
+                return self._take(now)
+            # > 0 here: an elapsed window makes _due true (a clock race
+            # just means an immediate recheck)
+            self._cond.wait(
+                max(self._pending[0][2] + self.window_s - now, 0.0))
+        return None
+
     def _loop(self) -> None:
         while True:
             with self._cond:
-                while not self._pending and not self._stopped:
-                    self._cond.wait()
-                if self._stopped:
-                    break
-                # wait until tick's OWN flush condition holds — _due is
-                # the single flush policy (bucket full, or the FIRST
-                # queued request's window elapsed; submit notifies on
-                # fill, the timed wait tracks the window deadline)
-                while (not self._due(self.clock.monotonic())
-                       and not self._stopped):
-                    remaining = (
-                        self._pending[0][2] + self.window_s
-                        - self.clock.monotonic()
-                    )
-                    # remaining > 0 here: an elapsed window makes _due
-                    # true (a clock race just means an immediate recheck)
-                    self._cond.wait(max(remaining, 0.0))
-                if self._stopped:
-                    break
-                batch = self._take(self.clock.monotonic())
+                batch = self._await_batch()
+            if batch is None:
+                break
             self._run_flush(batch)
         # drain on stop: fail whatever is still queued
         with self._cond:
@@ -172,5 +191,5 @@ class MicroBatcher:
         with self._cond:
             self._stopped = True
             self._cond.notify_all()
-        if self._thread is not None:
-            self._thread.join(timeout=5)
+        for t in self._threads:
+            t.join(timeout=5)

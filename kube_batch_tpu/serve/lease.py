@@ -26,14 +26,24 @@ so this is a first-request cost per (B, G, evictions) bucket, not a
 recurring one.)
 
 Version tokens are monotonic: a query answered against lease N reports
-``snapshot_version: N``, and N never decreases across responses.
+``snapshot_version: N``, and N never decreases across responses.  Two
+flushes are in flight at once (serve/batcher.py), so that takes a rule: a
+flush registers the version it took in the same locked step that hands it
+the lease (:meth:`LeaseBroker.dispatch` with a :class:`DeliveryTurn`), and
+answers only once no flush that took an OLDER version is still to answer
+(:meth:`DeliveryTurn.wait`).  Flushes of one version answer in any order,
+and the wait comes after the lease's release, so it never holds a swap.  On
+a donating backend a newer lease cannot exist until every reader of the
+older one has released, which leaves the rule a thread descheduled between
+its release and its answers; on CPU, where the old lease keeps serving
+through a swap, the later flush can hold the NEWER lease and finish first.
 """
 
 from __future__ import annotations
 
 import threading
 from contextlib import contextmanager
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 
 class SnapshotLease(NamedTuple):
@@ -57,6 +67,29 @@ class SnapshotLease(NamedTuple):
     seq: int = 0
 
 
+class DeliveryTurn:
+    """One flush's place in the order of answers (module docstring): made
+    by :meth:`LeaseBroker.delivery`, stamped with the lease's version by
+    :meth:`LeaseBroker.dispatch`, given up when ``delivery``'s block ends."""
+
+    __slots__ = ("_broker", "version")
+
+    def __init__(self, broker: "LeaseBroker") -> None:
+        self._broker = broker
+        self.version: Optional[int] = None  # None until a lease was taken
+
+    def wait(self) -> None:
+        """Block until no flush that took an older version than this one
+        is still to answer.  The order is strict, so two flushes never wait
+        for each other; a flush that took no lease does not wait at all."""
+        if self.version is None:
+            return
+        broker = self._broker
+        with broker._cond:
+            broker._cond.wait_for(lambda: not any(
+                t.version < self.version for t in broker._undelivered))
+
+
 def _donation_active() -> bool:
     """api/resident.py donates the stale resident buffers everywhere but
     CPU — mirror its gate, so the broker retires leases and waits out
@@ -68,10 +101,14 @@ def _donation_active() -> bool:
 
 class LeaseBroker:
     def __init__(self) -> None:
-        self._cond = threading.Condition()
+        # the lock is made here, not inside threading, so that the runtime
+        # lockdep checker tracks it (as CycleTrigger's)
+        self._cond = threading.Condition(lock=threading.Lock())
         self._lease: Optional[SnapshotLease] = None
         self._readers = 0       # in-flight probe dispatches
         self._swapping = False  # a resident swap holds exclusivity
+        # the turns of the flushes that took a lease and have not answered
+        self._undelivered: List[DeliveryTurn] = []
         self.published = 0   # publish count (diagnostics)
         self.retired = 0     # swap-guard retirements (donating backends)
 
@@ -133,12 +170,30 @@ class LeaseBroker:
             return self._lease
 
     @contextmanager
-    def dispatch(self, timeout: Optional[float] = None):
+    def delivery(self):
+        """A flush's turn in the order of answers: yields the
+        :class:`DeliveryTurn` to hand to :meth:`dispatch` and to wait on
+        before answering, and takes it off the list when the block ends,
+        however it ends: a flush that failed must not hold the later ones."""
+        turn = DeliveryTurn(self)
+        try:
+            yield turn
+        finally:
+            with self._cond:
+                if turn in self._undelivered:
+                    self._undelivered.remove(turn)
+                    self._cond.notify_all()
+
+    @contextmanager
+    def dispatch(self, timeout: Optional[float] = None,
+                 turn: Optional[DeliveryTurn] = None):
         """Probe-dispatch region: yields the lease (or None on timeout)
         registered as an in-flight reader, so a concurrent swap cannot
         donate the buffers mid-read.  The broker lock itself is NOT held
         across the device round-trip — publish() and other dispatches
-        proceed concurrently."""
+        proceed concurrently.  A ``turn`` is stamped with the lease's
+        version and listed as undelivered in the step that reads the lease:
+        no newer version can be taken, let alone answered, in between."""
         with self._cond:
             if timeout:
                 self._cond.wait_for(
@@ -151,6 +206,9 @@ class LeaseBroker:
             lease = self._lease
             if lease is not None:
                 self._readers += 1
+                if turn is not None:
+                    turn.version = lease.version
+                    self._undelivered.append(turn)
         try:
             yield lease
         finally:

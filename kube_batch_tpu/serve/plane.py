@@ -256,10 +256,14 @@ class QueryPlane:
             self._flush, max_batch=max_batch, window_s=window_s,
             max_queue=max_queue, start_thread=start_thread,
         )
+        # two batcher workers flush at once: the totals below move under
+        # this lock, each flush adding what it tallied for itself
+        self._mu = threading.Lock()
         self.dispatches = 0
         self.points = 0  # live lanes put into those dispatches
         self.requests_served = 0
         self.flushes = 0  # the sequence number a flush's span carries
+        self._in_flight = 0  # flushes between entry and their last answer
         # the cache's one span plane: a flush is a tree of its own there
         # (whatif:*), never part of a cycle's record
         self.tracer = tracer_of(cache)
@@ -442,8 +446,7 @@ class QueryPlane:
                 # a tree of its own: the compile lands on its whatif:probe
                 # child (/v1/trace last_detached), outside every cycle record
                 with self.tracer.detached_span("whatif:prewarm"):
-                    self._probe(lease._replace(snap=twin), [req],
-                                record=False)
+                    self._probe(lease._replace(snap=twin), [req])
             except Exception:  # noqa: BLE001 — warm-up only; serving still works cold
                 logger.exception("whatif probe pre-warm failed")
 
@@ -499,15 +502,29 @@ class QueryPlane:
         batch = [(r, f) for r, f in batch if not f.cancelled()]
         if not batch:
             return
-        metrics.observe_whatif_batch(len(batch), self.batcher.depth())
-        self.flushes += 1
-        # the flush's own span tree (this is the batcher's thread): timed,
-        # on the profiler's clock and totalled by name, never on a cycle's
+        with self._mu:
+            self.flushes += 1
+            seq = self.flushes
+            self._in_flight += 1
+            in_flight = self._in_flight
+        metrics.observe_whatif_batch(len(batch), self.batcher.depth(),
+                                     in_flight)
+        # the flush's own span tree (this is one of the batcher's worker
+        # threads, or a test's tick; the open-span stacks are the
+        # thread's own, so two flushes' trees do not mix): timed, on the
+        # profiler's clock and totalled by name, never on a cycle's
         # record — ~28 flushes a second would push the solving cycles out
-        # of the 256-cycle ring within seconds
-        with self.tracer.detached_span(
-                "whatif:flush", seq=self.flushes, batch=len(batch)) as sp:
-            self._flush_traced(batch, sp)
+        # of the 256-cycle ring within seconds.  in_flight: the flushes in
+        # flight as this one began, itself included (2 = it ran beside
+        # another)
+        try:
+            with self.tracer.detached_span(
+                    "whatif:flush", seq=seq, batch=len(batch),
+                    in_flight=in_flight) as sp:
+                self._flush_traced(batch, sp)
+        finally:
+            with self._mu:
+                self._in_flight -= 1
 
     def _flush_traced(self, batch, sp_flush) -> None:
         tracer = self.tracer
@@ -534,44 +551,54 @@ class QueryPlane:
                 first.append((i, None))
         evicting = [(i, None) for i, (req, _) in enumerate(batch)
                     if req["evictions"]]
-        dispatches0, points0 = self.dispatches, self.points
-        with contextlib.ExitStack() as held:
-            # from entering the broker until the lease is held or refused:
-            # a resident swap in flight, or no lease since the last one
-            with tracer.span("whatif:lease"):
-                lease = held.enter_context(
-                    self.broker.dispatch(timeout=self.dispatch_timeout))
-            if lease is None:
-                err = WhatifError(
-                    503, "no snapshot lease published yet (scheduler warming)"
-                )
-                for _req, fut in batch:
-                    if self._deliver(fut, error=err):
-                        metrics.register_whatif_request("error")
-                return
-            sp_flush.set(lease_version=lease.version)
-            # every dispatch inside the single held region: each point of
-            # the window answers against one snapshot
-            self._dispatch_points(lease, batch, sweeps, answers, first)
-            self._dispatch_points(lease, batch, sweeps, answers, evicting)
-            while True:
-                step = [(i, c) for i, plan in sweeps.items()
-                        if answers[i] is None for c in plan.next_points()]
-                if not step:
-                    break
-                self._dispatch_points(lease, batch, sweeps, answers, step)
-            staleness = self._staleness(lease)
-            for i, plan in sweeps.items():
-                if answers[i] is None:
-                    answers[i] = plan.response(lease, staleness)
-            sp_flush.set(dispatches=self.dispatches - dispatches0,
-                         points=self.points - points0)
-        # delivered once the lease is released, so a waiting swap goes first
-        with tracer.span("whatif:deliver"):
-            self._deliver_all(batch, answers)
+        tally = [0, 0]  # this flush's own dispatches and live points
+        # the flush's place in the order of answers (serve/lease.py): taken
+        # with the lease, given up when the block ends, whatever ends it
+        with self.broker.delivery() as turn:
+            with contextlib.ExitStack() as held:
+                # from entering the broker until the lease is held or
+                # refused: a resident swap in flight, or no lease since the
+                # last one
+                with tracer.span("whatif:lease"):
+                    lease = held.enter_context(self.broker.dispatch(
+                        timeout=self.dispatch_timeout, turn=turn))
+                if lease is None:
+                    err = WhatifError(
+                        503,
+                        "no snapshot lease published yet (scheduler warming)")
+                    for _req, fut in batch:
+                        if self._deliver(fut, error=err):
+                            metrics.register_whatif_request("error")
+                    return
+                sp_flush.set(lease_version=lease.version)
+                # every dispatch inside the single held region: each point
+                # of the window answers against one snapshot
+                self._dispatch_points(lease, batch, sweeps, answers, first,
+                                      tally)
+                self._dispatch_points(lease, batch, sweeps, answers,
+                                      evicting, tally)
+                while True:
+                    step = [(i, c) for i, plan in sweeps.items()
+                            if answers[i] is None
+                            for c in plan.next_points()]
+                    if not step:
+                        break
+                    self._dispatch_points(lease, batch, sweeps, answers,
+                                          step, tally)
+                staleness = self._staleness(lease)
+                for i, plan in sweeps.items():
+                    if answers[i] is None:
+                        answers[i] = plan.response(lease, staleness)
+                sp_flush.set(dispatches=tally[0], points=tally[1])
+            # delivered once the lease is released, so a waiting swap goes
+            # first, and once no flush that holds an older version is still
+            # to deliver, so versions never decrease across responses
+            with tracer.span("whatif:deliver"):
+                turn.wait()
+                self._deliver_all(batch, answers)
 
     def _dispatch_points(self, lease: SnapshotLease, batch, sweeps, answers,
-                         points) -> None:
+                         points, tally) -> None:
         """Answer ``points`` in chunks of at most the batch bucket's lanes,
         in the order given, one dispatch a chunk, and route each lane back:
         a plain request's response into ``answers``, a count's verdict
@@ -586,7 +613,7 @@ class QueryPlane:
                 for i, c in chunk
             ]
             try:
-                host = self._probe(lease, reqs)
+                host = self._probe(lease, reqs, tally=tally)
                 with self.tracer.span("whatif:decode"):
                     for b, (i, c) in enumerate(chunk):
                         if answers[i] is not None:
@@ -602,6 +629,7 @@ class QueryPlane:
                     answers[i] = WhatifError(500, f"{what} failed: {e}")
 
     def _deliver_all(self, batch, answers) -> None:
+        served = 0
         for (req, fut), answer in zip(batch, answers):
             if isinstance(answer, WhatifError):
                 if self._deliver(fut, error=answer):
@@ -617,7 +645,9 @@ class QueryPlane:
             metrics.observe_whatif_latency(
                 (telemetry.perf_counter() - req["_t0"]) * 1e3
             )
-            self.requests_served += 1
+            served += 1
+        with self._mu:
+            self.requests_served += served
 
     @staticmethod
     def _deliver(fut: Future, result=None, error=None) -> bool:
@@ -702,9 +732,11 @@ class QueryPlane:
 
     # ---- dispatch + decode -------------------------------------------
     def _probe(self, lease: SnapshotLease, reqs: List[dict],
-               record: bool = True):
+               tally: Optional[list] = None):
         """One ``(B, G)`` program over ``reqs``, a lane each: the device's
-        answers on the host, for :meth:`_decode` to read by lane."""
+        answers on the host, for :meth:`_decode` to read by lane.  ``tally``
+        is the calling flush's own ``[dispatches, points]``; a pre-warm has
+        none and stays out of the serving counters."""
         import jax
 
         from kube_batch_tpu.ops.probe import probe_solve
@@ -729,9 +761,12 @@ class QueryPlane:
                     lease.snap, pbatch, rows, lease.config,
                     lease.evict_config, with_evictions,
                 )
-            if record:  # pre-warm dispatches stay out of the serving counters
-                self.dispatches += 1
-                self.points += len(reqs)
+            if tally is not None:
+                tally[0] += 1
+                tally[1] += len(reqs)
+                with self._mu:
+                    self.dispatches += 1
+                    self.points += len(reqs)
                 metrics.register_whatif_dispatch(len(reqs))
             if not with_evictions:
                 # the eviction fields are all-zeros placeholders on this

@@ -11,11 +11,13 @@ cycle would record."""
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import threading
 import time
 import urllib.request
+from concurrent.futures import TimeoutError as FutureTimeout
 
 import numpy as np
 import pytest
@@ -633,6 +635,116 @@ class TestLeaseBroker:
         release.set()
         t.join(timeout=5)
 
+    def test_swap_guard_parks_a_new_flush_and_waits_out_two_readers(
+            self, monkeypatch):
+        """Two flushes in flight are two readers: a donating swap waits
+        for BOTH, and a flush that comes while the swap waits parks behind
+        it (the writer has priority: two readers cannot starve the cycle)."""
+        from kube_batch_tpu.serve import lease as lease_mod
+
+        monkeypatch.setattr(lease_mod, "_donation_active", lambda: True)
+        broker = LeaseBroker()
+        broker.publish(_mk_lease(1))
+        order = []
+        reading = threading.Semaphore(0)
+        release = {"r1": threading.Event(), "r2": threading.Event()}
+
+        def reader(name):
+            with broker.dispatch(timeout=5) as lease:
+                assert lease.version == 1
+                reading.release()
+                assert release[name].wait(timeout=10)
+                order.append(name)
+
+        def swapper():
+            with broker.swap_guard():
+                order.append("swap")
+            broker.publish(_mk_lease(2))
+
+        def late_flush():
+            with broker.dispatch(timeout=10) as lease:
+                order.append(("late", lease.version))
+
+        readers = [threading.Thread(target=reader, args=(n,))
+                   for n in release]
+        for t in readers:
+            t.start()
+        assert reading.acquire(timeout=5) and reading.acquire(timeout=5)
+        swap = threading.Thread(target=swapper)
+        swap.start()
+        deadline = time.monotonic() + 5
+        while not broker._swapping and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert broker._swapping
+        late = threading.Thread(target=late_flush)
+        late.start()
+        release["r1"].set()
+        readers[0].join(timeout=5)
+        time.sleep(0.05)
+        assert order == ["r1"]  # one reader left: the swap still waits
+        release["r2"].set()
+        for t in readers + [swap, late]:
+            t.join(timeout=10)
+            assert not t.is_alive()
+        # the parked flush reads what the cycle published after its swap
+        assert order == ["r1", "r2", "swap", ("late", 2)]
+
+    def test_a_newer_version_answers_after_every_older_one(self):
+        """The order of answers with two flushes in flight: a flush that
+        took version 2 waits for every flush that took version 1 and has
+        not answered; flushes of one version do not wait for each other."""
+        broker = LeaseBroker()
+        broker.publish(_mk_lease(1))
+        with contextlib.ExitStack() as old, contextlib.ExitStack() as same:
+            t_old = old.enter_context(broker.delivery())
+            t_same = same.enter_context(broker.delivery())
+            for turn in (t_old, t_same):
+                with broker.dispatch(timeout=5, turn=turn) as lease:
+                    assert lease.version == turn.version == 1
+            broker.publish(_mk_lease(2))
+            answered = threading.Event()
+
+            def newer():
+                with broker.delivery() as turn:
+                    with broker.dispatch(timeout=5, turn=turn) as lease:
+                        assert lease.version == turn.version == 2
+                    turn.wait()
+                    answered.set()
+
+            t = threading.Thread(target=newer)
+            t.start()
+            t_same.wait()  # the same version: returns at once
+            t_old.wait()
+            assert not answered.wait(timeout=0.1)
+            old.close()    # one older flush answered, one still to
+            assert not answered.wait(timeout=0.1)
+            same.close()
+            assert answered.wait(timeout=5)
+            t.join(timeout=5)
+        assert broker._undelivered == []
+
+    def test_a_flush_that_fails_gives_up_its_turn(self):
+        broker = LeaseBroker()
+        broker.publish(_mk_lease(1))
+        with pytest.raises(RuntimeError):
+            with broker.delivery() as turn:
+                with broker.dispatch(timeout=5, turn=turn):
+                    raise RuntimeError("flush fell over")
+        assert broker._undelivered == [] and broker._readers == 0
+        broker.publish(_mk_lease(2))
+        with broker.delivery() as turn:
+            with broker.dispatch(timeout=5, turn=turn):
+                pass
+            turn.wait()  # nothing older is left to wait for
+
+    def test_a_flush_without_a_lease_has_no_turn_to_wait_for(self):
+        broker = LeaseBroker()
+        with broker.delivery() as turn:
+            with broker.dispatch(timeout=0.01, turn=turn) as lease:
+                assert lease is None
+            assert turn.version is None and broker._undelivered == []
+            turn.wait()
+
 
 class TestLeaseUnderChurn:
     def test_versions_monotonic_and_answers_valid_under_live_cycles(self):
@@ -649,10 +761,18 @@ class TestLeaseUnderChurn:
         try:
             _run(cache)
             stop = threading.Event()
-            seen: list = []
+            seen: dict = {c: [] for c in range(3)}
             errors: list = []
+            delivered: list = []  # a flush's version as it starts to answer
+            deliver_all = qp._deliver_all
 
-            def client():
+            def spy(batch, answers):
+                delivered.append(answers[0]["snapshot_version"])
+                deliver_all(batch, answers)
+
+            qp._deliver_all = spy
+
+            def client(c):
                 while not stop.is_set():
                     try:
                         fut = qp.submit({
@@ -662,16 +782,28 @@ class TestLeaseUnderChurn:
                         resp = fut.result(timeout=30)
                         assert isinstance(resp["feasible"], bool)
                         assert len(resp["nodes"]) == 2
-                        seen.append(resp["snapshot_version"])
+                        seen[c].append(resp["snapshot_version"])
                     except Exception as e:  # noqa: BLE001
                         errors.append(repr(e))
                         return
 
-            threads = [threading.Thread(target=client) for _ in range(3)]
+            # the probe program compiles with the first answer: had the
+            # clients started cold, every cycle below would be over before
+            # the first flush took its lease
+            qp.submit({"queue": "default", "count": 2,
+                       "requests": {"cpu": 500, "memory": GiB}}
+                      ).result(timeout=120)
+            threads = [threading.Thread(target=client, args=(c,))
+                       for c in seen]
             for t in threads:
                 t.start()
             serial = itertools.count()
             for _ in range(6):  # churning cycles concurrent with serving
+                # ...and serving between the cycles: a few flushes answer
+                # from each version before the next is published
+                n, deadline = len(delivered), time.monotonic() + 10
+                while len(delivered) < n + 4 and time.monotonic() < deadline:
+                    time.sleep(0.001)
                 j = next(serial)
                 cache.add_pod_group(PodGroup(
                     name=f"churn{j}", namespace="w", min_member=1,
@@ -687,17 +819,17 @@ class TestLeaseUnderChurn:
             for t in threads:
                 t.join(timeout=60)
             assert not errors, errors
-            assert seen, "clients never got an answer"
+            assert all(seen.values()), "a client never got an answer"
             published = qp.broker.current().version
-            assert max(seen) <= published
-            # within each client the token sequence is non-decreasing —
-            # interleave-safe because each client appends its own results
-            # sequentially; global max-so-far must also never regress
-            hi = 0
-            for v in seen:
-                assert v >= 0
-                hi = max(hi, v)
-            assert hi == max(seen)
+            # each client's tokens never regress (it appends its own
+            # answers in the order it got them), and neither do the
+            # flushes' in the order they answered, whichever of the two
+            # workers ran them
+            for c, versions in seen.items():
+                assert versions == sorted(versions), (c, versions)
+                assert 0 <= versions[0] and versions[-1] <= published
+            assert delivered == sorted(delivered)
+            assert len(set(delivered)) >= 6, "cycles did not interleave"
         finally:
             qp.close()
 
@@ -864,6 +996,121 @@ class TestMicroBatcher:
         b.stop()
         assert isinstance(fut.exception(timeout=5), QueueFull)
         assert b.submit("after-stop").exception(timeout=1) is not None
+
+
+class TestTwoWorkers:
+    """Two flushes in flight: the batcher's workers on real threads, with a
+    flush that blocks until the test lets it go."""
+
+    @staticmethod
+    def _blocking(fail=()):
+        started, entered = [], threading.Semaphore(0)
+        gates: dict = {}
+
+        def flush(batch):
+            name = batch[0][0]
+            gates.setdefault(name, threading.Event())
+            started.append(name)
+            entered.release()
+            assert gates[name].wait(timeout=30)
+            if name in fail:
+                raise RuntimeError(f"flush {name} exploded")
+            for req, fut in batch:
+                fut.set_result(req)
+
+        def release(name):
+            gates.setdefault(name, threading.Event()).set()
+
+        return flush, started, entered, release
+
+    def test_a_second_batch_starts_beside_the_first_and_a_third_waits(self):
+        flush, started, entered, release = self._blocking()
+        b = MicroBatcher(flush, max_batch=1, window_s=0.0, max_queue=8)
+        try:
+            futs = {n: b.submit(n) for n in "abc"}
+            assert entered.acquire(timeout=5) and entered.acquire(timeout=5)
+            assert sorted(started) == ["a", "b"]
+            # both workers are taken: the third batch is due and waits
+            assert not entered.acquire(timeout=0.2)
+            assert b.depth() == 1 and not futs["c"].done()
+            release("b")
+            assert futs["b"].result(timeout=5) == "b"
+            assert entered.acquire(timeout=5)
+            assert started[2] == "c" and not futs["a"].done()
+            release("a")
+            release("c")
+            assert futs["a"].result(timeout=5) == "a"
+            assert futs["c"].result(timeout=5) == "c"
+        finally:
+            for n in "abc":
+                release(n)
+            b.stop()
+
+    def test_stop_joins_both_workers_and_fails_what_is_queued(self):
+        flush, started, entered, release = self._blocking()
+        b = MicroBatcher(flush, max_batch=1, window_s=0.0, max_queue=8)
+        futs = {n: b.submit(n) for n in "abcd"}
+        assert entered.acquire(timeout=5) and entered.acquire(timeout=5)
+        assert len(b._threads) == 2
+        stopper = threading.Thread(target=b.stop)
+        stopper.start()
+        deadline = time.monotonic() + 5
+        while not b._stopped and time.monotonic() < deadline:
+            time.sleep(0.001)
+        release("a")
+        release("b")
+        stopper.join(timeout=15)
+        assert not stopper.is_alive()
+        assert not any(t.is_alive() for t in b._threads)
+        # in flight at the stop: answered; queued: failed, as with one worker
+        assert futs["a"].result(timeout=1) == "a"
+        assert futs["b"].result(timeout=1) == "b"
+        for n in "cd":
+            assert isinstance(futs[n].exception(timeout=1), QueueFull)
+        assert sorted(started) == ["a", "b"]  # "c" and "d" never began
+        assert b.submit("after-stop").exception(timeout=1) is not None
+
+    def test_a_failing_flush_fails_its_batch_only_beside_another(self):
+        flush, started, entered, release = self._blocking(fail=("a",))
+        b = MicroBatcher(flush, max_batch=1, window_s=0.0, max_queue=8)
+        try:
+            futs = {n: b.submit(n) for n in "ab"}
+            assert entered.acquire(timeout=5) and entered.acquire(timeout=5)
+            release("a")
+            assert isinstance(futs["a"].exception(timeout=5), RuntimeError)
+            assert not futs["b"].done()  # in flight beside it, untouched
+            # the worker whose flush failed keeps serving
+            futs["c"] = b.submit("c")
+            assert entered.acquire(timeout=5)
+            release("c")
+            assert futs["c"].result(timeout=5) == "c"
+            release("b")
+            assert futs["b"].result(timeout=5) == "b"
+        finally:
+            for n in "abc":
+                release(n)
+            b.stop()
+
+    def test_tick_stays_synchronous_on_the_callers_thread(self):
+        ran = []
+
+        def flush(batch):
+            ran.append(threading.get_ident())
+            for req, fut in batch:
+                fut.set_result(req)
+
+        clock = FakeClock()
+        b = MicroBatcher(flush, max_batch=2, window_s=0.01, max_queue=8,
+                        clock=clock, start_thread=False)
+        assert b._threads == []
+        futs = [b.submit(n) for n in "abc"]
+        assert b.tick() == 2
+        # the flush ran, whole, before tick returned, and on this thread
+        assert ran == [threading.get_ident()]
+        assert [f.done() for f in futs] == [True, True, False]
+        clock.t = 1.0
+        assert b.tick() == 1 and futs[2].result(timeout=1) == "c"
+        b.stop()
 
 
 # ==========================================================================
@@ -1352,6 +1599,221 @@ class TestFlushPlan:
                 if i == 2:
                     assert (resp["max_fit"], resp["probes"]) == (5, 9)
         assert qp.requests_served - served0 == len(futs) - len(failed)
+
+
+# ==========================================================================
+# two flushes in flight: the plane under the batcher's two workers
+# ==========================================================================
+
+
+class TestTwoFlushesInFlight:
+    BODY = {"queue": "default", "count": 1,
+            "requests": {"cpu": 500, "memory": GiB}}
+    #: the request whose probe the test holds on the "device": by its count
+    HELD = dict(BODY, count=3)
+
+    def _plane(self, plane_factory, **kw):
+        cache = build_cache(
+            queues=[Queue(name="default", weight=1)],
+            nodes=[build_node(f"t{i}", cpu=8000, mem=16 * GiB)
+                   for i in range(4)],
+        )
+        kw.setdefault("max_batch", 1)
+        kw.setdefault("window_s", 0.0)
+        qp = plane_factory(cache, start_thread=True, **kw)
+        _run(cache)
+        # compile the probe before any thread waits on one
+        qp.submit(self.BODY).result(timeout=120)
+        qp.submit(self.HELD).result(timeout=120)
+        return qp
+
+    @staticmethod
+    def _hold_probes(qp, held_counts=(3,)):
+        """Probes of a request with a count in ``held_counts`` wait, after
+        their program ran, until the test lets them go: a flush held on the
+        device while the other worker goes on."""
+        probe, entered = qp._probe, threading.Semaphore(0)
+        go = threading.Event()
+
+        def held(lease, reqs, **kw):
+            host = probe(lease, reqs, **kw)
+            if reqs[0]["count"] in held_counts:
+                entered.release()
+                assert go.wait(timeout=30)
+            return host
+
+        qp._probe = held
+        return entered, go
+
+    @staticmethod
+    def _flush_attrs(qp):
+        """The attributes every flush span from now on begins with."""
+        seen, traced = [], qp._flush_traced
+
+        def spy(batch, sp):
+            seen.append(dict(sp.attrs))
+            traced(batch, sp)
+
+        qp._flush_traced = spy
+        return seen
+
+    def test_one_client_never_overlaps_and_two_held_flushes_do(
+            self, plane_factory):
+        from kube_batch_tpu.metrics import metrics as prom
+
+        qp = self._plane(plane_factory)
+        attrs = self._flush_attrs(qp)
+        overlapped0 = prom.WHATIF_FLUSHES_OVERLAPPED._values.get((), 0.0)
+        flushes0 = prom.WHATIF_BATCH_SIZE._count[()]
+
+        def grown():
+            return (prom.WHATIF_FLUSHES_OVERLAPPED._values.get((), 0.0)
+                    - overlapped0,
+                    prom.WHATIF_BATCH_SIZE._count[()] - flushes0)
+
+        # one client, one request at a time: a flush begins alone
+        for _ in range(5):
+            assert qp.submit(self.BODY).result(timeout=30)["feasible"]
+        assert grown() == (0, 5)
+        assert [a["in_flight"] for a in attrs] == [1] * 5
+        # two flushes held on the device at once: the second began beside
+        # the first, and a third waits for a worker
+        entered, go = self._hold_probes(qp, held_counts=(3, 4))
+        futs = [qp.submit(self.HELD), qp.submit(dict(self.HELD, count=4))]
+        assert entered.acquire(timeout=30) and entered.acquire(timeout=30)
+        third = qp.submit(self.BODY)
+        time.sleep(0.1)
+        assert grown() == (1, 7) and qp.batcher.depth() == 1
+        assert sorted(a["in_flight"] for a in attrs[5:]) == [1, 2]
+        assert qp._in_flight == 2
+        go.set()
+        for f in futs + [third]:
+            assert f.result(timeout=30)["feasible"]
+        deadline = time.monotonic() + 5
+        while qp._in_flight and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert qp._in_flight == 0 and grown()[1] == 8
+        # the flush's span says so on /v1/trace
+        flush = qp.tracer.state()["last_detached"]["whatif:flush"]
+        assert flush["attrs"]["in_flight"] in (1, 2)
+        assert "volcano_whatif_flushes_overlapped_total{} " in (
+            prom.render_prometheus())
+
+    @pytest.mark.parametrize("newer", [True, False])
+    def test_an_older_lease_answers_first_and_an_equal_one_does_not_wait(
+            self, plane_factory, newer):
+        """Flush 1 takes version v and is held on the device.  Flush 2
+        takes a NEWER version (a cycle published meanwhile: on CPU the old
+        lease keeps serving, nothing waits for its reader) and is ready
+        first: it answers only after flush 1.  With the SAME version it
+        answers at once, while flush 1 is still held."""
+        qp = self._plane(plane_factory)
+        entered, go = self._hold_probes(qp)
+        order = []
+        first = qp.submit(self.HELD)
+        first.add_done_callback(lambda f: order.append("first"))
+        assert entered.acquire(timeout=30)
+        v = qp.broker.current().version
+        if newer:
+            qp.cache.add_node(build_node("late", cpu=8000, mem=16 * GiB))
+            _run(qp.cache)
+            assert qp.broker.current().version > v
+        second = qp.submit(self.BODY)
+        second.add_done_callback(lambda f: order.append("second"))
+        if newer:
+            with pytest.raises(FutureTimeout):
+                second.result(timeout=0.3)
+            assert order == [] and not second.done()
+        else:
+            assert second.result(timeout=30)["snapshot_version"] == v
+            assert order == ["second"] and not first.done()
+        go.set()
+        r1, r2 = first.result(timeout=30), second.result(timeout=30)
+        assert r1["snapshot_version"] == v
+        if newer:
+            assert order == ["first", "second"]
+            # each answered from the lease ITS flush held
+            assert r2["snapshot_version"] == qp.broker.current().version > v
+            assert r2["staleness"]["version"] == r2["snapshot_version"]
+
+    def test_the_planes_totals_are_exact_after_concurrent_flushes(
+            self, plane_factory):
+        """Eight clients against two workers, the interpreter switching
+        threads every 10 us: every flush, dispatch, point and answer is
+        counted once (a lost update would leave a total short)."""
+        import sys
+
+        from kube_batch_tpu.metrics import metrics as prom
+
+        qp = self._plane(plane_factory, max_batch=4, window_s=0.0005)
+        attrs = self._flush_attrs(qp)
+        before = (qp.flushes, qp.dispatches, qp.points, qp.requests_served,
+                  prom.WHATIF_BATCH_SIZE._count[()],
+                  prom.WHATIF_DISPATCHES._values.get((), 0.0),
+                  prom.WHATIF_DISPATCH_POINTS._values.get((), 0.0))
+        clients, each = 8, 25
+        errors: list = []
+
+        def client():
+            try:
+                for _ in range(each):
+                    assert qp.submit(self.BODY).result(timeout=60)["feasible"]
+            except Exception as e:  # noqa: BLE001
+                errors.append(repr(e))
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=client)
+                       for _ in range(clients)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(interval)
+        assert not errors, errors
+        deadline = time.monotonic() + 5
+        while qp._in_flight and time.monotonic() < deadline:
+            time.sleep(0.001)
+        total = clients * each
+        flushes = len(attrs)
+        assert sum(a["batch"] for a in attrs) == total
+        assert len({a["seq"] for a in attrs}) == flushes
+        assert any(a["in_flight"] == 2 for a in attrs)
+        assert all(a["in_flight"] in (1, 2) for a in attrs)
+        after = (qp.flushes, qp.dispatches, qp.points, qp.requests_served,
+                 prom.WHATIF_BATCH_SIZE._count[()],
+                 prom.WHATIF_DISPATCHES._values.get((), 0.0),
+                 prom.WHATIF_DISPATCH_POINTS._values.get((), 0.0))
+        # a plain request is one point and a flush of up to four one dispatch
+        assert [a - b for a, b in zip(after, before)] == [
+            flushes, flushes, total, total, flushes, flushes, total]
+
+    def test_a_failing_flush_fails_its_own_batch_beside_a_held_one(
+            self, plane_factory):
+        qp = self._plane(plane_factory)
+        entered, go = self._hold_probes(qp)
+        held = qp.submit(self.HELD)
+        assert entered.acquire(timeout=30)
+        staleness = qp._staleness
+        qp._staleness = lambda lease: (_ for _ in ()).throw(
+            RuntimeError("flush fell over"))
+        failed = qp.submit(self.BODY)
+        assert isinstance(failed.exception(timeout=30), RuntimeError)
+        qp._staleness = staleness
+        # it gave up its reader and its turn: the held flush and the next
+        # one answer, and nothing is left registered
+        assert not held.done()
+        assert qp.submit(self.BODY).result(timeout=30)["feasible"]
+        go.set()
+        assert held.result(timeout=30)["feasible"]
+        deadline = time.monotonic() + 5
+        while qp._in_flight and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert qp._in_flight == 0
+        assert qp.broker._undelivered == [] and qp.broker._readers == 0
 
 
 # ==========================================================================
