@@ -35,7 +35,7 @@ from kube_batch_tpu.api.types import TaskStatus
 from kube_batch_tpu.framework.interface import Action
 from kube_batch_tpu.framework.session import FitFailure, JOB_READY
 from kube_batch_tpu import metrics
-from kube_batch_tpu.ops.assignment import AllocateConfig, allocate_solve
+from kube_batch_tpu.ops.assignment import AllocateConfig
 
 logger = logging.getLogger("kube_batch_tpu")
 
@@ -248,7 +248,7 @@ class AllocateDispatchPlan(NamedTuple):
 
     mesh: Optional[object]   # the mesh the solve shards over; None = one device
     impl: Optional[str]      # "pjit" where shard_map is demoted, else None
-    #                          (KB_SHARD_MAP selects, parallel.mesh._impl)
+    #                          (KB_SHARD_MAP selects, mesh.resolve_impl)
     kind: str                # "full" | "topk"; the dispatch turns "topk"
     #                          into "warm" once a carried-table plan exists
     k: int                   # candidate-list width K (0 in the full program)
@@ -269,8 +269,8 @@ def plan_allocate_dispatch(snap, config, cols, guard, warm
     resident swap (:func:`_warm_state` says why)."""
     from kube_batch_tpu.parallel.mesh import (
         TASK_AXIS,
-        _impl as resolve_impl,
         default_mesh,
+        resolve_impl,
         should_shard,
     )
 
@@ -310,7 +310,7 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
                             warm=False, tracer=None):
     """Shard-or-local solve dispatch; returns (result, mode, topk_info,
     ginfo): plan (:func:`plan_allocate_dispatch`), resident swap, program
-    lookup (parallel.mesh.allocate_program), one call.
+    lookup (parallel.mesh.program), one call (parallel.mesh.call).
 
     ``warm=True`` (the allocate action's steady path) lets the compacted
     program run WARM-STARTED: the [P, K] candidate table carries across
@@ -341,7 +341,7 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
     # kbt: allow[KBT013] the dispatch RETURNS the sentinel verdict to its
     # caller — consume_verdict happens at the action's readback, the one
     # place the verdict exists on host
-    from kube_batch_tpu.parallel.mesh import allocate_program
+    from kube_batch_tpu.parallel.mesh import call, program
 
     plan = plan_allocate_dispatch(snap, config, cols, guard, warm)
     mesh, kind, pend_rows = plan.mesh, plan.kind, plan.pend_rows
@@ -351,7 +351,7 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
     # bundle must replay the condemned program, not the session's nominal
     # one (the carry itself is not replayable: the table is cross-cycle
     # state, so a warm trip replays the cold compacted program at W)
-    cfg, args, k_min, info = config, (dev,), 0, None
+    cfg, args, statics, info = config, (dev,), {}, None
     if kind == "topk":
         info = {"k": plan.k, "bucket": int(pend_rows.shape[0])}
         cfg = config._replace(topk=plan.k)
@@ -362,7 +362,7 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
             kind = "warm"
             info["warm"] = dict(plan.wstate.last)
             cfg = config._replace(topk=wplan["w"])
-            k_min = warm_k_min(plan.k)
+            statics = {"k_min": warm_k_min(plan.k)}
             args += (*wplan["table"], wplan["row_map"], wplan["changed"],
                      wplan["rerank_rows"], wplan["rerank_slots"])
     elif plan.demoted:
@@ -370,17 +370,16 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
         # holds the cluster (a cold start runs it undemoted, and the
         # deployment is sized for that)
         _require_full_matrix_fit(
-            allocate_program("full", mesh, plan.impl, config, False),
-            dev, config, mesh, plan.impl)
-    call = partial(
-        _call_program,
-        allocate_program(kind, mesh, plan.impl, cfg, plan.sentinel, k_min),
-        args, mesh, kind, cfg, k_min)
+            program("full", mesh, plan.impl, config), dev, config, mesh,
+            plan.impl)
+    run = partial(
+        call, program(kind, mesh, plan.impl, cfg, plan.sentinel, **statics),
+        mesh, *args, config=cfg, **statics)
     if kind == "warm":
         # the last two outputs are the refreshed table the commit adopts
-        out = _warm_commit(plan.wstate, call)[:-2]
+        out = _warm_commit(plan.wstate, run)[:-2]
     else:
-        out = call() if plan.sentinel else (call(),)
+        out = run() if plan.sentinel else (run(),)
     ginfo = {
         "engaged": list(plan.engaged) + (["warm"] if kind == "warm" else []),
         "sentinel": tuple(out[1:]) or None,  # (verdict, hist, checksum)
@@ -393,25 +392,12 @@ def dispatch_allocate_solve(snap, config, cols=None, guard=None,
     return out[0], "single" if mesh is None else "sharded", info, ginfo
 
 
-def _call_program(fn, args, mesh, kind, cfg, k_min):
-    """The one call of an allocate program (parallel.mesh.allocate_program):
-    under its mesh, where the statics are baked in; on one device the
-    programs take them at the call, as their other callers (the oracle,
-    bundle replay) pass them."""
-    if mesh is not None:
-        with mesh:
-            return fn(*args)
-    if kind == "warm":
-        return fn(*args, config=cfg, k_min=k_min)
-    return fn(*args, cfg)
-
-
 def _require_full_matrix_fit(fn, dev, config, mesh, impl):
     """Raise :class:`guard.OracleUnfit` unless ``fn``, the bare full [T, N]
     allocate program, holds ``dev`` on one device (guard/fit.py): across
     ``mesh`` with ``impl``, or on a single device."""
     from kube_batch_tpu.guard.fit import require_fit
-    from kube_batch_tpu.parallel.mesh import NODE_AXIS, _impl as resolve_impl
+    from kube_batch_tpu.parallel.mesh import NODE_AXIS, resolve_impl
 
     if mesh is None:
         require_fit("the demotion's target, the full-matrix solve,",
@@ -430,18 +416,13 @@ def dispatch_allocate_oracle(snap, config, cols, mode):
     snapshot through the all-oracle program (KB_TOPK=0; pjit impl when the
     committed solve ran sharded).  ``resident_snap`` is memoized on the
     snap object, so this re-dispatch is device work only — no re-upload."""
-    oracle_cfg = config._replace(topk=0)
-    if mode == "sharded":
-        from kube_batch_tpu.parallel.mesh import (
-            default_mesh,
-            sharded_allocate_solve,
-        )
+    from kube_batch_tpu.parallel.mesh import call, default_mesh, program
 
-        mesh = default_mesh()
-        return sharded_allocate_solve(
-            resident_snap(cols, snap, mesh), oracle_cfg, mesh, impl="pjit"
-        )
-    return allocate_solve(resident_snap(cols, snap), oracle_cfg)
+    oracle_cfg = config._replace(topk=0)
+    mesh = default_mesh() if mode == "sharded" else None
+    return call(
+        program("full", mesh, "pjit", oracle_cfg), mesh,
+        resident_snap(cols, snap, mesh), config=oracle_cfg)
 
 
 def republish_query_lease(ssn, snap=None, meta=None, build=None,
@@ -767,38 +748,24 @@ class AllocateAction(Action):
         unplaced pending rows — so failure cycles walk [P, N] instead of
         [T, N] whenever a bucket exists (ROADMAP standing item: the PR 10
         bucket applies to the histogram verbatim)."""
-        if self.last_solve_mode == "sharded":
-            from kube_batch_tpu.parallel.mesh import (
-                TASK_AXIS as _TA,
-                default_mesh as _dm,
-                sharded_failure_histogram,
-                sharded_failure_histogram_bucket,
-            )
+        from kube_batch_tpu.parallel.mesh import (
+            TASK_AXIS,
+            call,
+            default_mesh,
+            program,
+        )
 
-            mesh = _dm()
-            # the bucketed body requires a 1-D node mesh, exactly like the
-            # compacted solve (which also declined on a 2-D grid even
-            # though the bucket was planned)
-            if dict(mesh.shape).get(_TA, 1) != 1:
-                p_rows = None
-            if p_rows is not None:
-                return sharded_failure_histogram_bucket(
-                    resident_snap(cols, snap, mesh), p_rows, mesh
-                )
-            return sharded_failure_histogram(
-                resident_snap(cols, snap, mesh), mesh
-            )
-        if p_rows is not None:
-            from kube_batch_tpu.ops.assignment import (
-                failure_histogram_bucket_solve,
-            )
-
-            return failure_histogram_bucket_solve(
-                resident_snap(cols, snap), p_rows
-            )
-        from kube_batch_tpu.ops.assignment import failure_histogram_solve
-
-        return failure_histogram_solve(resident_snap(cols, snap))
+        mesh = default_mesh() if self.last_solve_mode == "sharded" else None
+        # the bucketed body requires a 1-D node mesh, exactly like the
+        # compacted solve (which also declined on a 2-D grid even
+        # though the bucket was planned)
+        if mesh is not None and dict(mesh.shape).get(TASK_AXIS, 1) != 1:
+            p_rows = None
+        dev = resident_snap(cols, snap, mesh)
+        if p_rows is None:
+            return call(program("fail_hist", mesh, None, None), mesh, dev)
+        return call(
+            program("fail_hist_bucket", mesh, None, None), mesh, dev, p_rows)
 
     # ------------------------------------------------------------------
     # guard plane wiring (tiers 1 + 2)

@@ -229,8 +229,6 @@ class EnqueueAction(Action):
         # cycle's solves shard over the mesh, the scan rides the mesh too
         # (a replicated shard_map body: every device/process computes the
         # same admitted mask — multi-controller placement consistency)
-        from kube_batch_tpu.parallel.mesh import dispatch_enqueue_gate
-
         capJ = cols.jobs.cap
         k = ordered.size
         minr = np.zeros((capJ, spec.n), np.float32)
@@ -240,6 +238,9 @@ class EnqueueAction(Action):
         from kube_batch_tpu.guard import guard_of
         from kube_batch_tpu.obs.trace import tracer_of
         from kube_batch_tpu.parallel.mesh import (
+            call,
+            default_mesh,
+            program,
             shard_map_enabled,
             should_shard,
         )
@@ -248,44 +249,37 @@ class EnqueueAction(Action):
         tracer = tracer_of(ssn.cache)
         idle_v = idle.vec.astype(np.float32)
         quanta_v = spec.quanta.astype(np.float32)
-        use_mesh = should_shard(cols.nodes.cap) and shard_map_enabled()
+        # the gate rides the mesh where the cycle's solves shard and the
+        # shard_map path is on; verdicts are bit-equal either way (both
+        # trace ops.admission.gate_scan)
+        mesh = (default_mesh()
+                if should_shard(cols.nodes.cap) and shard_map_enabled()
+                else None)
+        # the FUSED gate sentinel (ops/invariants): admitted ⊆ candidates +
+        # the all-finite budget sweep run in the same compiled program as
+        # the admission scan, verdict riding the one readback — the
+        # single-device twin of the solve sentinels.  The replicated
+        # shard_map gate has no fused variant
+        fused = gp.enabled and mesh is None
         with tracer.device_span("gate_dispatch", cols=cols) as sp_gate:
-            if gp.enabled and not use_mesh:
-                # the FUSED gate sentinel (ops/invariants): admitted ⊆
-                # candidates + the all-finite budget sweep run in the same
-                # compiled program as the admission scan, verdict riding the
-                # one readback — the single-device twin of the solve
-                # sentinels
-                from kube_batch_tpu.ops.invariants import (
-                    enqueue_gate_sentinel_solve,
-                )
-
-                admitted_dev, v_dev, _hist = enqueue_gate_sentinel_solve(
-                    minr, candv, idle_v, quanta_v
-                )
-                # kbt: allow[KBT010] the enqueue gate's ONE sanctioned
-                # readback: the admitted-rows mask + the fused verdict
-                admitted, verdict = jax.device_get((admitted_dev, v_dev))
-                admitted = np.asarray(admitted)[:k]
-                bad = int(verdict)
-            else:
-                admitted_dev = dispatch_enqueue_gate(
-                    minr, candv, idle_v, quanta_v,
-                    n_nodes_padded=cols.nodes.cap,
-                )
-                # kbt: allow[KBT010] the enqueue gate's ONE sanctioned
-                # readback: the admitted-rows mask the promotions consume
-                admitted = np.asarray(jax.device_get(admitted_dev))[:k]
-                bad = 0
-                if gp.enabled:
-                    # mesh path (the replicated shard_map gate has no fused
-                    # variant): the invariant is host-checkable from the
-                    # dispatch's own host-built inputs
-                    bad = int(np.sum(admitted & ~candv[:k]))
-                    if (not np.isfinite(minr).all()
-                            or not np.isfinite(idle_v).all()
-                            or not np.isfinite(quanta_v).all()):
-                        bad += 1
+            out = call(
+                program("gate", mesh, None, None, fused), mesh,
+                minr, candv, idle_v, quanta_v)
+            # kbt: allow[KBT010] the enqueue gate's ONE sanctioned
+            # readback: the admitted-rows mask the promotions consume +
+            # the fused verdict
+            admitted, verdict = jax.device_get(
+                out[:2] if fused else (out, np.int32(0)))
+            admitted = np.asarray(admitted)[:k]
+            bad = int(verdict)
+            if gp.enabled and not fused:
+                # mesh path: the invariant is host-checkable from the
+                # dispatch's own host-built inputs
+                bad = int(np.sum(admitted & ~candv[:k]))
+                if (not np.isfinite(minr).all()
+                        or not np.isfinite(idle_v).all()
+                        or not np.isfinite(quanta_v).all()):
+                    bad += 1
         sp_gate.set(candidates=int(k))
         # a violation fails CLOSED: no scan-derived promotions from a
         # condemned verdict (the Pending walk re-decides next cycle); the

@@ -14,7 +14,7 @@ from __future__ import annotations
 import contextlib
 import logging
 from collections import defaultdict
-from typing import Dict, List
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -24,7 +24,7 @@ from kube_batch_tpu.api.cluster_info import ClusterInfo
 from kube_batch_tpu.api.snapshot import build_snapshot
 from kube_batch_tpu.framework.interface import Action
 from kube_batch_tpu.framework.session import FitFailure
-from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
+from kube_batch_tpu.ops.eviction import EvictConfig
 
 logger = logging.getLogger("kube_batch_tpu")
 
@@ -63,6 +63,52 @@ def victim_gates(ssn, mode: str):
         if voters:
             return voters
     return set()
+
+
+class EvictDispatchPlan(NamedTuple):
+    """What one evict dispatch will run, decided on the host before
+    anything touches the device (:func:`plan_evict_dispatch`)."""
+
+    mesh: Optional[object]   # the mesh the solve shards over; None = one device
+    impl: Optional[str]      # "pjit" where shard_map is demoted, else None
+    pend_rows: Optional[np.ndarray]  # the [P] pending bucket the bids run
+    #                          on; None = the whole task axis
+    claimants: int           # pending rows
+    bucket: Optional[int]    # the one bucket the task axis compacts into
+    sentinel: bool           # the invariant tail is fused behind the solve
+    engaged: Tuple[str, ...]  # the guard fast paths the program engages
+    audit: bool              # the shadow oracle runs behind the solve
+
+
+def plan_evict_dispatch(snap, config: EvictConfig, guard) -> EvictDispatchPlan:
+    """Choose the program of one evict dispatch from what the host can
+    observe.  The claimant axis of the bids first: the pending bucket
+    wherever the pending set fits the one bucket the task axis' shape gives
+    (allocate's rule, shared), else the whole task axis — what the input
+    shows, no knob.  Then whether a mesh exists and the cluster is wide
+    enough to shard; on it, the guard's demotion (a tripped shard_map path
+    runs the pjit oracle until its half-open probe re-promotes it) and
+    whether the shadow audit falls due.  Touches no device."""
+    from kube_batch_tpu.actions.allocate import plan_pend_bucket
+    from kube_batch_tpu.parallel.mesh import (
+        default_mesh,
+        resolve_impl,
+        should_shard,
+    )
+
+    pend_rows, claimants, bucket = plan_pend_bucket(snap)
+    mesh, impl, engaged = None, None, ()
+    if should_shard(snap.node_alloc.shape[0]):
+        mesh = default_mesh()
+        pend_rows = None  # the sharded bodies bid on the task axis
+        impl = None if guard.allow("shard_map") else "pjit"
+        if resolve_impl(impl) == "shard_map":
+            engaged = ("shard_map",)
+    return EvictDispatchPlan(
+        mesh=mesh, impl=impl, pend_rows=pend_rows, claimants=claimants,
+        bucket=bucket, sentinel=guard.enabled, engaged=engaged,
+        audit=bool(engaged) and guard.audit_due(config.mode),
+    )
 
 
 def solve_claims(ssn, mode: str):
@@ -128,69 +174,35 @@ def solve_claims(ssn, mode: str):
         victim_drf="drf" in gates,
         weights=ssn.score_weights,
     )
-    from kube_batch_tpu.actions.allocate import (
-        plan_pend_bucket,
-        republish_query_lease,
-    )
+    from kube_batch_tpu.actions.allocate import republish_query_lease
     from kube_batch_tpu.api.columns import resident_snap
     from kube_batch_tpu.guard import guard_of
     from kube_batch_tpu.obs.trace import tracer_of
-    from kube_batch_tpu.parallel.mesh import (
-        default_mesh,
-        sentinel_sharded_evict_solve,
-        sharded_evict_solve,
-        should_shard,
-    )
+    from kube_batch_tpu.parallel.mesh import call, program
 
     gp = guard_of(ssn.cache)
     tracer = tracer_of(ssn.cache)
-    sentinel = None
+    plan = plan_evict_dispatch(snap, config, gp)
+    mesh, pend_rows, engaged = plan.mesh, plan.pend_rows, list(plan.engaged)
+    rows = () if pend_rows is None else (pend_rows,)
     audit_dev = None
-    engaged: List[str] = []
-    mesh = None
-    # the claimant axis of the bids: the pending bucket wherever the pending
-    # set fits the one bucket the task axis' shape gives (allocate's rule,
-    # shared), else the whole task axis — what the input shows, no knob
-    pend_rows, claimants, bucket = plan_pend_bucket(snap)
     # device-resident feature cache (see allocate's dispatch): the decode
     # below keeps reading the ORIGINAL host-backed snap
     with tracer.device_span("solve_dispatch", cols=cols, action=mode) as sp:
-        if should_shard(snap.node_alloc.shape[0]):
-            mesh = default_mesh()
-            pend_rows = None  # the sharded bodies bid on the task axis
-            from kube_batch_tpu.parallel.mesh import _impl as _resolve_impl
-
-            # demotion-aware path selection: a tripped shard_map path runs
-            # the pjit oracle until its half-open probe re-promotes it
-            impl = None if gp.allow("shard_map") else "pjit"
-            if _resolve_impl(impl) == "shard_map":
-                engaged = ["shard_map"]
-            dev = resident_snap(cols, snap, mesh)
-            if gp.enabled:
-                result, v_dev, h_dev, e_dev = sentinel_sharded_evict_solve(
-                    dev, config, mesh, impl=impl
-                )
-                sentinel = (v_dev, h_dev, e_dev)
-            else:
-                result = sharded_evict_solve(dev, config, mesh, impl=impl)
-            if engaged and gp.audit_due(mode):
-                # shadow oracle (tier 2): the pjit program on the same
-                # snapshot, read back only after the host decode below
-                audit_dev = sharded_evict_solve(dev, config, mesh,
-                                                impl="pjit")
-        else:
-            dev = resident_snap(cols, snap)
-            if gp.enabled:
-                from kube_batch_tpu.ops.invariants import evict_sentinel_solve
-
-                result, v_dev, h_dev, e_dev = evict_sentinel_solve(
-                    dev, config, pend_rows)
-                sentinel = (v_dev, h_dev, e_dev)
-            else:
-                result = evict_solve(dev, config, pend_rows)
+        dev = resident_snap(cols, snap, mesh)
+        out = call(
+            program("evict", mesh, plan.impl, config, plan.sentinel),
+            mesh, dev, *rows, config=config)
+        result, *sentinel = out if plan.sentinel else (out,)
+        if plan.audit:
+            # shadow oracle (tier 2): the pjit program on the same
+            # snapshot, read back only after the host decode below
+            audit_dev = call(
+                program("evict", mesh, "pjit", config), mesh, dev)
     tracer.note_evict_dispatch(
         sp, mode, "sharded" if mesh is not None else "single", engaged,
-        compact=pend_rows is not None, claimants=claimants, bucket=bucket,
+        compact=pend_rows is not None, claimants=plan.claimants,
+        bucket=plan.bucket,
     )
     # this swap retired the what-if lease on donating backends — re-arm it
     # off the same (memoized) resident snapshot so serving doesn't stay
@@ -206,9 +218,9 @@ def solve_claims(ssn, mode: str):
             jax.device_get(  # kbt: allow[KBT010] the annotated choke point ^
                 (result.claim_node, result.evicted, result.victim_claimant,
                  result.rounds_run, result.gated_releasing,
-                 sentinel[0] if sentinel is not None else np.int32(0),
-                 sentinel[1] if sentinel is not None else None,
-                 sentinel[2] if sentinel is not None else np.int32(0))
+                 sentinel[0] if sentinel else np.int32(0),
+                 sentinel[1] if sentinel else None,
+                 sentinel[2] if sentinel else np.int32(0))
             )
         )
     claim_node = claim_node[: meta.n_tasks]
@@ -220,7 +232,7 @@ def solve_claims(ssn, mode: str):
     )
     metrics.register_evict_claims(mode, "gated_releasing", int(gated))
 
-    if sentinel is not None:
+    if sentinel:
         from kube_batch_tpu.api.types import TaskStatus as _TS
         from kube_batch_tpu.guard import consume_sentinel
 

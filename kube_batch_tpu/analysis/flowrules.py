@@ -593,13 +593,13 @@ class TelemetryMisuseRule(Rule):
 # KBT010 — host-device sync on resident values in the action layer
 # --------------------------------------------------------------------------
 
-#: calls whose results live on device (the PR 3 resident/solve surface,
-#: extended for the PR 5 sharded scatters + enqueue gate dispatch shapes,
-#: the PR 8 what-if probe — the query plane's outputs are device arrays
-#: until its one sanctioned batch readback — and the KB_TOPK compacted
-#: solves, whose candidate-table intermediates and exhaustion counters are
-#: device values until the allocate action's single choke-point readback)
+#: calls whose results live on device: THE call of a program looked up in
+#: parallel/mesh.py's table (every dispatch site's shape: solves, the
+#: fit-error histograms, the what-if probe, the enqueue gate — device
+#: values until the site's one sanctioned readback), the one-device
+#: programs of ops/ called directly, and the resident swap
 _DEVICE_SOURCES = {
+    "kube_batch_tpu.parallel.mesh.call",
     "kube_batch_tpu.ops.assignment.allocate_solve",
     "kube_batch_tpu.ops.assignment.allocate_topk_solve",
     "kube_batch_tpu.ops.assignment.warm_allocate_solve",
@@ -607,11 +607,6 @@ _DEVICE_SOURCES = {
     "kube_batch_tpu.ops.assignment.failure_histogram_bucket_solve",
     "kube_batch_tpu.ops.eviction.evict_solve",
     "kube_batch_tpu.ops.probe.probe_solve",
-    "kube_batch_tpu.parallel.mesh.sharded_allocate_solve",
-    "kube_batch_tpu.parallel.mesh.sharded_failure_histogram",
-    "kube_batch_tpu.parallel.mesh.sharded_failure_histogram_bucket",
-    "kube_batch_tpu.parallel.mesh.sharded_evict_solve",
-    "kube_batch_tpu.parallel.mesh.sharded_probe_solve",
     "kube_batch_tpu.api.columns.resident_snap",
     "kube_batch_tpu.ops.admission.enqueue_gate_solve",
     "jax.device_put",

@@ -86,11 +86,10 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from kube_batch_tpu.analysis.engine import Finding
 from kube_batch_tpu.analysis.jaxpr_audit import (
-    REGISTRY,
     EntryPoint,
     ShapePoint,
+    full_registry,
     shape_point,
-    sharded_registry,
 )
 
 HBM_RULES = {
@@ -831,7 +830,7 @@ def run_hbm_audit(
     (same contract as tier A/B suppressions: a waiver that no longer
     waives anything must be deleted, not accumulate)."""
     if registry is None:
-        registry = tuple(REGISTRY) + sharded_registry()
+        registry = full_registry()
     if points is None:
         points = shape_points()
     if allowlist is None:
@@ -891,7 +890,7 @@ def headroom_report(
 ) -> Dict:
     """bytes-vs-budget per entry per shape point."""
     if registry is None:
-        registry = tuple(REGISTRY) + sharded_registry()
+        registry = full_registry()
     if points is None:
         points = shape_points()
     budget, label = budget_bytes()

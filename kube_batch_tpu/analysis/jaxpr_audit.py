@@ -9,8 +9,9 @@ all of them cost the <1s/50k-pod target on a real accelerator.  This
 module is the JaxPruner-style answer (PAPERS.md): audit what actually gets
 compiled, not what the source looks like.
 
-Mechanism: a REGISTRY of the package's jitted entry points (ops/ solves,
-the resident swap).  Each entry is traced with
+Mechanism: a REGISTRY of the package's jitted entry points, read off the
+program table of parallel/mesh.py (plus the resident swaps, which the
+table does not own).  Each entry is traced with
 ABSTRACT inputs (jax.ShapeDtypeStruct — no device work, no compile) under
 ``jax.enable_x64`` so dtype promotion is visible instead of
 silently canonicalized away, then the closed jaxpr is walked recursively
@@ -41,6 +42,7 @@ sub-second after the jax import.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from kube_batch_tpu.analysis.engine import Finding
@@ -206,20 +208,6 @@ def _snap(ax: ShapePoint):
         T=ax.T, N=ax.N, J=ax.J, Q=ax.Q, R=ax.R, W=ax.W, K=ax.K_aff)
 
 
-def _build_allocate(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig, allocate_solve
-
-    ax = sp or _AUDIT_POINT
-    return allocate_solve, (_snap(ax), AllocateConfig())
-
-
-def _build_failure_histogram(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import failure_histogram_solve
-
-    ax = sp or _AUDIT_POINT
-    return failure_histogram_solve, (_snap(ax),)
-
-
 #: audit-scale pending bucket + candidate width for the compacted solve
 _P, _TOPK = 8, 2
 
@@ -229,16 +217,6 @@ def _abstract_pend_rows(P=_P):
     from jax import ShapeDtypeStruct as S
 
     return S((P,), jnp.int32)
-
-
-def _build_topk_allocate(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig, allocate_topk_solve
-
-    ax = sp or _AUDIT_POINT
-    return allocate_topk_solve, (
-        _snap(ax), _abstract_pend_rows(ax.P),
-        AllocateConfig(topk=ax.topk),
-    )
 
 
 #: warm-carry audit shapes: stored width W, changed-node slots, rerank
@@ -265,70 +243,144 @@ def _abstract_warm_args(P=_P, W=_WARM_W, C=_WARM_C, Pi=_WARM_PI):
 def _warm_donation() -> Dict[str, Tuple[int, ...]]:
     # the warm solve donates the stale carried-table buffers into the
     # refresh everywhere donation is supported; CPU skips it.  Literal
-    # positions (no ops.assignment import — the registry is built before
-    # jax loads): must match ops.assignment.WARM_TABLE_ARGNUMS, which the
+    # positions: must match ops.assignment.WARM_TABLE_ARGNUMS, which the
     # warm entry's KBT104 check pins per backend.
     return {"cpu": (), "*": (2, 3, 4, 5)}
 
 
-def _warm_args_at(ax: ShapePoint):
-    return _abstract_warm_args(P=ax.P, W=ax.warm_w, C=ax.warm_c, Pi=ax.warm_pi)
+def _abstract_probe_batch(B=2, G=4, R=_R, W=_W):
+    """A ProbeBatch of ShapeDtypeStructs + the [G] row oracle — the query
+    plane's serving shapes at audit scale."""
+    import jax.numpy as jnp
+    from jax import ShapeDtypeStruct as S
 
+    from kube_batch_tpu.ops.probe import ProbeBatch
 
-def _build_warm_allocate(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig, warm_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    return warm_solve_fn(), (
-        _snap(ax), *_warm_args_at(ax),
-        AllocateConfig(topk=ax.warm_w), ax.topk,
+    f32, i32, b, u32 = jnp.float32, jnp.int32, jnp.bool_, jnp.uint32
+    batch = ProbeBatch(
+        req=S((B, G, R), f32), valid=S((B, G), b),
+        min_avail=S((B,), i32), queue=S((B,), i32), prio=S((B,), i32),
+        sel_bits=S((B, W), u32), sel_impossible=S((B,), b),
+        tol_bits=S((B, W), u32), min_res=S((B, R), f32),
+        has_min_res=S((B,), b),
     )
+    return batch, S((G,), i32)
 
 
-def _build_warm_sentinel(sp: Optional[ShapePoint] = None):
+# ---- one abstract-arguments maker per kind of parallel/mesh.py's program
+# table: (config, statics, arrays) at a shape point — the only thing an
+# entry adds to the table's row
+
+
+def _args_full(ax: ShapePoint):
     from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.ops.invariants import warm_sentinel_solve_fn
 
-    ax = sp or _AUDIT_POINT
-    return warm_sentinel_solve_fn(), (
-        _snap(ax), *_warm_args_at(ax),
-        AllocateConfig(topk=ax.warm_w), ax.topk,
-    )
+    return AllocateConfig(), {}, (_snap(ax),)
 
 
-def _build_bucket_histogram(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import failure_histogram_bucket_solve
+def _args_topk(ax: ShapePoint):
+    from kube_batch_tpu.ops.assignment import AllocateConfig
 
-    ax = sp or _AUDIT_POINT
-    return failure_histogram_bucket_solve, (
-        _snap(ax), _abstract_pend_rows(ax.P),
-    )
+    return AllocateConfig(topk=ax.topk), {}, (
+        _snap(ax), _abstract_pend_rows(ax.P))
 
 
-def _build_topk_probe(sp: Optional[ShapePoint] = None):
-    """The probe traced with a topk>0 config: the query plane reuses the
-    session's AllocateConfig, and the probe's [G, N] head ignores the
-    compaction knob by design (a gang's task axis is already tiny) — this
-    entry pins that the knob stays inert on the probe program."""
+def _args_warm(ax: ShapePoint):
+    from kube_batch_tpu.ops.assignment import AllocateConfig
+
+    return AllocateConfig(topk=ax.warm_w), {"k_min": ax.topk}, (
+        _snap(ax),
+        *_abstract_warm_args(P=ax.P, W=ax.warm_w, C=ax.warm_c, Pi=ax.warm_pi))
+
+
+def _args_evict(ax: ShapePoint, mode, compact=False):
+    from kube_batch_tpu.ops.eviction import EvictConfig
+
+    rows = (_abstract_pend_rows(ax.P),) if compact else ()
+    return EvictConfig(mode=mode), {}, (_snap(ax),) + rows
+
+
+def _args_fail_hist(ax: ShapePoint):
+    return None, {}, (_snap(ax),)
+
+
+def _args_fail_hist_bucket(ax: ShapePoint):
+    return None, {}, (_snap(ax), _abstract_pend_rows(ax.P))
+
+
+def _args_probe(ax: ShapePoint, topk=False):
+    """``topk``: the probe traced with a topk>0 config.  The query plane
+    reuses the session's AllocateConfig, and the probe's [G, N] head
+    ignores the compaction knob by design (a gang's task axis is already
+    tiny) — that entry pins that the knob stays inert on the program."""
     from kube_batch_tpu.ops.assignment import AllocateConfig
     from kube_batch_tpu.ops.eviction import EvictConfig
-    from kube_batch_tpu.ops.probe import probe_solve
 
-    ax = sp or _AUDIT_POINT
     batch, rows = _abstract_probe_batch(
         B=ax.probe_b, G=ax.probe_g, R=ax.R, W=ax.W)
-    return probe_solve, (
-        _snap(ax), batch, rows, AllocateConfig(topk=ax.topk),
-        EvictConfig(mode="preempt"), True,
+    # with_evictions=True traces the superset program (head + admission +
+    # histogram + the eviction probe's while_loop)
+    return AllocateConfig(topk=ax.topk if topk else 0), {
+        "evict_config": EvictConfig(mode="preempt"), "with_evictions": True,
+    }, (_snap(ax), batch, rows)
+
+
+def _args_gate(ax: ShapePoint):
+    import jax.numpy as jnp
+    from jax import ShapeDtypeStruct as S
+
+    return None, {}, (
+        S((ax.J, ax.R), jnp.float32), S((ax.J,), jnp.bool_),
+        S((ax.R,), jnp.float32), S((ax.R,), jnp.float32),
     )
 
 
-def _build_evict(mode, compact, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
+_ARGS = {
+    "full": _args_full, "topk": _args_topk, "warm": _args_warm,
+    "evict": _args_evict, "fail_hist": _args_fail_hist,
+    "fail_hist_bucket": _args_fail_hist_bucket, "probe": _args_probe,
+    "gate": _args_gate,
+}
 
-    ax = sp or _AUDIT_POINT
-    rows = (_abstract_pend_rows(ax.P),) if compact else ()
-    return evict_solve, (_snap(ax), EvictConfig(mode=mode)) + rows
+#: the kinds dispatched every cycle at scale (EntryPoint.steady).  The
+#: full-matrix allocate is the COLD oracle — not steady by design; the
+#: compacted topk/warm programs are what dispatches at scale.  Eviction
+#: runs inside production cycles, so KBT202 pins the known bid planes
+#: (ROADMAP 1.(1)) via the allowlist: [P, N] on the pending bucket (what
+#: actions/reclaim.py dispatches wherever the pending set fits it), [T, N]
+#: in the full-axis fallback
+_STEADY = frozenset({"topk", "warm", "evict", "probe", "gate"})
+
+
+def _variants(kind: str, one_device: bool):
+    """What is traced of a kind, as (name tags, maker arguments): one
+    program, but for the evict kind one per mode — and on one device one
+    per claimant axis (the sharded bodies bid on the task axis only)."""
+    if kind != "evict":
+        return [((), {})]
+    return [
+        ((mode,) + (("compact",) if compact else ()),
+         {"mode": mode, "compact": compact})
+        for mode in ("reclaim", "preempt")
+        for compact in ((False, True) if one_device else (False,))
+    ]
+
+
+def _build(kind, variant, mesh, impl, sentinel,
+           sp: Optional[ShapePoint] = None):
+    """``(program, abstract arguments)`` of one cell of the table: the very
+    object the dispatch looks up, and the arguments in the shape the
+    dispatch calls it with."""
+    from kube_batch_tpu.parallel.mesh import KINDS, program
+
+    config, statics, arrays = _ARGS[kind](sp or _AUDIT_POINT, **variant)
+    fn = program(kind, mesh, impl, config, sentinel, **statics)
+    if mesh is None:
+        # the one-device program takes config and statics at the call;
+        # keyword statics trace in their positions (the trailing ones)
+        arrays = KINDS[kind].one_device(
+            lambda *a, **kw: a + tuple(kw.values()), arrays, config, statics)
+    return fn, arrays
 
 
 def _abstract_swap_args(ax: ShapePoint, fields, slots: int, lead=()):
@@ -364,301 +416,6 @@ def _build_resident_swap(sp: Optional[ShapePoint] = None):
         sp or _AUDIT_POINT, SWAP_FIELDS, SCATTER_SLOTS)
 
 
-def _build_enqueue_gate(sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.ops.admission import enqueue_gate_fn
-
-    ax = sp or _AUDIT_POINT
-    return enqueue_gate_fn(), (
-        S((ax.J, ax.R), jnp.float32), S((ax.J,), jnp.bool_),
-        S((ax.R,), jnp.float32), S((ax.R,), jnp.float32),
-    )
-
-
-def _abstract_probe_batch(B=2, G=4, R=_R, W=_W):
-    """A ProbeBatch of ShapeDtypeStructs + the [G] row oracle — the query
-    plane's serving shapes at audit scale."""
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.ops.probe import ProbeBatch
-
-    f32, i32, b, u32 = jnp.float32, jnp.int32, jnp.bool_, jnp.uint32
-    batch = ProbeBatch(
-        req=S((B, G, R), f32), valid=S((B, G), b),
-        min_avail=S((B,), i32), queue=S((B,), i32), prio=S((B,), i32),
-        sel_bits=S((B, W), u32), sel_impossible=S((B,), b),
-        tol_bits=S((B, W), u32), min_res=S((B, R), f32),
-        has_min_res=S((B,), b),
-    )
-    return batch, S((G,), i32)
-
-
-def _build_probe(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.ops.eviction import EvictConfig
-    from kube_batch_tpu.ops.probe import probe_solve
-
-    ax = sp or _AUDIT_POINT
-    batch, rows = _abstract_probe_batch(
-        B=ax.probe_b, G=ax.probe_g, R=ax.R, W=ax.W)
-    # with_evictions=True traces the superset program (head + admission +
-    # histogram + the eviction probe's while_loop)
-    return probe_solve, (
-        _snap(ax), batch, rows, AllocateConfig(),
-        EvictConfig(mode="preempt"), True,
-    )
-
-
-def _swap_donation() -> Dict[str, Tuple[int, ...]]:
-    # a resident swap program donates the dict of stale device buffers it
-    # refreshes (argument 0, every leaf) everywhere donation is supported;
-    # CPU skips it (api/resident.py's own gate)
-    return {"cpu": (), "*": (0,)}
-
-
-# ---- sentinel-fused solve variants (guard plane tier 1): the dispatch-
-# facing programs are solve body + ops/invariants tail in ONE jaxpr — they
-# must pass KBT101-104 like the bare solves (a sentinel that smuggled an
-# f64 upcast or a host callback into every production dispatch would tax
-# exactly the path it guards)
-
-
-def _build_sentinel_allocate(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.ops.invariants import allocate_sentinel_solve
-
-    ax = sp or _AUDIT_POINT
-    return allocate_sentinel_solve, (_snap(ax), AllocateConfig())
-
-
-def _build_sentinel_topk(sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.ops.invariants import allocate_topk_sentinel_solve
-
-    ax = sp or _AUDIT_POINT
-    return allocate_topk_sentinel_solve, (
-        _snap(ax), _abstract_pend_rows(ax.P),
-        AllocateConfig(topk=ax.topk),
-    )
-
-
-def _build_sentinel_evict(mode, compact, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.eviction import EvictConfig
-    from kube_batch_tpu.ops.invariants import evict_sentinel_solve
-
-    ax = sp or _AUDIT_POINT
-    rows = (_abstract_pend_rows(ax.P),) if compact else ()
-    return evict_sentinel_solve, (_snap(ax), EvictConfig(mode=mode)) + rows
-
-
-def _build_sentinel_gate(sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.ops.invariants import enqueue_gate_sentinel_fn
-
-    ax = sp or _AUDIT_POINT
-    return enqueue_gate_sentinel_fn(), (
-        S((ax.J, ax.R), jnp.float32), S((ax.J,), jnp.bool_),
-        S((ax.R,), jnp.float32), S((ax.R,), jnp.float32),
-    )
-
-
-REGISTRY: Tuple[EntryPoint, ...] = (
-    # the full-matrix allocate is the COLD oracle — steady=False by design;
-    # the compacted topk/warm programs are what dispatches at scale
-    EntryPoint("ops.assignment.allocate_solve", _build_allocate),
-    EntryPoint("ops.assignment.allocate_topk_solve", _build_topk_allocate,
-               steady=True),
-    EntryPoint("ops.assignment.warm_allocate_solve", _build_warm_allocate,
-               donate=_warm_donation(), steady=True),
-    EntryPoint("ops.assignment.failure_histogram_solve",
-               _build_failure_histogram),
-    EntryPoint("ops.assignment.failure_histogram_bucket_solve",
-               _build_bucket_histogram),
-    # eviction runs inside production cycles — steady, so KBT202 pins the
-    # known bid planes (ROADMAP 1.(1)) via the allowlist: [P, N] on the
-    # pending bucket (what actions/reclaim.py dispatches wherever the
-    # pending set fits it), [T, N] in the full-axis fallback
-    EntryPoint("ops.eviction.evict_solve[reclaim]",
-               lambda sp=None: _build_evict("reclaim", False, sp),
-               steady=True),
-    EntryPoint("ops.eviction.evict_solve[preempt]",
-               lambda sp=None: _build_evict("preempt", False, sp),
-               steady=True),
-    EntryPoint("ops.eviction.evict_solve[reclaim,compact]",
-               lambda sp=None: _build_evict("reclaim", True, sp),
-               steady=True),
-    EntryPoint("ops.eviction.evict_solve[preempt,compact]",
-               lambda sp=None: _build_evict("preempt", True, sp),
-               steady=True),
-    EntryPoint("api.resident.swap", _build_resident_swap,
-               donate=_swap_donation(), steady=True),
-    EntryPoint("ops.admission.enqueue_gate", _build_enqueue_gate,
-               steady=True),
-    EntryPoint("ops.probe.probe_solve", _build_probe, steady=True),
-    EntryPoint("ops.probe.probe_solve[topk-inert]", _build_topk_probe,
-               steady=True),
-    EntryPoint("ops.invariants.allocate_sentinel_solve",
-               _build_sentinel_allocate),
-    EntryPoint("ops.invariants.allocate_topk_sentinel_solve",
-               _build_sentinel_topk, steady=True),
-    EntryPoint("ops.invariants.warm_allocate_sentinel_solve",
-               _build_warm_sentinel, donate=_warm_donation(), steady=True),
-    EntryPoint("ops.invariants.evict_sentinel_solve[reclaim]",
-               lambda sp=None: _build_sentinel_evict("reclaim", False, sp),
-               steady=True),
-    EntryPoint("ops.invariants.evict_sentinel_solve[preempt]",
-               lambda sp=None: _build_sentinel_evict("preempt", False, sp),
-               steady=True),
-    EntryPoint("ops.invariants.evict_sentinel_solve[reclaim,compact]",
-               lambda sp=None: _build_sentinel_evict("reclaim", True, sp),
-               steady=True),
-    EntryPoint("ops.invariants.evict_sentinel_solve[preempt,compact]",
-               lambda sp=None: _build_sentinel_evict("preempt", True, sp),
-               steady=True),
-    EntryPoint("ops.invariants.enqueue_gate_sentinel", _build_sentinel_gate,
-               steady=True),
-)
-
-
-# --------------------------------------------------------------------------
-# the mesh-sharded solve variants (ROADMAP follow-on): traced whenever the
-# backend exposes ≥2 devices — on CPU a forced host-platform device count
-# (XLA_FLAGS=--xla_force_host_platform_device_count=N; tier-1's conftest
-# forces 8) stands in for a multi-device CI mesh, so KBT101-104 cover the
-# sharded entry points without real hardware.  Single-device runs skip them
-# (the registry is empty there, never silently "clean" — the CLI exit code
-# reflects only what was actually traced).
-# --------------------------------------------------------------------------
-
-
-def _build_sharded_allocate(mesh, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.parallel.mesh import allocate_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    return allocate_solve_fn(mesh, AllocateConfig(), impl=impl), (
-        _snap(ax),)
-
-
-def _build_sharded_topk(mesh, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.parallel.mesh import allocate_topk_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    fn = allocate_topk_solve_fn(mesh, AllocateConfig(topk=ax.topk), impl=impl)
-    return fn, (_snap(ax), _abstract_pend_rows(ax.P))
-
-
-def _build_sharded_warm(mesh, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.parallel.mesh import warm_allocate_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    fn = warm_allocate_solve_fn(
-        mesh, AllocateConfig(topk=ax.warm_w), ax.topk, impl=impl)
-    return fn, (_snap(ax), *_warm_args_at(ax))
-
-
-def _build_sharded_sentinel_warm(mesh, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.parallel.mesh import (
-        sentinel_warm_allocate_solve_fn,
-    )
-
-    ax = sp or _AUDIT_POINT
-    fn = sentinel_warm_allocate_solve_fn(
-        mesh, AllocateConfig(topk=ax.warm_w), ax.topk, impl=impl)
-    return fn, (_snap(ax), *_warm_args_at(ax))
-
-
-def _build_sharded_bucket_histogram(mesh, impl,
-                                    sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.parallel.mesh import failure_histogram_bucket_fn
-
-    ax = sp or _AUDIT_POINT
-    fn = failure_histogram_bucket_fn(mesh, impl=impl)
-    return fn, (_snap(ax), _abstract_pend_rows(ax.P))
-
-
-def _build_sharded_histogram(mesh, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.parallel.mesh import failure_histogram_fn
-
-    ax = sp or _AUDIT_POINT
-    return failure_histogram_fn(mesh, impl=impl), (_snap(ax),)
-
-
-def _build_sharded_evict(mesh, mode, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.eviction import EvictConfig
-    from kube_batch_tpu.parallel.mesh import evict_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    return evict_solve_fn(mesh, EvictConfig(mode=mode), impl=impl), (
-        _snap(ax),)
-
-
-def _build_sharded_sentinel_allocate(mesh, impl,
-                                     sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.parallel.mesh import sentinel_allocate_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    fn = sentinel_allocate_solve_fn(mesh, AllocateConfig(), impl=impl)
-    return fn, (_snap(ax),)
-
-
-def _build_sharded_sentinel_topk(mesh, impl,
-                                 sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.parallel.mesh import sentinel_allocate_topk_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    fn = sentinel_allocate_topk_solve_fn(
-        mesh, AllocateConfig(topk=ax.topk), impl=impl)
-    return fn, (_snap(ax), _abstract_pend_rows(ax.P))
-
-
-def _build_sharded_sentinel_evict(mesh, mode, impl,
-                                  sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.eviction import EvictConfig
-    from kube_batch_tpu.parallel.mesh import sentinel_evict_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    fn = sentinel_evict_solve_fn(mesh, EvictConfig(mode=mode), impl=impl)
-    return fn, (_snap(ax),)
-
-
-def _build_sharded_probe(mesh, impl, sp: Optional[ShapePoint] = None):
-    from kube_batch_tpu.ops.assignment import AllocateConfig
-    from kube_batch_tpu.ops.eviction import EvictConfig
-    from kube_batch_tpu.parallel.mesh import probe_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    batch, rows = _abstract_probe_batch(
-        B=ax.probe_b, G=ax.probe_g, R=ax.R, W=ax.W)
-    fn = probe_solve_fn(
-        mesh, AllocateConfig(), EvictConfig(mode="preempt"), True, impl=impl
-    )
-    return fn, (_snap(ax), batch, rows)
-
-
-def _build_sharded_gate(mesh, sp: Optional[ShapePoint] = None):
-    import jax.numpy as jnp
-    from jax import ShapeDtypeStruct as S
-
-    from kube_batch_tpu.parallel.mesh import enqueue_gate_solve_fn
-
-    ax = sp or _AUDIT_POINT
-    return enqueue_gate_solve_fn(mesh), (
-        S((ax.J, ax.R), jnp.float32), S((ax.J,), jnp.bool_),
-        S((ax.R,), jnp.float32), S((ax.R,), jnp.float32),
-    )
-
-
 def _build_shard_swap(mesh, sp: Optional[ShapePoint] = None):
     from kube_batch_tpu.api.resident import (
         NODE_SWAP_FIELDS,
@@ -683,25 +440,76 @@ def _build_repl_swap(mesh, sp: Optional[ShapePoint] = None):
         sp or _AUDIT_POINT, REPL_SWAP_FIELDS, SCATTER_SLOTS)
 
 
+def _swap_donation() -> Dict[str, Tuple[int, ...]]:
+    # a resident swap program donates the dict of stale device buffers it
+    # refreshes (argument 0, every leaf) everywhere donation is supported;
+    # CPU skips it (api/resident.py's own gate)
+    return {"cpu": (), "*": (0,)}
+
+
+@functools.lru_cache(maxsize=None)
+def _one_device_registry() -> Tuple[EntryPoint, ...]:
+    """REGISTRY: the table's rows on one device, bare and sentinel-fused —
+    the dispatch-facing sentinel programs are solve body + ops/invariants
+    tail in ONE jaxpr and must pass KBT101-104 like the bare solves (a
+    sentinel that smuggled an f64 upcast or a host callback into every
+    production dispatch would tax exactly the path it guards) — and, by
+    hand, what the table does not own."""
+    from kube_batch_tpu.parallel.mesh import KINDS, tagged
+
+    p = functools.partial
+    entries = [
+        EntryPoint(
+            tagged(row.ops_names[sentinel], tags),
+            p(_build, kind, variant, None, None, sentinel),
+            donate=_warm_donation() if kind == "warm" else {"*": ()},
+            steady=kind in _STEADY)
+        for sentinel in (False, True)
+        for kind, row in KINDS.items() if row.fused or not sentinel
+        for tags, variant in _variants(kind, one_device=True)
+    ]
+    entries += [
+        EntryPoint("api.resident.swap", _build_resident_swap,
+                   donate=_swap_donation(), steady=True),
+        EntryPoint("ops.probe.probe_solve[topk-inert]",
+                   p(_build, "probe", {"topk": True}, None, None, False),
+                   steady=True),
+    ]
+    return tuple(entries)
+
+
+def __getattr__(name: str):
+    # REGISTRY is read off parallel/mesh.py's table, which imports jax: on
+    # first use, so that this module (the audit-rule ids, ShapePoint)
+    # stays importable without it
+    if name == "REGISTRY":
+        return _one_device_registry()
+    raise AttributeError(name)
+
+
 def sharded_registry(n_devices: Optional[int] = None
                      ) -> Tuple[EntryPoint, ...]:
-    """Entry points for the mesh-sharded solve path — empty on single-device
-    backends (no mesh to shard over); over every device by default, over
-    the first ``n_devices`` where a caller asks what ONE host's chips hold
-    (the v5e-4 envelope tests).  BOTH implementations are traced:
-    the shard_map bodies (the production path — KBT101-104 must cover the
-    authored-collective programs) and the pjit oracle (KB_SHARD_MAP=0), so
-    neither can silently regress.  On ≥4-device backends a 2-D
-    (tasks × nodes) mesh variant of the shard_map allocate body is traced
-    too — the task-axis-sharded program is a distinct jaxpr (block
-    slicing + task-axis all_gathers) and needs its own audit."""
-    import functools
-
+    """The table's rows on a mesh — traced whenever the backend exposes ≥2
+    devices.  On CPU a forced host-platform device count
+    (XLA_FLAGS=--xla_force_host_platform_device_count=N; tier-1's conftest
+    forces 8) stands in for a multi-device CI mesh, so KBT101-104 cover the
+    sharded entry points without real hardware.  Single-device runs skip
+    them (the registry is empty there, never silently "clean" — the CLI
+    exit code reflects only what was actually traced).  Over every device
+    by default, over the first ``n_devices`` where a caller asks what ONE
+    host's chips hold (the v5e-4 envelope tests).  BOTH implementations
+    are traced: the shard_map bodies (the production path — KBT101-104
+    must cover the authored-collective programs) and the pjit oracle
+    (KB_SHARD_MAP=0), so neither can silently regress.  By hand: the two
+    mesh swap programs, and on ≥4-device backends a 2-D (tasks × nodes)
+    mesh variant of the shard_map allocate body — the task-axis-sharded
+    program is a distinct jaxpr (block slicing + task-axis all_gathers)
+    and needs its own audit."""
     import jax
 
     if len(jax.devices()) < 2:
         return ()
-    from kube_batch_tpu.parallel.mesh import make_mesh
+    from kube_batch_tpu.parallel.mesh import KINDS, make_mesh
 
     # _N (8) must divide the mesh for the per-shard scatter's local indexing
     n_dev = min(n_devices or len(jax.devices()), len(jax.devices()))
@@ -709,53 +517,21 @@ def sharded_registry(n_devices: Optional[int] = None
         n_dev -= 1
     mesh = make_mesh(n_dev)
     p = functools.partial
-    entries = []
-    for impl in ("shard_map", "pjit"):
-        tag = f"[{impl}]"
-        made = [
-            EntryPoint(f"parallel.mesh.sharded_allocate_solve{tag}",
-                       p(_build_sharded_allocate, mesh, impl)),
-            EntryPoint(f"parallel.mesh.sharded_allocate_topk_solve{tag}",
-                       p(_build_sharded_topk, mesh, impl), steady=True),
-            EntryPoint(f"parallel.mesh.sharded_warm_allocate_solve{tag}",
-                       p(_build_sharded_warm, mesh, impl), steady=True),
-            EntryPoint(
-                f"parallel.mesh.sentinel_sharded_warm_allocate_solve{tag}",
-                p(_build_sharded_sentinel_warm, mesh, impl), steady=True),
-            EntryPoint(f"parallel.mesh.sharded_failure_histogram{tag}",
-                       p(_build_sharded_histogram, mesh, impl)),
-            EntryPoint(
-                f"parallel.mesh.sharded_failure_histogram_bucket{tag}",
-                p(_build_sharded_bucket_histogram, mesh, impl)),
-            EntryPoint(f"parallel.mesh.sharded_evict_solve[reclaim]{tag}",
-                       p(_build_sharded_evict, mesh, "reclaim", impl),
-                       steady=True),
-            EntryPoint(f"parallel.mesh.sharded_evict_solve[preempt]{tag}",
-                       p(_build_sharded_evict, mesh, "preempt", impl),
-                       steady=True),
-            EntryPoint(f"parallel.mesh.sharded_probe_solve{tag}",
-                       p(_build_sharded_probe, mesh, impl), steady=True),
-            EntryPoint(f"parallel.mesh.sentinel_sharded_allocate_solve{tag}",
-                       p(_build_sharded_sentinel_allocate, mesh, impl)),
-            EntryPoint(
-                f"parallel.mesh.sentinel_sharded_allocate_topk_solve{tag}",
-                p(_build_sharded_sentinel_topk, mesh, impl), steady=True),
-            EntryPoint(
-                f"parallel.mesh.sentinel_sharded_evict_solve[reclaim]{tag}",
-                p(_build_sharded_sentinel_evict, mesh, "reclaim", impl),
-                steady=True),
-            EntryPoint(
-                f"parallel.mesh.sentinel_sharded_evict_solve[preempt]{tag}",
-                p(_build_sharded_sentinel_evict, mesh, "preempt", impl),
-                steady=True),
-        ]
-        if impl == "pjit":
+    entries = [
+        EntryPoint(
+            "parallel.mesh." + ("sentinel_" if sentinel else "")
+            + row.mesh_name + "".join(
+                f"[{t}]" for t in tags + ((impl,) if row.pjit else ())),
+            p(_build, kind, variant, mesh, impl, sentinel),
+            steady=kind in _STEADY,
             # a pjit's intermediates carry no specs: tier C models them
-            made = [dataclasses.replace(e, spmd_shards=n_dev) for e in made]
-        entries += made
+            spmd_shards=n_dev if impl == "pjit" else 1)
+        for kind, row in KINDS.items()
+        for impl in (("shard_map", "pjit") if row.pjit else ("shard_map",))
+        for sentinel in ((False, True) if row.invariants else (False,))
+        for tags, variant in _variants(kind, one_device=False)
+    ]
     entries += [
-        EntryPoint("parallel.mesh.sharded_enqueue_gate",
-                   p(_build_sharded_gate, mesh), steady=True),
         EntryPoint("api.resident.swap_sharded",
                    p(_build_shard_swap, mesh),
                    donate=_swap_donation(), steady=True),
@@ -764,12 +540,18 @@ def sharded_registry(n_devices: Optional[int] = None
                    donate=_swap_donation(), steady=True),
     ]
     if n_dev >= 4 and n_dev % 2 == 0 and _T % 2 == 0:
-        mesh2 = make_mesh(n_dev, task_shards=2)
         entries.append(EntryPoint(
             "parallel.mesh.sharded_allocate_solve[shard_map,2d]",
-            p(_build_sharded_allocate, mesh2, "shard_map"),
+            p(_build, "full", {}, make_mesh(n_dev, task_shards=2),
+              "shard_map", False),
         ))
     return tuple(entries)
+
+
+def full_registry() -> Tuple[EntryPoint, ...]:
+    """Every audited entry point: one device plus, on multi-device
+    backends, the mesh."""
+    return _one_device_registry() + sharded_registry()
 
 
 # --------------------------------------------------------------------------
@@ -909,7 +691,7 @@ def run_audit(
     restricts to a rule subset (CLI --select parity with the static
     tier)."""
     if registry is None:
-        registry = tuple(REGISTRY) + sharded_registry()
+        registry = full_registry()
     findings: List[Finding] = []
     for entry in registry:
         findings.extend(audit_entry(entry))

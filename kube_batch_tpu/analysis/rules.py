@@ -528,17 +528,19 @@ class SentinelConsumeRule(Rule):
     scope = ("actions/",)
 
     #: callables whose results become binds/evictions — the committed
-    #: solve dispatch surface (single-device, sharded, the allocate
-    #: dispatch's program lookup, and the actions' own dispatch helpers)
+    #: solve dispatch surface: the one-device programs called directly, the
+    #: actions' own dispatch helper ...
     DISPATCH_FNS = {
-        "dispatch_allocate_solve", "allocate_program",
+        "dispatch_allocate_solve",
         "allocate_solve", "allocate_topk_solve", "warm_allocate_solve",
         "allocate_sentinel_solve", "allocate_topk_sentinel_solve",
         "evict_solve", "evict_sentinel_solve",
-        "sharded_allocate_solve", "sharded_evict_solve",
-        "sentinel_sharded_evict_solve",
-        "dispatch_enqueue_gate",
     }
+    #: ... and THE lookup of a program in parallel/mesh.py's table
+    #: (``call(program(kind, ...), mesh, ...)``), whatever its kind — but
+    #: for a kind written out that commits nothing
+    TABLE_LOOKUP = "program"
+    UNCOMMITTED_KINDS = {"fail_hist", "fail_hist_bucket", "probe"}
     #: verdict consumers: the GuardPlane choke point and the shared
     #: readback-side consumers (guard/plane.consume_sentinel /
     #: consume_assignment_sentinel) — matched by SUBSTRING so an action's
@@ -559,7 +561,11 @@ class SentinelConsumeRule(Rule):
                 if not isinstance(sub, ast.Call):
                     continue
                 name = _terminal_name(sub.func)
-                if name in self.DISPATCH_FNS:
+                if name in self.DISPATCH_FNS or (
+                    name == self.TABLE_LOOKUP and sub.args
+                    and not (isinstance(sub.args[0], ast.Constant)
+                             and sub.args[0].value in self.UNCOMMITTED_KINDS)
+                ):
                     dispatches.append(sub)
                 elif (name in self.CONSUME_FNS
                         or (self.CONSUME_SUBSTR in name

@@ -48,7 +48,7 @@ _GATE = None
 def gate_scan(min_res, cand, idle0, quanta):
     """The raw (untraced) admission scan — shared by the single-device jit
     wrapper below AND the mesh-replicated shard_map wrapper
-    (parallel/mesh.enqueue_gate_solve_fn), so both paths trace the
+    (parallel/mesh.py's "gate" row), so both paths trace the
     identical program and the verdicts are bit-equal by construction."""
     import jax
     import jax.numpy as jnp

@@ -3,7 +3,7 @@ this package must not pull in ops.assignment's module-level jnp constants,
 which would initialise the XLA backend before a multi-host deployment's
 jax.distributed.initialize (parallel/distributed.py) gets to run."""
 
-__all__ = ["make_mesh", "sharded_allocate_solve", "snapshot_shardings"]
+__all__ = ["call", "make_mesh", "program", "snapshot_shardings"]
 
 
 def __getattr__(name):

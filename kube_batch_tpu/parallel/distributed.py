@@ -18,7 +18,7 @@ Usage on each host of the cluster:
     from kube_batch_tpu.parallel.distributed import initialize, global_mesh
     initialize(coordinator="host0:9000", num_processes=4, process_id=rank)
     mesh = global_mesh()          # 1-D 'nodes' mesh over ALL devices
-    # leader: sharded_allocate_solve(snap, config, mesh)
+    # leader: call(program("full", mesh, None, config), mesh, snap)
 """
 
 from __future__ import annotations

@@ -21,6 +21,10 @@ Two implementations share the mesh and the snapshot shardings:
   on the snapshot pytree and jit's in_shardings/out_shardings, collectives
   compiler-inserted by GSPMD.  Kept as the bit-exactness oracle.
 
+Which jitted program a dispatch runs — mesh or one device, which impl, bare
+or sentinel-fused, of which kind — is looked up in the program table below
+(``KINDS``, :func:`program`, :func:`call`).
+
 A second mesh dim shards the TASK axis too (KB_TASK_SHARDS=k or
 ``make_mesh(task_shards=k)``) for when node-axis sharding alone no longer
 fits the [T, N] round intermediates in HBM (shard_map path only)."""
@@ -29,13 +33,14 @@ from __future__ import annotations
 
 import os
 from functools import lru_cache, partial
-from typing import Optional
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kube_batch_tpu.api.snapshot import DeviceSnapshot
+from kube_batch_tpu.ops import admission, assignment, invariants, probe
 from kube_batch_tpu.ops.assignment import AllocateConfig, AllocateResult, allocate_solve
 from kube_batch_tpu.ops.eviction import EvictConfig, EvictResult, evict_solve
 from kube_batch_tpu.utils import jitstats
@@ -183,12 +188,7 @@ def snapshot_shardings(mesh: Mesh) -> DeviceSnapshot:
     )
 
 
-# jitted solve per (mesh, config, impl) — a fresh jax.jit wrapper per call
-# would retrace and recompile the whole solve every scheduling cycle
-_jit_cache: dict = {}
-
-
-def _impl(impl: Optional[str]) -> str:
+def resolve_impl(impl: Optional[str]) -> str:
     """Resolve the sharded-solve implementation: explicit override, else
     the KB_SHARD_MAP knob (shard_map by default, pjit as the oracle)."""
     if impl is not None:
@@ -196,57 +196,14 @@ def _impl(impl: Optional[str]) -> str:
     return "shard_map" if shard_map_enabled() else "pjit"
 
 
-def allocate_solve_fn(mesh: Mesh, config: AllocateConfig,
-                      impl: Optional[str] = None):
-    """The memoized jitted allocate solve for (mesh, config, impl) — the
-    dispatch below calls it; the jaxpr audit (analysis/jaxpr_audit.py)
-    traces BOTH impls abstractly so KBT101-104 cover the sharded variants
-    in tier-1."""
-    impl = _impl(impl)
-    key = (mesh, config, impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.allocate_shard_map(mesh, config)
-        else:
-            in_shardings = snapshot_shardings(mesh)
-            node2 = NamedSharding(mesh, P(NODE_AXIS, None))
-            repl = NamedSharding(mesh, P())
-            out_shardings = AllocateResult(
-                assigned=repl,
-                pipelined=repl,
-                committed=repl,
-                node_idle=node2,
-                node_releasing=node2,
-                node_used=node2,
-                deserved=repl,
-                rounds_run=repl,
-                topk_exhausted=repl,
-                topk_reentries=repl,
-            )
-            fn = jax.jit(
-                partial(_solve, config=config),
-                in_shardings=(in_shardings,),
-                out_shardings=out_shardings,
-            )
-        jitstats.register(f"sharded_allocate_solve[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sharded_allocate_solve(
-    snap: DeviceSnapshot, config: AllocateConfig, mesh: Mesh,
-    impl: Optional[str] = None,
-) -> AllocateResult:
-    """The allocate solve jitted over the mesh. Node-axis inputs/outputs are
-    sharded; the assignment vector comes back replicated.  ``impl``
-    overrides the KB_SHARD_MAP selection — the guard plane's demotion
-    passes ``"pjit"`` here to pin a tripped shard_map path to its oracle."""
-    fn = allocate_solve_fn(mesh, config, impl=impl)
-    with mesh:
-        return fn(snap)
+# --------------------------------------------------------------------------
+# THE program table.  Every device dispatch is plan -> program -> call: the
+# site decides on the host what to run (mesh or one device, which impl,
+# sentinel or bare), looks the jitted program up HERE, and calls it.  The
+# table is the one place that can enumerate the programs a process may
+# dispatch: the jaxpr audit (analysis/jaxpr_audit.py) derives its registry
+# by walking it.
+# --------------------------------------------------------------------------
 
 
 def _solve(snap: DeviceSnapshot, config: AllocateConfig) -> AllocateResult:
@@ -256,422 +213,288 @@ def _solve(snap: DeviceSnapshot, config: AllocateConfig) -> AllocateResult:
         return allocate_solve(snap, config)
 
 
-def allocate_topk_solve_fn(mesh: Mesh, config: AllocateConfig,
-                           impl: Optional[str] = None):
-    """The memoized jitted COMPACTED allocate solve for (mesh, config,
-    impl) — config.topk > 0 selects the [P, K] candidate-table program
-    (ops.assignment.allocate_topk_solve).  The shard_map impl builds
-    per-shard candidate lists and merges them with one per-solve gather
-    (parallel/shard_solve.allocate_topk_shard_map — zero per-round
-    collectives); the pjit impl re-jits the single-device compacted body
-    with mesh shardings as the sharded bit-exactness oracle, mirroring the
-    full solve's impl split."""
-    from kube_batch_tpu.ops.assignment import allocate_topk_solve
-
-    impl = _impl(impl)
-    key = (mesh, config, "topk", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.allocate_topk_shard_map(mesh, config)
-        else:
-            in_shardings = snapshot_shardings(mesh)
-            node2 = NamedSharding(mesh, P(NODE_AXIS, None))
-            repl = NamedSharding(mesh, P())
-            out_shardings = AllocateResult(
-                assigned=repl, pipelined=repl, committed=repl,
-                node_idle=node2, node_releasing=node2, node_used=node2,
-                deserved=repl, rounds_run=repl,
-                topk_exhausted=repl, topk_reentries=repl,
-            )
-            fn = jax.jit(
-                partial(allocate_topk_solve.__wrapped__, config=config),
-                in_shardings=(in_shardings, repl),
-                out_shardings=out_shardings,
-            )
-        jitstats.register(f"sharded_allocate_topk_solve[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def warm_allocate_solve_fn(mesh: Mesh, config: AllocateConfig, k_min: int,
-                           impl: Optional[str] = None):
-    """The memoized jitted WARM-STARTED compacted solve for (mesh, config,
-    k_min, impl) — the cross-cycle candidate-table carry
-    (ops.assignment._warm_allocate_solve).  The shard_map impl contributes
-    delta-sized per-shard work (fresh changed-node keys via one psum, the
-    invalidated sub-bucket via one all_gather + replicated merge) and
-    keeps the round loop collective-free; the pjit impl re-jits the
-    single-device warm body with mesh shardings (table + plan replicated)
-    as the sharded bit-exactness oracle — the same split as every solve."""
-    from kube_batch_tpu.ops.assignment import _warm_allocate_solve
-
-    impl = _impl(impl)
-    key = (mesh, config, "warm", k_min, impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.warm_allocate_shard_map(mesh, config, k_min)
-        else:
-            in_shardings = snapshot_shardings(mesh)
-            node2 = NamedSharding(mesh, P(NODE_AXIS, None))
-            repl = NamedSharding(mesh, P())
-            res_shardings = AllocateResult(
-                assigned=repl, pipelined=repl, committed=repl,
-                node_idle=node2, node_releasing=node2, node_used=node2,
-                deserved=repl, rounds_run=repl,
-                topk_exhausted=repl, topk_reentries=repl,
-            )
-            fn = jax.jit(
-                partial(_warm_allocate_solve, config=config, k_min=k_min),
-                in_shardings=(in_shardings,) + (repl,) * 9,
-                out_shardings=(res_shardings, (repl,) * 4, repl),
-            )
-        jitstats.register(f"sharded_warm_allocate_solve[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def failure_histogram_bucket_fn(mesh: Mesh, impl: Optional[str] = None):
-    """Memoized jitted sharded BUCKETED fit-error histogram for `mesh`
-    (dispatch + jaxpr-audit entry point) — the [P] pending-bucket variant
-    of failure_histogram_fn."""
-    from kube_batch_tpu.ops.assignment import failure_histogram_bucket_solve
-
-    impl = _impl(impl)
-    key = (mesh, "fail_hist_bucket", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.failure_histogram_bucket_shard_map(mesh)
-        else:
-            repl = NamedSharding(mesh, P())
-            fn = jax.jit(
-                failure_histogram_bucket_solve.__wrapped__,
-                in_shardings=(snapshot_shardings(mesh), repl),
-                out_shardings=repl,
-            )
-        jitstats.register(f"sharded_failure_histogram_bucket[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sharded_failure_histogram_bucket(snap: DeviceSnapshot, pend_rows,
-                                     mesh: Mesh):
-    """The lazy fit-error histogram over the mesh, restricted to the [P]
-    pending bucket — per-shard [P, N_loc] partials, one psum, scattered
-    back to the replicated [T, N_REASONS] result."""
-    fn = failure_histogram_bucket_fn(mesh)
-    with mesh:
-        return fn(snap, pend_rows)
-
-
-def failure_histogram_fn(mesh: Mesh, impl: Optional[str] = None):
-    """Memoized jitted sharded fit-error histogram for `mesh` (dispatch +
-    jaxpr-audit entry point)."""
-    from kube_batch_tpu.ops.assignment import failure_histogram_solve
-
-    impl = _impl(impl)
-    key = (mesh, "fail_hist", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.failure_histogram_shard_map(mesh)
-        else:
-            fn = jax.jit(
-                failure_histogram_solve.__wrapped__,
-                in_shardings=(snapshot_shardings(mesh),),
-                out_shardings=NamedSharding(mesh, P()),
-            )
-        jitstats.register(f"sharded_failure_histogram[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sharded_failure_histogram(snap: DeviceSnapshot, mesh: Mesh):
-    """The lazy fit-error histogram over the mesh: [T, N]-scale predicate
-    masks shard along the node axis, the per-reason node counts reduce
-    (an explicit psum on the shard_map path) into the replicated
-    [T, N_REASONS] result."""
-    fn = failure_histogram_fn(mesh)
-    with mesh:
-        return fn(snap)
-
-
-def evict_solve_fn(mesh: Mesh, config: EvictConfig,
-                   impl: Optional[str] = None):
-    """Memoized jitted sharded eviction solve for (mesh, config, impl)
-    (dispatch + jaxpr-audit entry point)."""
-    impl = _impl(impl)
-    key = (mesh, config, "evict", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.evict_shard_map(mesh, config)
-        else:
-            in_shardings = snapshot_shardings(mesh)
-            repl = NamedSharding(mesh, P())
-            out_shardings = EvictResult(
-                claim_node=repl, evicted=repl, victim_claimant=repl,
-                rounds_run=repl, gated_releasing=repl,
-            )
-            fn = jax.jit(
-                partial(_evict, config=config),
-                in_shardings=(in_shardings,),
-                out_shardings=out_shardings,
-            )
-        jitstats.register(f"sharded_evict_solve[{config.mode},{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sharded_evict_solve(
-    snap: DeviceSnapshot, config: EvictConfig, mesh: Mesh,
-    impl: Optional[str] = None,
-) -> EvictResult:
-    """The eviction solve (preempt/reclaim) jitted over the mesh: node-axis
-    inputs shard exactly like the allocate solve's; every EvictResult field
-    is task-axis, so outputs replicate.  ``impl`` is the guard plane's
-    demotion override (``"pjit"`` = the oracle)."""
-    fn = evict_solve_fn(mesh, config, impl=impl)
-    with mesh:
-        return fn(snap)
-
-
 def _evict(snap: DeviceSnapshot, config: EvictConfig) -> EvictResult:
     return evict_solve(snap, config)
 
 
-# --------------------------------------------------------------------------
-# sentinel-fused sharded solves (guard plane tier 1): the memoized sharded
-# solve body plus the ops/invariants tail in ONE jitted program — the
-# invariant reductions run on the replicated result vectors and the
-# node-sharded ledgers (GSPMD partitions the O(N) cross-checks), and the
-# verdict/histogram ride the action's single readback exactly like the
-# single-device sentinel programs.
-# --------------------------------------------------------------------------
+#: the result shardings of a pjit program, as specs, once per result type:
+#: an allocate result's node ledgers are node-sharded, its task-axis
+#: vectors replicated; every field of the other two is task- or gang-axis
+_ALLOCATE_OUT = AllocateResult(
+    assigned=P(),
+    pipelined=P(),
+    committed=P(),
+    node_idle=P(NODE_AXIS, None),
+    node_releasing=P(NODE_AXIS, None),
+    node_used=P(NODE_AXIS, None),
+    deserved=P(),
+    rounds_run=P(),
+    topk_exhausted=P(),
+    topk_reentries=P(),
+)
+_EVICT_OUT = EvictResult(*[P()] * len(EvictResult._fields))
+_PROBE_OUT = probe.ProbeResult(*[P()] * len(probe.ProbeResult._fields))
 
 
-def _sentinel_fn(key, name: str, inner_fn, invariants, config,
-                 carries_table: bool = False):
-    """The memoized jitted ``inner`` program with ``invariants`` and the
-    eligibility checksum fused behind it: ``(result, verdict, hist,
-    checksum)``, and behind those the refreshed table and erosion flag of
-    a warm program (``carries_table``), which returns ``(result, table',
-    eroded)``.  ``inner_fn`` builds the inner program on first use."""
+class Kind(NamedTuple):
+    """One row of the program table: what differs between the kinds of
+    device program, and nothing else."""
+
+    #: getters of the one-device program of ops/, as it is, and of the same
+    #: with its invariant tail fused behind it (None: no such program)
+    bare: Callable
+    fused: Optional[Callable]
+    #: the names those two are audited under
+    ops_names: Tuple[str, ...]
+    #: what the mesh programs register under, before the [mode,impl] tag
+    mesh_name: str
+    #: parallel/shard_solve.py's builder of the shard_map program
+    shard_map: str
+    #: the pjit program (None: shard_map is the kind's one mesh program):
+    #: the single-device body re-jitted with mesh shardings, collectives
+    #: compiler-inserted — shard_map's bit-exactness oracle and the guard's
+    #: demotion target.  (body, specs of the arguments behind the snapshot,
+    #: specs of the result); config and statics reach the body by keyword
+    pjit: Optional[tuple] = None
+    #: ops/invariants.py's function that the mesh sentinel fuses behind the
+    #: program (None: the kind has no mesh sentinel)
+    invariants: Optional[str] = None
+    #: the program returns (result, table', eroded): a warm program's
+    #: sentinel passes the refreshed table and the erosion flag through
+    carries_table: bool = False
+    #: how the one-device program takes its arrays, config and statics.
+    #: The mesh programs have config and statics baked in; ops/ takes them
+    #: at the call, each in the argument order its other callers (bundle
+    #: replay, the fit check, tests) use — a call of another shape would be
+    #: another entry of the jit cache, compiled again
+    one_device: Callable = lambda fn, arrays, config, statics: fn(
+        *arrays, config)
+
+
+def _arrays_only(fn, arrays, config, statics):
+    return fn(*arrays)
+
+
+KINDS = {
+    "full": Kind(
+        bare=lambda: assignment.allocate_solve,
+        fused=lambda: invariants.allocate_sentinel_solve,
+        ops_names=("ops.assignment.allocate_solve",
+                   "ops.invariants.allocate_sentinel_solve"),
+        mesh_name="sharded_allocate_solve",
+        shard_map="allocate_shard_map",
+        pjit=(_solve, (), _ALLOCATE_OUT),
+        invariants="allocate_invariants",
+    ),
+    # config.topk > 0: the [P, K] candidate-table program.  shard_map
+    # builds per-shard candidate lists and merges them with one per-solve
+    # gather — zero per-round collectives
+    "topk": Kind(
+        bare=lambda: assignment.allocate_topk_solve,
+        fused=lambda: invariants.allocate_topk_sentinel_solve,
+        ops_names=("ops.assignment.allocate_topk_solve",
+                   "ops.invariants.allocate_topk_sentinel_solve"),
+        mesh_name="sharded_allocate_topk_solve",
+        shard_map="allocate_topk_shard_map",
+        pjit=(assignment.allocate_topk_solve.__wrapped__, (P(),),
+              _ALLOCATE_OUT),
+        invariants="allocate_invariants",
+    ),
+    # the cross-cycle candidate-table carry (static ``k_min``).  shard_map
+    # contributes delta-sized per-shard work (fresh changed-node keys via
+    # one psum, the invalidated sub-bucket via one all_gather + replicated
+    # merge) and keeps the round loop collective-free; under pjit the
+    # pending bucket, the table (4) and the plan (4) replicate
+    "warm": Kind(
+        bare=assignment.warm_solve_fn,
+        fused=invariants.warm_sentinel_solve_fn,
+        ops_names=("ops.assignment.warm_allocate_solve",
+                   "ops.invariants.warm_allocate_sentinel_solve"),
+        mesh_name="sharded_warm_allocate_solve",
+        shard_map="warm_allocate_shard_map",
+        pjit=(assignment._warm_allocate_solve, (P(),) * 9,
+              (_ALLOCATE_OUT, (P(),) * 4, P())),
+        invariants="allocate_invariants", carries_table=True,
+        one_device=lambda fn, arrays, config, statics: fn(
+            *arrays, config=config, **statics),
+    ),
+    # reclaim / preempt (mode and gates in the EvictConfig).  One device
+    # bids on the pending bucket where one is passed behind the snapshot;
+    # the sharded bodies bid on the task axis
+    "evict": Kind(
+        bare=lambda: evict_solve,
+        fused=lambda: invariants.evict_sentinel_solve,
+        ops_names=("ops.eviction.evict_solve",
+                   "ops.invariants.evict_sentinel_solve"),
+        mesh_name="sharded_evict_solve",
+        shard_map="evict_shard_map",
+        pjit=(_evict, (), _EVICT_OUT),
+        invariants="evict_invariants",
+        one_device=lambda fn, arrays, config, statics: fn(
+            arrays[0], config, *(arrays[1:] or (None,))),
+    ),
+    # the lazy fit-error histogram: [T, N]-scale predicate masks shard
+    # along the node axis, the per-reason counts reduce (one psum on the
+    # shard_map path) into the replicated [T, N_REASONS] result
+    "fail_hist": Kind(
+        bare=lambda: assignment.failure_histogram_solve, fused=None,
+        ops_names=("ops.assignment.failure_histogram_solve",),
+        mesh_name="sharded_failure_histogram",
+        shard_map="failure_histogram_shard_map",
+        pjit=(assignment.failure_histogram_solve.__wrapped__, (), P()),
+        one_device=_arrays_only,
+    ),
+    # the same restricted to the [P] pending bucket (1-D node meshes only)
+    "fail_hist_bucket": Kind(
+        bare=lambda: assignment.failure_histogram_bucket_solve, fused=None,
+        ops_names=("ops.assignment.failure_histogram_bucket_solve",),
+        mesh_name="sharded_failure_histogram_bucket",
+        shard_map="failure_histogram_bucket_shard_map",
+        pjit=(assignment.failure_histogram_bucket_solve.__wrapped__, (P(),),
+              P()),
+        one_device=_arrays_only,
+    ),
+    # the batched what-if probe (statics ``evict_config``,
+    # ``with_evictions``), the query plane's dispatch: node-axis snapshot
+    # columns stay sharded (the lease's resident placement), the B-gang
+    # batch and the row oracle replicate
+    "probe": Kind(
+        bare=lambda: probe.probe_solve, fused=None,
+        ops_names=("ops.probe.probe_solve",),
+        mesh_name="sharded_probe_solve",
+        shard_map="probe_shard_map",
+        pjit=(probe.probe_body,
+              (probe.ProbeBatch(*[P()] * len(probe.ProbeBatch._fields)), P()),
+              _PROBE_OUT),
+        one_device=lambda fn, arrays, config, statics: fn(
+            *arrays, config, statics["evict_config"],
+            statics["with_evictions"]),
+    ),
+    # the enqueue admission scan.  On the mesh it is one replicated
+    # shard_map body around ops.admission.gate_scan — zero cross-shard
+    # bytes (shard_solve.enqueue_gate_shard_map says why it exists) — with
+    # no pjit twin and no fused sentinel: the caller checks on the host
+    "gate": Kind(
+        bare=admission.enqueue_gate_fn,
+        fused=invariants.enqueue_gate_sentinel_fn,
+        ops_names=("ops.admission.enqueue_gate",
+                   "ops.invariants.enqueue_gate_sentinel"),
+        mesh_name="sharded_enqueue_gate",
+        shard_map="enqueue_gate_shard_map",
+        one_device=_arrays_only,
+    ),
+}
+
+# one jitted program per (kind, mesh, impl, sentinel, config, statics) — a
+# fresh jax.jit wrapper per call would retrace and recompile the whole
+# solve every scheduling cycle.  The dispatch, the guard's audit, the read
+# plane's prewarm and the jaxpr audit all get THIS object, so nothing
+# compiles twice
+_jit_cache: dict = {}
+
+
+def mesh_tags(kind: str, impl: str, config) -> Tuple[str, ...]:
+    """What tells one mesh program of ``kind`` from the next in a name:
+    the eviction mode, and the impl where the kind has two."""
+    return ((config.mode,) if kind == "evict" else ()) + (
+        (impl,) if KINDS[kind].pjit else ())
+
+
+def tagged(name: str, tags) -> str:
+    return name + (f"[{','.join(tags)}]" if tags else "")
+
+
+def _shardings(mesh: Mesh, specs):
+    return jax.tree.map(lambda spec: NamedSharding(mesh, spec), specs)
+
+
+def _mesh_program(kind: str, mesh: Mesh, impl: str, sentinel: bool, config,
+                  statics: dict):
+    """THE factory: builds, registers and memoizes the jitted mesh program
+    of one cell of the table."""
+    key = (kind, mesh, impl, sentinel, config, tuple(sorted(statics.items())))
     fn = _jit_cache.get(key)
-    if fn is None:
-        from kube_batch_tpu.ops.invariants import eligibility_checksum
-
-        inner = inner_fn()
+    if fn is not None:
+        return fn
+    row = KINDS[kind]
+    if sentinel:
+        # the memoized bare program plus the ops/invariants tail in ONE
+        # jitted program: the invariant reductions run on the replicated
+        # result vectors and the node-sharded ledgers (GSPMD partitions the
+        # O(N) cross-checks), and the verdict/histogram ride the action's
+        # single readback exactly like the single-device sentinel programs
+        inner = _mesh_program(kind, mesh, impl, False, config, statics)
+        check = getattr(invariants, row.invariants)
 
         def fused(snap, *rest):
             out = inner(snap, *rest)
-            res, *carry = out if carries_table else (out,)
-            verdict, hist = invariants(snap, res, config)
-            return (res, verdict, hist, eligibility_checksum(snap), *carry)
+            res, *carry = out if row.carries_table else (out,)
+            verdict, hist = check(snap, res, config)
+            return (res, verdict, hist,
+                    invariants.eligibility_checksum(snap), *carry)
 
         fn = jax.jit(fused)
-        jitstats.register(name, fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sentinel_allocate_solve_fn(mesh: Mesh, config: AllocateConfig,
-                               impl: Optional[str] = None):
-    from kube_batch_tpu.ops.invariants import allocate_invariants
-
-    impl = _impl(impl)
-    return _sentinel_fn(
-        (mesh, config, "sentinel_alloc", impl),
-        f"sentinel_sharded_allocate_solve[{impl}]",
-        lambda: allocate_solve_fn(mesh, config, impl=impl),
-        allocate_invariants, config)
-
-
-def sentinel_allocate_topk_solve_fn(mesh: Mesh, config: AllocateConfig,
-                                    impl: Optional[str] = None):
-    from kube_batch_tpu.ops.invariants import allocate_invariants
-
-    impl = _impl(impl)
-    return _sentinel_fn(
-        (mesh, config, "sentinel_topk", impl),
-        f"sentinel_sharded_allocate_topk_solve[{impl}]",
-        lambda: allocate_topk_solve_fn(mesh, config, impl=impl),
-        allocate_invariants, config)
-
-
-def sentinel_warm_allocate_solve_fn(mesh: Mesh, config: AllocateConfig,
-                                    k_min: int,
-                                    impl: Optional[str] = None):
-    from kube_batch_tpu.ops.invariants import allocate_invariants
-
-    impl = _impl(impl)
-    return _sentinel_fn(
-        (mesh, config, "sentinel_warm", k_min, impl),
-        f"sentinel_sharded_warm_allocate_solve[{impl}]",
-        lambda: warm_allocate_solve_fn(mesh, config, k_min, impl=impl),
-        allocate_invariants, config, carries_table=True)
-
-
-def sentinel_evict_solve_fn(mesh: Mesh, config: EvictConfig,
-                            impl: Optional[str] = None):
-    from kube_batch_tpu.ops.invariants import evict_invariants
-
-    impl = _impl(impl)
-    return _sentinel_fn(
-        (mesh, config, "sentinel_evict", impl),
-        f"sentinel_sharded_evict_solve[{config.mode},{impl}]",
-        lambda: evict_solve_fn(mesh, config, impl=impl),
-        evict_invariants, config)
-
-
-def sentinel_sharded_evict_solve(snap, config, mesh, impl=None):
-    fn = sentinel_evict_solve_fn(mesh, config, impl=impl)
-    with mesh:
-        return fn(snap)
-
-
-#: (kind, sentinel) -> the getter that memoizes the program on a mesh
-_MESH_ALLOCATE_GETTERS = {
-    ("full", False): allocate_solve_fn,
-    ("full", True): sentinel_allocate_solve_fn,
-    ("topk", False): allocate_topk_solve_fn,
-    ("topk", True): sentinel_allocate_topk_solve_fn,
-    ("warm", False): warm_allocate_solve_fn,
-    ("warm", True): sentinel_warm_allocate_solve_fn,
-}
-
-
-def allocate_program(kind: str, mesh: Optional[Mesh], impl: Optional[str],
-                     config: AllocateConfig, sentinel: bool, k_min: int = 0):
-    """THE lookup of an allocate dispatch's program: the memoized jitted
-    callable for ``kind`` ("full" | "topk" | "warm"), bare or with the
-    invariant tail fused behind it (``sentinel``).  On a ``mesh`` it is
-    what the ``*_solve_fn`` getter memoizes for (mesh, config, impl), with
-    ``config`` (and a warm program's ``k_min``) baked in; ``mesh=None`` is
-    the single-device program of ops/assignment.py or ops/invariants.py,
-    which takes them as static arguments at the call."""
-    if mesh is not None:
-        getter = _MESH_ALLOCATE_GETTERS[kind, sentinel]
-        if kind == "warm":
-            return getter(mesh, config, k_min, impl=impl)
-        return getter(mesh, config, impl=impl)
-    from kube_batch_tpu.ops import assignment, invariants
-
-    if kind == "warm":
-        return (invariants.warm_sentinel_solve_fn() if sentinel
-                else assignment.warm_solve_fn())
-    return {
-        ("full", False): assignment.allocate_solve,
-        ("full", True): invariants.allocate_sentinel_solve,
-        ("topk", False): assignment.allocate_topk_solve,
-        ("topk", True): invariants.allocate_topk_sentinel_solve,
-    }[kind, sentinel]
-
-
-def probe_solve_fn(mesh: Mesh, config: AllocateConfig,
-                   evict_config: EvictConfig, with_evictions: bool,
-                   impl: Optional[str] = None):
-    """Memoized jitted sharded what-if probe (ops/probe.py) for (mesh,
-    config, evict_config, with_evictions, impl) — the query plane's
-    dispatch on multi-device leases, and a jaxpr-audit entry point.  The
-    shard_map impl authors its collectives (parallel/shard_solve.py);
-    the pjit impl re-jits the single-device :func:`ops.probe.probe_body`
-    with mesh shardings — the bit-exactness oracle, same split as the
-    solves."""
-    impl = _impl(impl)
-    key = (mesh, config, evict_config, with_evictions, "probe", impl)
-    fn = _jit_cache.get(key)
-    if fn is None:
-        if impl == "shard_map":
-            from kube_batch_tpu.parallel import shard_solve
-
-            fn = shard_solve.probe_shard_map(
-                mesh, config, evict_config, with_evictions
-            )
-        else:
-            from kube_batch_tpu.ops.probe import (
-                ProbeBatch,
-                ProbeResult,
-                probe_body,
-            )
-
-            repl = NamedSharding(mesh, P())
-            batch_shardings = ProbeBatch(
-                *([repl] * len(ProbeBatch._fields)))
-            out_shardings = ProbeResult(
-                *([repl] * len(ProbeResult._fields)))
-            fn = jax.jit(
-                partial(probe_body, config=config,
-                        evict_config=evict_config,
-                        with_evictions=with_evictions),
-                in_shardings=(snapshot_shardings(mesh), batch_shardings,
-                              repl),
-                out_shardings=out_shardings,
-            )
-        jitstats.register(f"sharded_probe_solve[{impl}]", fn)
-        _jit_cache[key] = fn
-    return fn
-
-
-def sharded_probe_solve(snap: DeviceSnapshot, batch, probe_rows, mesh: Mesh,
-                        config: AllocateConfig, evict_config: EvictConfig,
-                        with_evictions: bool = False):
-    """The batched what-if probe over the mesh: node-axis snapshot columns
-    stay sharded (the lease's resident placement), the B-gang batch and
-    row oracle replicate, every ProbeResult field comes back replicated."""
-    fn = probe_solve_fn(mesh, config, evict_config, with_evictions)
-    with mesh:
-        return fn(snap, batch, probe_rows)
-
-
-def enqueue_gate_solve_fn(mesh: Mesh):
-    """Memoized mesh-replicated enqueue admission scan (the shard_map
-    wrapper around ops.admission.gate_scan — zero cross-shard bytes; see
-    shard_solve.enqueue_gate_shard_map for why it exists)."""
-    key = (mesh, "enqueue_gate")
-    fn = _jit_cache.get(key)
-    if fn is None:
+    elif impl == "shard_map":
         from kube_batch_tpu.parallel import shard_solve
 
-        fn = shard_solve.enqueue_gate_shard_map(mesh)
-        jitstats.register("sharded_enqueue_gate", fn)
-        _jit_cache[key] = fn
+        fn = getattr(shard_solve, row.shard_map)(
+            mesh, *(() if config is None else (config,)), **statics)
+    else:
+        body, in_specs, out_specs = row.pjit
+        if config is not None:
+            body = partial(body, config=config, **statics)
+        fn = jax.jit(
+            body,
+            in_shardings=(snapshot_shardings(mesh),
+                          *_shardings(mesh, in_specs)),
+            out_shardings=_shardings(mesh, out_specs))
+    jitstats.register(
+        tagged(("sentinel_" if sentinel else "") + row.mesh_name,
+               mesh_tags(kind, impl, config)), fn)
+    _jit_cache[key] = fn
     return fn
 
 
-def dispatch_enqueue_gate(min_res, cand, idle0, quanta, n_nodes_padded: int):
-    """The enqueue action's gate dispatch: ride the mesh (replicated
-    shard_map) when the cycle's solves shard and the shard_map path is on,
-    else the single-device jitted scan.  Verdicts are bit-equal either way
-    (both trace ops.admission.gate_scan)."""
-    if should_shard(n_nodes_padded) and shard_map_enabled():
-        mesh = default_mesh()
+def program(kind: str, mesh: Optional[Mesh], impl: Optional[str], config,
+            sentinel: bool = False, **statics):
+    """THE lookup of a device dispatch's program: the memoized jitted
+    callable of ``kind`` (a key of :data:`KINDS`), bare or with the
+    invariant tail fused behind it (``sentinel``).  On a ``mesh`` it is the
+    program of (mesh, impl, config, statics), all of them baked in;
+    ``impl`` None follows KB_SHARD_MAP, and ``"pjit"`` is what the guard's
+    demotion and its shadow audit pass.  ``mesh=None`` is the one-device
+    program of ops/, which takes config and statics at the call
+    (:func:`call`)."""
+    row = KINDS[kind]
+    if mesh is None:
+        return (row.fused if sentinel else row.bare)()
+    return _mesh_program(
+        kind, mesh, resolve_impl(impl) if row.pjit else "shard_map",
+        sentinel, config, statics)
+
+
+def call(fn, mesh: Optional[Mesh], *arrays, config=None, **statics):
+    """THE call of a looked-up program (:func:`program`) on its arrays:
+    under its mesh, where config and statics are baked in; on one device
+    as ``one_device`` of the row that ``fn`` is the program of passes
+    them."""
+    if mesh is not None:
         with mesh:
-            return enqueue_gate_solve_fn(mesh)(min_res, cand, idle0, quanta)
-    from kube_batch_tpu.ops.admission import enqueue_gate_solve
+            return fn(*arrays)
+    for row in KINDS.values():
+        if fn is row.bare() or (row.fused and fn is row.fused()):
+            return row.one_device(fn, arrays, config, statics)
+    raise KeyError(f"{fn} is no one-device program of the table")
 
-    return enqueue_gate_solve(min_res, cand, idle0, quanta)
 
-
-def collective_stats(mesh: Mesh, config: Optional[AllocateConfig] = None,
-                     snap=None, pend_bucket: Optional[int] = None) -> dict:
+def collective_stats(mesh: Mesh, snap, config: Optional[AllocateConfig] = None,
+                     pend_bucket: Optional[int] = None) -> dict:
     """Traced collective inventory of the shard_map allocate solve on
     `mesh` — the per-round / per-solve cross-shard byte accounting
     (utils/jitstats.collective_inventory) of the program XLA actually
-    compiles, at the abstract shapes of ``snap`` (defaults to the audit's
-    small shapes).  The bench and the sim report this next to the measured
+    compiles, at the abstract shapes of ``snap`` (a snapshot of
+    ShapeDtypeStructs: analysis.jaxpr_audit.abstract_snapshot).  The bench
+    and the sim report this next to the measured
     round counts, so the O(tasks) comms claim is checked against the real
     traced program, not asserted in a comment.
 
@@ -688,19 +511,13 @@ def collective_stats(mesh: Mesh, config: Optional[AllocateConfig] = None,
     HBM audit's KBT204 reads the same fields for its byte formulas."""
     import jax.numpy as jnp
 
-    if snap is None:
-        from kube_batch_tpu.analysis.jaxpr_audit import abstract_snapshot
-
-        snap = abstract_snapshot()
     config = config or AllocateConfig()
     if config.topk and pend_bucket:
-        fn = allocate_topk_solve_fn(mesh, config, impl="shard_map")
-        traced = fn.trace(
+        traced = program("topk", mesh, "shard_map", config).trace(
             snap, jax.ShapeDtypeStruct((pend_bucket,), jnp.int32)
         )
     else:
-        fn = allocate_solve_fn(mesh, config, impl="shard_map")
-        traced = fn.trace(snap)
+        traced = program("full", mesh, "shard_map", config).trace(snap)
     stats = jitstats.collective_inventory(traced.jaxpr)
     stats["mesh"] = {k: int(v) for k, v in dict(mesh.shape).items()}
     stats["task_bucket"] = int(snap.task_req.shape[0])

@@ -739,7 +739,7 @@ class QueryPlane:
         none and stays out of the serving counters."""
         import jax
 
-        from kube_batch_tpu.ops.probe import probe_solve
+        from kube_batch_tpu.parallel.mesh import call, program
 
         # children of the caller's root (a flush, a pre-warm): one of each
         # per dispatch
@@ -749,18 +749,12 @@ class QueryPlane:
         with_evictions = any(r["evictions"] for r in reqs)
         # program dispatch + device_get: the device's share of a flush
         with tracer.span("whatif:probe", batch=len(reqs), gang=len(rows)):
-            if lease.mesh is not None:
-                from kube_batch_tpu.parallel.mesh import sharded_probe_solve
-
-                res = sharded_probe_solve(
-                    lease.snap, pbatch, rows, lease.mesh, lease.config,
-                    lease.evict_config, with_evictions,
-                )
-            else:
-                res = probe_solve(
-                    lease.snap, pbatch, rows, lease.config,
-                    lease.evict_config, with_evictions,
-                )
+            statics = dict(evict_config=lease.evict_config,
+                           with_evictions=with_evictions)
+            res = call(
+                program("probe", lease.mesh, None, lease.config, **statics),
+                lease.mesh, lease.snap, pbatch, rows, config=lease.config,
+                **statics)
             if tally is not None:
                 tally[0] += 1
                 tally[1] += len(reqs)

@@ -24,11 +24,7 @@ def run(n_devices: int) -> None:
 
     from kube_batch_tpu.ops.assignment import AllocateConfig, allocate_solve
     from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
-    from kube_batch_tpu.parallel.mesh import (
-        make_mesh,
-        sharded_allocate_solve,
-        sharded_evict_solve,
-    )
+    from kube_batch_tpu.parallel.mesh import call, make_mesh, program
     from kube_batch_tpu.testing.synthetic import synthetic_device_snapshot
 
     assert len(jax.devices()) >= n_devices, (
@@ -41,7 +37,7 @@ def run(n_devices: int) -> None:
         n_tasks=256, n_nodes=max(64, n_devices * 8), gang_size=4, n_queues=3,
         gpu_task_frac=0.2,
     )
-    result = sharded_allocate_solve(snap, AllocateConfig(), mesh)
+    result = call(program("full", mesh, None, AllocateConfig()), mesh, snap)
     assigned = np.asarray(result.assigned)[: meta.n_tasks]
     placed = int((assigned >= 0).sum())
     assert placed > 0, "multichip dryrun placed nothing"
@@ -59,7 +55,7 @@ def run(n_devices: int) -> None:
         n_tasks=5000, n_nodes=1024, gang_size=4, n_queues=3,
     )
     cfg = AllocateConfig()
-    sharded = sharded_allocate_solve(snap_big, cfg, mesh)
+    sharded = call(program("full", mesh, None, cfg), mesh, snap_big)
     single = allocate_solve(snap_big, cfg)
     s_a = np.asarray(single.assigned)[: meta_big.n_tasks]
     m_a = np.asarray(sharded.assigned)[: meta_big.n_tasks]
@@ -79,7 +75,7 @@ def run(n_devices: int) -> None:
     )
     snap_ev = _with_running(snap_ev, meta_ev, frac=0.7)
     ev_cfg = EvictConfig(mode="reclaim")
-    ev_sharded = sharded_evict_solve(snap_ev, ev_cfg, mesh)
+    ev_sharded = call(program("evict", mesh, None, ev_cfg), mesh, snap_ev)
     ev_single = evict_solve(snap_ev, ev_cfg)
     assert (
         np.asarray(ev_sharded.claim_node) == np.asarray(ev_single.claim_node)

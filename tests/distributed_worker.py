@@ -39,10 +39,7 @@ def main() -> None:
     from kube_batch_tpu.framework.conf import load_scheduler_conf
     from kube_batch_tpu.framework.session import close_session, open_session
     from kube_batch_tpu.ops.assignment import allocate_solve
-    from kube_batch_tpu.parallel.mesh import (
-        sharded_allocate_solve,
-        snapshot_shardings,
-    )
+    from kube_batch_tpu.parallel.mesh import call, program, snapshot_shardings
     from kube_batch_tpu.testing.synthetic import synthetic_cluster
 
     # deterministic: both ranks build the same cluster (seed=0) — the
@@ -69,22 +66,19 @@ def main() -> None:
             )
 
         gsnap = jax.tree.map(distribute, snap, shardings)
-        result = sharded_allocate_solve(gsnap, config, mesh)
+        result = call(program("full", mesh, None, config), mesh, gsnap)
         dist = jax.device_get(result.assigned)  # replicated output
 
         # BOTH sharded implementations, explicitly: the shard_map body's
         # authored collectives must cross the real two-process boundary
         # (ICI within a rank, DCN between) and still match the pjit oracle
         # and the local solve bit-for-bit
-        from kube_batch_tpu.parallel.mesh import allocate_solve_fn
-
         with mesh:
             sm = jax.device_get(
-                allocate_solve_fn(mesh, config, impl="shard_map")(gsnap)
-                .assigned
+                program("full", mesh, "shard_map", config)(gsnap).assigned
             )
             pj = jax.device_get(
-                allocate_solve_fn(mesh, config, impl="pjit")(gsnap).assigned
+                program("full", mesh, "pjit", config)(gsnap).assigned
             )
 
         # per-host sharded residency: each process diffs the full host

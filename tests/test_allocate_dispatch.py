@@ -1,8 +1,8 @@
 """The allocate dispatch is plan → program → call: every leaf of {single
 device, the test backend's 8-device mesh} x {full, topk, warm} x {guard
 attached, none} and the three guard demotions, each held to what
-``plan_allocate_dispatch`` chose, which memoized program
-``allocate_program`` handed out, the shape of the dispatch's return, and
+``plan_allocate_dispatch`` chose, which memoized program the lookup
+(``parallel.mesh.program``) handed out, the shape of the dispatch's return, and
 the all-oracle program's placements on the same snapshot.
 
 The expected table is written out below: it is the contract, not a
@@ -32,6 +32,7 @@ from kube_batch_tpu.guard.plane import DEMOTED, GuardPlane
 from kube_batch_tpu.ops import assignment, invariants
 from kube_batch_tpu.parallel import mesh as mesh_mod
 from kube_batch_tpu.testing.synthetic import GiB
+from kube_batch_tpu.utils import jitstats
 
 CONF = load_scheduler_conf(shipped_conf_path())
 
@@ -50,11 +51,18 @@ def _single(name):
     return obj() if name.endswith("_fn") else obj
 
 
+def _registered(name):
+    """The mesh programs that utils/jitstats tracks under ``name`` (one per
+    mesh and config the process has dispatched)."""
+    return [f for n, f in jitstats._TRACKED if n == name]
+
+
 # id: (devices, kind, guard attached, demoted path | None, expected)
 # expected: what plan_allocate_dispatch chose — kind, impl, engaged,
 # demoted, wstate is None — then the dispatch's ginfo["engaged"], the keys
-# of topk_info (None = no compaction ran), and the getter that memoizes
-# the program the leaf runs
+# of topk_info (None = no compaction ran), and the program the leaf runs:
+# on one device the ops/ function (or what its ``*_fn`` getter returns), on
+# the mesh the name it registers under
 LEAVES = {
     "single-full-bare": ("single", "full", False, None, dict(
         kind="full", impl=None, engaged=(), demoted=False, cold=True,
@@ -80,28 +88,31 @@ LEAVES = {
     "mesh8-full-bare": ("mesh8", "full", False, None, dict(
         kind="full", impl=None, engaged=("shard_map",), demoted=False,
         cold=True, ran=["shard_map"], info=None,
-        getter="allocate_solve_fn")),
+        getter="sharded_allocate_solve[shard_map]")),
     "mesh8-full-guard": ("mesh8", "full", True, None, dict(
         kind="full", impl=None, engaged=("shard_map",), demoted=False,
         cold=True, ran=["shard_map"], info=None,
-        getter="sentinel_allocate_solve_fn")),
+        getter="sentinel_sharded_allocate_solve[shard_map]")),
     "mesh8-topk-bare": ("mesh8", "topk", False, None, dict(
         kind="topk", impl=None, engaged=("shard_map", "topk"),
         demoted=False, cold=True, ran=["shard_map", "topk"],
-        info={"k", "bucket"}, getter="allocate_topk_solve_fn")),
+        info={"k", "bucket"},
+        getter="sharded_allocate_topk_solve[shard_map]")),
     "mesh8-topk-guard": ("mesh8", "topk", True, None, dict(
         kind="topk", impl=None, engaged=("shard_map", "topk"),
         demoted=False, cold=True, ran=["shard_map", "topk"],
-        info={"k", "bucket"}, getter="sentinel_allocate_topk_solve_fn")),
+        info={"k", "bucket"},
+        getter="sentinel_sharded_allocate_topk_solve[shard_map]")),
     "mesh8-warm-bare": ("mesh8", "warm", False, None, dict(
         kind="topk", impl=None, engaged=("shard_map", "topk"),
         demoted=False, cold=False, ran=["shard_map", "topk", "warm"],
-        info={"k", "bucket", "warm"}, getter="warm_allocate_solve_fn")),
+        info={"k", "bucket", "warm"},
+        getter="sharded_warm_allocate_solve[shard_map]")),
     "mesh8-warm-guard": ("mesh8", "warm", True, None, dict(
         kind="topk", impl=None, engaged=("shard_map", "topk"),
         demoted=False, cold=False, ran=["shard_map", "topk", "warm"],
         info={"k", "bucket", "warm"},
-        getter="sentinel_warm_allocate_solve_fn")),
+        getter="sentinel_sharded_warm_allocate_solve[shard_map]")),
     # the demotions: topk → the full matrix, shard_map → the pjit oracle,
     # warm → the cold per-solve table build
     "demoted-topk": ("single", "topk", True, "topk", dict(
@@ -110,7 +121,7 @@ LEAVES = {
     "demoted-shard_map": ("mesh8", "topk", True, "shard_map", dict(
         kind="topk", impl="pjit", engaged=("topk",), demoted=True,
         cold=True, ran=["topk"], info={"k", "bucket"},
-        getter="sentinel_allocate_topk_solve_fn")),
+        getter="sentinel_sharded_allocate_topk_solve[pjit]")),
     "demoted-warm": ("single", "warm", True, "warm", dict(
         kind="topk", impl=None, engaged=("topk",), demoted=False, cold=True,
         ran=["topk"], info={"k", "bucket"},
@@ -152,19 +163,18 @@ class _Spy:
 
     def __init__(self, monkeypatch):
         self.plans, self.programs = [], []
-        plan, program = (alloc_mod.plan_allocate_dispatch,
-                         mesh_mod.allocate_program)
+        plan, program = alloc_mod.plan_allocate_dispatch, mesh_mod.program
 
         def spy_plan(*a, **kw):
             self.plans.append(plan(*a, **kw))
             return self.plans[-1]
 
         def spy_program(*a, **kw):
-            self.programs.append((a, program(*a, **kw)))
-            return self.programs[-1][1]
+            self.programs.append((a, kw, program(*a, **kw)))
+            return self.programs[-1][2]
 
         monkeypatch.setattr(alloc_mod, "plan_allocate_dispatch", spy_plan)
-        monkeypatch.setattr(mesh_mod, "allocate_program", spy_program)
+        monkeypatch.setattr(mesh_mod, "program", spy_program)
 
 
 def _dispatch(cache, guard, warm):
@@ -241,22 +251,24 @@ def test_dispatch_leaf(leaf, monkeypatch):
     if "warm" in (want["info"] or ()):
         assert info["warm"]["cold"] is want["cold"]  # a CARRIED table
 
-    # ---- the program is the very object its getter memoizes -------------
+    # ---- the program is the very object that is memoized ----------------
     # (a demotion to the full matrix looks the bare program up first, for
-    # its fit check: the program that RAN is the last lookup)
-    (args, program) = spy.programs[-1]
-    assert len(spy.programs) == (2 if demote == "topk" else 1)
-    k_min = alloc_mod.warm_k_min(32)
+    # its fit check; the all-oracle twin is looked up last)
+    (args, statics_asked, program), oracle_lookup = spy.programs[-2:]
+    assert len(spy.programs) == (3 if demote == "topk" else 2)
+    assert oracle_lookup[0] == (
+        "full", mesh, "pjit", config._replace(topk=0))
+    run_kind = "warm" if "warm" in want["ran"] else want["kind"]
+    statics = ({"k_min": alloc_mod.warm_k_min(32)} if run_kind == "warm"
+               else {})
     if devices == "single":
         assert program is _single(want["getter"])
     else:
-        getter = getattr(mesh_mod, want["getter"])
-        extra = (k_min,) if "warm" in want["getter"] else ()
-        assert program is getter(mesh, ginfo["config"], *extra,
-                                 impl=want["impl"])
-    run_kind = "warm" if "warm" in want["ran"] else want["kind"]
-    assert args[:3] == (run_kind, mesh, want["impl"])
-    assert args[4] is guarded
+        assert any(program is fn for fn in _registered(want["getter"]))
+        assert program is mesh_mod.program(
+            run_kind, mesh, want["impl"], ginfo["config"], guarded, **statics)
+    assert args == (run_kind, mesh, want["impl"], ginfo["config"], guarded)
+    assert statics_asked == statics
 
     # ---- and it places what the all-oracle program places ---------------
     np.testing.assert_array_equal(assigned, oracle)

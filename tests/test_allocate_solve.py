@@ -230,7 +230,7 @@ class TestShardedSolveAgreement:
         GSPMD partitioning is an execution detail, not a semantic one."""
         import jax
 
-        from kube_batch_tpu.parallel.mesh import make_mesh, sharded_allocate_solve
+        from kube_batch_tpu.parallel.mesh import call, make_mesh, program
         from kube_batch_tpu.testing.synthetic import synthetic_device_snapshot
 
         if len(jax.devices()) < 8:
@@ -240,7 +240,7 @@ class TestShardedSolveAgreement:
         cfg = AllocateConfig()
         single = allocate_solve(snap, cfg)
         mesh = make_mesh(8)
-        sharded = sharded_allocate_solve(snap, cfg, mesh)
+        sharded = call(program("full", mesh, None, cfg), mesh, snap)
         s_a = np.asarray(single.assigned)[: meta.n_tasks]
         m_a = np.asarray(sharded.assigned)[: meta.n_tasks]
         np.testing.assert_array_equal(s_a, m_a)
@@ -256,11 +256,11 @@ class TestShardedSolveAgreement:
         # the lazy fit-error histogram's sharded twin (failure cycles in
         # sharded mode dispatch it) must match the single-device one
         from kube_batch_tpu.ops.assignment import failure_histogram_solve
-        from kube_batch_tpu.parallel.mesh import sharded_failure_histogram
 
         np.testing.assert_array_equal(
             np.asarray(failure_histogram_solve(snap)),
-            np.asarray(sharded_failure_histogram(snap, mesh)),
+            np.asarray(call(
+                program("fail_hist", mesh, None, None), mesh, snap)),
         )
 
 

@@ -875,6 +875,20 @@ class TestKBT010:
         """
         assert rule_ids(findings_for(src, "actions/x.py")) == ["KBT010"]
 
+    def test_the_call_of_a_table_program_is_a_device_source(self):
+        # every dispatch site's shape since the program table
+        # (parallel/mesh.py): plan -> program -> call, imported in place
+        src = """
+        import numpy as np
+
+        def read(snap, mesh):
+            from kube_batch_tpu.parallel.mesh import call, program
+
+            hist = call(program("fail_hist", mesh, None, None), mesh, snap)
+            return np.asarray(hist)
+        """
+        assert rule_ids(findings_for(src, "actions/x.py")) == ["KBT010"]
+
     def test_scatter_factory_result_is_a_device_source(self):
         # PR 5 dispatch shape: the per-mesh resident scatter factory form
         # (`_mesh_shard_scatter_fn(mesh)(dev, rows, vals)`)
@@ -1181,6 +1195,41 @@ class TestKBT013:
             return evict_solve(snap, config)
         """
         assert rule_ids(findings_for(src, "actions/x.py")) == ["KBT013"]
+
+    def test_call_of_a_table_program_without_consumer_triggers(self):
+        src = """
+        def solve(snap, mesh, config):
+            out = call(program("evict", mesh, None, config, True), mesh,
+                       snap, config=config)
+            return out[0]
+        """
+        assert rule_ids(findings_for(src, "actions/x.py")) == ["KBT013"]
+        # whatever kind a variable holds may commit
+        assert rule_ids(findings_for(
+            src.replace('program("evict"', "program(kind"), "actions/x.py",
+        )) == ["KBT013"]
+
+    def test_call_of_a_kind_that_commits_nothing_is_clean(self):
+        src = """
+        def histogram(snap, mesh):
+            return call(program("fail_hist", mesh, None, None), mesh, snap)
+        """
+        assert findings_for(src, "actions/x.py") == []
+
+    def test_solve_claims_without_its_consumer_is_flagged(self):
+        """The evict dispatch site itself, its verdict left unconsumed."""
+        import pathlib
+
+        import kube_batch_tpu.actions.reclaim as reclaim
+
+        src = pathlib.Path(reclaim.__file__).read_text()
+        assert [f for f in check_source(src, "actions/reclaim.py")
+                if f.rule == "KBT013"] == []
+        assert "consume_sentinel(" in src
+        flagged = [f for f in check_source(
+            src.replace("consume_sentinel(", "look_at("),
+            "actions/reclaim.py") if f.rule == "KBT013"]
+        assert flagged and all("program" in f.message for f in flagged)
 
     def test_dispatch_seam_layer_is_exempt(self):
         # dispatch_*-named helpers RETURN the un-consumed sentinel — the

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import gc
 import json
+import sys
 import threading
 import time
 import urllib.error
@@ -592,28 +593,34 @@ class TestInert:
     def test_churn_decides_the_same_with_the_hook_and_a_watchdog_running(
             self, seed, monkeypatch):
         """The randomized churn of tests/test_trace.py, one side with
-        retention off, the other traced, watched by a live watchdog that
-        declares stalls (a bound of nothing) and interrupted by a full
-        collection every cycle: identical decisions."""
+        retention off, the other traced, watched by a watchdog that declares
+        a stall in every cycle and interrupted by a full collection in
+        every cycle: identical decisions.  The watched side runs on an
+        injected clock and the watchdog's looks are scripted, as in
+        :class:`TestWatchdog`: each cycle's ``resync`` root holds for more
+        than the bound, the watchdog looks, and the collector runs — what
+        a live watchdog and a collecting thread did only when the machine
+        gave them a turn inside a cycle."""
         monkeypatch.setenv("KB_TRACE", "0")
         c_off = _mk_cache()
         s_off = _mk_scheduler(c_off)
-        monkeypatch.setenv("KB_TRACE", "1")
-        c_on = _mk_cache()
-        s_on = _mk_scheduler(c_on)
+        clock = VirtualClock(start=100.0)
+        s_on = _watched(clock)
+        c_on = s_on.cache
         wd = LoopWatchdog(s_on)
-        monkeypatch.setattr(LoopWatchdog, "FLOOR_S", 0.0)
-        monkeypatch.setattr(LoopWatchdog, "TICK_S", 0.002)
-        s_on.cycle_cost_ewma = 0.0
-        wd.start()
-        done = threading.Event()
+        wd.loop_tid = threading.get_ident()
+        resync = c_on.process_resync_tasks
+        looks = []
 
-        def collect_all_the_time():
-            while not done.wait(0.002):
-                gc.collect(2)
+        def resync_under_watch():
+            bound = max(wd.FACTOR * (s_on.cycle_cost_ewma or 0.0), wd.FLOOR_S)
+            clock.sleep(bound + 0.05)
+            wd.check()
+            looks.append(gc.collect(2))
+            return resync()
 
-        collector = threading.Thread(target=collect_all_the_time)
-        collector.start()
+        c_on.process_resync_tasks = resync_under_watch
+        declared0, _ = _stalls("cycle")
         ch_off, ch_on = _Churner(c_off, seed), _Churner(c_on, seed)
         try:
             for _ in range(3):
@@ -631,14 +638,14 @@ class TestInert:
                     s_on.run_once_pipelined()
                     s_on.drain_pipeline()
         finally:
-            done.set()
-            collector.join(timeout=60)
             wd.stop()
-        assert not collector.is_alive()
         assert _observable_state(c_on) == _observable_state(c_off)
+        assert len(looks) == 8
+        # a look finds the last cycle's stall closed or declares this one's
+        assert _stalls("cycle")[0] == declared0 + 4
         state = c_on.tracer.state()
-        assert any("stall" in row["why"] for row in state["kept"])
-        assert any(row["gc_full"] for row in state["cycles"] + state["kept"])
+        assert sum("stall" in row["why"] for row in state["kept"]) == 4
+        assert all(row["gc_full"] for row in state["cycles"])
         assert c_off.tracer.state()["kept"] == []
         s_off.close()
         s_on.close()
@@ -647,15 +654,32 @@ class TestInert:
 
 
 # ---------------------------------------------------------------------------
-# the planted stall, through the served path
+# the planted stall: a cycle's ingest drain behind a held cache lock
 # ---------------------------------------------------------------------------
 
 
-def holder_of_the_cache_lock(cache, seconds, holding: threading.Event):
+def holder_of_the_cache_lock(cache, holding: threading.Event,
+                             release: threading.Event):
     with cache._lock:
         holding.set()
         with allow_blocking("the planted stall: this IS the fault"):
-            time.sleep(seconds)
+            release.wait(120)
+
+
+def _frames_of(tid):
+    frame, names = sys._current_frames().get(tid), []
+    while frame is not None:
+        names.append(frame.f_code.co_name)
+        frame = frame.f_back
+    return names
+
+
+def _until(what, timeout=120.0):
+    """Wait for a state another thread reaches (nothing is measured)."""
+    deadline = time.monotonic() + timeout
+    while not what():
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
 
 
 class TestPlantedStall:
@@ -663,114 +687,143 @@ class TestPlantedStall:
 
     def test_a_held_cache_lock_is_one_kept_stall_that_names_the_holder(
             self, monkeypatch):
+        """The hold is scripted on the injected clock (latency telemetry
+        reads it too, so the decision's wait is the hold): the loop's thread
+        sits in its ingest drain behind the lock for 800 ms of it, and the
+        watchdog looks when the script says."""
+        from kube_batch_tpu.utils import telemetry
+
+        clock = VirtualClock(start=100.0)
+        monkeypatch.setattr(telemetry, "perf_counter", clock.monotonic)
+        sched = _watched(clock)         # EWMA 0.1 s: the bound is 400 ms
+        cache = sched.cache
+        wd = LoopWatchdog(sched)
+
+        def cycle():
+            sched.run_once_pipelined()
+            sched.drain_pipeline()
+
+        _add_gang(cache, 1)
+        cycle()                         # compiles the solve
+        cache.enable_ingest_staging()
+        holding, release = threading.Event(), threading.Event()
+        holder = threading.Thread(
+            target=holder_of_the_cache_lock, name="holder",
+            args=(cache, holding, release))
+        loop = threading.Thread(target=cycle, name="loop")
+        try:
+            holder.start()
+            assert holding.wait(60)
+            _add_gang(cache, 2, size=1)     # staged; the drain needs the lock
+            declared0, seconds0 = _stalls("cycle")
+            loop.start()
+            wd.loop_tid = loop.ident
+            _until(lambda: "drain_staged_ingest" in _frames_of(loop.ident))
+            clock.sleep(0.3)
+            wd.check()                      # inside max(4 x 100, 250) ms
+            assert _stalls("cycle")[0] == declared0
+            clock.sleep(0.2)
+            for _ in range(3):              # past it, however many looks
+                wd.check()
+            assert _stalls("cycle") == (declared0 + 1, seconds0)
+            clock.sleep(self.HOLD_S - 0.5)
+        finally:
+            release.set()
+            holder.join(timeout=60)
+            if loop.ident is not None:
+                loop.join(timeout=120)
+        assert not holder.is_alive() and not loop.is_alive()
+        wd.check()                          # the next look closes it
+        assert _stalls("cycle") == (
+            declared0 + 1, pytest.approx(seconds0 + self.HOLD_S))
+        assert cache.binder.binds.get("tr/g2-0")
+        (row,) = [r for r in sched.tracer.state()["kept"]
+                  if "stall" in r["why"]]
+        assert row["stall_ms"] == pytest.approx(self.HOLD_S * 1e3)
+        assert row["decided"] == 1
+        # the decision waited out the hold, and all of that wait has a name
+        assert row["worst_ms"] == pytest.approx(self.HOLD_S * 1e3)
+        assert row["worst_named_ms"] >= 0.9 * row["worst_ms"]
+        # the ring of 4 rolls past it and the kept list still has it
+        for _ in range(5):
+            cycle()
+        state = sched.tracer.state()
+        assert row["cycle"] not in [r["cycle"] for r in state["cycles"]]
+        tree = sched.tracer.cycle_tree(row["cycle"])
+        (stall,) = tree["stalls"]
+        assert stall["phase"] == "cycle"
+        assert stall["declared_after_ms"] == pytest.approx(500.0)
+        assert stall["span_path"].startswith("ingest_drain")
+        held_by = [frames for name, frames in stall["threads"].items()
+                   if name.startswith("holder-")]
+        assert held_by and any(
+            "holder_of_the_cache_lock" in f for f in held_by[0])
+        assert any("drain_staged_ingest" in f for f in stall["stack"])
+        cache.disable_ingest_staging()
+        sched.close()
+        cache.stop()
+
+    def test_the_served_loop_declares_it_too(self):
+        """The same fault through the served path — a pod posted over HTTP,
+        ``run_forever`` and its own watchdog thread on the wall clock — with
+        no duration asked of the machine: the lock is held UNTIL the
+        watchdog has declared, however long a loaded machine takes."""
         from kube_batch_tpu.cmd.server import AdminServer
 
-        monkeypatch.setenv("KB_TRACE_RING", "4")
         cache = _mk_cache()
         sched = Scheduler(cache, conf=load_scheduler_conf(None),
                           schedule_period=0.05)
         admin = AdminServer(cache, "127.0.0.1", 0)
         admin.start()
-        base = f"http://127.0.0.1:{admin.port}"
 
         def post(kind, body):
             req = urllib.request.Request(
-                f"{base}/v1/{kind}", data=json.dumps(body).encode(),
+                f"http://127.0.0.1:{admin.port}/v1/{kind}",
+                data=json.dumps(body).encode(),
                 headers={"Content-Type": "application/json"}, method="POST")
             with urllib.request.urlopen(req, timeout=30) as r:
                 assert json.loads(r.read())["ok"] is True
 
-        def send_group(name):
+        def send(name):
+            """A one-pod gang in q0, as a client posts it."""
             post("podgroups", [{"name": name, "namespace": "st",
                                 "uid": f"pg-{name}", "min_member": 1,
                                 "queue": "q0"}])
-
-        def send_pod(name):
             post("pods", [{"name": name, "namespace": "st",
                            "uid": f"u-{name}", "requests": {"cpu": 500.0},
                            "phase": "Pending",
                            "annotations": {GROUP_NAME_ANNOTATION: name}}])
 
-        def send(name):
-            """A one-pod gang in q0, as a client posts it."""
-            send_group(name)
-            send_pod(name)
-
-        def bound(name, timeout):
-            deadline = time.monotonic() + timeout
-            while time.monotonic() < deadline:
-                if cache.binder.binds.get(f"st/{name}"):
-                    return True
-                time.sleep(0.01)
-            return False
-
-        def trace():
-            with urllib.request.urlopen(base + "/v1/trace") as r:
-                return json.loads(r.read())
-
         loop = threading.Thread(target=sched.run_forever, daemon=True)
         loop.start()
+        holding, release = threading.Event(), threading.Event()
+        holder = threading.Thread(
+            target=holder_of_the_cache_lock, name="holder",
+            args=(cache, holding, release))
         try:
-            send("warm")
-            assert bound("warm", 120.0), "the warm-up pod compiles the solve"
-            sched.cycle_cost_ewma = 0.01    # forget the compile's seconds
-            deadline = time.monotonic() + 10
-            while sched._watchdog._open and time.monotonic() < deadline:
-                time.sleep(0.02)            # the compile was a stall itself
-            # the late pod's PodGroup goes ahead of the hold, so that ONE
-            # request wakes the loop under it: with both under it, a loaded
-            # machine puts them more than the settle's few ms apart, the
-            # first wakes a cycle that drains the PodGroup alone and the pod
-            # is decided by the next (two kept rows; seen under -n 6)
-            send_group("late")
-            time.sleep(0.3)
-            declared0, seconds0 = _stalls("cycle")
-            holding = threading.Event()
-            holder = threading.Thread(
-                target=holder_of_the_cache_lock, name="holder",
-                args=(cache, self.HOLD_S, holding))
+            send("warm")                    # compiles the solve
+            _until(lambda: cache.binder.binds.get("st/warm"))
+            _until(lambda: not sched._watchdog._open)  # a stall itself
+            declared0, _ = _stalls("cycle")
             holder.start()
-            assert holding.wait(10)
-            held_from = time.monotonic()
-            send_pod("late")                # staged; the drain needs the lock
-            assert bound("late", 30.0)
-            holder.join(timeout=10)
-            waited = time.monotonic() - held_from
-            deadline = time.monotonic() + 5
-            while (_stalls("cycle")[1] == seconds0
-                   and time.monotonic() < deadline):
-                time.sleep(0.02)            # the watchdog's next look closes it
-            declared, seconds = _stalls("cycle")
-            assert declared == declared0 + 1
-            assert abs((seconds - seconds0) - self.HOLD_S) <= 0.1, waited
-            # (the warm-up's compile was a stall of its own, and is kept)
-            (row,) = [r for r in trace()["kept"]
-                      if "stall" in r["why"] and not r["compile_ms"]]
-            assert abs(row["stall_ms"] - self.HOLD_S * 1e3) <= 100.0
-            assert row["decided"] == 1 and row["worst_ms"] >= 700.0
-            # nearly all of that decision's wait has a name
-            assert row["worst_named_ms"] >= 0.9 * row["worst_ms"]
-            # the ring of 4 rolls past it (idle ticks every 50 ms)
-            deadline = time.monotonic() + 30
-            while (row["cycle"] in [r["cycle"] for r in trace()["cycles"]]
-                   and time.monotonic() < deadline):
-                send(f"more-{time.monotonic_ns()}")
-                time.sleep(0.05)
-            assert row["cycle"] not in [r["cycle"] for r in trace()["cycles"]]
-            with urllib.request.urlopen(
-                    f"{base}/v1/trace/cycles/{row['cycle']}") as r:
-                assert r.status == 200
-                tree = json.loads(r.read())
-            (stall,) = tree["stalls"]
-            assert stall["phase"] == "cycle"
-            assert stall["span_path"].startswith("ingest_drain")
-            held_by = [frames for name, frames in stall["threads"].items()
-                       if name.startswith("holder-")]
-            assert held_by and any(
-                "holder_of_the_cache_lock" in f for f in held_by[0])
-            assert any("drain_staged_ingest" in f for f in stall["stack"])
+            assert holding.wait(60)
+            send("late")                    # staged; the drain needs the lock
+            _until(lambda: _stalls("cycle")[0] > declared0)
+            release.set()
+            _until(lambda: cache.binder.binds.get("st/late"))
+            held = [stall for rec in cache.flight_recorder.records()
+                    for stall in rec.stalls
+                    if any(name.startswith("holder-")
+                           for name in stall["threads"])]
+            assert held, "a record in the ring carries the stall"
+            assert held[-1]["phase"] == "cycle"
+            assert held[-1]["span_path"].startswith("ingest_drain")
+            assert any("drain_staged_ingest" in f for f in held[-1]["stack"])
         finally:
+            release.set()
             sched.stop()
             loop.join(timeout=30)
+            if holder.ident is not None:
+                holder.join(timeout=30)
             admin.stop()
         assert not loop.is_alive()

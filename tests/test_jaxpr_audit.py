@@ -16,6 +16,66 @@ from kube_batch_tpu.analysis.jaxpr_audit import (
 )
 
 
+#: the 51 audited entry points on the 8-device test backend, written out:
+#: HBM_ALLOWLIST and the other allowlists key on these names, the ledger's
+#: readers on the programs behind them.  Marks: ``steady`` (KBT202 applies),
+#: ``donate`` (a non-empty donation declaration), ``spmd`` (a pjit oracle
+#: that tier C charges per node shard).
+PINNED_ENTRIES = [
+    ("api.resident.swap", "steady donate"),
+    ("api.resident.swap_repl", "steady donate"),
+    ("api.resident.swap_sharded", "steady donate"),
+    ("ops.admission.enqueue_gate", "steady"),
+    ("ops.assignment.allocate_solve", ""),
+    ("ops.assignment.allocate_topk_solve", "steady"),
+    ("ops.assignment.failure_histogram_bucket_solve", ""),
+    ("ops.assignment.failure_histogram_solve", ""),
+    ("ops.assignment.warm_allocate_solve", "steady donate"),
+    ("ops.eviction.evict_solve[preempt,compact]", "steady"),
+    ("ops.eviction.evict_solve[preempt]", "steady"),
+    ("ops.eviction.evict_solve[reclaim,compact]", "steady"),
+    ("ops.eviction.evict_solve[reclaim]", "steady"),
+    ("ops.invariants.allocate_sentinel_solve", ""),
+    ("ops.invariants.allocate_topk_sentinel_solve", "steady"),
+    ("ops.invariants.enqueue_gate_sentinel", "steady"),
+    ("ops.invariants.evict_sentinel_solve[preempt,compact]", "steady"),
+    ("ops.invariants.evict_sentinel_solve[preempt]", "steady"),
+    ("ops.invariants.evict_sentinel_solve[reclaim,compact]", "steady"),
+    ("ops.invariants.evict_sentinel_solve[reclaim]", "steady"),
+    ("ops.invariants.warm_allocate_sentinel_solve", "steady donate"),
+    ("ops.probe.probe_solve", "steady"),
+    ("ops.probe.probe_solve[topk-inert]", "steady"),
+    ("parallel.mesh.sentinel_sharded_allocate_solve[pjit]", "spmd"),
+    ("parallel.mesh.sentinel_sharded_allocate_solve[shard_map]", ""),
+    ("parallel.mesh.sentinel_sharded_allocate_topk_solve[pjit]", "steady spmd"),
+    ("parallel.mesh.sentinel_sharded_allocate_topk_solve[shard_map]", "steady"),
+    ("parallel.mesh.sentinel_sharded_evict_solve[preempt][pjit]", "steady spmd"),
+    ("parallel.mesh.sentinel_sharded_evict_solve[preempt][shard_map]", "steady"),
+    ("parallel.mesh.sentinel_sharded_evict_solve[reclaim][pjit]", "steady spmd"),
+    ("parallel.mesh.sentinel_sharded_evict_solve[reclaim][shard_map]", "steady"),
+    ("parallel.mesh.sentinel_sharded_warm_allocate_solve[pjit]", "steady spmd"),
+    ("parallel.mesh.sentinel_sharded_warm_allocate_solve[shard_map]", "steady"),
+    ("parallel.mesh.sharded_allocate_solve[pjit]", "spmd"),
+    ("parallel.mesh.sharded_allocate_solve[shard_map,2d]", ""),
+    ("parallel.mesh.sharded_allocate_solve[shard_map]", ""),
+    ("parallel.mesh.sharded_allocate_topk_solve[pjit]", "steady spmd"),
+    ("parallel.mesh.sharded_allocate_topk_solve[shard_map]", "steady"),
+    ("parallel.mesh.sharded_enqueue_gate", "steady"),
+    ("parallel.mesh.sharded_evict_solve[preempt][pjit]", "steady spmd"),
+    ("parallel.mesh.sharded_evict_solve[preempt][shard_map]", "steady"),
+    ("parallel.mesh.sharded_evict_solve[reclaim][pjit]", "steady spmd"),
+    ("parallel.mesh.sharded_evict_solve[reclaim][shard_map]", "steady"),
+    ("parallel.mesh.sharded_failure_histogram[pjit]", "spmd"),
+    ("parallel.mesh.sharded_failure_histogram[shard_map]", ""),
+    ("parallel.mesh.sharded_failure_histogram_bucket[pjit]", "spmd"),
+    ("parallel.mesh.sharded_failure_histogram_bucket[shard_map]", ""),
+    ("parallel.mesh.sharded_probe_solve[pjit]", "steady spmd"),
+    ("parallel.mesh.sharded_probe_solve[shard_map]", "steady"),
+    ("parallel.mesh.sharded_warm_allocate_solve[pjit]", "steady spmd"),
+    ("parallel.mesh.sharded_warm_allocate_solve[shard_map]", "steady"),
+]
+
+
 def _entry(name, build, **kw):
     return EntryPoint(name=name, build=build, **kw)
 
@@ -47,6 +107,26 @@ class TestRegistryClean:
         assert any("warm_allocate_sentinel_solve" in n for n in names)
         assert any("enqueue_gate" in n for n in names)
         assert any("topk-inert" in n for n in names)
+
+    def test_the_entry_names_and_marks_are_the_pinned_list(self):
+        """The registry is derived (parallel/mesh.py's table walked by
+        analysis/jaxpr_audit.py): what it derives is held to this list,
+        name for name and mark for mark."""
+        from kube_batch_tpu.analysis.jaxpr_audit import sharded_registry
+
+        assert len(jax.devices()) == 8
+        entries = tuple(REGISTRY) + sharded_registry()
+
+        def marks(e):
+            return " ".join(m for m, on in (
+                ("steady", e.steady),
+                ("donate", e.donate != {"*": ()}),
+                ("spmd", e.spmd_shards != 1),
+            ) if on)
+
+        assert len(PINNED_ENTRIES) == 51
+        assert sorted((e.name, marks(e)) for e in entries) == PINNED_ENTRIES
+        assert {e.spmd_shards for e in entries} == {1, 8}
 
     def test_sharded_variants_traced_on_the_virtual_mesh(self):
         """The conftest's forced 8-device CPU mesh stands in for multi-chip

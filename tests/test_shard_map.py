@@ -176,21 +176,16 @@ def test_forced_4_device_solves_bit_exact(_env_guard):
         failure_histogram_solve,
     )
     from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
-    from kube_batch_tpu.parallel.mesh import (
-        allocate_solve_fn,
-        evict_solve_fn,
-        failure_histogram_fn,
-        make_mesh,
-    )
+    from kube_batch_tpu.parallel.mesh import make_mesh, program
 
     snap, config = _session_snapshot()
     mesh = make_mesh(4)
     local = jax.device_get(allocate_solve(snap, config))
     with mesh:
         sm = jax.device_get(
-            allocate_solve_fn(mesh, config, impl="shard_map")(snap))
+            program("full", mesh, "shard_map", config)(snap))
         pj = jax.device_get(
-            allocate_solve_fn(mesh, config, impl="pjit")(snap))
+            program("full", mesh, "pjit", config)(snap))
     for name in local._fields:
         assert np.array_equal(getattr(local, name), getattr(sm, name)), (
             f"shard_map {name} diverged on the 4-device mesh")
@@ -200,7 +195,7 @@ def test_forced_4_device_solves_bit_exact(_env_guard):
     hist = jax.device_get(failure_histogram_solve(snap))
     with mesh:
         hist_sm = jax.device_get(
-            failure_histogram_fn(mesh, impl="shard_map")(snap))
+            program("fail_hist", mesh, "shard_map", None)(snap))
     assert np.array_equal(hist, hist_sm)
 
     for mode in ("reclaim", "preempt"):
@@ -208,7 +203,7 @@ def test_forced_4_device_solves_bit_exact(_env_guard):
         ev = jax.device_get(evict_solve(snap, ec))
         with mesh:
             ev_sm = jax.device_get(
-                evict_solve_fn(mesh, ec, impl="shard_map")(snap))
+                program("evict", mesh, "shard_map", ec)(snap))
         for name in ev._fields:
             assert np.array_equal(getattr(ev, name), getattr(ev_sm, name)), (
                 f"shard_map evict[{mode}] {name} diverged")
@@ -223,11 +218,7 @@ def test_capped_pass_solves_bit_exact_on_the_test_mesh(kind):
     import jax
 
     from kube_batch_tpu.ops.assignment import allocate_solve
-    from kube_batch_tpu.parallel.mesh import (
-        allocate_solve_fn,
-        allocate_topk_solve_fn,
-        make_mesh,
-    )
+    from kube_batch_tpu.parallel.mesh import make_mesh, program
 
     snap, config = _session_snapshot(n_tasks=600, n_nodes=48)
     config = config._replace(rounds=2)
@@ -240,14 +231,12 @@ def test_capped_pass_solves_bit_exact_on_the_test_mesh(kind):
         pend = np.flatnonzero(np.asarray(snap.task_pending))
         rows[: pend.size] = pend
         args = (snap, rows)
-        solve_fn = allocate_topk_solve_fn
     else:
         args = (snap,)
-        solve_fn = allocate_solve_fn
     mesh = make_mesh()
     assert mesh.devices.size == 8
     with mesh:
-        got = {impl: jax.device_get(solve_fn(mesh, config, impl=impl)(*args))
+        got = {impl: jax.device_get(program(kind, mesh, impl, config)(*args))
                for impl in ("shard_map", "pjit")}
     for impl, res in got.items():
         for name in local._fields:
@@ -261,7 +250,7 @@ def test_enqueue_gate_mesh_matches_single():
     import jax
 
     from kube_batch_tpu.ops.admission import enqueue_gate_solve
-    from kube_batch_tpu.parallel.mesh import enqueue_gate_solve_fn, make_mesh
+    from kube_batch_tpu.parallel.mesh import make_mesh, program
 
     rng = np.random.default_rng(11)
     minr = rng.uniform(0, 4, (64, 3)).astype(np.float32)
@@ -273,7 +262,7 @@ def test_enqueue_gate_mesh_matches_single():
     mesh = make_mesh(8)
     with mesh:
         sharded = np.asarray(jax.device_get(
-            enqueue_gate_solve_fn(mesh)(minr, cand, idle0, quanta)))
+            program("gate", mesh, None, None)(minr, cand, idle0, quanta)))
     assert np.array_equal(single, sharded)
 
 

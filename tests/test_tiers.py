@@ -524,7 +524,7 @@ def test_the_sharded_evict_program_agrees_under_both_gates():
 
     from kube_batch_tpu.framework.session import close_session
     from kube_batch_tpu.ops.eviction import EvictConfig, evict_solve
-    from kube_batch_tpu.parallel.mesh import evict_solve_fn, make_mesh
+    from kube_batch_tpu.parallel.mesh import make_mesh, program
 
     served, ssn, snap, meta = _gated_snapshot()
     try:
@@ -538,9 +538,9 @@ def test_the_sharded_evict_program_agrees_under_both_gates():
                 ev = jax.device_get(evict_solve(snap, ec))
                 with mesh:
                     ev_sm = jax.device_get(
-                        evict_solve_fn(mesh, ec, impl="shard_map")(snap))
+                        program("evict", mesh, "shard_map", ec)(snap))
                     ev_pj = jax.device_get(
-                        evict_solve_fn(mesh, ec, impl="pjit")(snap))
+                        program("evict", mesh, "pjit", ec)(snap))
                 for name in ev._fields:
                     assert np.array_equal(
                         getattr(ev, name), getattr(ev_sm, name)), (mode, name)

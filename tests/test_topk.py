@@ -212,7 +212,7 @@ def test_forced_exhaustion_sharded_bit_exact():
     import jax
 
     from kube_batch_tpu.ops.assignment import allocate_solve
-    from kube_batch_tpu.parallel.mesh import allocate_topk_solve_fn, make_mesh
+    from kube_batch_tpu.parallel.mesh import make_mesh, program
 
     snap, config = _session_snapshot(240, 8)
     full = jax.device_get(allocate_solve(snap, config))
@@ -221,9 +221,9 @@ def test_forced_exhaustion_sharded_bit_exact():
     mesh = make_mesh(4)
     with mesh:
         sm = jax.device_get(
-            allocate_topk_solve_fn(mesh, cfg, impl="shard_map")(snap, rows))
+            program("topk", mesh, "shard_map", cfg)(snap, rows))
         pj = jax.device_get(
-            allocate_topk_solve_fn(mesh, cfg, impl="pjit")(snap, rows))
+            program("topk", mesh, "pjit", cfg)(snap, rows))
     for name in full._fields:
         if name.startswith("topk_"):
             continue

@@ -43,7 +43,7 @@ def test_the_pjit_oracle_at_the_envelope_fits_as_the_audit_models_it(topo):
 
     from kube_batch_tpu.analysis.jaxpr_audit import (
         EntryPoint,
-        _build_sharded_allocate,
+        _build,
         _snap,
     )
     from kube_batch_tpu.ops.assignment import AllocateConfig
@@ -55,14 +55,14 @@ def test_the_pjit_oracle_at_the_envelope_fits_as_the_audit_models_it(topo):
     snap = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         _snap(point), pm.snapshot_shardings(mesh))
-    fn = pm.allocate_solve_fn(mesh, AllocateConfig(), impl="pjit")
+    fn = pm.program("full", mesh, "pjit", AllocateConfig())
     with mesh:
         memory = fn.lower(snap).compile().memory_analysis()
     compiled = (memory.temp_size_in_bytes + memory.argument_size_in_bytes
                 + memory.output_size_in_bytes)
     modelled = audit_entry_at(EntryPoint(
         "parallel.mesh.sharded_allocate_solve[pjit]",
-        lambda sp: _build_sharded_allocate(mesh, "pjit", sp),
+        lambda sp: _build("full", {}, mesh, "pjit", False, sp),
         spmd_shards=4), point).peak_bytes
     # XLA keeps the [150528, 5120] planes node-sharded: about one quarter
     # plane set a device, where a replicated plane alone is 2.9 GiB

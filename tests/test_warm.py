@@ -538,18 +538,15 @@ def test_failure_histogram_bucket_matches_full():
                    if r not in set(live.tolist())]].any()
 
     if len(jax.devices()) >= 4:
-        from kube_batch_tpu.parallel.mesh import (
-            failure_histogram_bucket_fn,
-            make_mesh,
-        )
+        from kube_batch_tpu.parallel.mesh import make_mesh, program
 
         mesh = make_mesh(4)
         with mesh:
             hs = np.asarray(
-                failure_histogram_bucket_fn(mesh, impl="shard_map")(
+                program("fail_hist_bucket", mesh, "shard_map", None)(
                     snap, jnp.asarray(rows)))
             hp = np.asarray(
-                failure_histogram_bucket_fn(mesh, impl="pjit")(
+                program("fail_hist_bucket", mesh, "pjit", None)(
                     snap, jnp.asarray(rows)))
         assert np.array_equal(hf[live], hs[live])
         assert np.array_equal(hf[live], hp[live])

@@ -1191,7 +1191,7 @@ class TestShardedProbe:
         from kube_batch_tpu.ops.probe import probe_solve
         from kube_batch_tpu.parallel.mesh import (
             make_mesh,
-            probe_solve_fn,
+            program,
             snapshot_shardings,
         )
 
@@ -1206,7 +1206,8 @@ class TestShardedProbe:
         mesh = make_mesh(len(jax.devices()))
         dev = jax.device_put(snap, snapshot_shardings(mesh))
         for impl in ("shard_map", "pjit"):
-            fn = probe_solve_fn(mesh, config, evc, True, impl=impl)
+            fn = program("probe", mesh, impl, config, evict_config=evc,
+                         with_evictions=True)
             with mesh:
                 res = fn(dev, batch, rows)
             for f in single._fields:
