@@ -15,7 +15,14 @@ ever be replayed from a corrupted or divergent result.
 
 Phase 2 (intra-job task-priority rebalancing, preempt.go:145-174) stays a
 host loop but only runs for jobs where a pending task outranks a running one
-— the common all-equal-priority case short-circuits to nothing."""
+— the common all-equal-priority case short-circuits to nothing.
+
+Spans: ``preempt_replay`` (phase 1's replay, around ``evict_replay``, which
+both evict actions share: claimant jobs, Statements opened, committed and
+discarded) and ``preempt_phase2`` (the walk of every job: jobs walked,
+Statements opened), both under ``action:preempt``;
+``volcano_evict_statements_total{action="preempt",outcome}`` counts the
+same Statements."""
 
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ import logging
 from collections import defaultdict
 from typing import Callable, Dict, List, Tuple
 
+from kube_batch_tpu import metrics
 from kube_batch_tpu.actions.reclaim import (
     ReplayTally,
     covering_prefix,
@@ -33,6 +41,7 @@ from kube_batch_tpu.api.task_info import TaskInfo
 from kube_batch_tpu.api.types import PodGroupPhase, TaskStatus
 from kube_batch_tpu.framework.interface import Action
 from kube_batch_tpu.framework.session import FitFailure
+from kube_batch_tpu.obs.trace import tracer_of
 from kube_batch_tpu.utils.priority_queue import PriorityQueue
 
 logger = logging.getLogger("kube_batch_tpu")
@@ -50,10 +59,15 @@ class PreemptAction(Action):
         claims, _ = solve_claims(ssn, "preempt")
         if not claims:
             return
-        with ReplayTally.replaying(ssn, "preempt", claims) as tally:
-            self._replay(ssn, tally, claims)
+        with tracer_of(ssn.cache).span("preempt_replay") as span:
+            with ReplayTally.replaying(ssn, "preempt", claims) as tally:
+                opened, committed = self._replay(ssn, tally, claims)
+            span.set(claims=len(claims), statements=opened,
+                     committed=committed, discarded=opened - committed)
+        _count_statements(opened, committed)
 
-    def _replay(self, ssn, tally, claims) -> None:
+    def _replay(self, ssn, tally, claims) -> Tuple[int, int]:
+        """Replay ``claims``; returns (Statements opened, committed)."""
         # group claims by preemptor job — the Statement boundary
         by_job: Dict[str, List[Tuple[TaskInfo, str, List[tuple]]]] = defaultdict(list)
         for claimant_ref, node_name, victim_refs in claims:
@@ -63,12 +77,14 @@ class PreemptAction(Action):
             else:
                 tally.host_rejected += 1
 
+        opened = committed = 0
         for job_uid, job_claims in by_job.items():
             job = ssn.jobs.get(job_uid)
             if job is None:
                 tally.host_rejected += len(job_claims)
                 continue
             stmt = ssn.statement()
+            opened += 1
             staged = []  # victims evicted for each claim of this Statement
             for task, node_name, victim_refs in job_claims:
                 # host predicate re-check (preempt.go:191), only for
@@ -103,14 +119,25 @@ class PreemptAction(Action):
             if ssn.job_pipelined(job):
                 stmt.commit()  # its evictions reach the cache in one call
                 tally.commits += bool(staged)
+                committed += 1
                 for evicted in staged:
                     tally.commit(evicted)
             else:
                 stmt.discard()
                 tally.host_rejected += len(staged)
+        return opened, committed
 
     # ---- phase 2: intra-job (host, guarded) ----------------------------
     def _phase2(self, ssn) -> None:
+        with tracer_of(ssn.cache).span("preempt_phase2") as span:
+            opened = self._rebalance(ssn)
+            span.set(jobs=len(ssn.jobs), statements=opened)
+        _count_statements(opened, opened)
+
+    def _rebalance(self, ssn) -> int:
+        """Phase 2; returns the Statements it opened (each commits,
+        preempt.go:168)."""
+        opened = 0
         for job in ssn.jobs.values():
             # claimant gates (preempt.go:59-63): enqueued jobs in known queues
             if job.pod_group and job.pod_group.phase == PodGroupPhase.PENDING:
@@ -165,10 +192,12 @@ class PreemptAction(Action):
                     )
 
                 stmt = ssn.statement()
+                opened += 1
                 assigned = self._preempt_host(ssn, stmt, preemptor, intra_job_filter)
                 stmt.commit()  # phase 2 commits unconditionally (preempt.go:168)
                 if not assigned:
                     break
+        return opened
 
     def _preempt_host(
         self,
@@ -200,6 +229,12 @@ class PreemptAction(Action):
             stmt.pipeline(preemptor, node.name)
             return True
         return False
+
+
+def _count_statements(opened: int, committed: int) -> None:
+    for outcome, n in (("opened", opened), ("committed", committed),
+                       ("discarded", opened - committed)):
+        metrics.register_evict_statements("preempt", outcome, n)
 
 
 def _lowest_first(ssn, victims: List[TaskInfo]) -> List[TaskInfo]:

@@ -594,6 +594,15 @@ EVICT_COMMITS = Counter(
     "one task from outside a replay)",
     ("action", "path"),
 )
+EVICT_STATEMENTS = Counter(
+    f"{_SUBSYSTEM}_evict_statements_total",
+    "Statements of an evict action's replay, by action and outcome (opened: "
+    "one a claimant job of preempt's phase 1, one a preemptor of its phase "
+    "2; reclaim holds none | committed: the job reached Pipelined and its "
+    "evictions went to the cache | discarded: it did not, and the session "
+    "was put back as it was)",
+    ("action", "outcome"),
+)
 EVICT_SOLVE_COMPACTED = Counter(
     f"{_SUBSYSTEM}_evict_solve_compacted_total",
     "Evict solve dispatches, by action and whether the bids ran on the "
@@ -688,6 +697,8 @@ for _action in ("reclaim", "preempt"):
         EVICT_SOLVE_COMPACTED.add(0.0, _action, _compacted)
     for _path in ("bulk", "single"):
         EVICT_COMMITS.add(0.0, _action, _path)
+for _outcome in ("opened", "committed", "discarded"):
+    EVICT_STATEMENTS.add(0.0, "preempt", _outcome)
 JIT_COMPILES.add(0.0)
 for _phase in ("trace", "lower", "backend"):
     JIT_COMPILE_SECONDS.add(0.0, _phase)
@@ -774,6 +785,7 @@ METRICS = [
     EVICTIONS,
     EVICT_CLAIMS,
     EVICT_COMMITS,
+    EVICT_STATEMENTS,
     EVICT_SOLVE_COMPACTED,
     EVICTION_RELEASE_LATENCY,
     EVICT_REPEAT_CLAIMS,
@@ -1057,6 +1069,11 @@ def register_evict_commit(action: str, path: str) -> None:
 def register_evict_claims(action: str, outcome: str, n: int) -> None:
     if n:
         EVICT_CLAIMS.add(n, action, outcome)
+
+
+def register_evict_statements(action: str, outcome: str, n: int) -> None:
+    if n:
+        EVICT_STATEMENTS.add(n, action, outcome)
 
 
 def register_evict_solve_compacted(action: str, compacted: bool) -> None:

@@ -771,8 +771,12 @@ class TestPlantedStall:
         from kube_batch_tpu.cmd.server import AdminServer
 
         cache = _mk_cache()
+        # a floor far beyond the test: only an ingest wakes the loop, so
+        # the lock is taken while it is parked and the one stage that can
+        # meet it is the late pod's drain (a 50 ms floor put whichever
+        # stage of a tick was running behind the lock instead)
         sched = Scheduler(cache, conf=load_scheduler_conf(None),
-                          schedule_period=0.05)
+                          schedule_period=5.0)
         admin = AdminServer(cache, "127.0.0.1", 0)
         admin.start()
 
@@ -804,6 +808,9 @@ class TestPlantedStall:
             send("warm")                    # compiles the solve
             _until(lambda: cache.binder.binds.get("st/warm"))
             _until(lambda: not sched._watchdog._open)  # a stall itself
+            _until(lambda: [sp.name.startswith("park:") for sp in
+                            sched.tracer.open_spans_of(loop.ident)[:1]]
+                   == [True])
             declared0, _ = _stalls("cycle")
             holder.start()
             assert holding.wait(60)
@@ -811,14 +818,27 @@ class TestPlantedStall:
             _until(lambda: _stalls("cycle")[0] > declared0)
             release.set()
             _until(lambda: cache.binder.binds.get("st/late"))
-            held = [stall for rec in cache.flight_recorder.records()
-                    for stall in rec.stalls
-                    if any(name.startswith("holder-")
-                           for name in stall["threads"])]
-            assert held, "a record in the ring carries the stall"
-            assert held[-1]["phase"] == "cycle"
-            assert held[-1]["span_path"].startswith("ingest_drain")
-            assert any("drain_staged_ingest" in f for f in held[-1]["stack"])
+            # the cycle's stall; the pod's POST may reach the trigger after
+            # its PodGroup's has already woken the loop into the held drain,
+            # and that signal, which no cycle can start for meanwhile, is
+            # then a ``parked`` stall of its own beside it
+            def held():
+                return [stall for rec in cache.flight_recorder.records()
+                        for stall in rec.stalls
+                        if any(name.startswith("holder-")
+                               for name in stall["threads"])]
+
+            # the bind is out before its cycle's record reaches the ring
+            _until(lambda: any(s["phase"] == "cycle" for s in held()))
+            stalls = held()
+            cycle = [s for s in stalls if s["phase"] == "cycle"]
+            assert cycle, "a record in the ring carries the cycle's stall"
+            assert {s["phase"] for s in stalls} <= {"cycle", "parked"}
+            # the cycle's stall is the drain's (the parked one beside it
+            # may name the same open span: it is declared while that runs)
+            assert all(s["span_path"].startswith("ingest_drain")
+                       for s in cycle)
+            assert any("drain_staged_ingest" in f for f in cycle[-1]["stack"])
         finally:
             release.set()
             sched.stop()
